@@ -1,0 +1,529 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that fedml_tpu still starts on the chip.
+
+    python chip_smoke.py               # one TPU chip: cli_cnn, lm_flagship, kernels
+    python chip_smoke.py --multichip   # four TPU chips: ONLY the mesh runtime
+                                       # and the single-device run it is compared with
+    python chip_smoke.py --rehearse [--multichip]
+                                       # any backend, tiny sizes, kernels where the
+                                       # backend has them: finds wrong paths and
+                                       # arguments before chip time is spent; never
+                                       # prints the result line
+
+One process, jax imported once, no child process. Every phase goes through
+an entry point a user would call (``fedml_tpu.cli.main`` exactly as
+``python -m fedml_tpu`` dispatches it, or ``FedAvgAPI(...).train()``),
+checks what came out by the repo's own means, and prints one JSON line
+with its wall seconds and what it asserted. A phase that fails raises: the
+script exits non-zero and prints no result line. Without ``--rehearse`` it
+refuses to run unless ``jax.devices()[0].platform == "tpu"``.
+
+The last line of a passing run is the contract's device line:
+``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}``.
+
+Compile cache: ``fedml_tpu.compile.resolve_cache_dir`` — the directory
+``$JAX_COMPILATION_CACHE_DIR`` names when set, else ``<checkout>/.jax_cache``.
+The ``compile`` line before the result line reports backend-compile events,
+their summed wall seconds and persistent-cache hits, so two runs over one
+cache directory can be told apart (cold vs warm).
+"""
+
+from __future__ import annotations
+
+import argparse
+import dataclasses
+import json
+import math
+import pathlib
+import shutil
+import sys
+import time
+
+REPO = pathlib.Path(__file__).resolve().parent
+
+# The north star (BASELINE.json): FEMNIST geometry, CNNOriginalFedAvg,
+# 10 clients/round, batch 20, E=1, SGD lr 0.1.
+NORTH_STAR = [
+    "--algorithm", "fedavg", "--model", "cnn", "--dataset", "femnist_synth",
+    "--client_num_per_round", "10", "--batch_size", "20", "--lr", "0.1",
+    "--epochs", "1", "--comm_round", "5",
+]
+
+# The flagship LM (bench.py _flagship_bf16): d768 / L6 / H8, vocab 1024,
+# seq 256, batch 32, 8 clients x 512 sequences, bf16, Adam clients.
+FLAGSHIP = dict(
+    vocab=1024, seq=256, layers=6, heads=8, dim=768, clients=8,
+    samples=512, batch=32, dtype="bfloat16",
+)
+FLAGSHIP_TINY = dict(
+    vocab=64, seq=32, layers=2, heads=2, dim=32, clients=4, samples=32,
+    batch=16, dtype="float32",
+)
+
+# (B, H, S, d) of the flash-attention check, and the dtype.
+FLASH = ((1, 8, 8192, 96), "bfloat16")
+FLASH_TINY = ((1, 2, 256, 32), "float32")
+
+
+class CompileClock:
+    """Wall-clock stamps of every XLA backend-compile event — the stream
+    ``fedml_tpu.analysis.sentinel.RecompileSentinel`` counts — so a window
+    of ROUNDS inside one ``train()`` call can be shown to compile nothing.
+    jax wraps persistent-cache retrievals in the same event; ``hits``
+    counts those."""
+
+    def __init__(self):
+        import jax.monitoring
+
+        self.events = []  # (unix time at event end, seconds)
+        self.hits = 0
+        jax.monitoring.register_event_duration_secs_listener(self._on_duration)
+        jax.monitoring.register_event_listener(self._on_event)
+
+    def _on_duration(self, name, secs, **_):
+        if name.endswith("backend_compile_duration"):
+            self.events.append((time.time(), float(secs)))
+
+    def _on_event(self, name, **_):
+        if name == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+
+    def mark(self):
+        return len(self.events), self.hits
+
+    def since(self, mark):
+        n0, h0 = mark
+        ev = self.events[n0:]
+        return {
+            "backend_compile_events": len(ev),
+            "backend_compile_s": round(sum(s for _, s in ev), 3),
+            "persistent_cache_hits": self.hits - h0,
+        }
+
+    def between(self, t0, t1):
+        return [t for t, _ in self.events if t0 < t <= t1]
+
+
+def check(cond, what):
+    """An assertion that survives ``python -O`` and names what failed."""
+    if not cond:
+        raise AssertionError(what)
+    return what
+
+
+def run_cli(args, log_dir):
+    """``python -m fedml_tpu <args> --log_dir <log_dir>``, in this process:
+    the same click command ``fedml_tpu/__main__.py`` dispatches to.
+    Returns (api, per-round metric rows, summary.json)."""
+    from fedml_tpu import cli
+
+    shutil.rmtree(log_dir, ignore_errors=True)
+    api = cli.main(
+        [*args, "--log_dir", str(log_dir)], standalone_mode=False
+    )
+    rows = [
+        json.loads(line)
+        for line in (log_dir / "metrics.jsonl").read_text().splitlines()
+    ]
+    check((log_dir / "summary.json").is_file(), "summary.json written")
+    summary = json.loads((log_dir / "summary.json").read_text())
+    return api, rows, summary
+
+
+def train_losses(rows):
+    losses = [r["Train/Loss"] for r in rows if "Train/Loss" in r]
+    check(losses and all(math.isfinite(x) for x in losses),
+          f"every Train/Loss finite: {losses}")
+    return losses
+
+
+def round_program_text(api):
+    """(placed round-0 batch, optimized HLO) of the round program
+    ``api.train_round(0)`` dispatches. Lowering executes nothing."""
+    fn, (global_vars, *placed) = api.round_program(0)
+    return placed, fn.lower(global_vars, *placed).compile().as_text()
+
+
+def devices_of(tree):
+    import jax
+
+    return {d for leaf in jax.tree_util.tree_leaves(tree) for d in leaf.devices()}
+
+
+def platforms_of(tree):
+    return {d.platform for d in devices_of(tree)}
+
+
+# ---------------------------------------------------------------------------
+# phases
+# ---------------------------------------------------------------------------
+
+
+def phase_cli_cnn(ctx):
+    """The north star through the CLI: 5 FedAvg rounds of the FEMNIST CNN."""
+    api, rows, summary = run_cli(
+        [*NORTH_STAR, "--seed", str(ctx.seed), "--recompile_budget", "200"],
+        ctx.out / "cli_cnn",
+    )
+    losses = train_losses(rows)
+    asserted = [
+        "summary.json written",
+        check(len(losses) == 5, f"5 rounds logged, got {len(losses)}"),
+        check(losses[-1] < losses[0],
+              f"last Train/Loss {losses[-1]:.4f} < round 0's {losses[0]:.4f}"),
+        check(platforms_of(api.global_vars) == {ctx.platform},
+              f"parameters live on {ctx.platform}"),
+    ]
+    # rounds 2-4: from the moment round 2's cohort is selected (before its
+    # dispatch — the round pipeline logs that row first) to the last
+    # round's metrics row, no XLA backend compile may happen.
+    t_sel2 = min(r["_ts"] for r in rows if r.get("round") == 2)
+    t_end = max(r["_ts"] for r in rows if "Train/Loss" in r)
+    late = ctx.clock.between(t_sel2, t_end)
+    asserted.append(check(
+        not late, f"rounds 2-4 compiled nothing ({len(late)} events)"
+    ))
+    return {
+        "asserted": asserted,
+        "train_loss": [round(x, 4) for x in losses],
+        "recompiles_total": summary.get("compile/recompiles"),
+    }
+
+
+def phase_lm_flagship(ctx):
+    """Full width of the flagship LM through ``FedAvgAPI(...).train()``."""
+    from fedml_tpu.algorithms import FedAvgAPI
+    from fedml_tpu.config import DataConfig, FedConfig, RunConfig, TrainConfig
+    from fedml_tpu.data.synthetic import synthetic_shakespeare
+    from fedml_tpu.models import create_model
+
+    m = FLAGSHIP_TINY if ctx.rehearse else FLAGSHIP
+    data = synthetic_shakespeare(
+        num_clients=m["clients"], samples_per_client=m["samples"],
+        seq_len=m["seq"], vocab_size=m["vocab"], seed=ctx.seed,
+        seq_targets=True,
+    )
+    model = create_model(
+        "transformer", "shakespeare_synth", (m["seq"],), m["vocab"],
+        num_layers=m["layers"], num_heads=m["heads"], embed_dim=m["dim"],
+    )
+    cfg = RunConfig(
+        data=DataConfig(batch_size=m["batch"], pad_bucket=1),
+        fed=FedConfig(
+            client_num_in_total=m["clients"],
+            client_num_per_round=m["clients"],
+            comm_round=3, epochs=1, frequency_of_the_test=10_000,
+            # client_parallelism stays at its default ("auto", which
+            # resolves to vmap for every transformer): the default path
+            # is what a user gets, and on one v5e chip it runs — with
+            # 13.9 GB of the chip's 16 reserved (CHANGES.md, PR 21).
+        ),
+        train=TrainConfig(
+            client_optimizer="adam", lr=1e-3, compute_dtype=m["dtype"]
+        ),
+        seed=ctx.seed,
+    )
+    rows = []
+    api = FedAvgAPI(cfg, data, model, task="nwp", log_fn=rows.append)
+    api.train()
+    losses = train_losses(rows)
+    return {
+        "asserted": [
+            check(len(losses) == 3, f"3 rounds logged, got {len(losses)}"),
+            check(losses[-1] < losses[0],
+                  f"last Train/Loss {losses[-1]:.4f} < round 0's {losses[0]:.4f}"),
+            check(platforms_of(api.global_vars) == {ctx.platform},
+                  f"parameters live on {ctx.platform}"),
+        ],
+        "client_parallelism": cfg.fed.client_parallelism,
+        "model": {k: m[k] for k in ("dim", "layers", "heads", "vocab", "seq")},
+        "train_loss": [round(x, 4) for x in losses],
+    }
+
+
+def _flash_check(ctx):
+    """flash_attention forward + grad, compiled, against plain attention
+    computed head by head in float32 at the highest matmul precision."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from fedml_tpu.ops import flash_attention
+    from fedml_tpu.ops.flash_attention import _use_interpret
+
+    shape, dtype = FLASH_TINY if ctx.rehearse else FLASH
+    dtype = jnp.dtype(dtype)
+    keys = jax.random.split(jax.random.PRNGKey(ctx.seed), 3)
+    q, k, v = (jax.random.normal(kk, shape, dtype) for kk in keys)
+
+    def loss_flash(q, k, v):
+        out = flash_attention(q, k, v, causal=True)
+        return jnp.sum(jnp.sin(out.astype(jnp.float32))), out
+
+    def head_ref(qkv):  # one [S, d] head, float32
+        def loss(q, k, v):
+            s = (q @ k.T) / jnp.sqrt(jnp.float32(q.shape[-1]))
+            s = jnp.where(jnp.tril(jnp.ones(s.shape, bool)), s, -jnp.inf)
+            out = jax.nn.softmax(s, axis=-1) @ v
+            return jnp.sum(jnp.sin(out)), out
+
+        (_, out), grads = jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True
+        )(*qkv)
+        return out, grads
+
+    step = jax.jit(jax.value_and_grad(loss_flash, argnums=(0, 1, 2), has_aux=True))
+    compiled = step.lower(q, k, v).compile()
+    asserted = []
+    if ctx.platform == "tpu":
+        asserted += [
+            check(_use_interpret() is False, "flash interpret resolved to False"),
+            check("tpu_custom_call" in compiled.as_text(),
+                  "flash fwd+grad program contains tpu_custom_call"),
+        ]
+    (_, out), grads = compiled(q, k, v)
+
+    heads = tuple(
+        a.astype(jnp.float32).reshape((-1,) + shape[-2:]) for a in (q, k, v)
+    )
+    with jax.default_matmul_precision("highest"):
+        ref_out, ref_grads = jax.jit(lambda h: jax.lax.map(head_ref, h))(heads)
+
+    # tests/test_flash_attention.py pins float32 (interpret mode) at atol
+    # 2e-5 forward / 5e-5 grads, and the float32 rehearsal is held to
+    # exactly that. At bfloat16 the inputs, P and the outputs each round
+    # to 8 bits, so there the bound is set from the dtype: 4 eps of the
+    # largest reference magnitude.
+    errs = {}
+    for name, got, ref, f32_atol in (
+        ("out", out, ref_out, 2e-5),
+        ("dq", grads[0], ref_grads[0], 5e-5),
+        ("dk", grads[1], ref_grads[1], 5e-5),
+        ("dv", grads[2], ref_grads[2], 5e-5),
+    ):
+        got = np.asarray(got.astype(jnp.float32)).reshape(ref.shape)
+        ref = np.asarray(ref)
+        check(np.isfinite(got).all(), f"flash {name} finite")
+        tol = f32_atol if dtype == jnp.float32 else (
+            4 * float(jnp.finfo(dtype).eps) * max(1.0, float(np.abs(ref).max()))
+        )
+        err = float(np.abs(got - ref).max())
+        errs[name] = {"max_abs_err": err, "tol": tol}
+        asserted.append(check(
+            err <= tol,
+            f"flash {name} within {tol:.3g} of plain attention (err {err:.3g})",
+        ))
+    return {"shape": list(shape), "dtype": dtype.name, "errors": errs}, asserted
+
+
+def _robust_stats_check(ctx):
+    """median_1d / trimmed_mean_1d at C=10, D = FEMNIST-CNN parameter
+    count, kernel path vs the jnp.sort path."""
+    import jax
+    import numpy as np
+
+    from fedml_tpu.models import create_model
+    from fedml_tpu.ops.robust_stats import median_1d, trimmed_mean_1d
+
+    shapes = jax.eval_shape(
+        create_model("cnn", "femnist", (28, 28, 1), 62).init,
+        jax.random.PRNGKey(0),
+    )
+    D = sum(int(np.prod(l.shape)) for l in jax.tree_util.tree_leaves(shapes))
+    if ctx.rehearse:
+        D = 5000
+    x = jax.random.normal(jax.random.PRNGKey(ctx.seed + 1), (10, D))
+    asserted = []
+    for name, kernel, ref in (
+        ("median_1d", median_1d(x, use_kernel=True),
+         median_1d(x, use_kernel=False)),
+        ("trimmed_mean_1d", trimmed_mean_1d(x, 2, use_kernel=True),
+         trimmed_mean_1d(x, 2, use_kernel=False)),
+    ):
+        np.testing.assert_allclose(  # tests/test_robust_stats.py tolerance
+            np.asarray(kernel), np.asarray(ref), atol=1e-6, rtol=1e-6,
+            err_msg=name,
+        )
+        asserted.append(f"{name} kernel == sort path at [10, {D}] (1e-6)")
+    return {"C": 10, "D": D}, asserted
+
+
+def phase_kernels(ctx):
+    """Both Pallas kernels, compiled, against their references — alone and
+    (robust stats) through the normal CLI path."""
+    flash, asserted = _flash_check(ctx)
+    robust, more = _robust_stats_check(ctx)
+    asserted += more
+
+    api, rows, _ = run_cli(
+        ["--algorithm", "fedavg_robust", "--defense", "trimmed_mean",
+         "--num_byzantine", "2", "--model", "cnn", "--dataset",
+         "femnist_synth", "--comm_round", "2", "--seed", str(ctx.seed)],
+        ctx.out / "cli_robust",
+    )
+    losses = train_losses(rows)
+    asserted.append(
+        "fedavg_robust/trimmed_mean: 2 rounds, losses finite "
+        f"{[round(x, 4) for x in losses]}"
+    )
+    if ctx.platform == "tpu":
+        _, text = round_program_text(api)
+        asserted.append(check(
+            "tpu_custom_call" in text,
+            "robust round (aggregation) program contains tpu_custom_call",
+        ))
+    return {"asserted": asserted, "flash": flash, "robust_stats": robust}
+
+
+def phase_multichip(ctx):
+    """``--runtime mesh`` on four chips against the single-device run."""
+    import jax
+    import numpy as np
+
+    common = [
+        "--algorithm", "fedavg", "--model", "cnn", "--dataset",
+        "femnist_synth", "--client_num_in_total", "32",
+        "--client_num_per_round", "16", "--batch_size", "20", "--lr", "0.1",
+        "--epochs", "1", "--comm_round", "2", "--seed", str(ctx.seed),
+    ]
+    mesh_api, mesh_rows, _ = run_cli(
+        [*common, "--runtime", "mesh", "--client_shards", "4"],
+        ctx.out / "mesh",
+    )
+    single_api, single_rows, _ = run_cli(common, ctx.out / "single")
+    mesh_losses, single_losses = train_losses(mesh_rows), train_losses(single_rows)
+
+    placed, text = round_program_text(mesh_api)
+    shard_devices = {s.device for s in placed[0].addressable_shards}
+    asserted = [
+        check(len(shard_devices) == 4,
+              "cohort batch shards sit on 4 distinct devices: "
+              f"{sorted(map(str, shard_devices))}"),
+        check("all-reduce" in text, "compiled mesh round contains an all-reduce"),
+        check(len(devices_of(single_api.global_vars)) == 1,
+              "the single-device run kept its parameters on one device"),
+    ]
+
+    def flat(api):
+        return np.concatenate([
+            np.asarray(l, np.float32).ravel()
+            for l in jax.tree_util.tree_leaves(api.global_vars)
+        ])
+
+    single, mesh = flat(single_api), flat(mesh_api)
+    diff = np.abs(single - mesh)
+    # tests/test_sharded_fedavg.py::test_sharded_matches_single_chip holds
+    # EVERY parameter to atol = rtol = 1e-5, and on the CPU every parameter
+    # meets it. On the chip the two programs tile their convolutions
+    # differently (16 clients in one vmap vs 4 per shard), float32 sums
+    # round differently, and a pre-activation that lands on the other side
+    # of a ReLU moves the few parameters it feeds by one sample's step,
+    # lr/B/C * |g| (0.1/20/16 * |g| ~ 3e-4 |g|). So: the round losses must
+    # agree, 99% of the parameters must meet the test's tolerance, and
+    # none may differ by more than 1e-3. A dropped or mis-weighted client
+    # moves every parameter and the loss, and fails all three. (PR 21, four
+    # v5e chips: losses identical, 6 of 1 690 046 parameters outside the
+    # tolerance, the largest difference 1.64e-5.)
+    within = float(np.mean(diff <= 1e-5 + 1e-5 * np.abs(single)))
+    stats = {
+        "parameters": int(diff.size),
+        "max_abs_diff": float(diff.max()),
+        "fraction_within_1e-5": within,
+        "train_loss_single": single_losses, "train_loss_mesh": mesh_losses,
+    }
+    print(json.dumps({"phase": "multichip", "comparison": stats}), flush=True)
+    asserted += [
+        check(np.allclose(mesh_losses, single_losses, rtol=1e-6, atol=0),
+              "per-round Train/Loss: mesh == single-device (rtol 1e-6)"),
+        check(within >= 0.99,
+              f"{within:.4%} of parameters within atol=rtol=1e-5 (need 99%)"),
+        check(stats["max_abs_diff"] <= 1e-3,
+              f"max abs parameter diff {stats['max_abs_diff']:.3g} <= 1e-3"),
+    ]
+    return {"asserted": asserted}
+
+
+# ---------------------------------------------------------------------------
+
+
+@dataclasses.dataclass
+class Ctx:
+    seed: int
+    rehearse: bool
+    platform: str
+    out: pathlib.Path
+    clock: CompileClock
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--multichip", action="store_true",
+                    help="run ONLY the four-chip mesh phase and the "
+                         "single-device run it is compared with")
+    ap.add_argument("--rehearse", action="store_true",
+                    help="any backend, tiny sizes; prints no result line")
+    ap.add_argument("--seed", type=int, default=0)
+    args = ap.parse_args(argv)
+
+    import jax
+
+    dev = jax.devices()
+    device = {
+        "platform": dev[0].platform,
+        "kind": dev[0].device_kind,
+        "count": len(dev),
+    }
+    need = 4 if args.multichip else 1
+    if not args.rehearse and device["platform"] != "tpu":
+        print(f"chip_smoke: no TPU (jax found {device}); nothing was run",
+              file=sys.stderr)
+        return 1
+    if len(dev) < need:
+        print(f"chip_smoke: need {need} devices, jax found {device}",
+              file=sys.stderr)
+        return 1
+
+    from fedml_tpu.compile import install_hardened_cache
+
+    cache = install_hardened_cache()
+
+    ctx = Ctx(
+        seed=args.seed, rehearse=args.rehearse, platform=device["platform"],
+        out=REPO / "chiprun_out"
+        / ("chip_smoke_rehearsal" if args.rehearse else "chip_smoke"),
+        clock=CompileClock(),
+    )
+
+    phases = (
+        [("multichip", phase_multichip)]
+        if args.multichip
+        else [("cli_cnn", phase_cli_cnn), ("lm_flagship", phase_lm_flagship),
+              ("kernels", phase_kernels)]
+    )
+    t_run = time.perf_counter()
+    for name, fn in phases:
+        mark, t0 = ctx.clock.mark(), time.perf_counter()
+        result = fn(ctx)
+        hbm = dev[0].memory_stats() or {}  # None on the CPU backend
+        print(json.dumps({
+            "phase": name, "passed": True,
+            "wall_s": round(time.perf_counter() - t0, 2),
+            "hbm": {k: hbm.get(k) for k in (
+                "peak_bytes_in_use", "peak_bytes_reserved", "bytes_limit")},
+            **ctx.clock.since(mark), **result,
+        }), flush=True)
+    print(json.dumps({
+        "phase": "compile", "cache_dir": str(cache.path),
+        "wall_s_total": round(time.perf_counter() - t_run, 2),
+        **ctx.clock.since((0, 0)), "hardened_store": cache.stats(),
+    }), flush=True)
+    if args.rehearse:
+        print(json.dumps({"rehearsal": True, "phases": [n for n, _ in phases],
+                          "device": device}), flush=True)
+        return 0
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

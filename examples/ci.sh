@@ -411,6 +411,11 @@ echo "== compile warmup smoke: AOT warmup + hardened persistent cache (docs/COMP
 # slowly enough (>= 2 s) to clear the conservative persistence threshold,
 # so run 2 must LOAD its compile (persistent hit) and report strictly
 # lower measured compile time — and warmup runs are numerically identical.
+# The cache stages below need FRESH directories they place themselves; where
+# JAX_COMPILATION_CACHE_DIR is set it would win over --compile_cache_dir
+# (compile/persistent.resolve_cache_dir), so it is cleared for the rest of
+# this script.
+unset JAX_COMPILATION_CACHE_DIR
 CCDIR=$(mktemp -d); CLOG1=$(mktemp -d); CLOG2=$(mktemp -d)
 for log in "$CLOG1" "$CLOG2"; do
   python -m fedml_tpu --algorithm fedavg --model rnn \

@@ -1,4 +1,4 @@
-"""Calibrate the flagship bf16 gated bench row (VERDICT r3 Next #1):
+"""Calibrate the flagship bf16 gated bench row:
 ResNet-18-GN, synthetic fed-CIFAR-100 geometry, bf16 — find the
 accuracy-vs-rounds curve and per-round cost so bench.py can pin a
 target/horizon with a stable 'expected: reach'."""

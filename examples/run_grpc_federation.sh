@@ -4,6 +4,10 @@
 # participant is a plain OS process — clients first, server last, but any
 # order works: the first send per peer blocks until the peer is up).
 #
+# One process per chip: client ranks (1..K) pin themselves to the host CPU
+# (fedml_tpu/cli.py run()), so on a TPU host only the rank-0 server opens
+# the chip.
+#
 # Cross-host: give every process the same --ip_config CSV ("rank,ip" lines,
 # ref grpc_ipconfig.csv) and run each rank on its machine.
 set -euo pipefail

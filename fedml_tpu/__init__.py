@@ -30,6 +30,5 @@ __version__ = "0.2.0"
 
 # NOTE: this file deliberately imports nothing. `import fedml_tpu` (and in
 # particular `import fedml_tpu.telemetry`, which is jax-free by contract)
-# must not pay the jax import. The jax API-compat shims for older jaxlib
-# live in fedml_tpu/_jax_compat.py and are installed by the modules that
-# actually call the newer APIs (parallel/, the sharded algorithm variants).
+# must not pay the jax import. The supported jax is the one pyproject.toml
+# pins (0.9.x); there are no compatibility shims.

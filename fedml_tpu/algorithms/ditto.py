@@ -36,10 +36,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from fedml_tpu import _jax_compat
-
-_jax_compat.install()  # jax.shard_map / jax.lax.pcast on older jaxlib
-
 from fedml_tpu.algorithms.fedavg import (
     FedAvgAPI,
     client_axis_map,
@@ -210,7 +206,7 @@ def make_sharded_ditto_cohort_round(
     task: str = "classification",
 ):
     """Cohort-form Ditto round over a client-sharded mesh (the spill-tier
-    x multi-chip composition, VERDICT r4 Weak #4 — same shape as
+    x multi-chip composition — same shape as
     scaffold.make_sharded_scaffold_cohort_round): personal rows arrive
     SHARDED over the client axis straight from the host store's cohort
     gather and leave sharded for the scatter; the global FedAvg update is
@@ -389,8 +385,8 @@ class DittoAPI(FedAvgAPI):
     personal-model store and per-client personalized evaluation. The store
     is a stacked on-device [N, ...] pytree while it fits
     FedConfig.state_budget_bytes and SPILLS to the disk tier beyond it
-    (state_store.MmapClientState; round 3 refused instead, VERDICT r3
-    Weak #3) — Ditto is cross-device by nature, so the spill path is the
+    (state_store.MmapClientState; round 3 refused instead) — Ditto is
+    cross-device by nature, so the spill path is the
     one that scales it to the data layer's 100k-client regime."""
 
     _supports_fused = False  # per-round personal-state exchange
@@ -441,7 +437,7 @@ class DittoAPI(FedAvgAPI):
     def _build_ditto_cohort_round(self):
         """Jitted cohort-form round for the SPILLED store. The mesh
         subclass swaps in the shard_map form — spill and multi-chip
-        compose (round 4 refused here, VERDICT r4 Weak #4)."""
+        compose (round 4 refused here)."""
         return make_ditto_cohort_round(
             self.model, self.config, self.lam, task=self.task,
             client_mode=self._client_mode,

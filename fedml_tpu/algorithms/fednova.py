@@ -26,10 +26,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from fedml_tpu import _jax_compat
-
-_jax_compat.install()  # jax.shard_map / jax.lax.pcast on older jaxlib
-
 from fedml_tpu.algorithms.fedavg import FedAvgAPI
 from fedml_tpu.train.client import make_local_train
 

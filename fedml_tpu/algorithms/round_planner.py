@@ -3,7 +3,7 @@
 The fused multi-round scan (``make_fedavg_multiround``) exists to
 amortize per-round host dispatch; whether it actually WINS depends on
 the model, the backend, and everything the compile runtime has since
-changed about the eager path's cost (BENCH_r05 measured the fused
+changed about the eager path's cost (the round-5 record had the fused
 north-star row 36% SLOWER than eager — the config heuristic "fuse
 whenever ``fused_rounds > 1``" had gone stale). This module replaces
 that heuristic with a measurement: under ``FedConfig.fused_plan =

@@ -36,10 +36,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from fedml_tpu import _jax_compat
-
-_jax_compat.install()  # jax.shard_map / jax.lax.pcast on older jaxlib
-
 from fedml_tpu.algorithms.fedavg import (
     FedAvgAPI,
     client_axis_map,
@@ -324,7 +320,7 @@ def make_sharded_scaffold_cohort_round(
     model: ModelDef, config: RunConfig, mesh, task: str = "classification"
 ):
     """Cohort-form SCAFFOLD round over a client-sharded mesh — the
-    composition VERDICT r4 Weak #4 asked for: the 100k-client spilled
+    composition of the two scale stories: the 100k-client spilled
     state tier and the multi-chip runtime in one round.
 
     ``(global_vars, c_server, c_rows, x, y, mask, ns, rngs) ->
@@ -572,8 +568,7 @@ class ScaffoldAPI(FedAvgAPI):
     variate and the per-client control store. The store lives in HBM as a
     stacked [N, ...] pytree while it fits FedConfig.state_budget_bytes and
     SPILLS to the disk tier beyond it (state_store.MmapClientState —
-    cohort rows only ride to device; round 3 refused instead,
-    VERDICT r3 Weak #3)."""
+    cohort rows only ride to device; round 3 refused instead)."""
 
     _supports_fused = False  # per-round control-variate state exchange
 
@@ -614,15 +609,15 @@ class ScaffoldAPI(FedAvgAPI):
                 population=getattr(config, "population", None),
             )
             # overlap the NEXT cohort's disk gather with the current
-            # round's device compute (the measured spill tax was 3.1x —
-            # VERDICT r4 Weak #4; the gather is the front half of it)
+            # round's device compute (the recorded spill tax was 3.1x;
+            # the gather is the front half of it)
             self._c_prefetch = CohortPrefetcher(self._c_store)
             self._scaffold_round = self._build_scaffold_cohort_round()
 
     def _build_scaffold_cohort_round(self):
         """Jitted cohort-form round for the SPILLED store. The mesh
         subclass swaps in the shard_map form — spill and multi-chip
-        compose (round 4 refused here, VERDICT r4 Weak #4)."""
+        compose (round 4 refused here)."""
         return make_scaffold_cohort_round(
             self.model, self.config, task=self.task,
             client_mode=self._client_mode,

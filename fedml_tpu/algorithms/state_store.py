@@ -3,7 +3,7 @@ federated algorithms (SCAFFOLD control variates, Ditto personal models).
 
 The round-3 stateful algorithms pinned their N × |params| state as one
 stacked pytree in HBM and hard-refused past 8 GiB while the data layer
-already scaled to 100k clients on disk (VERDICT r3 Weak #3). This module
+already scaled to 100k clients on disk. This module
 closes that asymmetry with the data layer's own tiering
 (data/mmap_store.py):
 

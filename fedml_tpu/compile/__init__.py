@@ -29,7 +29,6 @@ from fedml_tpu.compile.executable_cache import (
     install_executable_cache,
     install_run_executable_cache,
     installed_executable_cache,
-    supports_serialization,
 )
 from fedml_tpu.compile.digest import (
     call_signature,
@@ -43,6 +42,8 @@ from fedml_tpu.compile.persistent import (
     install_hardened_cache,
     install_run_cache,
     installed_cache,
+    resolve_cache_dir,
+    resolve_executable_cache_dir,
 )
 from fedml_tpu.compile.program_cache import (
     CachedProgram,
@@ -78,7 +79,8 @@ __all__ = [
     "mesh_fingerprint",
     "model_fingerprint",
     "program_digest",
-    "supports_serialization",
+    "resolve_cache_dir",
+    "resolve_executable_cache_dir",
     "use_program_cache",
     "warmup_api",
     "warmup_local_train",

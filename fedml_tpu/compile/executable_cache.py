@@ -41,14 +41,15 @@ AUTHORSHIP (both live in the same file an attacker would write). Point
 ``--executable_cache`` only at directories writable solely by principals
 you would let run code in the training process (the same trust you
 already extend to the Python environment itself). The store chmods a
-directory it creates to 0700, and tests/conftest.py keys its session
-path by uid, so the default posture on shared machines is private.
+directory it creates to 0700, so the default posture on shared machines
+is private.
 
-Capability gate: serialization support differs across jaxlib versions.
-:func:`supports_serialization` probes ``jax.experimental.
-serialize_executable`` once; when absent, :func:`install_executable_cache`
-warns LOUDLY and returns None — every caller degrades to the plain
-compile path (slower, never wrong).
+Device binding: an executable is compiled for an ordered device
+assignment, and ``deserialize_and_load`` binds to EVERY device of the
+backend unless told otherwise — so :meth:`ExecutableCache.save` records
+the assignment's device ids and :meth:`ExecutableCache.load` hands the
+same devices back as ``execution_devices``. A one-device program stays a
+one-device program on an 8-device host.
 
 Observability: deserialize hits/seconds land in summary.json
 (``compile/deserialize_hits``, ``compile/deserialize_s``, plus the
@@ -68,10 +69,13 @@ import threading
 import time
 from typing import Any, Optional
 
-from fedml_tpu.compile.persistent import HardenedFileCache
+from fedml_tpu.compile.persistent import (
+    HardenedFileCache,
+    resolve_executable_cache_dir,
+)
 
 _KEY_PREFIX = "xc-"
-_FORMAT = 1  # bump to invalidate every persisted executable at once
+_FORMAT = 2  # bump to invalidate every persisted executable at once
 # Entries not READ for this long are pruned on store construction. The
 # environment fingerprint contains a source-content hash, so every code
 # edit permanently orphans all prior entries under never-again-read keys
@@ -148,16 +152,6 @@ def environment_fingerprint() -> dict:
         "xla_flags": os.environ.get("XLA_FLAGS", ""),
         "code": code_fingerprint(),
     }
-
-
-def supports_serialization() -> bool:
-    """True when this jaxlib can serialize/deserialize AOT executables."""
-    try:
-        from jax.experimental import serialize_executable  # noqa: F401
-
-        return True
-    except Exception:  # noqa: BLE001 — older jaxlib without the module
-        return False
 
 
 class ExecutableCache:
@@ -265,10 +259,16 @@ class ExecutableCache:
                 raise ValueError(
                     "embedded environment/program fingerprint mismatch"
                 )
+            import jax
             from jax.experimental import serialize_executable as se
 
+            # bind to the devices the executable was compiled for — the
+            # default is every device of the backend, which turns a
+            # one-device program into an N-shard one on an N-device host
+            by_id = {d.id: d for d in jax.devices()}
             exe = se.deserialize_and_load(
-                doc["payload"], doc["in_tree"], doc["out_tree"]
+                doc["payload"], doc["in_tree"], doc["out_tree"],
+                execution_devices=[by_id[i] for i in doc["device_ids"]],
             )
         except Exception as e:  # noqa: BLE001 — any load fault = quarantine
             # quarantine_entry increments the STORE's quarantined counter
@@ -302,11 +302,15 @@ class ExecutableCache:
             from jax.experimental import serialize_executable as se
 
             payload, in_tree, out_tree = se.serialize(compiled)
+            # the same private handle serialize() reads: the ordered
+            # device assignment the executable was compiled for
+            unloaded = compiled._executable._unloaded_executable
             blob = pickle.dumps(
                 {
                     "format": _FORMAT,
                     "program": digest,
                     "env": self._env(),
+                    "device_ids": [int(d.id) for d in unloaded.device_list],
                     "payload": payload,
                     "in_tree": in_tree,
                     "out_tree": out_tree,
@@ -400,28 +404,20 @@ def installed_executable_cache() -> Optional[ExecutableCache]:
     return _INSTALLED
 
 
-def install_executable_cache(path: str) -> Optional[ExecutableCache]:
-    """Install an :class:`ExecutableCache` at ``path`` as the process's
-    executable store (``CachedProgram`` warmup/dispatch consults it).
-    Capability-gated: returns None — loudly — when this jaxlib cannot
-    serialize executables, so every caller degrades to plain compilation.
-    Idempotent per path."""
+def install_executable_cache(requested: str = "") -> ExecutableCache:
+    """Install an :class:`ExecutableCache` as the process's executable
+    store (``CachedProgram`` warmup/dispatch consults it), at
+    :func:`~fedml_tpu.compile.persistent.resolve_executable_cache_dir`
+    ``(requested)`` — beside the HLO cache, under the same
+    ``$JAX_COMPILATION_CACHE_DIR``-wins rule. Idempotent per directory."""
     global _INSTALLED
-    if not supports_serialization():
-        logging.warning(
-            "executable cache at %s DISABLED: this jaxlib has no "
-            "jax.experimental.serialize_executable — fresh processes will "
-            "recompile every program (slower startup, identical numerics)",
-            path,
-        )
-        return None
-    if _INSTALLED is not None and str(_INSTALLED.path) == str(path):
-        return _INSTALLED
-    _INSTALLED = ExecutableCache(path)
+    path = resolve_executable_cache_dir(requested)
+    if _INSTALLED is None or str(_INSTALLED.path) != str(path):
+        _INSTALLED = ExecutableCache(str(path))
     return _INSTALLED
 
 
-def install_run_executable_cache(path: str):
+def install_run_executable_cache(requested: str = ""):
     """Install an executable cache for ONE run and return ``(cache,
     restore)`` — ``restore()`` reinstates whatever binding existed before
     (the conftest-installed session store, or nothing), mirroring
@@ -429,7 +425,7 @@ def install_run_executable_cache(path: str):
     embedded in a long-lived process can't hijack later loads."""
     global _INSTALLED
     prev = _INSTALLED
-    cache = install_executable_cache(path)
+    cache = install_executable_cache(requested)
 
     def restore() -> None:
         global _INSTALLED

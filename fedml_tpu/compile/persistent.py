@@ -32,10 +32,10 @@ misread.
 
 :func:`install_hardened_cache` wires an instance in as the process's jax
 compilation cache and applies the cache-dir/threshold config in one
-place (tests/conftest.py and the CLI ``--compile_cache_dir`` flag both
-go through it). Installation is version-gated: if the jax internals
-drift, it falls back to the stock persistent cache with a loud warning
-rather than failing the run."""
+place. WHERE the cache lives is decided by :func:`resolve_cache_dir`
+alone — ``$JAX_COMPILATION_CACHE_DIR`` when set, else an explicitly
+requested directory, else ``<checkout>/.jax_cache`` — and the CLI,
+chip_smoke.py, bench.py and tests/conftest.py all go through it."""
 
 from __future__ import annotations
 
@@ -289,68 +289,92 @@ class HardenedFileCache:
 
 _INSTALLED: Optional[HardenedFileCache] = None
 
+# jax's own variable for the persistent compilation cache. Where it is
+# set, THAT directory is the cache — for jax's stock layer and for both
+# of ours — and an explicitly requested path only earns a warning.
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+# Where it is not set: one fixed, git-ignored directory in the checkout.
+# The directory is part of jax's cache key, so a path that moves (temp
+# name, pid, uid, time) never hits.
+_CHECKOUT_CACHE = pathlib.Path(__file__).resolve().parents[2] / ".jax_cache"
+
+
+def _resolve(requested: str, subdir: str) -> pathlib.Path:
+    env = os.environ.get(CACHE_DIR_ENV)
+    if not env:
+        return pathlib.Path(requested) if requested else _CHECKOUT_CACHE / subdir
+    chosen = pathlib.Path(env) / subdir
+    if requested and pathlib.Path(requested).resolve() != chosen.resolve():
+        logging.warning(
+            "compile cache: %s=%s is set and wins — using %s, not the "
+            "requested %s", CACHE_DIR_ENV, env, chosen, requested,
+        )
+    return chosen
+
+
+def resolve_cache_dir(requested: str = "") -> pathlib.Path:
+    """THE compile-cache directory of this process (CLI, chip_smoke.py,
+    bench.py and tests/conftest.py all ask here): ``$JAX_COMPILATION_
+    CACHE_DIR`` when set, else ``requested`` (a deployment's explicit
+    ``--compile_cache_dir``), else ``<checkout>/.jax_cache``."""
+    return _resolve(requested, "")
+
+
+def resolve_executable_cache_dir(requested: str = "") -> pathlib.Path:
+    """The serialized-executable store's directory, under the same rule:
+    ``executables/`` inside ``$JAX_COMPILATION_CACHE_DIR`` when that is
+    set, else ``requested``, else ``<checkout>/.jax_cache/executables``."""
+    return _resolve(requested, "executables")
+
 
 def installed_cache() -> Optional[HardenedFileCache]:
     """The process's installed hardened cache, if any."""
     return _INSTALLED
 
 
-def install_hardened_cache(
-    path: str,
-    min_compile_time_secs: float = 2.0,
-    min_entry_size_bytes: int = 0,
-) -> Optional[HardenedFileCache]:
-    """Enable jax's persistent compilation cache at ``path`` with the
-    hardened store underneath.
-
-    Applies the standard jax config (cache dir + write thresholds — the
-    conservative >= 2 s default matches tests/conftest.py's
-    corruption-clean setting; pass a fresh directory for a per-run
-    cache), then installs :class:`HardenedFileCache` as the process's
-    cache backend. Returns the cache, or None when the jax internals
-    don't match (the stock persistent cache then applies, with a
-    warning). Idempotent: re-installing over the same path returns the
-    existing instance."""
-    global _INSTALLED
+def _bind_jax_cache(path, min_compile_time_secs, cache, initialized) -> None:
+    """The one place this package points jax's persistent compilation
+    cache somewhere: install and the run-scoped restore both end here."""
     import jax
+    from jax._src import compilation_cache as cc
 
-    jax.config.update("jax_compilation_cache_dir", str(path))
+    jax.config.update(
+        "jax_compilation_cache_dir", None if path is None else str(path)
+    )
     jax.config.update(
         "jax_persistent_cache_min_compile_time_secs",
         float(min_compile_time_secs),
     )
-    try:
-        jax.config.update(
-            "jax_persistent_cache_min_entry_size_bytes",
-            int(min_entry_size_bytes),
-        )
-    except Exception:  # noqa: BLE001 — flag name drift across jax versions
-        pass
-    if _INSTALLED is not None and str(_INSTALLED.path) == str(path):
-        return _INSTALLED
-    try:
-        from jax._src import compilation_cache as cc
+    with cc._cache_initialized_mutex:
+        # claim the once-only initialization slot so jax neither replaces
+        # the hardened store nor trips its _cache-is-None assertion later
+        cc._cache = cache
+        cc._cache_initialized = initialized
 
-        cache = HardenedFileCache(path)
-        with cc._cache_initialized_mutex:
-            # claim the once-only initialization slot so jax neither
-            # replaces the hardened store nor trips its _cache-is-None
-            # assertion later
-            cc._cache = cache
-            cc._cache_initialized = True
-        _INSTALLED = cache
-        return cache
-    except Exception as e:  # noqa: BLE001 — private-API drift
-        logging.warning(
-            "hardened compile cache could not be installed (%s: %s) — "
-            "falling back to the stock jax persistent cache at %s",
-            type(e).__name__, e, path,
-        )
-        return None
+
+def install_hardened_cache(
+    requested: str = "",
+    min_compile_time_secs: float = 2.0,
+) -> HardenedFileCache:
+    """Enable jax's persistent compilation cache at
+    :func:`resolve_cache_dir` ``(requested)`` with the hardened store
+    underneath.
+
+    Applies the standard jax config (cache dir + write threshold — the
+    conservative >= 2 s default matches tests/conftest.py's
+    corruption-clean setting), then installs :class:`HardenedFileCache`
+    as the process's cache backend. Idempotent: re-installing over the
+    same directory keeps the existing instance (and its counters)."""
+    global _INSTALLED
+    path = resolve_cache_dir(requested)
+    if _INSTALLED is None or str(_INSTALLED.path) != str(path):
+        _INSTALLED = HardenedFileCache(path)
+    _bind_jax_cache(path, min_compile_time_secs, _INSTALLED, True)
+    return _INSTALLED
 
 
 def install_run_cache(
-    path: str, min_compile_time_secs: float = 2.0
+    requested: str = "", min_compile_time_secs: float = 2.0
 ):
     """Install a hardened cache for ONE run and return ``(cache,
     restore)``: ``restore()`` reinstates whatever persistent-cache binding
@@ -359,39 +383,25 @@ def install_run_cache(
     process (CliRunner tests, notebook sweeps) would leave every LATER
     compile in the process pointed at the run's — possibly deleted —
     cache directory."""
+    global _INSTALLED
     import jax
+    from jax._src import compilation_cache as cc
 
-    prev = {
-        "dir": jax.config.jax_compilation_cache_dir,
-        "min": jax.config.jax_persistent_cache_min_compile_time_secs,
-        "installed": _INSTALLED,
-        "cc": None,
-    }
-    try:
-        from jax._src import compilation_cache as cc
-
-        with cc._cache_initialized_mutex:
-            prev["cc"] = (cc._cache, cc._cache_initialized)
-    except Exception:  # noqa: BLE001 — private-API drift
-        pass
+    with cc._cache_initialized_mutex:
+        prev = (
+            jax.config.jax_compilation_cache_dir,
+            jax.config.jax_persistent_cache_min_compile_time_secs,
+            cc._cache,
+            cc._cache_initialized,
+        )
+    prev_installed = _INSTALLED
     cache = install_hardened_cache(
-        path, min_compile_time_secs=min_compile_time_secs
+        requested, min_compile_time_secs=min_compile_time_secs
     )
 
     def restore() -> None:
         global _INSTALLED
-        jax.config.update("jax_compilation_cache_dir", prev["dir"])
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs", prev["min"]
-        )
-        if prev["cc"] is not None:
-            try:
-                from jax._src import compilation_cache as cc
-
-                with cc._cache_initialized_mutex:
-                    cc._cache, cc._cache_initialized = prev["cc"]
-            except Exception:  # noqa: BLE001
-                pass
-        _INSTALLED = prev["installed"]
+        _bind_jax_cache(*prev)
+        _INSTALLED = prev_installed
 
     return cache, restore

@@ -148,7 +148,7 @@ class FedConfig:
     # sharded files; the million-client form), "auto" picks device while
     # the stack fits state_budget_bytes and spills beyond it (sharded
     # at/above PopulationConfig.ocohort_threshold clients, mmap below).
-    # Round 3 REFUSED past the budget (VERDICT r3 Weak #3); now it
+    # Round 3 REFUSED past the budget; now it
     # spills instead.
     state_store: str = "auto"
     state_budget_bytes: int = 8 << 30
@@ -316,7 +316,9 @@ class CompileConfig:
     warmup: bool = False
     # Persistent XLA compile-cache directory served by the hardened store
     # (compile/persistent.py: atomic writes, sha256 integrity check with
-    # quarantine, advisory file lock). "" = no persistent cache.
+    # quarantine, advisory file lock). "" = <checkout>/.jax_cache; where
+    # JAX_COMPILATION_CACHE_DIR is set it wins over either
+    # (compile/persistent.resolve_cache_dir).
     cache_dir: str = ""
     # Only persist compiles at least this slow. The conservative 2 s
     # default matches tests/conftest.py: aggressive thresholds (0.3-0.5 s)

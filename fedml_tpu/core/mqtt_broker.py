@@ -1,6 +1,6 @@
 """Minimal MQTT 3.1.1 broker + client over real TCP sockets.
 
-VERDICT r2 Missing #3: the paho path in core/mqtt_comm.py was import-gated
+Why it exists: the paho path in core/mqtt_comm.py was import-gated
 dead code in this image (paho is not vendored), so no socket-level MQTT was
 ever exercised. This module implements the QoS-0 subset of MQTT 3.1.1
 (CONNECT/CONNACK, SUBSCRIBE/SUBACK, PUBLISH, PINGREQ/PINGRESP, DISCONNECT

@@ -64,7 +64,7 @@ def femnist_synthetic_lda(
     pixel_noise: float = 0.45,
     label_noise: float = 0.08,
 ) -> FederatedDataset:
-    """The HARD femnist-geometry benchmark regime (VERDICT r2 Missing #1):
+    """The HARD femnist-geometry benchmark regime:
     same 28x28x1 / 62-class shapes, but built so a round-budget benchmark
     can FAIL and discriminate algorithms —
 

@@ -216,7 +216,7 @@ def load_lending_club(
 
 def run_vfl(dataset: VerticalDataset, epochs: int = 10, lr: float = 0.05, batch_size: int = 64, hidden_dim: int = 16, seed: int = 0):
     """Train VFLAPI on a VerticalDataset; returns (api, final_stats) — the
-    wiring that makes VFL run on real-shaped data (VERDICT r1 missing #3)."""
+    wiring that makes VFL run on real-shaped data."""
     from fedml_tpu.algorithms.vertical_fl import VFLAPI
 
     api = VFLAPI(

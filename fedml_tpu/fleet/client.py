@@ -274,6 +274,13 @@ def client_process_main(payload: dict, result_path: Optional[str]) -> None:
     (atomically — the launcher may be polling), exit with the class
     code. ``os._exit`` on purpose: a fleet child must never run the
     parent's atexit hooks (telemetry writers, exporters)."""
+    # One process per chip, and it is never a fleet child: the launcher's
+    # tenant (this child's forkserver grandparent) may hold the chip. A
+    # child simulates an edge device — pin the CPU platform before
+    # anything here could initialise a jax backend.
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
     rank = int(payload["rank"])
     # the launcher threads the env through the payload: children of a
     # long-lived forkserver inherit the FORKSERVER's environment (frozen

@@ -1,9 +1,14 @@
 """ctypes loader for the fastpack native library.
 
-Builds src/fastpack.cpp with g++ on first use (cached in build/), exposes
+Builds src/fastpack.cpp with g++ on first use (cached in the git-ignored
+build/ — the library comes from that source and nothing else), exposes
 :func:`gather_rows` and :func:`concat_buffers`. Every entry point has a pure
-numpy fallback, so the framework runs (slower) where no C++ toolchain
-exists. See src/fastpack.cpp for why these paths are native.
+numpy route, so the framework runs (slower) where no C++ toolchain exists —
+and says so ONCE, loudly, when the build or the load fails. The build
+targets the baseline ISA (no ``-march=native``): build/ travels with a
+copied checkout, and a library tuned to the machine that built it can
+fault on the machine that loads it. See src/fastpack.cpp for why these
+paths are native.
 
 Measured vs the numpy fallback (this container, single core — thread
 parallelism contributes nothing here, the win is contiguous row memcpy vs
@@ -14,6 +19,7 @@ shard 0.34 ms vs 0.62 ms (1.8×); on [5000, 32, 32, 3] 12 ms vs 119 ms
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
@@ -43,7 +49,7 @@ def _load() -> Optional[ctypes.CDLL]:
                 os.makedirs(_BUILD_DIR, exist_ok=True)
                 subprocess.run(
                     [
-                        "g++", "-O3", "-march=native", "-shared", "-fPIC",
+                        "g++", "-O3", "-shared", "-fPIC",
                         "-std=c++17", "-pthread", _SRC, "-o", _SO + ".tmp",
                     ],
                     check=True,
@@ -66,11 +72,21 @@ def _load() -> Optional[ctypes.CDLL]:
                 ctypes.c_char_p,
             ]
             lib.fp_version.restype = ctypes.c_int
-            assert lib.fp_version() == 1
+            if lib.fp_version() != 1:
+                raise OSError(f"{_SO} reports version {lib.fp_version()}, want 1")
             _lib = lib
-        except Exception:
+        except (OSError, subprocess.CalledProcessError) as e:
             _build_failed = True
             _lib = None
+            said = getattr(e, "stderr", None)  # g++'s own words, if it ran
+            logging.error(
+                "fastpack native library UNAVAILABLE (%s: %s)%s — every "
+                "gather_rows/concat_buffers call in this process takes the "
+                "slower numpy route",
+                type(e).__name__, e,
+                "; g++ said: " + said.decode("utf-8", "replace")[-800:]
+                if said else "",
+            )
     return _lib
 
 

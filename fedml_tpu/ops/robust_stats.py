@@ -27,12 +27,14 @@ Median is the same kernel at ``trim_k = (C-1)//2`` for odd C (keeps the
 middle value) and ``trim_k = C//2 - 1`` for even C (keeps — and averages
 — the two middle values), matching ``jnp.median``'s mean-of-middle-two.
 
-Kernel use is TPU-gated with the jnp sort path as the everywhere-else
-fallback (``use_kernel=None`` → auto): off-TPU the production path keeps
-XLA's lowering (byte-identical to the historical reference), and tests
-drive the kernel explicitly through interpret mode. Krum stays on XLA
-either way — its sort is over the tiny ``[C, C]`` Gram matrix, never a
-bottleneck."""
+Which path runs is decided once, from the backend (``use_kernel=None``):
+the kernel on a TPU, XLA's sort lowering everywhere else (byte-identical
+to the historical reference; tests drive the kernel explicitly through
+interpret mode). The sort path is the off-TPU route, NOT a rescue: on a
+TPU a kernel that fails to lower raises — nothing drops to interpret mode
+or to the sort (chip_smoke.py asserts the ``tpu_custom_call`` is in the
+aggregation program). Krum stays on XLA either way — its sort is over the
+tiny ``[C, C]`` Gram matrix, never a bottleneck."""
 
 from __future__ import annotations
 

@@ -7,10 +7,6 @@ Instead of `mpirun -np N+1` processes exchanging JSON-serialized state dicts
 "broadcast" is parameter replication, "gather + aggregate" is a weighted `psum`
 over ICI. The mesh spec replaces gpu_mapping.yaml."""
 
-from fedml_tpu import _jax_compat
-
-_jax_compat.install()  # jax.shard_map / jax.lax.pcast on older jaxlib
-
 from fedml_tpu.parallel.mesh import make_mesh, pad_client_batch
 from fedml_tpu.parallel.fedavg_sharded import (
     make_sharded_fedavg_round,

@@ -90,8 +90,8 @@ def make_sharded_fedavg_round(
         post_train, post_aggregate, aggregate_fn = make_defense_hooks(robust)
     # The client schedule matters on the mesh too: each shard runs its
     # C/n_shards clients, and under vmap their per-client weights turn the
-    # convs into grouped convs (the single-chip 1.8x ResNet finding,
-    # docs/PERF_R3.md §2). "scan" runs the shard's clients sequentially
+    # convs into grouped convs (the single-chip 1.8x ResNet
+    # finding). "scan" runs the shard's clients sequentially
     # with full MXU tiling. skip_empty_steps stays off here: lax.cond
     # branch types under shard_map's varying-axes rules don't admit the
     # constant-zero skip branch (padded steps remain where-gated no-ops).

@@ -113,14 +113,17 @@ def make_sp_train_step(
             n = jax.lax.psum(per_tok.size, axis_name)
             return s / n + aux_coef * aux
 
-        # Under jax's varying-manual-axes semantics the grads of the
-        # replicated (P()) params come back shard-varying (shard-local
-        # partial sums); the transpose does not reduce them through the
-        # custom-VJP norm ops (ops/fused_*.py), so reduce explicitly —
-        # this also makes the outputs provably replicated, satisfying the
-        # vma checker. Pinned bit-exact vs single-device training in
-        # tests/test_ring_attention.py::test_sp_lm_matches_single_device.
-        loss, grads = jax.value_and_grad(loss_fn)(params)
+        # Differentiate at the VARYING view of the replicated (P()) params:
+        # each shard's grads are then typed {V:seq} shard-local partial
+        # sums — which is what the custom-VJP norm ops (ops/fused_*.py)
+        # return for a replicated parameter, and what jax's
+        # varying-manual-axes check would otherwise reject — and the
+        # explicit psum below is the one reduction, making the outputs
+        # provably replicated. Pinned bit-exact vs single-device training
+        # in tests/test_ring_attention.py::test_sp_lm_matches_single_device.
+        loss, grads = jax.value_and_grad(loss_fn)(
+            jax.lax.pcast(params, axis_name, to="varying")
+        )
         grads = jax.lax.psum(grads, axis_name)
         updates, opt_state = opt.update(grads, opt_state, params)
         params = optax.apply_updates(params, updates)

@@ -52,7 +52,7 @@ def initialize_multihost(
     CRITICAL ORDERING: nothing here may touch the XLA backend before
     ``initialize`` — ``jax.devices()`` / ``jax.process_count()`` would
     initialize it, after which ``jax.distributed.initialize`` raises (the
-    same init-order pitfall as the dryrun device bootstrap, VERDICT r1 #1).
+    same init-order pitfall as the dryrun device bootstrap).
     ``jax.distributed.is_initialized()`` is backend-free.
     """
     if _distributed_initialized():

@@ -3,8 +3,8 @@
 The single-run CLI runs one federation to completion and exits
 (``run_federation``); the north star is a SERVICE holding heavy traffic:
 N concurrent federations in one process sharing one device, FedBuff-style
-async dispatch as the serving path (3.6-3.8x sync update throughput,
-BENCH_r05), elastic client join/leave with backpressure, rolling
+async dispatch as the serving path (3.6-3.8x sync update throughput
+when last recorded), elastic client join/leave with backpressure, rolling
 checkpoints, and per-tenant observability. This package is that service:
 
 - :mod:`fedml_tpu.serve.session` — :class:`FedSession`: ONE federation's

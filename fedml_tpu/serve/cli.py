@@ -268,10 +268,8 @@ def serve_main(spec, log_dir, prom_port, duration_s, stagger_s, slo_strict,
     """Run N federation tenants concurrently in one process."""
     import time
 
-    from fedml_tpu.cli import _apply_platform_env
     from fedml_tpu.serve.server import FederationServer
 
-    _apply_platform_env()
     tenants = load_spec(spec)
     if admin_token and prom_port is None:
         raise click.UsageError(

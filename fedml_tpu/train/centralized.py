@@ -10,7 +10,7 @@ inserts the gradient all-reduce over ICI itself. One code path serves
 single-chip and pod-scale DP.
 
 This is also the non-federated accuracy baseline the benchmark compares
-against (VERDICT r1 missing #5), and the "centralized" side of the
+against, and the "centralized" side of the
 federated==centralized oracle as a reusable component instead of test-inline
 code."""
 

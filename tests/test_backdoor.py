@@ -1,6 +1,6 @@
 """Backdoor attack vs defense: the eval the reference runs with
 FedAvgRobustAggregator.py:14-60 + edge_case_examples — round 1's gap was
-that the defense was never shown defeating an attack (VERDICT #4)."""
+that the defense was never shown defeating an attack."""
 
 import numpy as np
 import pytest
@@ -88,7 +88,7 @@ def _run(defense: RobustConfig, rounds: int = 4):
 
 
 def test_defense_reduces_attack_success_rate():
-    """The VERDICT #4 contract: ASR(defense) < ASR(no defense) at comparable
+    """The defense contract: ASR(defense) < ASR(no defense) at comparable
     main-task accuracy — the defense measurably defeats a boosted backdoor."""
     main_nodef, asr_nodef = _run(RobustConfig(defense_type="no_defense"))
     main_def, asr_def = _run(

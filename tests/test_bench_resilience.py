@@ -1,6 +1,6 @@
 """The benchmark's record must survive pathology — round 4 lost its ENTIRE
 perf record when the driver's timeout killed bench.py before its single
-end-of-run print (BENCH_r04.json: rc=124, parsed=null). The r5 design is
+end-of-run print (rc=124, nothing parsed). The r5 design is
 pinned here: a compact (<1800 char) record line is flushed to stdout after
 EVERY section and the full detail file is atomically rewritten alongside,
 so no kill — budget gate, SIGTERM, watchdog, or raw SIGKILL — can erase
@@ -22,7 +22,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def _env(budget, tiny=None, sleep=None, detail=None, wd_frac=None,
          sleep_only=None):
     env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"  # inherited by the backend-alive probe
+    env["JAX_PLATFORMS"] = "cpu"  # the bench initialises its backend in-process
     env["FEDML_TPU_BENCH_BUDGET_S"] = str(budget)
     if tiny:
         env["FEDML_TPU_BENCH_TINY"] = "1"
@@ -78,7 +78,7 @@ def test_bench_exhausted_budget_still_emits_parseable_record(tmp_path):
 
 @pytest.mark.slow
 def test_bench_survives_sigkill_mid_run(tmp_path):
-    """THE round-4 failure mode, pinned (VERDICT r4 Next #1): kill -9 the
+    """THE round-4 failure mode, pinned: kill -9 the
     bench mid-flight; everything completed before the kill must already
     be on stdout (compact line) and in the detail file."""
     detail = str(tmp_path / "detail.json")
@@ -124,7 +124,7 @@ def test_bench_sigterm_finalizes_record(tmp_path):
         env=_env(budget=3600, tiny=True, sleep=600, detail=detail), cwd=REPO,
     )
     try:
-        time.sleep(12)  # mid-probe / early first section
+        time.sleep(12)  # backend init / early first section
         p.send_signal(signal.SIGTERM)
         out, _ = p.communicate(timeout=60)
     finally:
@@ -144,7 +144,7 @@ def test_bench_watchdog_fires_before_driver_timeout(tmp_path):
     detail = str(tmp_path / "detail.json")
     t0 = time.time()
     # budget 120: the section gate admits the sleeper (start_deadline =
-    # 0.92*120-60 = 50s > probe time) and the watchdog fires at 110s,
+    # 0.92*120-60 = 50s > backend init) and the watchdog fires at 110s,
     # mid-sleep — the exact hang-past-the-budget scenario
     out = subprocess.run(
         [sys.executable, "bench.py"],

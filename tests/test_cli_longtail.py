@@ -1,4 +1,4 @@
-"""CLI completeness (VERDICT r1 #7): every algorithm package reachable from
+"""CLI completeness: every algorithm package reachable from
 one command, plus --resume kill-and-continue and the second-order DARTS
 architect."""
 

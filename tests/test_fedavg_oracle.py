@@ -127,8 +127,8 @@ def test_scan_and_vmap_client_schedules_agree():
     """The two client schedules are THE SAME math executed in different
     orders (scan: one client's full local run at a time, full-size
     matmuls; vmap: all clients batched). The flagship bench row rides the
-    scan schedule for its MXU tiling (docs/PERF_R5.md §1 — 0.77 vs 0.42
-    device MFU on the transformer LM), so their numerical agreement is a
+    scan schedule for its MXU tiling (0.77 vs 0.42 device MFU on the
+    transformer LM when last recorded), so their numerical agreement is a
     load-bearing contract, not an implementation detail."""
     import dataclasses
 

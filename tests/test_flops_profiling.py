@@ -1,6 +1,6 @@
 """Analytic FLOPs counter (utils/flops.py) vs hand-computed counts, and the
 scan-slope device timer (utils/profiling.py). These utilities back every MFU
-number the benchmark publishes (VERDICT r2: XLA's cost model undercounted
+number the benchmark publishes (XLA's cost model undercounted
 8-24x and silently deflated all round-2 MFU claims), so they get oracle
 tests of their own."""
 
@@ -125,3 +125,30 @@ def test_scan_slope_seconds_runs_and_is_positive():
     # be finite and not absurd
     assert np.isfinite(sec)
     assert sec < 1.0
+
+
+class _FakeDevice:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+@pytest.mark.parametrize(
+    "platform,kind,dtype,expect",
+    [
+        ("tpu", "TPU v5 lite", "bfloat16", 197e12),  # what a v5e reports
+        ("tpu", "TPU v5 lite", "float32", 25e12),
+        ("cpu", "cpu", "bfloat16", None),  # no device peak off the TPU
+        ("tpu", "TPU v9 mega", "bfloat16", ValueError),  # unknown = error
+    ],
+)
+def test_device_peak_flops_table(monkeypatch, platform, kind, dtype, expect):
+    """Peaks are keyed by device_kind; a TPU missing from the table is an
+    error (never None, never an environment override), and a CPU has no
+    device peak so its MFU stays unmeasured."""
+    monkeypatch.setenv("FEDML_TPU_PEAK_FLOPS", "1e15")  # retired: ignored
+    dev = _FakeDevice(platform, kind)
+    if expect is ValueError:
+        with pytest.raises(ValueError, match="v9 mega"):
+            profiling.device_peak_flops(dtype, device=dev)
+    else:
+        assert profiling.device_peak_flops(dtype, device=dev) == expect

@@ -1,4 +1,4 @@
-"""Loaders vs COMMITTED golden fixtures (VERDICT r2 Weak #9/Next #10): the
+"""Loaders vs COMMITTED golden fixtures: the
 fixtures in tests/golden/ are one-client byte-level files built to the real
 formats' published specs (leaf benchmark JSON layout, TFF federated-EMNIST
 h5 group structure, GLD-23k mapping CSV) — independent artifacts, not

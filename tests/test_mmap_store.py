@@ -1,6 +1,6 @@
 """Memory-mapped federated store (data/mmap_store.py): round math parity
 with the in-RAM path, streaming write, and a 10k-client reduced-shape run
-(VERDICT r2 Next #4 — the client-state store for clients >> RAM; ref
+(the client-state store for clients >> RAM; ref
 benchmark/README.md:57 federates 342,477 StackOverflow clients)."""
 
 import numpy as np

@@ -11,7 +11,7 @@ from fedml_tpu.models import create_model
 
 # heavy=True cases only shape-check via jax.eval_shape (no XLA compile):
 # compiling mobilenet_v3/efficientnet/etc. on the CPU test mesh costs
-# 10-45 s EACH and dominated the suite (VERDICT r2 Weak #8). Execution
+# 10-45 s EACH and dominated the suite. Execution
 # coverage for the conv families is kept by the executed rows below
 # (resnet56 BN, mobilenet depthwise) plus the federated integration tests
 CASES = [

@@ -1,5 +1,5 @@
-"""MQTT 3.1.1 wire conformance for the from-scratch broker (VERDICT r4
-Missing #2 / Next #9): the reference's backend ran against real paho
+"""MQTT 3.1.1 wire conformance for the from-scratch broker: the
+reference's backend ran against real paho
 (mqtt_comm_manager.py:14-123); paho is not installable here (no egress),
 so interop is proven at the layer that matters — the WIRE:
 

@@ -1,4 +1,4 @@
-"""Cross-process multihost (VERDICT r3 #9 / r4 Weak #7): the DCN story
+"""Cross-process multihost: the DCN story
 must cross a REAL OS process boundary.
 
 Two pins:

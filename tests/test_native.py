@@ -55,3 +55,29 @@ def test_message_roundtrip_uses_native(monkeypatch):
     assert wire_native == wire_fallback
     out = Message.from_bytes(wire_native)
     np.testing.assert_array_equal(out.get("params")["w"], tree["w"])
+
+
+def test_failed_build_is_logged_once_and_loudly(monkeypatch, tmp_path, caplog):
+    """No toolchain (or a broken source) -> the numpy route, announced at
+    ERROR with the compiler's own words, once per process — never a
+    silent slowdown."""
+    import logging
+
+    bad = tmp_path / "broken.cpp"
+    bad.write_text("this is not C++\n")
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_build_failed", False)
+    monkeypatch.setattr(native, "_SRC", str(bad))
+    monkeypatch.setattr(native, "_BUILD_DIR", str(tmp_path / "build"))
+    monkeypatch.setattr(native, "_SO", str(tmp_path / "build" / "libfastpack.so"))
+    with caplog.at_level(logging.ERROR):
+        assert not native.available()
+        assert not native.available()  # second call: no rebuild, no second log
+    errors = [r for r in caplog.records if r.levelno == logging.ERROR]
+    assert len(errors) == 1
+    msg = errors[0].getMessage()
+    assert "UNAVAILABLE" in msg and "g++ said" in msg and "numpy route" in msg
+    # and the numpy route still answers
+    out = np.zeros((2, 3), np.float32)
+    native.gather_rows(np.ones((4, 3), np.float32), np.array([0, 3]), out)
+    assert out.sum() == 6

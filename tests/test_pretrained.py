@@ -101,7 +101,7 @@ def test_create_model_pretrained_kwarg(template, tmp_path):
 
 
 def test_committed_pretrained_resnet56_artifact_loads_and_performs():
-    """The repo ships a REAL trained checkpoint (VERDICT r4 Missing #1):
+    """The repo ships a REAL trained checkpoint:
     fedml_tpu/models/pretrained_weights/resnet56_cifar10_synth.npz,
     trained by examples/train_pretrained_resnet56.py on the synthetic
     cross-silo CIFAR-10 regime (the ref ships torch .pth checkpoints for

@@ -1,0 +1,142 @@
+"""The main path's programs COMPILE for the real chip — checked here, on
+the CPU, with no chip attached.
+
+The TPU compiler ships with the installation and compiles for a chip that
+is *described* (``jax.experimental.topologies``), which shows what
+interpret mode cannot: a slice not aligned to the tiling, a kernel asking
+for more VMEM than it may use, a program that does not fit one chip's
+HBM. Nothing runs, so these tests say nothing about results or times —
+``chip_smoke.py`` is the run. Each case takes about two seconds; the
+shapes are the ones the chip smoke and the north-star cells use.
+
+The persistent compilation cache is switched off around the compiles: a
+TPU executable written to it cannot be read back without a chip (jax
+would warn and recompile on the next run)."""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.experimental.compilation_cache import compilation_cache as jax_cc
+from jax.sharding import SingleDeviceSharding
+
+from fedml_tpu.compile import install_hardened_cache
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """SingleDeviceSharding on one chip of a described v5e 2x2 host."""
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this install
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _compilation_cache_off():
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax_cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    jax_cc.reset_cache()
+    install_hardened_cache()  # re-bind conftest.py's session store
+
+
+def _on(sharding, tree):
+    """Arrays / shape structs -> ShapeDtypeStructs placed on the chip."""
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree,
+    )
+
+
+@pytest.mark.parametrize(
+    "C,D",
+    [(10, 1_690_046), (8, 200), (5, 62), (16, 4096)],
+    ids=lambda v: str(v),
+)
+def test_robust_stats_kernel_compiles_for_v5e(one_chip, C, D):
+    """ops/robust_stats rank-selection kernel, interpret=False, at the
+    FEMNIST-CNN parameter count and at the small/ragged widths the
+    aggregators also see (a bias vector of 62, a 200-wide leaf)."""
+    from fedml_tpu.ops.robust_stats import _BLOCK_D, _trimmed_mean_2d
+
+    x = jax.ShapeDtypeStruct((C, D), jnp.float32, sharding=one_chip)
+    compiled = _trimmed_mean_2d.lower(
+        x, trim_k=1, block_d=min(_BLOCK_D, max(128, D)), interpret=False
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize(
+    "shape,dtype",
+    [
+        ((1, 8, 8192, 96), jnp.bfloat16),  # chip_smoke.py's kernel check
+        ((4, 8, 256, 96), jnp.bfloat16),   # the flagship LM's (B, H, S, d)
+        ((1, 8, 4096, 64), jnp.bfloat16),
+        ((1, 8, 2048, 128), jnp.float32),
+    ],
+    ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else v.__name__,
+)
+def test_flash_attention_fwd_bwd_compiles_for_v5e(one_chip, shape, dtype):
+    """ops/flash_attention forward + both backward kernels, compiled."""
+    from fedml_tpu.ops import flash_attention
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, interpret=False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    qkv = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)] * 3
+    compiled = (
+        jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+        .lower(*qkv)
+        .compile()
+    )
+    # one custom call forward, two backward (dQ; dK/dV)
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+def test_femnist_cnn_round_program_compiles_for_one_v5e_chip(one_chip):
+    """The production round program (``api.round_fn``) of the north star —
+    FEMNIST CNN, 10 clients/round, batch 20 — lowered at its real round-0
+    shapes for one described chip, and small against its 16 GB."""
+    from fedml_tpu.algorithms import FedAvgAPI
+    from fedml_tpu.config import DataConfig, FedConfig, RunConfig, TrainConfig
+    from fedml_tpu.data.femnist_synth import femnist_synthetic
+    from fedml_tpu.models import create_model
+
+    cfg = RunConfig(
+        data=DataConfig(batch_size=20),
+        fed=FedConfig(
+            client_num_in_total=10, client_num_per_round=10, comm_round=1,
+            epochs=1,
+        ),
+        train=TrainConfig(client_optimizer="sgd", lr=0.1),
+        model="cnn",
+        seed=0,
+    )
+    api = FedAvgAPI(
+        cfg, femnist_synthetic(num_clients=10, seed=0),
+        create_model("cnn", "femnist", (28, 28, 1), 62),
+    )
+    fn, args = api.round_program(0)
+    compiled = fn.lower(*_on(one_chip, args)).compile()
+
+    text = compiled.as_text()
+    assert "convolution" in text  # the CNN's convs are in the chip program
+    mem = compiled.memory_analysis()
+    need = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert 0 < need < 1 << 30, need  # ~160 MB; the chip holds 16 GB
+    n_params = sum(
+        int(np.prod(l.shape)) for l in jax.tree_util.tree_leaves(api.global_vars)
+    )
+    assert n_params == 1_690_046  # the D the robust-stats case above uses

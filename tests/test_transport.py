@@ -42,7 +42,7 @@ def test_message_binary_roundtrip():
 def test_mqtt_federation_matches_simulator():
     """Same oracle as the loopback test, over the MQTT backend's embedded
     broker (ref mqtt topic scheme, mqtt_comm_manager.py:48-72,100-123) —
-    the VERDICT r1 #5 contract: federation==simulator over MQTT."""
+    the contract: federation==simulator over MQTT."""
     import jax
 
     from fedml_tpu.algorithms import FedAvgAPI
@@ -204,7 +204,7 @@ def test_grpc_roundtrip():
 
 
 def test_mqtt_socket_federation():
-    """Federation over REAL TCP MQTT (VERDICT r2 Next #6): mini broker +
+    """Federation over REAL TCP MQTT: mini broker +
     built-in 3.1.1 client, full-participation LR run matches the vmap
     simulator to float tolerance."""
     import jax
