@@ -17,7 +17,7 @@ sharded) instead of a Python loop over state_dict keys (HOT LOOP #3)."""
 
 from __future__ import annotations
 
-import time
+import math
 from typing import Callable, Dict, Optional, Sequence
 
 import jax
@@ -30,6 +30,7 @@ from fedml_tpu.models import ModelDef
 from fedml_tpu.telemetry import ClientHealthRegistry, get_tracer
 from fedml_tpu.train.client import make_local_train
 from fedml_tpu.train.evaluate import make_eval_fn
+from fedml_tpu.utils.profiling import span_annotation
 
 
 def weighted_average(stacked_tree, weights):
@@ -258,19 +259,24 @@ def make_fedavg_round(
             lifted = client_axis_map(local_train, mode)
 
             def round_fn(global_vars, x, y, mask, num_samples, client_rngs, *extra):
-                client_vars, metrics = lifted(global_vars, x, y, mask, client_rngs)
+                # named scopes put the layer into every device op's
+                # ``op_name`` (metadata only: the arithmetic is untouched)
+                with jax.named_scope("local_train"):
+                    client_vars, metrics = lifted(global_vars, x, y, mask, client_rngs)
                 if post_train is not None:
                     client_vars = post_train(client_vars, global_vars, *extra)
                 # aggregate_fn replaces the weighted average outright (Byzantine-
                 # robust aggregators: median/trimmed-mean/Krum; DP's fixed-
                 # denominator estimator needs w_t, hence the third argument)
-                if aggregate_fn is not None:
-                    new_global = aggregate_fn(client_vars, num_samples, global_vars)
-                else:
-                    new_global = weighted_average(client_vars, num_samples)
+                with jax.named_scope("aggregate"):
+                    if aggregate_fn is not None:
+                        new_global = aggregate_fn(client_vars, num_samples, global_vars)
+                    else:
+                        new_global = weighted_average(client_vars, num_samples)
                 if post_aggregate is not None:
                     new_global = post_aggregate(new_global, *extra)
-                agg_metrics = jax.tree_util.tree_map(jnp.sum, metrics)
+                with jax.named_scope("round_metrics"):
+                    agg_metrics = jax.tree_util.tree_map(jnp.sum, metrics)
                 if (
                     client_metrics
                     and isinstance(metrics, dict)
@@ -512,12 +518,16 @@ class FedAvgAPI:
         # uninterrupted run).
         self.start_round = 0
         # Telemetry: round-lifecycle spans (round → broadcast/local_train/
-        # eval) on the global tracer, and a client health registry updated
-        # per round. The vmap/mesh runtimes run the whole cohort as ONE
+        # eval) and the loop's own host phases (pack, prepare, health,
+        # flush: docs/OBSERVABILITY.md) on the global tracer, and a client
+        # health registry updated per round. The vmap/mesh runtimes run the whole cohort as ONE
         # jitted program, so per-client "train time" here is the cohort's
         # shared round wall time — participation/last-seen stay exact, and
         # the transport runtimes refine timing per client.
         self._tracer = get_tracer()
+        # every span is mirrored into a running jax profile as a
+        # ``fedml.<name>`` annotation, on the profiler's clock
+        self._tracer.annotate = span_annotation
         self.health = ClientHealthRegistry.from_config(config)
         # Scheduler: policy-driven cohort selection (FedConfig.selection /
         # .overprovision_factor, scheduler/policies.py). It shares this
@@ -673,7 +683,8 @@ class FedAvgAPI:
         # "broadcast" = ship the global model + cohort batch to the device
         # (the simulator's analog of the transport path's model broadcast)
         with self._tracer.span(
-            "broadcast", round=round_idx, clients=len(sampled)
+            "broadcast", round=round_idx, clients=len(sampled),
+            prepared=round_idx in self._warm_placed,
         ):
             # the AOT warmup path (or the round pipeline, which prepared
             # this round while the previous one executed) already stacked
@@ -713,9 +724,26 @@ class FedAvgAPI:
         placed = self._warm_placed.pop(round_idx, None)
         if placed is not None:
             return placed
-        batch = self._round_batch(sampled, round_idx)
+        return self._build_placed(round_idx, sampled)
+
+    def _build_placed(self, round_idx: int, sampled):
+        """Stack round ``round_idx``'s cohort batch and place it on the
+        device: the one place a round's batch is built, for the round
+        itself (``_round_placed``) or a round early (``_pipeline_prepare``).
+        ``stack`` is the host's index building plus the gather's dispatch;
+        ``place`` carries the batch's counts (shapes and host numbers, no
+        device read)."""
+        with self._tracer.span("stack", round=round_idx) as sp:
+            batch = self._round_batch(sampled, round_idx)
+            sp.set_attr("steps", int(batch.mask.shape[1]))
+            sp.set_attr("bs", int(batch.mask.shape[2]))
         rng = jax.random.fold_in(self.rng, round_idx + 1)
-        return self._place_batch(batch, rng)
+        with self._tracer.span(
+            "place", round=round_idx,
+            slots=math.prod(batch.mask.shape),
+            real_samples=float(np.sum(batch.num_samples)),
+        ):
+            return self._place_batch(batch, rng)
 
     def _pipeline_prepare(self, next_round: int) -> None:
         """The round pipeline's host stage: while the JUST-DISPATCHED
@@ -736,8 +764,8 @@ class FedAvgAPI:
         - a planner probe round (its fold must measure the serial
           schedule cost — round_planner.py).
 
-        The measured host seconds land in ``_pipeline_overlap`` and ride
-        the next round's span as ``overlap_s`` (flight records)."""
+        The ``prepare`` span's seconds land in ``_pipeline_overlap`` and
+        ride the next round's span as ``overlap_s`` (flight records)."""
         cfg = self.config
         if (
             cfg.fed.pipeline == "off"
@@ -758,12 +786,12 @@ class FedAvgAPI:
             return
         if self.planner is not None and self.planner.wants_sync(next_round):
             return
-        t0 = time.perf_counter()
-        sampled, _steps, _bs = self._round_plan(next_round)
-        batch = self._round_batch(sampled, next_round)
-        rng = jax.random.fold_in(self.rng, next_round + 1)
-        self._warm_placed[next_round] = self._place_batch(batch, rng)
-        self._pipeline_overlap[next_round] = time.perf_counter() - t0
+        with self._tracer.span("prepare", round=next_round) as sp:
+            sampled, _steps, _bs = self._round_plan(next_round)
+            self._warm_placed[next_round] = self._build_placed(
+                next_round, sampled
+            )
+        self._pipeline_overlap[next_round] = sp.dur_us / 1e6
         self.pipeline_rounds += 1
 
     def _report_client_losses(self, sampled, metrics, round_idx: int):
@@ -961,14 +989,18 @@ class FedAvgAPI:
             from fedml_tpu.data.base import bucket_steps
 
             cfg = self.config
-            sampled = self._sample_clients(round_idx)
-            steps, bs, _ = bucket_steps(
-                # an empty cohort (possible under DP's Poisson sampling) still
-                # needs a well-formed shape class — shape it like 1 sample
-                self._client_counts(sampled) if len(sampled) else [1],
-                cfg.data.batch_size,
-                cfg.data.pad_bucket,
-            )
+            # its ``parent`` says who paid for the selection: ``prepare``
+            # (a round early, hidden) or the round itself
+            with self._tracer.span("select", round=round_idx) as sp:
+                sampled = self._sample_clients(round_idx)
+                steps, bs, _ = bucket_steps(
+                    # an empty cohort (possible under DP's Poisson sampling) still
+                    # needs a well-formed shape class — shape it like 1 sample
+                    self._client_counts(sampled) if len(sampled) else [1],
+                    cfg.data.batch_size,
+                    cfg.data.pad_bucket,
+                )
+                sp.set_attr("clients", len(sampled))
             plan = (sampled, steps, bs)
             self._round_plans[round_idx] = plan
         return plan
@@ -1214,14 +1246,13 @@ class FedAvgAPI:
             self.rng,
         )
 
-    def _log_round(self, round_idx: int, metrics, round_time_s: float) -> dict:
+    def _log_round(self, round_idx: int, metrics) -> dict:
         cfg = self.config
         count = float(metrics["count"])
         row = {
             "round": round_idx,
             "Train/Loss": float(metrics["loss_sum"]) / max(count, 1e-9),
             "Train/Acc": float(metrics["correct"]) / max(count, 1e-9),
-            "round_time_s": round_time_s,
         }
         # feed power_of_choice: rounds whose program emitted per-client
         # loss vectors already reported TRUE per-client losses
@@ -1272,20 +1303,26 @@ class FedAvgAPI:
         final = {}
         if not pending:
             return final
-        host = np.asarray(
-            jnp.concatenate(
-                [v if v.ndim == 2 else v[None] for _, v, _ in pending]
-            )
-        )
-        rows = []
-        for (r, v, dt) in pending:
-            n = v.shape[0] if v.ndim == 2 else 1
-            for off in range(n):
-                rows.append((r + off, dt))
-        for (r, dt), vals in zip(rows, host):
-            final = self._log_round(
-                r, dict(zip(self._METRIC_KEYS, vals)), dt
-            )
+        rounds = []
+        for r, v in pending:
+            rounds.extend(range(r, r + (v.shape[0] if v.ndim == 2 else 1)))
+        with self._tracer.span(
+            "flush", first_round=rounds[0], last_round=rounds[-1],
+            rows=len(rounds),
+        ):
+            # the one device-to-host fetch: it returns when the device has
+            # finished every round flushed here, so this is the wait, not
+            # host work — and where a drained device idles. The stacking is
+            # part of it: its dispatches queue behind the rounds in flight
+            # and wait with them (on the v5e 70 ms a flush of 20 rounds).
+            with self._tracer.span("flush_wait", rows=len(rounds)):
+                host = np.asarray(
+                    jnp.concatenate(
+                        [v if v.ndim == 2 else v[None] for _, v in pending]
+                    )
+                )
+            for r, vals in zip(rounds, host):
+                final = self._log_round(r, dict(zip(self._METRIC_KEYS, vals)))
         pending.clear()
         return final
 
@@ -1293,10 +1330,9 @@ class FedAvgAPI:
         cfg = self.config
         final = {}
         round_idx = self.start_round
-        pending = []  # (round_idx, device metrics, round_time_s)
+        pending = []  # (first round_idx, device metrics [K] or [T, K])
         while round_idx < cfg.fed.comm_round:
             L = self._fused_chunk_len(round_idx)
-            t0 = time.perf_counter()
             # measured-probe segments sync on the device INSIDE the round
             # span: async dispatch makes an unsynced span measure host
             # dispatch only, and the planner's fused-vs-eager commitment
@@ -1308,12 +1344,10 @@ class FedAvgAPI:
             if L > 1:
                 with self._tracer.span(
                     "round", round=round_idx, fused_rounds=L
-                ):
+                ) as sp:
                     metrics = self.train_rounds_fused(round_idx, L)
                     if probe:
                         jax.block_until_ready(self.global_vars)
-                dt = (time.perf_counter() - t0) / L
-                pending.append((round_idx, self._pack_metrics(metrics), dt))
                 first_round, last_round = round_idx, round_idx + L - 1
                 round_idx += L
             else:
@@ -1327,16 +1361,17 @@ class FedAvgAPI:
                 ov = self._pipeline_overlap.pop(round_idx, None)
                 if ov is not None:
                     attrs = {"overlap_s": round(ov, 6), "pipeline_depth": 1}
-                with self._tracer.span("round", round=round_idx, **attrs):
+                with self._tracer.span("round", round=round_idx, **attrs) as sp:
                     _, metrics = self.train_round(round_idx)
                     if probe:
                         jax.block_until_ready(self.global_vars)
-                dt = time.perf_counter() - t0
-                pending.append(
-                    (round_idx, self._pack_metrics(metrics), dt)
-                )
                 first_round = last_round = round_idx
                 round_idx += 1
+            # a handful of small dispatches that queue behind the round just
+            # dispatched: where the device is the slower side, this is where
+            # the host waits for a free slot in the device's queue
+            with self._tracer.span("pack", round=first_round):
+                pending.append((first_round, self._pack_metrics(metrics)))
             # round pipeline: the dispatched rounds are still executing on
             # device (async dispatch; probe segments already synced inside
             # their span) — prepare the NEXT round's cohort/batch/placement
@@ -1346,12 +1381,22 @@ class FedAvgAPI:
             # adaptive policies, fault plans, fused chunks and probe rounds.
             self._pipeline_prepare(round_idx)
             # health: the cohort trained as one program — every sampled
-            # client shares the round's wall time; participation/last-seen
-            # are exact per client (_round_plan is memoized, so this costs
-            # no re-sampling)
-            for r in range(first_round, last_round + 1):
-                for cid in self._round_plan(r)[0]:
-                    self.health.observe_train(int(cid), r, dt)
+            # client shares the round's wall time (the ``round`` span's,
+            # per round of a fused chunk); participation/last-seen are
+            # exact per client (_round_plan is memoized, so this costs no
+            # re-sampling)
+            dt = sp.dur_us / 1e6 / (last_round - first_round + 1)
+            cohorts = [
+                (r, self._round_plan(r)[0])
+                for r in range(first_round, last_round + 1)
+            ]
+            with self._tracer.span(
+                "health", first_round=first_round, last_round=last_round,
+                clients=sum(len(cohort) for _, cohort in cohorts),
+            ):
+                for r, cohort in cohorts:
+                    for cid in cohort:
+                        self.health.observe_train(int(cid), r, dt)
             # Flush when the LAST executed round is an eval round — eval
             # must read global_vars exactly as of that round, and
             # _fused_chunk_len guarantees eval rounds terminate their

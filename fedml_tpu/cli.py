@@ -133,12 +133,20 @@ RUNTIMES = ("vmap", "mesh", "loopback", "mqtt", "shm", "grpc")
 @click.option("--resume", is_flag=True, default=False,
               help="Restore from --checkpoint_path and continue from the saved round")
 @click.option("--profile_dir", type=click.Path(path_type=Path), default=None,
-              help="Capture a jax.profiler device trace of the run into this dir")
+              help="Capture a jax.profiler trace of the run into this dir: "
+                   "the device ops (each op_name names its program — "
+                   "jit_round_fn, jit_device_store_gather, jit_eval_fn — and "
+                   "its scope: local_train/forward_backward|optimizer_update|"
+                   "keep_gate, aggregate, round_metrics, gather, mask_pad, "
+                   "eval) and, on the host plane and the same clock, every "
+                   "telemetry span as a fedml.<name> annotation")
 @click.option("--telemetry_dir", type=click.Path(path_type=Path), default=None,
               help="Write host-side telemetry here: trace.json (Chrome "
-                   "trace events — round/broadcast/local_train/aggregate/"
-                   "eval spans, viewable in Perfetto next to the "
-                   "--profile_dir device trace), health.json (per-client "
+                   "trace events on the tracer's own clock — round/"
+                   "broadcast/local_train/aggregate/eval and, from the "
+                   "simulator's loop, pack/prepare/select/stack/place/health/"
+                   "flush/flush_wait spans; docs/OBSERVABILITY.md has the "
+                   "table), health.json (per-client "
                    "participation/train-time/straggler registry) and "
                    "flight.json (the last-K-rounds flight-recorder ring: "
                    "per-round phase wall times + rolling p50/p95)")

@@ -52,11 +52,13 @@ def _gather(flat_x, flat_y, idx, mask):
     Plain traced function: the fused multi-round scan inlines it inside
     its own program, and :func:`gather_program` wraps it (plus the
     per-class reshape) for the eager per-round dispatch."""
-    x = jnp.take(flat_x, idx, axis=0)
-    y = jnp.take(flat_y, idx, axis=0)
-    mx = mask.reshape(mask.shape + (1,) * (x.ndim - mask.ndim))
-    my = mask.reshape(mask.shape + (1,) * (y.ndim - mask.ndim))
-    return x * mx.astype(x.dtype), y * my.astype(y.dtype)
+    with jax.named_scope("gather"):
+        x = jnp.take(flat_x, idx, axis=0)
+        y = jnp.take(flat_y, idx, axis=0)
+    with jax.named_scope("mask_pad"):
+        mx = mask.reshape(mask.shape + (1,) * (x.ndim - mask.ndim))
+        my = mask.reshape(mask.shape + (1,) * (y.ndim - mask.ndim))
+        return x * mx.astype(x.dtype), y * my.astype(y.dtype)
 
 
 def gather_program(steps: int, bs: int):
@@ -71,7 +73,8 @@ def gather_program(steps: int, bs: int):
     from fedml_tpu.compile import get_program_cache
 
     def builder():
-        def fn(flat_x, flat_y, idx, mask):
+        # the function's name is the XLA module's: jit_device_store_gather
+        def device_store_gather(flat_x, flat_y, idx, mask):
             x, y = _gather(flat_x, flat_y, idx, mask)
             C = idx.shape[0]
             feat = flat_x.shape[1:]
@@ -82,7 +85,7 @@ def gather_program(steps: int, bs: int):
                 mask.reshape((C, steps, bs)),
             )
 
-        return jax.jit(fn)
+        return jax.jit(device_store_gather)
 
     return get_program_cache().get_or_build(
         "device_store_gather",

@@ -90,7 +90,10 @@ class TransformerBlock(nn.Module):
         q = q.reshape(B, T, H, D)
         k = k.reshape(B, T, H, D)
         v = v.reshape(B, T, H, D)
-        attn = self.attn_fn(q, k, v)
+        # attn_fn is a plain function, not a module: without a scope its ops
+        # sit directly under the block, beside the residual adds and the GELU
+        with jax.named_scope("attention"):
+            attn = self.attn_fn(q, k, v)
         attn = attn.reshape(B, T, C)
         x = x + nn.Dense(C, use_bias=False, name="proj")(attn)
         h = fp32_layer_norm(name="ln2")(x)

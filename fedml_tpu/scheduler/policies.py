@@ -525,13 +525,19 @@ class ClientScheduler:
             while len(self._selections) > cap:
                 del self._selections[next(iter(self._selections))]
         if self._tracer is not None:
-            with self._tracer.span(
-                "select",
-                round=r,
-                policy=self.policy_name,
-                clients=int(len(sel)),
-            ):
-                pass
+            timing = self._tracer.current_span()
+            if timing is not None and timing.name == "select":
+                # the caller times this selection with a span of its own
+                # (FedAvgAPI._round_plan): name the policy there
+                timing.set_attr("policy", self.policy_name)
+            else:
+                with self._tracer.span(
+                    "select",
+                    round=r,
+                    policy=self.policy_name,
+                    clients=int(len(sel)),
+                ):
+                    pass
         if self._on_select is not None:
             self._on_select(r, sel)
         return sel

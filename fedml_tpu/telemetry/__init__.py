@@ -8,8 +8,11 @@ many bytes crossed each transport":
 
 - :mod:`fedml_tpu.telemetry.spans` — zero-dependency structured tracer.
   ``span("round", round=n)`` context manager, thread-safe, nestable; emits
-  Chrome-trace-event JSON loadable in Perfetto side by side with the
-  ``jax.profiler`` device traces from ``utils/profiling.py``.
+  Chrome-trace-event JSON loadable in Perfetto. Its clock is its own (epoch
+  anchor + monotonic delta), not the ``jax.profiler`` device trace's; with
+  ``Tracer.annotate`` set (``utils/profiling.span_annotation``, installed by
+  ``FedAvgAPI``) every span is also written into a running profile as a
+  ``fedml.<name>`` annotation, on the profiler's clock.
 - :mod:`fedml_tpu.telemetry.metrics` — counter/gauge/histogram primitives
   plus a registry that renders Prometheus text exposition format.
 - :mod:`fedml_tpu.telemetry.comm` — per-message traffic accounting wired

@@ -13,9 +13,15 @@ covers the server FSM thread, the client actor threads, and timer threads.
 
 The export format is Chrome trace events (the ``traceEvents`` JSON that
 Perfetto / ``chrome://tracing`` load natively), with complete ("X") events
-in epoch-anchored microseconds — the same timebase the jax profiler uses,
-so a host trace from ``--telemetry_dir`` can be viewed side by side with a
-device trace from ``--profile_dir`` and correlated by wall clock.
+in epoch-anchored microseconds: wall clock at the tracer's construction plus
+a monotonic delta. That is NOT the jax profiler's clock — a ``--telemetry_dir``
+trace and a ``--profile_dir`` device trace share no timebase and line up only
+as well as one reads both clocks at one moment. To put the spans on the
+profiler's own clock, set :attr:`Tracer.annotate`: every context-manager span
+then also enters the annotation the hook returns
+(``utils/profiling.span_annotation`` gives ``jax.profiler.TraceAnnotation``
+named ``fedml.<span>``), so a profile holds the host spans on its host plane
+beside the device ops.
 
 Cross-thread spans (a federated "round" begins on the broadcast path and
 ends in a receive handler on another thread) use the explicit handle API::
@@ -73,24 +79,39 @@ class SpanEvent:
         )
 
 
+# One lock for every span's end(): what it guards is a flag's test-and-set,
+# so spans never wait on each other for longer than that — and a span need
+# not allocate a lock of its own (the train loop opens ten a round).
+_END_LOCK = threading.Lock()
+
+# The recording process, kept current across a fork: asked once, not per span.
+_pid = os.getpid()
+
+
+def _refresh_pid() -> None:
+    global _pid
+    _pid = os.getpid()
+
+
+os.register_at_fork(after_in_child=_refresh_pid)
+
+
 class Span:
     """A live span handle. Created by ``Tracer.start_span`` / ``Tracer.span``;
     ``end()`` is idempotent and may be called from any thread."""
 
     __slots__ = (
-        "_tracer", "name", "attrs", "_t0_perf", "_ts_us", "_done", "_tid",
-        "_end_lock",
+        "_tracer", "name", "attrs", "_t0_perf", "_done", "_annotation", "dur_us",
     )
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any]):
         self._tracer = tracer
         self.name = name
         self.attrs = attrs
-        self._ts_us = tracer._now_us()
         self._t0_perf = time.perf_counter_ns()
         self._done = False
-        self._end_lock = threading.Lock()
-        self._tid = threading.get_ident()
+        self._annotation = None
+        self.dur_us: Optional[float] = None  # set by end()
 
     def set_attr(self, key: str, value: Any) -> "Span":
         self.attrs[key] = value
@@ -100,27 +121,34 @@ class Span:
         # atomic test-and-set: end() may race from two threads (e.g. a
         # timeout path vs the handler that completes the round) and must
         # record exactly once
-        with self._end_lock:
+        with _END_LOCK:
             if self._done:
                 return None
             self._done = True
-        dur_us = (time.perf_counter_ns() - self._t0_perf) / 1e3
+        t0, tracer = self._t0_perf, self._tracer
+        self.dur_us = dur_us = (time.perf_counter_ns() - t0) / 1e3
         ev = SpanEvent(
             self.name,
-            self._ts_us,
+            tracer._epoch_us + (t0 - tracer._anchor_ns) / 1e3,
             dur_us,
-            os.getpid(),
+            _pid,
             threading.get_ident(),
             self.attrs,
         )
-        self._tracer._record(ev)
+        tracer._record(ev)
         return ev
 
     def __enter__(self) -> "Span":
-        self._tracer._push(self)
+        tracer = self._tracer
+        tracer._push(self)
+        if tracer.annotate is not None:
+            self._annotation = tracer.annotate(self.name, self.attrs.get("round"))
+            self._annotation.__enter__()
         return self
 
     def __exit__(self, *exc) -> None:
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
         self._tracer._pop(self)
         self.end()
 
@@ -131,16 +159,24 @@ class Tracer:
     def __init__(self, max_events: int = DEFAULT_MAX_EVENTS):
         self._events: List[SpanEvent] = []
         self._lock = threading.Lock()
-        self._listeners: List[Callable[[SpanEvent], None]] = []
+        # replaced, never mutated (add/remove build a new tuple under the
+        # lock), so _record reads it without copying
+        self._listeners: tuple = ()
         self._local = threading.local()
         self.max_events = int(max_events)
         self.dropped = 0
         # epoch anchor: ts = wall clock at init + monotonic delta since,
-        # so timestamps are comparable across processes (and with the jax
-        # device trace) but never jump with NTP adjustments mid-run
+        # so timestamps are comparable across processes (not with the jax
+        # profiler's trace: see ``annotate``) but never jump with NTP
+        # adjustments mid-run
         self._epoch_us = time.time() * 1e6
         self._anchor_ns = time.perf_counter_ns()
         self.process_label: Optional[str] = None
+        # Optional mirror of every context-manager span onto another clock:
+        # ``annotate(name, round) -> context manager``, entered and left with
+        # the span on the span's own thread (handle spans from start_span
+        # may end on another thread and are not mirrored).
+        self.annotate: Optional[Callable[[str, Any], Any]] = None
 
     # -- time --
     def _now_us(self) -> float:
@@ -192,7 +228,7 @@ class Tracer:
             str(name),
             float(ts_us),
             float(dur_us),
-            os.getpid(),
+            _pid,
             threading.get_ident(),
             attrs,
         )
@@ -205,7 +241,7 @@ class Tracer:
                 self._events.append(ev)
             else:
                 self.dropped += 1
-            listeners = list(self._listeners)
+            listeners = self._listeners
         for fn in listeners:
             try:
                 fn(ev)
@@ -227,12 +263,12 @@ class Tracer:
     def add_listener(self, fn: Callable[[SpanEvent], None]) -> None:
         with self._lock:
             if fn not in self._listeners:
-                self._listeners.append(fn)
+                self._listeners = self._listeners + (fn,)
 
     def remove_listener(self, fn: Callable[[SpanEvent], None]) -> None:
         with self._lock:
             if fn in self._listeners:
-                self._listeners.remove(fn)
+                self._listeners = tuple(f for f in self._listeners if f != fn)
 
     def listeners(self) -> List[Callable[[SpanEvent], None]]:
         """Snapshot of the subscribed listeners (the supported read

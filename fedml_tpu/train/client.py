@@ -230,13 +230,15 @@ def make_local_train(
                 def real_step(carry):
                     params, extra, opt_state = carry
                     step_rng = jax.random.fold_in(ep_rng, sidx)
-                    (_, (new_extra, task_l, correct, total)), grads = grad_fn(
-                        params, extra, xb, yb, mb, step_rng
-                    )
-                    updates, new_opt_state = opt.update(
-                        grads, opt_state, params
-                    )
-                    new_params = optax.apply_updates(params, updates)
+                    with jax.named_scope("forward_backward"):
+                        (_, (new_extra, task_l, correct, total)), grads = grad_fn(
+                            params, extra, xb, yb, mb, step_rng
+                        )
+                    with jax.named_scope("optimizer_update"):
+                        updates, new_opt_state = opt.update(
+                            grads, opt_state, params
+                        )
+                        new_params = optax.apply_updates(params, updates)
                     mets = jnp.stack(
                         [task_l * total, correct, total, jnp.float32(1)]
                     )
@@ -264,11 +266,12 @@ def make_local_train(
                         lambda n, o: jnp.where(has_data, n, o), new, old
                     )
 
-                return (
-                    keep(new_params, params),
-                    keep(new_extra, extra),
-                    keep(new_opt_state, opt_state),
-                ), mets * has_data.astype(jnp.float32)
+                with jax.named_scope("keep_gate"):
+                    return (
+                        keep(new_params, params),
+                        keep(new_extra, extra),
+                        keep(new_opt_state, opt_state),
+                    ), mets * has_data.astype(jnp.float32)
 
             (params, extra, opt_state), mets = jax.lax.scan(
                 step_body,

@@ -47,7 +47,8 @@ def make_eval_fn(model: ModelDef, task: str = "classification"):
                 loss, correct, total = task_loss(logits, yb, mb)
                 return carry + jnp.stack([loss * total, correct, total]), None
 
-            sums, _ = jax.lax.scan(body, jnp.zeros(3), (x, y, mask))
+            with jax.named_scope("eval"):
+                sums, _ = jax.lax.scan(body, jnp.zeros(3), (x, y, mask))
             return {"loss_sum": sums[0], "correct": sums[1], "count": sums[2]}
 
         return eval_fn

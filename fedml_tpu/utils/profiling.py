@@ -136,6 +136,18 @@ def scan_slope_seconds(step_fn, init_carry, k1: int = 1, k2: int = 5, reps: int 
     return slopes[len(slopes) // 2]
 
 
+def span_annotation(name: str, round_idx=None):
+    """The tracer's ``annotate`` hook (``telemetry/spans.Tracer.annotate``):
+    a host span mirrored into the jax profiler's trace as ``fedml.<name>``,
+    carrying the round it works for. While no profile runs an annotation is
+    a no-op costing well under a microsecond, so nothing switches it; a
+    profile taken with ``--profile_dir`` holds the program's spans on its
+    host plane beside the device ops, on the profiler's one clock."""
+    if round_idx is None:
+        return jax.profiler.TraceAnnotation("fedml." + name)
+    return jax.profiler.TraceAnnotation("fedml." + name, round=round_idx)
+
+
 @contextlib.contextmanager
 def trace(log_dir: Optional[str]):
     """Capture a jax.profiler device trace into ``log_dir`` (TensorBoard /
