@@ -1,7 +1,8 @@
 #!/usr/bin/env python3
 """chip_smoke.py — the quickest proof that fedml_tpu still starts on the chip.
 
-    python chip_smoke.py               # one TPU chip: cli_cnn, lm_flagship, kernels
+    python chip_smoke.py               # one TPU chip: cli_cnn, store_gather,
+                                       # lm_flagship, kernels
     python chip_smoke.py --multichip   # four TPU chips: ONLY the mesh runtime
                                        # and the single-device run it is compared with
     python chip_smoke.py --rehearse [--multichip]
@@ -59,6 +60,12 @@ FLAGSHIP_TINY = dict(
     vocab=64, seq=32, layers=2, heads=2, dim=32, clients=4, samples=32,
     batch=16, dtype="float32",
 )
+
+# The device store's population: image-shaped samples, far more of them than
+# one cohort takes (10 clients a round), so that a program which copies the
+# population shows in its planned temporaries.
+STORE = dict(clients=2000, samples=200)
+STORE_TINY = dict(clients=400, samples=40)
 
 # (B, H, S, d) of the flash-attention check, and the dtype.
 FLASH = ((1, 8, 8192, 96), "bfloat16")
@@ -187,6 +194,72 @@ def phase_cli_cnn(ctx):
         "asserted": asserted,
         "train_loss": [round(x, 4) for x in losses],
         "recompiles_total": summary.get("compile/recompiles"),
+    }
+
+
+def phase_store_gather(ctx):
+    """The device store under ``FedAvgAPI``: an image-shaped population held
+    as rows, whose gather program plans cohort-sized temporaries only."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from fedml_tpu.algorithms import FedAvgAPI
+    from fedml_tpu.config import DataConfig, FedConfig, RunConfig, TrainConfig
+    from fedml_tpu.data.base import FederatedDataset, stack_clients
+    from fedml_tpu.models import create_model
+
+    m = STORE_TINY if ctx.rehearse else STORE
+    rng = np.random.default_rng(ctx.seed)
+    n = m["clients"] * m["samples"]
+    x = rng.random((n, 28, 28, 1), dtype=np.float32)
+    y = rng.integers(0, 62, size=n).astype(np.int32)
+    data = FederatedDataset(
+        name="store_smoke",
+        client_x=np.split(x, m["clients"]), client_y=np.split(y, m["clients"]),
+        test_x=x[:256], test_y=y[:256], num_classes=62,
+    )
+    cfg = RunConfig(
+        data=DataConfig(batch_size=20),
+        fed=FedConfig(
+            client_num_in_total=m["clients"], client_num_per_round=10,
+            comm_round=2, epochs=1, frequency_of_the_test=10_000,
+        ),
+        train=TrainConfig(lr=0.1),
+        seed=ctx.seed,
+    )
+    rows = []
+    api = FedAvgAPI(
+        cfg, data, create_model("cnn", "femnist_synth", (28, 28, 1), 62),
+        log_fn=rows.append,
+    )
+    store = api._store
+    check(store is not None, "the population is held on the device")
+    sampled = api._round_plan(0)[0]
+    idx, mask, steps, bs, _ = store.round_indices(sampled, 20, seed=1)
+    temp = store.gather_program(steps, bs).lower(
+        store.flat_x, store.flat_y, jnp.asarray(idx), jnp.asarray(mask)
+    ).compile().memory_analysis().temp_size_in_bytes
+    host = stack_clients(data, sampled, 20, seed=1)
+    dev = store.round_batch(sampled, 20, seed=1)
+    api.train()
+    losses = train_losses(rows)
+    return {
+        "asserted": [
+            check(store.flat_x.shape == (n, 896),
+                  "samples are held as rows of whole 128-lane tiles"),
+            # the assertion that catches a per-round copy of the population
+            check(temp * 10 < store.resident_bytes,
+                  f"the gather program plans {temp} B of temporaries, under a "
+                  f"tenth of the {store.resident_bytes} B the store holds"),
+            check(all(np.array_equal(np.asarray(getattr(dev, k)), getattr(host, k))
+                      for k in ("x", "y", "mask")),
+                  "the gathered batch is bit-equal to stack_clients"),
+            check(len(losses) == 2, f"2 rounds logged, got {len(losses)}"),
+        ],
+        "store": {"rows": n, "row_bytes": store.row_bytes,
+                  "resident_bytes": store.resident_bytes,
+                  "gather_temp_bytes": temp},
+        "train_loss": [round(v, 4) for v in losses],
     }
 
 
@@ -497,8 +570,8 @@ def main(argv=None):
     phases = (
         [("multichip", phase_multichip)]
         if args.multichip
-        else [("cli_cnn", phase_cli_cnn), ("lm_flagship", phase_lm_flagship),
-              ("kernels", phase_kernels)]
+        else [("cli_cnn", phase_cli_cnn), ("store_gather", phase_store_gather),
+              ("lm_flagship", phase_lm_flagship), ("kernels", phase_kernels)]
     )
     t_run = time.perf_counter()
     for name, fn in phases:

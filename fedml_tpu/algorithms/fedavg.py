@@ -345,6 +345,8 @@ def make_fedavg_multiround(
     config: RunConfig,
     steps: int,
     bs: int,
+    feat_shape: tuple,
+    label_shape: tuple,
     task: str = "classification",
     local_train_fn: Optional[Callable] = None,
     client_mode: Optional[str] = None,
@@ -364,6 +366,9 @@ def make_fedavg_multiround(
            mask_next [T,C,cap], num_samples [T,C], round_ids [T], base_rng)
             -> (global_vars', stacked per-round metrics)
 
+    ``flat_x``/``flat_y`` are the store's lane-padded rows; ``feat_shape``
+    and ``label_shape`` (the store's) are restored on each gathered batch.
+
     ``idx_next``/``mask_next`` arrive PRE-ROTATED by one round (host-side
     ``roll(-1)`` in ``_fused_plan``): iteration t's xs row is round t+1's
     gather — the double-buffer prefetch — and the last row wraps to round
@@ -376,7 +381,7 @@ def make_fedavg_multiround(
     Per-round math is identical to :func:`make_fedavg_round` at the same
     (steps, bs): the round body, the fold_in/split PRNG stream, and the
     weighted average are the same code."""
-    from fedml_tpu.data.device_store import _gather
+    from fedml_tpu.data.device_store import gather_batch
     from fedml_tpu.compile import get_program_cache, model_fingerprint
 
     mode = client_mode or resolve_client_parallelism(
@@ -389,17 +394,12 @@ def make_fedavg_multiround(
     lifted = client_axis_map(local_train, mode)
 
     def multi_fn(global_vars, flat_x, flat_y, idx_next, mask_next, num_samples, round_ids, base_rng):
-        feat = flat_x.shape[1:]
-        lab = flat_y.shape[1:]
         C = idx_next.shape[1]
 
         def gathered(idx_r, mask_r):
             # shared gather-and-zero-padding contract with the eager path
-            x, y = _gather(flat_x, flat_y, idx_r, mask_r)
-            return (
-                x.reshape((C, steps, bs) + feat),
-                y.reshape((C, steps, bs) + lab),
-                mask_r.reshape((C, steps, bs)),
+            return gather_batch(
+                flat_x, flat_y, idx_r, mask_r, steps, bs, feat_shape, label_shape
             )
 
         # Double-buffered: each iteration trains on the PRE-GATHERED batch
@@ -447,6 +447,8 @@ def make_fedavg_multiround(
             "mode": mode,
             "steps": steps,
             "bs": bs,
+            "feat": tuple(feat_shape),
+            "lab": tuple(label_shape),
             "may_pad": may_pad,
         },
         lambda: jax.jit(multi_fn, donate_argnums=(0,)),
@@ -612,7 +614,13 @@ class FedAvgAPI:
 
             if fits_on_device(data):
                 try:
-                    self._store = DeviceDataStore(data)
+                    # set-up, outside any round: what the device now holds
+                    with self._tracer.span("store_upload") as sp:
+                        store = DeviceDataStore(data)
+                        sp.set_attr("rows", int(store.flat_x.shape[0]))
+                        sp.set_attr("row_bytes", store.row_bytes)
+                        sp.set_attr("resident_bytes", store.resident_bytes)
+                    self._store = store
                 except ValueError:
                     # ragged per-client feature shapes cannot concatenate —
                     # the one EXPECTED reason to fall back to host stacking
@@ -1224,7 +1232,8 @@ class FedAvgAPI:
         fn = self._fused_fns.get(key)
         if fn is None:
             fn = make_fedavg_multiround(
-                self.model, cfg, max_steps, bs, task=self.task,
+                self.model, cfg, max_steps, bs,
+                store.feat_shape, store.label_shape, task=self.task,
                 local_train_fn=self._local_train_fn,
                 client_mode=self._client_mode,
                 may_pad=chunk_may_pad,

@@ -449,13 +449,15 @@ def default_specs() -> List[FactorySpec]:
     def multiround_build(cfg, ctx, kw):
         from fedml_tpu.algorithms.fedavg import make_fedavg_multiround
 
-        return make_fedavg_multiround(_model(ctx), cfg, steps=S, bs=B)
+        return make_fedavg_multiround(
+            _model(ctx), cfg, steps=S, bs=B, feat_shape=FEAT, label_shape=()
+        )
 
     def multiround_args(cfg, ctx, kw):
         T, cap, n = 2, S * B, 48
         return (
             _gv_shapes(_model(ctx)),
-            _sds((n,) + FEAT, np.float32),
+            _sds((n, 128), np.float32),  # the store's lane-padded rows
             _sds((n,), np.int32),
             _sds((T, C, cap), np.int32),
             _sds((T, C, cap), np.float32),

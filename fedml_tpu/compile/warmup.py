@@ -225,12 +225,10 @@ def _warm_partition_classes(api, rows: dict, tracer, r0: int) -> None:
             # the HBM-store round-batch program (gather + reshape) is a
             # per-class dispatch too — warm it, or round 1..R's first
             # cohort in this class pays ITS lazy compile instead
-            from fedml_tpu.data.device_store import gather_program
-
             _warm_one(
                 rows,
                 f"gather_s{st}b{bs}",
-                gather_program(st, bs),
+                store.gather_program(st, bs),
                 (
                     store.flat_x,
                     store.flat_y,

@@ -109,17 +109,6 @@ class MmapFederatedDataset(FederatedDataset):
     # train_sample_counts, which HERE is already the vectorized
     # np.diff(offsets) — no per-client lazy view is ever touched.
 
-    @property
-    def total_train_bytes(self) -> int:
-        """O(1) size for the HBM-budget guard — iterating 100k lazy views
-        to sum nbytes would defeat the point of the store."""
-        row = self._flat_x.dtype.itemsize * int(
-            np.prod(self._flat_x.shape[1:], dtype=np.int64)
-        ) + self._flat_y.dtype.itemsize * int(
-            np.prod(self._flat_y.shape[1:], dtype=np.int64)
-        )
-        return int(self._offsets[-1]) * row
-
 
 def write_mmap_dataset(
     path: str,

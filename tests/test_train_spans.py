@@ -163,6 +163,9 @@ def test_flush_holds_flush_wait_and_eval_on_evaluation_rounds(piped):
 def test_depth0_spans_are_the_loop_and_do_not_overlap(piped):
     top = sorted((e for e in piped.events if e.attrs.get("depth") == 0),
                  key=lambda e: e.ts_us)
+    # set-up's one span comes first and is no part of the loop
+    assert top[0].name == "store_upload"
+    top = top[1:]
     assert {e.name for e in top} == {"round", "pack", "prepare", "health", "flush"}
     for a, b in zip(top, top[1:]):
         # start and duration come from two clocks (epoch anchor, perf
@@ -170,6 +173,17 @@ def test_depth0_spans_are_the_loop_and_do_not_overlap(piped):
         assert a.ts_us + a.dur_us <= b.ts_us + 1.0, (a, b)
     # every other span sits beneath one of them
     assert all("parent" in e.attrs for e in piped.events if e.attrs.get("depth", 0) > 0)
+
+
+def test_store_upload_says_what_the_device_holds(piped):
+    (up,) = piped.named("store_upload")
+    store = piped.api._store
+    assert "round" not in up.attrs  # set-up: it works for no round
+    assert up.attrs["rows"] == 12 * 24 == store.flat_x.shape[0]
+    # 8 floats a sample, held as one 128-lane row
+    assert up.attrs["row_bytes"] == 128 * 4
+    assert store.flat_x.shape == (288, 128)
+    assert up.attrs["resident_bytes"] == store.resident_bytes == 288 * (128 * 4 + 4)
 
 
 @pytest.mark.parametrize("run_name", ["piped", "serial"])
@@ -221,7 +235,8 @@ def test_pipelined_and_serial_runs_log_the_same_rows(piped, serial):
 
 
 def test_annotation_entered_and_left_once_per_span(piped):
-    assert len(piped.annotations) == len(piped.events)
+    # set-up's store_upload ran before this test put its hook in
+    assert len(piped.annotations) == len(piped.events) - 1
     assert all(entered == 1 and left == 1 for _, _, entered, left in piped.annotations)
     # the identifier is the round the span works for, where it has one
     seen = {(name, r) for name, r, *_ in piped.annotations}
@@ -310,11 +325,9 @@ def test_round_program_names_its_scopes(piped, scope):
 
 @pytest.mark.parametrize("scope", ["gather", "mask_pad"])
 def test_gather_program_is_named_and_scoped(piped, scope):
-    from fedml_tpu.data.device_store import gather_program
-
     store = piped.api._store
     idx, mask, steps, bs, _ = store.round_indices([0, 1], 8, seed=0)
-    text = _lowered_text(gather_program(steps, bs),
+    text = _lowered_text(store.gather_program(steps, bs),
                      (store.flat_x, store.flat_y, jax.numpy.asarray(idx),
                       jax.numpy.asarray(mask)))
     assert re.search(r"module @jit_device_store_gather\b", text)
