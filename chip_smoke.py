@@ -2,7 +2,8 @@
 """chip_smoke.py — the quickest proof that fedml_tpu still starts on the chip.
 
     python chip_smoke.py               # one TPU chip: cli_cnn, store_gather,
-                                       # lm_flagship, kernels
+                                       # lm_flagship, decoder, kernels
+    python chip_smoke.py --phases decoder   # only the named phases
     python chip_smoke.py --multichip   # four TPU chips: ONLY the mesh runtime
                                        # and the single-device run it is compared with
     python chip_smoke.py --rehearse [--multichip]
@@ -66,6 +67,34 @@ FLAGSHIP_TINY = dict(
 # population shows in its planned temporaries.
 STORE = dict(clients=2000, samples=200)
 STORE_TINY = dict(clients=400, samples=40)
+
+# The spec-driven decoder at Mellum2-12B-A2.5B's published widths, one chip's
+# share of eight (experts 0..7 of 64, an eighth of the vocabulary), two
+# layers (one window, one full/YaRN), 2 silos x 2 steps of 2 x 2048 tokens.
+DECODER = dict(
+    vocab=12288, seq=2048, clients=2, samples=4, batch=2, dtype="bfloat16",
+    spec=dict(
+        hidden_size=2304, num_attention_heads=32, num_key_value_heads=4, head_dim=128,
+        layer_types=["sliding_attention", "full_attention"], sliding_window=1024,
+        rope_parameters={
+            "full_attention": {
+                "rope_type": "yarn", "rope_theta": 500000, "factor": 16,
+                "original_max_position_embeddings": 8192, "beta_fast": 32, "beta_slow": 1,
+                "attention_factor": 1.2772588722239782},
+            "sliding_attention": {"rope_type": "default", "rope_theta": 500000}},
+        num_experts=64, num_experts_per_tok=8, moe_intermediate_size=896,
+        experts_held=[0, 8],
+    ),
+)
+DECODER_TINY = dict(
+    vocab=97, seq=32, clients=2, samples=4, batch=2, dtype="float32",
+    spec=dict(
+        hidden_size=64, num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+        layer_types=["sliding_attention", "full_attention"], sliding_window=8,
+        num_experts=8, num_experts_per_tok=2, moe_intermediate_size=32,
+        experts_held=[0, 4],
+    ),
+)
 
 # (B, H, S, d) of the flash-attention check, and the dtype.
 FLASH = ((1, 8, 8192, 96), "bfloat16")
@@ -314,6 +343,96 @@ def phase_lm_flagship(ctx):
     }
 
 
+def phase_decoder(ctx):
+    """The spec-driven decoder's round through ``FedAvgAPI(...).train()``,
+    and its grouped product against the dense form of the same product in
+    float32 at the highest matmul precision."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from fedml_tpu.algorithms import FedAvgAPI
+    from fedml_tpu.config import DataConfig, FedConfig, RunConfig, TrainConfig
+    from fedml_tpu.data.base import FederatedDataset
+    from fedml_tpu.models import create_model
+    from fedml_tpu.models.decoder import grouped_dot
+    from fedml_tpu.telemetry import get_tracer
+
+    m = DECODER_TINY if ctx.rehearse else DECODER
+    spec = m["spec"]
+    d, f = spec["hidden_size"], spec["moe_intermediate_size"]
+    held = spec["experts_held"][1] - spec["experts_held"][0]
+    rows = m["batch"] * m["seq"] * spec["num_experts_per_tok"]
+
+    # the grouped product: rows x d times [held, d, f], a share of the rows in groups
+    rng = np.random.default_rng(ctx.seed)
+    sizes = rng.multinomial(rows // 8, np.ones(held) / held).astype(np.int32)
+    x = jnp.asarray(rng.standard_normal((rows, d)), jnp.float32)
+    w = jnp.asarray(0.02 * rng.standard_normal((held, d, f)), jnp.float32)
+    group = np.repeat(np.arange(held + 1), list(sizes) + [rows - int(sizes.sum())])
+    onehot = jnp.asarray(group[:, None] == np.arange(held)[None, :], jnp.float32)
+    live = int(sizes.sum())
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(grouped_dot)(x, w, jnp.asarray(sizes))
+        want = jax.jit(lambda x, w, oh: jnp.einsum("me,med->md", oh, jnp.einsum(
+            "mk,ekd->med", x, w)))(x[:live], w, onehot[:live])
+    err32 = float(jnp.max(jnp.abs(got[:live] - want)))
+    scale = float(jnp.max(jnp.abs(want)))
+    low = jax.jit(grouped_dot)(x.astype(jnp.bfloat16), w.astype(jnp.bfloat16), jnp.asarray(sizes))
+    err16 = float(jnp.max(jnp.abs(low[:live].astype(jnp.float32) - want)))
+
+    cx, cy = [], []
+    for c in range(m["clients"]):
+        doc = np.random.default_rng([ctx.seed, c]).integers(
+            1, m["vocab"], size=(m["samples"], m["seq"] + 1), dtype=np.int32)
+        cx.append(doc[:, :-1].copy())
+        cy.append(doc[:, 1:].copy())
+    data = FederatedDataset(
+        name="random_tokens", client_x=cx, client_y=cy,
+        test_x=cx[0][:2, :64], test_y=cy[0][:2, :64], num_classes=m["vocab"])
+    model = create_model("decoder", "random_tokens", (m["seq"],), m["vocab"], **spec)
+    cfg = RunConfig(
+        data=DataConfig(batch_size=m["batch"], pad_bucket=1),
+        fed=FedConfig(
+            client_num_in_total=m["clients"], client_num_per_round=m["clients"],
+            comm_round=2, epochs=1, frequency_of_the_test=10_000,
+        ),
+        train=TrainConfig(client_optimizer="sgd", lr=0.01, compute_dtype=m["dtype"]),
+        model="decoder", seed=ctx.seed,
+    )
+    out = []
+    tracer = get_tracer()
+    t0 = tracer.now_us()
+    api = FedAvgAPI(cfg, data, model, task="nwp", log_fn=out.append)
+    api.train()
+    losses = train_losses(out)
+    flushes = [e.attrs for e in tracer.events()
+               if e.name == "flush" and e.ts_us >= t0 and "moe_pairs" in e.attrs]
+    pairs = sum(a["moe_pairs"] for a in flushes)
+    tokens = 2 * m["clients"] * m["samples"] * m["seq"]
+    per_token = pairs / (tokens * len(spec["layer_types"]))
+    return {
+        "asserted": [
+            check(err32 <= 1e-5 * scale,
+                  f"grouped product in float32 at highest = dense form: {err32:.3g} of {scale:.3g}"),
+            check(err16 <= 2e-2 * scale, f"in bfloat16 within rounding: {err16:.3g} of {scale:.3g}"),
+            check(len(losses) == 2 and all(math.isfinite(v) for v in losses),
+                  f"2 rounds logged with finite losses: {losses}"),
+            check(bool(flushes) and sum(a["moe_dropped"] for a in flushes) == 0,
+                  "the flush spans carry the expert counters and no pair was dropped"),
+            check(0.5 < per_token < 2.0, f"held pairs per token and layer {per_token:.3f} near 1"),
+            check(platforms_of(api.global_vars) == {ctx.platform},
+                  f"parameters live on {ctx.platform}"),
+        ],
+        "schedule": api._client_mode, "train_loss": [round(v, 4) for v in losses],
+        "grouped_product": {"rows": rows, "live": live, "err_f32": err32, "err_bf16": err16,
+                            "scale": scale},
+        "held_pairs_per_token": per_token,
+        "moe": {k: sum(a[k] for a in flushes) for k in (
+            "moe_pairs", "moe_rows", "moe_load_max", "moe_load_mean")},
+    }
+
+
 def _flash_check(ctx):
     """flash_attention forward + grad, compiled, against plain attention
     computed head by head in float32 at the highest matmul precision."""
@@ -536,6 +655,8 @@ def main(argv=None):
     ap.add_argument("--rehearse", action="store_true",
                     help="any backend, tiny sizes; prints no result line")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--phases", default="",
+                    help="comma-separated phases to run, of those the mode has (default: all)")
     args = ap.parse_args(argv)
 
     import jax
@@ -571,8 +692,15 @@ def main(argv=None):
         [("multichip", phase_multichip)]
         if args.multichip
         else [("cli_cnn", phase_cli_cnn), ("store_gather", phase_store_gather),
-              ("lm_flagship", phase_lm_flagship), ("kernels", phase_kernels)]
+              ("lm_flagship", phase_lm_flagship), ("decoder", phase_decoder),
+              ("kernels", phase_kernels)]
     )
+    if args.phases:
+        wanted = args.phases.split(",")
+        unknown = sorted(set(wanted) - {n for n, _ in phases})
+        if unknown:
+            ap.error(f"unknown phases {unknown}; have {[n for n, _ in phases]}")
+        phases = [(n, fn) for n, fn in phases if n in wanted]
     t_run = time.perf_counter()
     for name, fn in phases:
         mark, t0 = ctx.clock.mark(), time.perf_counter()

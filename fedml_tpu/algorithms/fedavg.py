@@ -77,7 +77,16 @@ def resolve_client_parallelism(mode: str, model: ModelDef) -> str:
     or with sub-MB param copies keep "vmap": their per-step time is
     overhead-dominated and one big program wins. The heuristic: any 4-D
     conv kernel with <= 64 output channels (under-tiled on the MXU) and a
-    per-client param copy >= 1 MB."""
+    per-client param copy >= 1 MB.
+
+    "scan" also wins by bytes: under vmap every client of the cohort holds
+    its float32 copy, that copy's gradient and the compute-dtype copy at
+    once (10 bytes a parameter at bf16), under scan one client does. A
+    per-client copy of 1 GiB or more (268 M float32 parameters) makes that
+    2.5 GiB a client: two clients of such a model leave a 16 GB chip too
+    little for their activations, and its matmuls are large enough that
+    batching them over clients buys nothing. (GPT-2 124M with its untied
+    head is 0.61 GiB and keeps vmap.)"""
     if mode == "auto":
         shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
         leaves = jax.tree_util.tree_leaves(shapes)
@@ -88,7 +97,8 @@ def resolve_client_parallelism(mode: str, model: ModelDef) -> str:
             len(l.shape) == 4 and l.shape[-1] <= 64 and l.shape[0] <= 7
             for l in leaves
         )
-        mode = "scan" if (small_conv and param_bytes >= 1_000_000) else "vmap"
+        under_tiled = small_conv and param_bytes >= 1_000_000
+        mode = "scan" if under_tiled or param_bytes >= 2**30 else "vmap"
     if mode not in ("vmap", "scan"):
         raise ValueError(
             f"client_parallelism must be 'vmap', 'scan' or 'auto', got {mode!r}"
@@ -1299,9 +1309,12 @@ class FedAvgAPI:
         """One round's metrics dict -> a [K] device vector (single dispatch,
         issued while the round itself is still in flight), or a [T, K]
         matrix for a fused chunk's stacked metrics."""
-        return jnp.stack(
-            [jnp.asarray(metrics[k]) for k in self._METRIC_KEYS], axis=-1
+        # the model's device counters ride behind the fixed keys when the
+        # local train reports them (a caller's own local train need not)
+        keys = self._METRIC_KEYS + tuple(
+            k for k in self.model.counters if k in metrics
         )
+        return jnp.stack([jnp.asarray(metrics[k]) for k in keys], axis=-1)
 
     def _flush_pending(self, pending) -> dict:
         """Fetch all deferred per-round metrics in ONE device->host transfer
@@ -1318,7 +1331,7 @@ class FedAvgAPI:
         with self._tracer.span(
             "flush", first_round=rounds[0], last_round=rounds[-1],
             rows=len(rounds),
-        ):
+        ) as flush:
             # the one device-to-host fetch: it returns when the device has
             # finished every round flushed here, so this is the wait, not
             # host work — and where a drained device idles. The stacking is
@@ -1330,6 +1343,15 @@ class FedAvgAPI:
                         [v if v.ndim == 2 else v[None] for _, v in pending]
                     )
                 )
+            fixed = len(self._METRIC_KEYS)
+            if host.shape[1] > fixed:
+                # the model's device counters, summed over the rounds flushed
+                # here, with the constants their readers need beside them
+                sums = host[:, fixed:].sum(axis=0, dtype=np.float64)
+                for name, total in zip(self.model.counters, sums):
+                    flush.set_attr(name, float(total))
+                for name, value in self.model.counter_attrs.items():
+                    flush.set_attr(name, value)
             for r, vals in zip(rounds, host):
                 final = self._log_round(r, dict(zip(self._METRIC_KEYS, vals)))
         pending.clear()

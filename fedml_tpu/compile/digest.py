@@ -141,6 +141,7 @@ def model_fingerprint(model) -> Dict[str, Any]:
         "input_dtype": input_dtype,
         "has_dropout": bool(getattr(model, "has_dropout", False)),
         "has_batch_stats": bool(getattr(model, "has_batch_stats", False)),
+        "counters": list(getattr(model, "counters", ())),
     }
 
 

@@ -28,20 +28,41 @@ class ModelDef:
     has_dropout: bool = False
     has_batch_stats: bool = False
     name: str = "model"
+    # Device counters the module reports in training: it sows one float32
+    # vector (an entry per name here) into the "counters" collection wherever
+    # it counts, and ``apply(..., counters=True)`` hands back their sum. The
+    # local-train loop carries them with its metrics; ``counter_attrs`` are
+    # the model's constants a reader of the counters needs beside them.
+    counters: Tuple[str, ...] = ()
+    counter_attrs: dict = dataclasses.field(default_factory=dict)
 
     def init(self, rng) -> dict:
         dummy = jnp.zeros((1,) + tuple(self.input_shape), dtype=self.input_dtype)
         rngs = {"params": rng}
         if self.has_dropout:
             rngs["dropout"] = jax.random.fold_in(rng, 1)
-        variables = self.module.init(rngs, dummy, train=False)
-        return jax.tree_util.tree_map(lambda a: a, dict(variables))
+        variables = dict(self.module.init(rngs, dummy, train=False))
+        variables.pop("counters", None)  # what init's own pass sowed
+        return jax.tree_util.tree_map(lambda a: a, variables)
 
-    def apply(self, variables, x, train: bool, rng=None):
-        """Returns (outputs, updated_variables)."""
+    def apply(self, variables, x, train: bool, rng=None, counters: bool = False):
+        """Returns (outputs, updated_variables), and with ``counters=True``
+        a third item: the module's counters summed into one vector (see
+        ``counters`` above; a model that reports none gives an empty one)."""
         rngs = {}
         if self.has_dropout and train:
             rngs["dropout"] = rng if rng is not None else jax.random.PRNGKey(0)
+        if counters:
+            if self.has_batch_stats:
+                raise NotImplementedError("no model reports counters beside batch statistics")
+            out, sown = self.module.apply(
+                variables, x, train=train, rngs=rngs, mutable=["counters"]
+            )
+            counted = sum(
+                jax.tree_util.tree_leaves(sown),
+                jnp.zeros((len(self.counters),), jnp.float32),
+            )
+            return out, variables, counted
         if self.has_batch_stats and train:
             out, mutated = self.module.apply(
                 variables, x, train=train, rngs=rngs, mutable=["batch_stats"]

@@ -1,0 +1,354 @@
+"""Spec-driven decoder-only language model: the block shape of today's open
+models, written from the keys of a Hugging Face ``config.json``.
+
+    x0 = E[token]
+    h  = x + Wo . Attn(n Wq, n Wk, n Wv)        n = RMSNorm(x)
+    y  = h + MoE(RMSNorm(h))
+    logits = RMSNorm(y_L) W_head                 (W_head = E^T when tied)
+
+- RMS norms (float32 statistics, learned scale), no biases, no learned
+  positions.
+- Grouped-query attention: ``num_attention_heads`` query heads of
+  ``head_dim`` on ``num_key_value_heads`` key/value heads (the query width
+  need not equal ``hidden_size``), through the one attention core
+  ``parallel/ring_attention.full_attention``.
+- Rotate-half rotary embeddings on every dim of q and k, one table per layer
+  kind from ``rope_parameters[kind]``: ``rope_type`` ``default`` or ``yarn``
+  (as HF's ``_compute_yarn_parameters``: blended frequencies, cos and sin
+  scaled by ``attention_factor``).
+- ``layer_types`` gives every layer's kind: ``full_attention`` (causal) or
+  ``sliding_attention`` (causal, and ``i - j < sliding_window``).
+- Every MLP is a routed expert layer (:func:`routed_experts`): softmax router
+  over all ``num_experts``, top-``num_experts_per_tok``, renormalised when
+  ``norm_topk_prob``, gated-SiLU experts of width ``moe_intermediate_size``,
+  no capacity and no dropped pair.
+
+``experts_held = (lo, hi)`` is the expert-parallel share of one chip: the
+layer holds the weights of experts ``lo..hi-1`` only, still routes over all
+``num_experts``, and returns its own experts' part of the sum. What the
+absent experts would add is left out (on a mesh it arrives by the exchange;
+on one chip there is none). The default holds every expert.
+
+In training the model sows five counters per expert layer into the
+``counters`` collection (:data:`COUNTERS`; ``ModelDef.apply(...,
+counters=True)`` sums them over the layers); it returns logits only."""
+
+from __future__ import annotations
+
+import functools
+import math
+from typing import Any, Mapping, Optional, Sequence
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from jax.custom_batching import custom_vmap
+
+from fedml_tpu.parallel.ring_attention import full_attention
+
+LAYER_KINDS = ("full_attention", "sliding_attention")
+
+# Per expert layer and call, as float32 (whole numbers below 2**24 a round):
+# (token, slot) pairs routed to a held expert; held pairs that the dispatch
+# left outside the grouped products (0 by construction: the check of the
+# sort); rows the grouped products run over; the largest and the mean number
+# of pairs of one held expert.
+COUNTERS = ("moe_pairs", "moe_dropped", "moe_rows", "moe_load_max", "moe_load_mean")
+
+
+def rotary_tables(rope: Mapping[str, Any], head_dim: int, length: int):
+    """(cos, sin), each [length, head_dim] float32, of one layer kind."""
+    theta = float(rope["rope_theta"])
+    m = jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+    inv_freq = theta ** (-m / head_dim)
+    scale = 1.0
+    kind = rope.get("rope_type", "default")
+    if kind == "yarn":
+        factor = float(rope["factor"])
+        original = float(rope["original_max_position_embeddings"])
+
+        def correction_dim(rotations):
+            return head_dim * math.log(original / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+        low = max(math.floor(correction_dim(float(rope.get("beta_fast", 32)))), 0)
+        high = min(math.ceil(correction_dim(float(rope.get("beta_slow", 1)))), head_dim - 1)
+        if low == high:
+            high += 0.001
+        ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low) / (high - low), 0, 1)
+        inv_freq = (inv_freq / factor) * ramp + inv_freq * (1 - ramp)
+        scale = float(rope.get("attention_factor") or 0.1 * math.log(factor) + 1.0)
+    elif kind != "default":
+        raise ValueError(f"unknown rope_type {kind!r}; have 'default' and 'yarn'")
+    angles = jnp.arange(length, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    angles = jnp.concatenate([angles, angles], axis=-1)
+    return jnp.cos(angles) * scale, jnp.sin(angles) * scale
+
+
+def apply_rotary(x, cos, sin):
+    """Rotate-half rotary on x [B, T, H, D], in float32, back in x's dtype."""
+    x32 = x.astype(jnp.float32)
+    half = x.shape[-1] // 2
+    rotated = jnp.concatenate([-x32[..., half:], x32[..., :half]], axis=-1)
+    return (x32 * cos[None, :, None, :] + rotated * sin[None, :, None, :]).astype(x.dtype)
+
+
+@jax.custom_vjp
+def take_rows(x, idx, readers):
+    """``x[idx]`` where the rows of the result that read row ``r`` of ``x``
+    are exactly ``readers[r]`` (a fixed number each): the backward pass is
+    then a gather too, not a scatter-add."""
+    return x[idx]
+
+
+def _take_rows_fwd(x, idx, readers):
+    return x[idx], readers
+
+
+def _take_rows_bwd(readers, g):
+    return jnp.sum(g[readers], axis=1), None, None
+
+
+take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+# lhs [M, K] and rhs [M, N], both ragged over M: the groups' K x N products.
+_TO_WEIGHTS = jax.lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(([0], [0]), ([], [])),
+    lhs_ragged_dimensions=[0], rhs_group_dimensions=[],
+)
+
+
+def _any_batched(fn):
+    """``fn`` with a vmap rule of its own: one call per member of the batch
+    (the clients of a round; a static handful), each on its own slice.
+    ``ragged_dot``'s rule wants every argument batched at dim 0, which the
+    first pass over a scan's body under the clients' vmap does not give
+    (the weights are not batched yet), and the TPU compiler's grouped
+    matmul takes no batch dimension at all."""
+    wrapped = custom_vmap(fn)
+
+    @wrapped.def_vmap
+    def rule(axis_size, in_batched, *args):
+        outs = [fn(*(a[i] if b else a for a, b in zip(args, in_batched)))
+                for i in range(axis_size)]
+        return jnp.stack(outs), True
+
+    return wrapped
+
+
+_rows_by_group = _any_batched(
+    lambda rows, weights, sizes: jax.lax.ragged_dot(rows, weights, sizes))
+_weights_by_group = _any_batched(
+    lambda rows, cot, sizes: jax.lax.ragged_dot_general(rows, cot, sizes, _TO_WEIGHTS))
+
+
+@jax.custom_vjp
+def grouped_dot(rows, weights, sizes):
+    """Grouped product: row r of the result is ``rows[r] @ weights[g]`` for
+    the group g that r lies in, the groups being consecutive runs of
+    ``sizes[g]`` rows. rows [M, K], weights [G, K, N], sizes [G] -> [M, N].
+    XLA's ``ragged_dot`` (a grouped-matmul kernel on the TPU whose work
+    follows the rows inside the groups), differentiable and batchable under
+    both client schedules; rows after the last group hold nothing to rely on."""
+    return _rows_by_group(rows, weights, sizes)
+
+
+def _grouped_dot_fwd(rows, weights, sizes):
+    return _rows_by_group(rows, weights, sizes), (rows, weights, sizes)
+
+
+def _grouped_dot_bwd(res, g):
+    rows, weights, sizes = res
+    return (
+        _rows_by_group(g, jnp.swapaxes(weights, 1, 2), sizes),
+        _weights_by_group(rows, g, sizes).astype(weights.dtype),
+        None,
+    )
+
+
+grouped_dot.defvjp(_grouped_dot_fwd, _grouped_dot_bwd)
+
+
+def routed_experts(x, router, w_gate, w_up, w_down, *, top_k: int,
+                   norm_topk_prob: bool = True, held_from: int = 0):
+    """The held experts' part of a routed expert layer.
+
+    x [N, d] tokens; router [d, E]; w_gate, w_up [Eh, d, f] and w_down
+    [Eh, f, d], the weights of experts ``held_from .. held_from + Eh - 1``.
+    Routes every token over all E experts (logits and softmax in float32),
+    sorts the N*top_k (token, slot) pairs by expert with the pairs of absent
+    experts last, runs the three products as grouped products
+    (:func:`grouped_dot`) over the held experts' rows, and sums each
+    token's held slots by its (renormalised) top-k weights. Returns
+    ``(y [N, d], counters [len(COUNTERS)] float32)``."""
+    N, d = x.shape
+    Eh = w_gate.shape[0]
+    with jax.named_scope("router"):
+        logits = jnp.dot(x, router, preferred_element_type=jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_w, top_e = jax.lax.top_k(probs, top_k)
+        if norm_topk_prob:
+            top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+    with jax.named_scope("dispatch"):
+        rows = N * top_k
+        local = top_e.reshape(rows) - held_from
+        held = (local >= 0) & (local < Eh)
+        key = jnp.where(held, local, Eh)
+        order = jnp.argsort(key, stable=True)
+        inverse = jnp.argsort(order)
+        group_sizes = jnp.sum(
+            key[:, None] == jnp.arange(Eh, dtype=key.dtype)[None, :], axis=0, dtype=jnp.int32)
+        pairs = jnp.sum(group_sizes)
+        # sorted rows that carry a pair of a held expert; the grouped products
+        # leave whatever they find in the rows after them (on the chip: not
+        # zeros), so every result is cleared there
+        live = (jnp.arange(rows) < pairs)[:, None]
+        xs = take_rows(x, order // top_k, inverse.reshape(N, top_k))
+        xs = jnp.where(live, xs, jnp.zeros((), xs.dtype))
+    with jax.named_scope("experts"):
+        gate = grouped_dot(xs, w_gate, group_sizes)
+        up = grouped_dot(xs, w_up, group_sizes)
+        hidden = jnp.where(live, jax.nn.silu(gate) * up, jnp.zeros((), up.dtype))
+        ys = grouped_dot(hidden, w_down, group_sizes)
+        ys = jnp.where(live, ys, jnp.zeros((), ys.dtype))
+    with jax.named_scope("combine"):
+        slots = take_rows(ys, inverse, order[:, None]).reshape(N, top_k, d)
+        y = jnp.sum(slots.astype(jnp.float32) * top_w[..., None], axis=1).astype(x.dtype)
+        f32 = jnp.float32
+        counters = jnp.stack([
+            pairs.astype(f32),
+            jnp.sum(held[order] & ~live[:, 0]).astype(f32),
+            jnp.asarray(rows, f32),
+            jnp.max(group_sizes).astype(f32),
+            pairs.astype(f32) / Eh,
+        ])
+    return y, jax.lax.stop_gradient(counters)
+
+
+class RMSNorm(nn.Module):
+    eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        x32 = x.astype(jnp.float32)
+        var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+        return (x32 * jax.lax.rsqrt(var + self.eps) * scale).astype(x.dtype)
+
+
+class DecoderLayer(nn.Module):
+    """One layer. Every weight is a leaf of the layer itself, and every part
+    of the computation a ``jax.named_scope`` directly beneath it (``qkv``,
+    ``rope``, ``attention_full`` / ``attention_sliding``, ``out``,
+    ``router``, ``dispatch``, ``experts``, ``combine``), so a device trace
+    splits the layer by them."""
+
+    kind: str
+    num_attention_heads: int
+    num_key_value_heads: int
+    head_dim: int
+    sliding_window: int
+    num_experts: int
+    num_experts_per_tok: int
+    moe_intermediate_size: int
+    norm_topk_prob: bool
+    rms_norm_eps: float
+    experts_held: Sequence[int]
+
+    @nn.compact
+    def __call__(self, x, cos, sin):
+        B, T, d = x.shape
+        H, KV, D = self.num_attention_heads, self.num_key_value_heads, self.head_dim
+        lo, hi = self.experts_held
+        f = self.moe_intermediate_size
+        init = nn.initializers.normal(0.02)
+        n = RMSNorm(self.rms_norm_eps, name="input_layernorm")(x)
+        with jax.named_scope("qkv"):
+            q = jnp.dot(n, self.param("q_proj", init, (d, H * D))).reshape(B, T, H, D)
+            k = jnp.dot(n, self.param("k_proj", init, (d, KV * D))).reshape(B, T, KV, D)
+            v = jnp.dot(n, self.param("v_proj", init, (d, KV * D))).reshape(B, T, KV, D)
+        with jax.named_scope("rope"):
+            q, k = apply_rotary(q, cos, sin), apply_rotary(k, cos, sin)
+        sliding = self.kind == "sliding_attention"
+        with jax.named_scope("attention_sliding" if sliding else "attention_full"):
+            a = full_attention(
+                q, k, v, causal=True, window=self.sliding_window if sliding else None)
+        with jax.named_scope("out"):
+            x = x + jnp.dot(a.reshape(B, T, H * D), self.param("o_proj", init, (H * D, d)))
+        n = RMSNorm(self.rms_norm_eps, name="post_attention_layernorm")(x)
+        # Recomputed in the backward pass, not kept: the tokens x top-k rows
+        # of the sorted copies, of both hidden products and of the output are
+        # a gigabyte a layer at 4 096 tokens of width 2 304 and top-8, as
+        # much again as the attention probabilities that have to stay.
+        experts = jax.checkpoint(functools.partial(
+            routed_experts, top_k=self.num_experts_per_tok,
+            norm_topk_prob=self.norm_topk_prob, held_from=lo))
+        y, counters = experts(
+            n.reshape(B * T, d),
+            self.param("router", init, (d, self.num_experts)),
+            self.param("experts_gate", init, (hi - lo, d, f)),
+            self.param("experts_up", init, (hi - lo, d, f)),
+            self.param("experts_down", init, (hi - lo, f, d)),
+        )
+        self.sow("counters", "moe", counters)
+        return x + y.reshape(B, T, d)
+
+
+class DecoderLM(nn.Module):
+    """Arguments mirror the ``config.json`` keys of the source model (plus
+    ``experts_held``); the depth is ``len(layer_types)``. The defaults are a
+    small model for the CLI and tests, not a published one."""
+
+    vocab_size: int
+    hidden_size: int = 128
+    num_attention_heads: int = 4
+    num_key_value_heads: int = 2
+    head_dim: int = 32
+    layer_types: Sequence[str] = ("sliding_attention", "full_attention")
+    sliding_window: int = 64
+    rope_parameters: Optional[Mapping[str, Any]] = None
+    num_experts: int = 8
+    num_experts_per_tok: int = 2
+    moe_intermediate_size: int = 128
+    norm_topk_prob: bool = True
+    rms_norm_eps: float = 1e-6
+    tie_word_embeddings: bool = False
+    experts_held: Optional[Sequence[int]] = None
+
+    def held(self):
+        lo, hi = self.experts_held or (0, self.num_experts)
+        if not 0 <= lo < hi <= self.num_experts:
+            raise ValueError(f"experts_held {(lo, hi)} is no range within {self.num_experts} experts")
+        return int(lo), int(hi)
+
+    @nn.compact
+    def __call__(self, tokens, train: bool = False):
+        B, T = tokens.shape
+        unknown = sorted(set(self.layer_types) - set(LAYER_KINDS))
+        if unknown:
+            raise ValueError(f"unknown layer kinds {unknown}; have {LAYER_KINDS}")
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError("num_attention_heads must be a multiple of num_key_value_heads")
+        rope = self.rope_parameters or {
+            kind: {"rope_type": "default", "rope_theta": 10000.0} for kind in LAYER_KINDS}
+        with jax.named_scope("rope"):
+            tables = {kind: rotary_tables(rope[kind], self.head_dim, T)
+                      for kind in dict.fromkeys(self.layer_types)}
+        # unit-RMS embedding: under an RMS norm a 0.02 embedding is drowned by
+        # the attention branch's mean over the context, which every position
+        # shares, and a fresh router collapses onto a few experts
+        embed = nn.Embed(self.vocab_size, self.hidden_size, name="embed_tokens",
+                         embedding_init=nn.initializers.normal(1.0))
+        x = embed(tokens)
+        for i, kind in enumerate(self.layer_types):
+            x = DecoderLayer(
+                kind, self.num_attention_heads, self.num_key_value_heads, self.head_dim,
+                self.sliding_window, self.num_experts, self.num_experts_per_tok,
+                self.moe_intermediate_size, self.norm_topk_prob, self.rms_norm_eps,
+                self.held(), name=f"layers_{i}",
+            )(x, *tables[kind])
+        x = RMSNorm(self.rms_norm_eps, name="norm")(x)
+        if self.tie_word_embeddings:
+            return embed.attend(x)
+        return nn.Dense(self.vocab_size, use_bias=False, name="lm_head",
+                        kernel_init=nn.initializers.normal(0.02))(x)

@@ -82,14 +82,37 @@ def create(
 
         if kw.get("moe_experts"):
             raise ValueError(
-                "MoE transformers return (logits, aux) and train through "
-                "parallel/expert_parallel.py, not the federated ModelDef path"
+                "TransformerLM(moe_experts=...) returns (logits, aux) and "
+                "trains through parallel/expert_parallel.py; on the federated "
+                "ModelDef path routed experts are the 'decoder' model "
+                "(models/decoder.py: num_experts, num_experts_per_tok, "
+                "experts_held)"
             )
         kw.setdefault("max_len", int(input_shape[0]))
         m = TransformerLM(vocab_size=num_classes, **kw)
         return ModelDef(
             m, input_shape, num_classes, input_dtype=jnp.int32,
             name="transformer",
+        )
+
+    if name == "decoder":
+        # Spec-driven decoder (GQA, rotary/YaRN, window and full layers,
+        # routed experts); kw mirrors the source model's config.json keys,
+        # lists and nested mappings included (flax freezes them as given).
+        # Returns logits only and trains under task="nwp" like
+        # "transformer"; its expert layers' counters ride with the round's
+        # metrics (ModelDef.counters).
+        from fedml_tpu.models.decoder import COUNTERS, DecoderLM
+
+        m = DecoderLM(vocab_size=num_classes, **kw)
+        m.held()  # a share outside the experts fails here, not at first trace
+        return ModelDef(
+            m, input_shape, num_classes, input_dtype=jnp.int32, name="decoder",
+            counters=COUNTERS,
+            counter_attrs={
+                "hidden": m.hidden_size, "expert_width": m.moe_intermediate_size,
+                "layers": len(m.layer_types),
+            },
         )
 
     if name in ("resnet56", "resnet110"):
@@ -171,7 +194,7 @@ def create(
 
     raise KeyError(
         f"unknown model {model_name!r}; available: lr, cnn, cnn_dropout, rnn, "
-        "transformer, resnet56, resnet110, resnet18_gn..resnet152_gn, "
+        "transformer, decoder, resnet56, resnet110, resnet18_gn..resnet152_gn, "
         "mobilenet, mobilenet_v3, vgg11..vgg19(_bn), efficientnet, segnet, "
         "darts, mnistgan"
     )

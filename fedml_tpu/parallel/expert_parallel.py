@@ -15,6 +15,12 @@ capacity-factor all_to_all dispatch is the known upgrade path at large E.
 
 The reference has no MoE/EP (SURVEY §2g); first-class here per the task's
 multi-chip contract.
+
+This module is the mesh path. On the federated path (``FedAvgAPI``, the
+``ModelDef`` contract of logits only) routed experts train through the
+``decoder`` model (models/decoder.py): top-k routing over all experts,
+grouped products over the sorted pairs of the experts a chip holds
+(``experts_held``), no capacity and no dropped pair.
 """
 
 from __future__ import annotations

@@ -105,15 +105,46 @@ def make_ring_attention(mesh: Mesh, axis_name: str = "seq", causal: bool = False
     return jax.jit(fn)  # fedlint: disable=uncached-jit -- bespoke ring-attention kernel wrapper closed over the mesh; built once per benchmark run
 
 
-def full_attention(q, k, v, causal: bool = False):
-    """Reference O(T²) attention for correctness checks."""
+def full_attention(q, k, v, causal: bool = False, window: Optional[int] = None):
+    """Reference O(T²) attention: q [B, T, H, D], k/v [B, T, KV, D].
+
+    ``window`` keeps, beside the causal mask, only the keys with
+    ``i - j < window`` (sliding-window layers). With fewer key/value heads
+    than query heads (KV divides H) each key/value head serves H/KV query
+    heads, and the scores are accumulated in float32. With equal head counts
+    and no window the traced program is what it was before either existed."""
     D = q.shape[-1]
+    H, KV = q.shape[2], k.shape[2]
+    if H != KV:
+        return _grouped_query_attention(q, k, v, causal, window)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(
         jnp.asarray(D, jnp.float32)
     )
-    if causal:
-        T = q.shape[1]
-        mask = jnp.tril(jnp.ones((T, T), bool))
-        s = jnp.where(mask[None, None], s, _NEG_INF)
+    if causal or window is not None:
+        s = jnp.where(_attention_mask(q.shape[1], causal, window)[None, None], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p, v).astype(q.dtype)
+
+
+def _attention_mask(T: int, causal: bool, window: Optional[int]):
+    """[T, T] bool, query position by key position."""
+    mask = jnp.tril(jnp.ones((T, T), bool)) if causal else jnp.ones((T, T), bool)
+    if window is not None:
+        i = jax.lax.iota(jnp.int32, T)
+        mask = mask & (i[:, None] - i[None, :] < window)
+    return mask
+
+
+def _grouped_query_attention(q, k, v, causal: bool, window: Optional[int]):
+    B, T, H, D = q.shape
+    KV = k.shape[2]
+    if H % KV:
+        raise ValueError(f"{H} query heads do not divide over {KV} key/value heads")
+    qg = q.reshape(B, T, KV, H // KV, D)
+    s = jnp.einsum(
+        "bqkgd,bskd->bkgqs", qg, k, preferred_element_type=jnp.float32
+    ) / jnp.sqrt(jnp.asarray(D, jnp.float32))
+    if causal or window is not None:
+        s = jnp.where(_attention_mask(T, causal, window)[None, None, None], s, _NEG_INF)
+    p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+    return jnp.einsum("bkgqs,bskd->bqkgd", p, v).reshape(B, T, H, D).astype(q.dtype)

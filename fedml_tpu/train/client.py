@@ -59,7 +59,7 @@ def cast_floats(tree, dtype):
     )
 
 
-def make_mixed_forward(model: ModelDef, tc: TrainConfig):
+def make_mixed_forward(model: ModelDef, tc: TrainConfig, counters: bool = False):
     """The shared mixed-precision forward: fp32 master params are cast to
     ``tc.compute_dtype`` inside the differentiated function (the cast is
     linear, so grads come back fp32); logits are restored to fp32 so scan
@@ -72,7 +72,9 @@ def make_mixed_forward(model: ModelDef, tc: TrainConfig):
     augmentation runs here — inside jit, fused with the forward — so both
     the federated and centralized paths share one definition.
 
-    Returns ``fwd(params, extra, xb, step_rng) -> (logits_f32, new_extra_f32)``.
+    Returns ``fwd(params, extra, xb, step_rng) -> (logits_f32, new_extra_f32)``;
+    with ``counters=True`` a third item, the model's device counters of this
+    call as one float32 vector (``ModelDef.counters`` names its entries).
     Used by both the per-client local-train scan and the centralized DP
     trainer so the compute-dtype policy can never diverge between them."""
     from fedml_tpu.train.augment import resolve_augment
@@ -80,6 +82,8 @@ def make_mixed_forward(model: ModelDef, tc: TrainConfig):
     cdt = jnp.dtype(tc.compute_dtype)
     mixed = cdt != jnp.dtype(jnp.float32)
     augment_fn = resolve_augment(getattr(tc, "augment", "none"))
+    # asked for only where wanted: a model object without the keyword still applies
+    apply_kw = {"counters": True} if counters else {}
 
     def fwd(params, extra, xb, step_rng):
         if augment_fn is not None:
@@ -93,14 +97,14 @@ def make_mixed_forward(model: ModelDef, tc: TrainConfig):
             xb_c = cast_floats(xb, cdt)
         else:
             params_c, xb_c = params, xb
-        logits, new_vars = model.apply(
-            {"params": params_c, **extra}, xb_c, train=True, rng=step_rng
+        logits, new_vars, *counted = model.apply(
+            {"params": params_c, **extra}, xb_c, train=True, rng=step_rng, **apply_kw
         )
         logits = logits.astype(jnp.float32)
         if mixed:
             new_vars = cast_floats(new_vars, jnp.float32)
         _, new_extra = _split_vars(new_vars)
-        return logits, new_extra
+        return (logits, new_extra, *counted)
 
     return fwd
 
@@ -167,7 +171,10 @@ def make_local_train(
 
     Returned fn: ``(variables, x, y, mask, rng) -> (variables', metrics)`` with
     x [S, B, *feat], y [S, B, *lab], mask [S, B]. metrics are SUMS
-    {loss_sum, correct, count} so they aggregate exactly across clients.
+    {loss_sum, correct, count} so they aggregate exactly across clients; a
+    model that reports device counters (``ModelDef.counters``) adds one sum
+    per counter under its name, over the real steps, and a model that
+    reports none leaves the metrics as they are.
 
     ``external_prox=True`` prepends a parameter tree to the signature —
     ``(prox_ref_params, variables, x, y, mask, rng)`` — and points the
@@ -180,7 +187,8 @@ def make_local_train(
     """
     opt = build_client_optimizer(tc)
     task_loss = make_task_loss(task)
-    fwd = make_mixed_forward(model, tc)
+    counter_names = tuple(getattr(model, "counters", ()))
+    fwd = make_mixed_forward(model, tc, counters=bool(counter_names))
 
     def _local_train(variables, x, y, mask, rng, prox_ref=None):
         params0, extra0 = _split_vars(variables)
@@ -192,7 +200,7 @@ def make_local_train(
         m_flat = mask.reshape((n_flat,))
 
         def loss_fn(params, extra, xb, yb, mb, step_rng):
-            logits, new_extra = fwd(params, extra, xb, step_rng)
+            logits, new_extra, *counted = fwd(params, extra, xb, step_rng)
             task_l, correct, total = task_loss(logits, yb, mb)
             loss = task_l
             if tc.prox_mu:
@@ -201,7 +209,7 @@ def make_local_train(
                 )
             # task_l (not loss) feeds the metrics so FedProx runs report plain
             # task loss, comparable to FedAvg and the reference's logs.
-            return loss, (new_extra, task_l, correct, total)
+            return loss, (new_extra, task_l, correct, total, *counted)
 
         grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
@@ -231,7 +239,7 @@ def make_local_train(
                     params, extra, opt_state = carry
                     step_rng = jax.random.fold_in(ep_rng, sidx)
                     with jax.named_scope("forward_backward"):
-                        (_, (new_extra, task_l, correct, total)), grads = grad_fn(
+                        (_, (new_extra, task_l, correct, total, *counted)), grads = grad_fn(
                             params, extra, xb, yb, mb, step_rng
                         )
                     with jax.named_scope("optimizer_update"):
@@ -242,6 +250,8 @@ def make_local_train(
                     mets = jnp.stack(
                         [task_l * total, correct, total, jnp.float32(1)]
                     )
+                    if counted:
+                        mets = jnp.concatenate([mets, counted[0]])
                     return (new_params, new_extra, new_opt_state), mets
 
                 if skip_empty_steps:
@@ -251,7 +261,7 @@ def make_local_train(
                     # ~nothing, which is what lets fused round chunks pad
                     # every round to a shared step count for free.
                     def skip_step(carry):
-                        return carry, jnp.zeros((4,), jnp.float32)
+                        return carry, jnp.zeros((4 + len(counter_names),), jnp.float32)
 
                     return jax.lax.cond(has_data, real_step, skip_step, carry)
 
@@ -293,6 +303,7 @@ def make_local_train(
             "count": mets[2],
             "steps": mets[3],
         }
+        metrics.update({name: mets[4 + i] for i, name in enumerate(counter_names)})
         return {"params": params, **extra}, metrics
 
     if external_prox:

@@ -1,0 +1,341 @@
+"""The spec-driven decoder (models/decoder.py) against its plain reference
+(benchmarks/configs/mellum2-12b-a2.5b_ref.py), at small sizes on the CPU in
+float32 with seeded random weights: loss and every leaf's gradient, the
+grouped form of the expert layer against the dense masked form, the shares
+of an expert-parallel deployment against the uncut layer, and one federated
+round under both client schedules with the expert counters on the ``flush``
+span."""
+
+import copy
+import dataclasses
+import importlib.util
+import pathlib
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from fedml_tpu.compile import model_fingerprint
+from fedml_tpu.models import create_model
+from fedml_tpu.models.decoder import COUNTERS, grouped_dot, routed_experts
+from fedml_tpu.parallel.ring_attention import full_attention
+
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(ROOT))
+from benchmarks.lib import fedavg_ref  # noqa: E402
+
+YARN = {"rope_type": "yarn", "rope_theta": 500000, "factor": 16,
+        "original_max_position_embeddings": 16, "beta_fast": 32, "beta_slow": 1,
+        "attention_factor": 1.2772588722239782}
+PLAIN = {"rope_type": "default", "rope_theta": 500000}
+BASE = dict(
+    hidden_size=32, num_attention_heads=4, num_key_value_heads=4, head_dim=8,
+    layer_types=["full_attention"], sliding_window=64,
+    rope_parameters={"full_attention": PLAIN, "sliding_attention": PLAIN},
+    num_experts=4, num_experts_per_tok=2, moe_intermediate_size=16,
+    norm_topk_prob=True, rms_norm_eps=1e-6, tie_word_embeddings=False,
+)
+CASES = {
+    "window_shorter_than_sequence": dict(layer_types=["sliding_attention"], sliding_window=5),
+    "four_query_heads_on_two_kv_heads": dict(num_key_value_heads=2),
+    "yarn_layer_and_plain_rotary_layer": dict(
+        layer_types=["sliding_attention", "full_attention"], sliding_window=6,
+        rope_parameters={"full_attention": YARN, "sliding_attention": PLAIN}),
+    "top2_of_8_with_4_held": dict(num_experts=8, experts_held=[2, 6]),
+    "all_experts_held": dict(num_experts=8, num_experts_per_tok=3),
+}
+VOCAB, LENGTH = 61, 24
+
+
+def reference():
+    path = ROOT / "benchmarks" / "configs" / "mellum2-12b-a2.5b_ref.py"
+    spec = importlib.util.spec_from_file_location("mellum_ref", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def config(**over):
+    return {"model": {"name": "decoder", "dataset": "random_tokens", "input_shape": [LENGTH],
+                      "num_classes": VOCAB, "kwargs": dict(copy.deepcopy(BASE), **over)}}
+
+
+def nest(flat):
+    tree = {}
+    for name, leaf in flat.items():
+        node = tree
+        *parents, last = name.split("/")
+        for p in parents:
+            node = node.setdefault(p, {})
+        node[last] = leaf
+    return tree
+
+
+def flatten(tree):
+    return {"/".join(str(k.key) for k in path): leaf
+            for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_loss_and_every_gradient_match_the_reference(case):
+    """Both sides are exact float32 on the CPU, so they differ by the order
+    of their sums only: 2e-5 of a leaf's largest gradient (measured: under
+    5e-7 in every case), 1e-6 of the loss (measured: equal). A top-k choice
+    flipped, or a slot's weight off, shows as 1e-2 and more."""
+    ref = reference()
+    cfg = config(**CASES[case])
+    m = cfg["model"]
+    model = create_model(m["name"], m["dataset"], (LENGTH,), VOCAB, **m["kwargs"])
+    flat = ref.init_params(5, cfg)
+    have = jax.eval_shape(model.init, jax.random.PRNGKey(0))["params"]
+    assert {k: v.shape for k, v in flatten(have).items()} == ref.param_shapes(cfg)
+    doc = jax.random.randint(jax.random.PRNGKey(9), (3, LENGTH + 1), 1, VOCAB)
+    x, y = doc[:, :-1], doc[:, 1:]
+    mask = jnp.ones((3,), jnp.float32)
+
+    def program_loss(flat):
+        logits, _ = model.apply({"params": nest(flat)}, x, train=True)
+        return fedavg_ref.task_loss("nwp", logits, y, mask)[0]
+
+    def reference_loss(flat):
+        return fedavg_ref.task_loss(
+            "nwp", ref.logits_fn(flat, x, fedavg_ref.REFERENCE, cfg), y, mask)[0]
+
+    loss_p, grad_p = jax.value_and_grad(program_loss)(flat)
+    loss_r, grad_r = jax.value_and_grad(reference_loss)(flat)
+    assert abs(float(loss_p) - float(loss_r)) <= 1e-6 * abs(float(loss_r))
+    for name in grad_r:
+        scale = float(jnp.max(jnp.abs(grad_r[name])))
+        assert scale > 0, name
+        gap = float(jnp.max(jnp.abs(grad_p[name] - grad_r[name])))
+        assert gap <= 2e-5 * scale, (name, gap, scale)
+
+
+def expert_weights(key, d=16, f=24, experts=8):
+    ks = jax.random.split(key, 5)
+    return (jax.random.normal(ks[0], (40, d)), jax.random.normal(ks[1], (d, experts)),
+            0.3 * jax.random.normal(ks[2], (experts, d, f)),
+            0.3 * jax.random.normal(ks[3], (experts, d, f)),
+            0.3 * jax.random.normal(ks[4], (experts, f, d)))
+
+
+def dense_masked(x, router, gate, up, down, top_k, lo, hi):
+    """Every held expert on every token, weighted by the renormalised top-k
+    probability of the slots that chose it (0 where none did)."""
+    probs = jax.nn.softmax(x @ router, axis=-1)
+    values, experts = jax.lax.top_k(probs, top_k)
+    values = values / jnp.sum(values, axis=-1, keepdims=True)
+    out = jnp.zeros_like(x)
+    for e in range(lo, hi):
+        weight = jnp.sum(jnp.where(experts == e, values, 0.0), axis=-1, keepdims=True)
+        out = out + weight * ((jax.nn.silu(x @ gate[e]) * (x @ up[e])) @ down[e])
+    return out
+
+
+@pytest.mark.parametrize("held", [(0, 8), (0, 4), (3, 5)])
+def test_grouped_form_matches_dense_masked_form(held):
+    """Value and gradients towards the tokens, the router and the held
+    experts' weights; float32 both ways, 1e-5 of the largest entry for the
+    different order of the sums."""
+    lo, hi = held
+    x, router, gate, up, down = expert_weights(jax.random.PRNGKey(3))
+
+    def grouped(x, router, gate, up, down):
+        y, counters = routed_experts(x, router, gate[lo:hi], up[lo:hi], down[lo:hi],
+                                     top_k=3, held_from=lo)
+        return jnp.sum(jnp.sin(y)), counters
+
+    def dense(x, router, gate, up, down):
+        return jnp.sum(jnp.sin(dense_masked(x, router, gate, up, down, 3, lo, hi)))
+
+    (vg, counters), gg = jax.value_and_grad(grouped, argnums=(0, 1, 2, 3, 4), has_aux=True)(
+        x, router, gate, up, down)
+    vd, gd = jax.value_and_grad(dense, argnums=(0, 1, 2, 3, 4))(x, router, gate, up, down)
+    assert abs(float(vg - vd)) <= 1e-5 * abs(float(vd))
+    for a, b in zip(gg, gd):
+        assert float(jnp.max(jnp.abs(a - b))) <= 1e-5 * float(jnp.max(jnp.abs(b))) + 1e-7
+    c = dict(zip(COUNTERS, np.asarray(counters)))
+    assert c["moe_dropped"] == 0 and c["moe_rows"] == 40 * 3
+    assert c["moe_pairs"] == 120 if held == (0, 8) else c["moe_pairs"] < 120
+    assert c["moe_load_mean"] == c["moe_pairs"] / (hi - lo) <= c["moe_load_max"]
+
+
+def test_the_eight_shares_add_up_to_the_uncut_layer():
+    """model-configs guide, section 4: 16 experts over 8 chips, 2 each. The
+    shares' expert outputs, nothing counted twice (the layer has no shared
+    expert and the router's weights are every chip's alike), add up to what
+    the layer gives with every expert held, and their held pairs to all
+    tokens x top-k. 1e-5: the order of eight partial sums in float32."""
+    x, router, gate, up, down = expert_weights(jax.random.PRNGKey(4), experts=16)
+    whole, counted = routed_experts(x, router, gate, up, down, top_k=4)
+    parts, pairs = jnp.zeros_like(whole), 0.0
+    for chip in range(8):
+        lo = 2 * chip
+        y, c = routed_experts(x, router, gate[lo:lo + 2], up[lo:lo + 2], down[lo:lo + 2],
+                              top_k=4, held_from=lo)
+        parts, pairs = parts + y, pairs + float(c[0])
+    assert pairs == float(counted[0]) == 40 * 4
+    assert float(jnp.max(jnp.abs(parts - whole))) <= 1e-5 * float(jnp.max(jnp.abs(whole)))
+
+
+def test_grouped_dot_batches_over_clients_with_unbatched_weights():
+    """What the clients' vmap does on its first pass over the local steps'
+    scan: rows and sizes per client, weights not yet."""
+    rows = jax.random.normal(jax.random.PRNGKey(0), (3, 12, 5))
+    weights = jax.random.normal(jax.random.PRNGKey(1), (2, 5, 4))
+    sizes = jnp.asarray([[4, 6], [0, 12], [5, 0]], jnp.int32)
+    got = jax.vmap(grouped_dot, in_axes=(0, None, 0))(rows, weights, sizes)
+    for c in range(3):
+        want = jax.lax.ragged_dot(rows[c], weights, sizes[c])
+        live = int(sizes[c].sum())
+        assert jnp.allclose(got[c, :live], want[:live], atol=1e-6)
+
+
+def naive_attention(q, k, v, window):
+    B, T, H, D = q.shape
+    k = jnp.repeat(k, H // k.shape[2], axis=2)
+    v = jnp.repeat(v, H // v.shape[2], axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(D)
+    i, j = np.arange(T)[:, None], np.arange(T)[None, :]
+    keep = (j <= i) & ((i - j < window) if window else True)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(jnp.where(keep, s, -1e30), -1), v)
+
+
+@pytest.mark.parametrize("kv_heads,window", [(4, 3), (2, None), (2, 5), (1, 16)])
+def test_full_attention_window_and_grouped_queries(kv_heads, window):
+    ks = jax.random.split(jax.random.PRNGKey(2), 3)
+    q = jax.random.normal(ks[0], (2, 16, 4, 8))
+    k = jax.random.normal(ks[1], (2, 16, kv_heads, 8))
+    v = jax.random.normal(ks[2], (2, 16, kv_heads, 8))
+    got = full_attention(q, k, v, causal=True, window=window)
+    assert jnp.allclose(got, naive_attention(q, k, v, window), atol=2e-6)
+
+
+def test_full_attention_traces_as_before_without_window_or_groups():
+    """``gpt2-124m.silo4`` runs this path: the jaxpr of a call with equal
+    head counts and no window is the one the function gave before it knew
+    either (its body then, restated here)."""
+
+    def before(q, k, v):
+        D = q.shape[-1]
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(jnp.asarray(D, jnp.float32))
+        T = q.shape[1]
+        mask = jnp.tril(jnp.ones((T, T), bool))
+        s = jnp.where(mask[None, None], s, -1e30)
+        p = jax.nn.softmax(s, axis=-1)
+        return jnp.einsum("bhqk,bkhd->bqhd", p, v).astype(q.dtype)
+
+    q = jnp.zeros((2, 8, 4, 8), jnp.bfloat16)
+    now = jax.make_jaxpr(lambda q, k, v: full_attention(q, k, v, causal=True))(q, q, q)
+    assert str(now) == str(jax.make_jaxpr(before)(q, q, q))
+
+
+def test_create_model_builds_it_and_the_spec_arrives_whole():
+    spec = dict(CASES["yarn_layer_and_plain_rotary_layer"], experts_held=[1, 3])
+    model = create_model("decoder", "random_tokens", (LENGTH,), VOCAB, **dict(BASE, **spec))
+    assert tuple(model.module.layer_types) == ("sliding_attention", "full_attention")
+    assert dict(model.module.rope_parameters["full_attention"]) == YARN
+    assert model.counters == COUNTERS and model.counter_attrs["layers"] == 2
+    params = model.init(jax.random.PRNGKey(0))
+    assert set(params) == {"params"}
+    assert params["params"]["layers_1"]["experts_gate"].shape == (2, 32, 16)
+    logits, _ = model.apply(params, jnp.ones((2, LENGTH), jnp.int32), train=False)
+    assert logits.shape == (2, LENGTH, VOCAB)
+    assert create_model("decoder", "x", (16,), 50).name == "decoder"  # the CLI's call
+
+
+@pytest.mark.parametrize("change", [
+    dict(layer_types=["full_attention", "sliding_attention"]),
+    dict(rope_parameters={"full_attention": dict(YARN, factor=8), "sliding_attention": PLAIN}),
+    dict(experts_held=[0, 2]),
+    dict(sliding_window=7),
+])
+def test_model_fingerprint_tells_two_specs_apart(change):
+    spec = dict(BASE, **CASES["yarn_layer_and_plain_rotary_layer"])
+    one = create_model("decoder", "random_tokens", (LENGTH,), VOCAB, **spec)
+    same = create_model("decoder", "random_tokens", (LENGTH,), VOCAB, **copy.deepcopy(spec))
+    other = create_model("decoder", "random_tokens", (LENGTH,), VOCAB, **dict(spec, **change))
+    assert model_fingerprint(one) == model_fingerprint(same)
+    assert model_fingerprint(one) != model_fingerprint(other)
+
+
+def test_transformer_moe_message_points_to_the_decoder():
+    with pytest.raises(ValueError, match="'decoder' model"):
+        create_model("transformer", "shakespeare", (80,), 90, moe_experts=4)
+
+
+def one_round(mode):
+    from fedml_tpu.algorithms import FedAvgAPI
+    from fedml_tpu.config import DataConfig, FedConfig, RunConfig, TrainConfig
+    from fedml_tpu.data.base import FederatedDataset
+    from fedml_tpu.telemetry import get_tracer
+
+    docs = np.random.default_rng(0).integers(1, VOCAB, size=(3, 4, LENGTH + 1), dtype=np.int32)
+    data = FederatedDataset(
+        name="random_tokens", client_x=list(docs[:, :, :-1]), client_y=list(docs[:, :, 1:]),
+        test_x=docs[0, :2, :-1], test_y=docs[0, :2, 1:], num_classes=VOCAB)
+    model = create_model("decoder", "random_tokens", (LENGTH,), VOCAB,
+                         **dict(BASE, **CASES["top2_of_8_with_4_held"]))
+    cfg = RunConfig(
+        data=DataConfig(batch_size=2, pad_bucket=1),
+        fed=FedConfig(client_num_in_total=3, client_num_per_round=3, comm_round=1, epochs=1,
+                      client_parallelism=mode),
+        train=TrainConfig(client_optimizer="sgd", lr=0.05), model="decoder", seed=1,
+    )
+    tracer = get_tracer()
+    t0 = tracer.now_us()
+    api = FedAvgAPI(cfg, data, model, task="nwp", log_fn=lambda row: None)
+    api.train()
+    flushes = [e.attrs for e in tracer.events() if e.name == "flush" and e.ts_us >= t0]
+    return api, flushes
+
+
+def test_a_federated_round_is_the_same_under_vmap_and_scan_and_counts_its_experts():
+    vmapped, flushes = one_round("vmap")
+    scanned, _ = one_round("scan")
+    assert (vmapped._client_mode, scanned._client_mode) == ("vmap", "scan")
+    for a, b in zip(jax.tree_util.tree_leaves(vmapped.global_vars),
+                    jax.tree_util.tree_leaves(scanned.global_vars)):
+        # the same float32 steps, batched or one client after another
+        assert jnp.allclose(a, b, rtol=0, atol=1e-6)
+    assert len(flushes) == 1
+    attrs = flushes[0]
+    tokens, layers, top_k = 3 * 4 * LENGTH, 1, 2
+    assert attrs["moe_dropped"] == 0
+    assert attrs["moe_rows"] == tokens * layers * top_k
+    assert 0 < attrs["moe_pairs"] <= attrs["moe_rows"]
+    assert attrs["moe_load_mean"] * 4 == attrs["moe_pairs"]
+    assert attrs["moe_load_max"] >= attrs["moe_load_mean"]
+    assert (attrs["hidden"], attrs["expert_width"], attrs["layers"]) == (32, 16, 1)
+
+
+def test_a_model_without_counters_keeps_its_metrics_and_flush_span():
+    from fedml_tpu.config import TrainConfig
+    from fedml_tpu.train.client import make_local_train
+
+    model = create_model("lr", "synthetic", (6,), 3)
+    assert model.counters == ()
+    train = make_local_train(model, TrainConfig(lr=0.1), epochs=1)
+    variables = model.init(jax.random.PRNGKey(0))
+    x = jnp.ones((2, 4, 6))
+    _, metrics = train(variables, x, jnp.zeros((2, 4), jnp.int32), jnp.ones((2, 4)),
+                       jax.random.PRNGKey(1))
+    assert sorted(metrics) == ["correct", "count", "loss_sum", "steps"]
+    out, same, counted = model.apply(variables, x[0], train=True, counters=True)
+    assert counted.shape == (0,) and same is variables
+
+
+def test_auto_takes_scan_for_a_gigabyte_of_parameters_and_vmap_below():
+    from fedml_tpu.algorithms.fedavg import resolve_client_parallelism
+
+    big = dataclasses.replace(create_model("lr", "synthetic", (6,), 3))
+    big.init = lambda rng: {"params": {"w": jax.ShapeDtypeStruct((2**28,), jnp.float32)}}
+    below = dataclasses.replace(big)
+    below.init = lambda rng: {"params": {"w": jax.ShapeDtypeStruct((2**28 - 1,), jnp.float32)}}
+    assert resolve_client_parallelism("auto", big) == "scan"
+    assert resolve_client_parallelism("auto", below) == "vmap"
+    gpt2 = create_model("transformer", "random_tokens", (1024,), 50257,
+                        num_layers=12, num_heads=12, embed_dim=768)
+    assert resolve_client_parallelism("auto", gpt2) == "vmap"
