@@ -541,6 +541,20 @@ class FedAvgAPI:
         # ``fedml.<name>`` annotation, on the profiler's clock
         self._tracer.annotate = span_annotation
         self.health = ClientHealthRegistry.from_config(config)
+        # How many of the round program's attention call sites take the
+        # blockwise kernel at the training length, and all of them: host
+        # numbers from the shapes alone, carried by every ``flush`` span.
+        self._attention_attrs = {}
+        if model.attention_sites:
+            # imported here: a model without attention pays no Pallas import
+            from fedml_tpu.ops.attention import takes_kernel
+
+            self._attention_attrs = {
+                "attn_kernel_sites": sum(
+                    takes_kernel(model.input_shape[0], *site)
+                    for site in model.attention_sites),
+                "attn_sites": len(model.attention_sites),
+            }
         # Scheduler: policy-driven cohort selection (FedConfig.selection /
         # .overprovision_factor, scheduler/policies.py). It shares this
         # API's health registry (straggler_aware consults the straggler
@@ -1332,6 +1346,8 @@ class FedAvgAPI:
             "flush", first_round=rounds[0], last_round=rounds[-1],
             rows=len(rounds),
         ) as flush:
+            for name, value in self._attention_attrs.items():
+                flush.set_attr(name, value)
             # the one device-to-host fetch: it returns when the device has
             # finished every round flushed here, so this is the wait, not
             # host work — and where a drained device idles. The stacking is

@@ -35,6 +35,10 @@ class ModelDef:
     # the model's constants a reader of the counters needs beside them.
     counters: Tuple[str, ...] = ()
     counter_attrs: dict = dataclasses.field(default_factory=dict)
+    # One (query heads, key/value heads, head dim) per call of
+    # ``ops/attention.attention`` in a forward pass: with the sequence length
+    # it is what ``ops/attention.takes_kernel`` decides each call from.
+    attention_sites: Tuple[Tuple[int, int, int], ...] = ()
 
     def init(self, rng) -> dict:
         dummy = jnp.zeros((1,) + tuple(self.input_shape), dtype=self.input_dtype)

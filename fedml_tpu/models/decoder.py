@@ -11,7 +11,8 @@ models, written from the keys of a Hugging Face ``config.json``.
 - Grouped-query attention: ``num_attention_heads`` query heads of
   ``head_dim`` on ``num_key_value_heads`` key/value heads (the query width
   need not equal ``hidden_size``), through the one attention core
-  ``parallel/ring_attention.full_attention``.
+  ``ops/attention.attention`` (the blockwise kernel at training lengths, the
+  plain form at short ones).
 - Rotate-half rotary embeddings on every dim of q and k, one table per layer
   kind from ``rope_parameters[kind]``: ``rope_type`` ``default`` or ``yarn``
   (as HF's ``_compute_yarn_parameters``: blended frequencies, cos and sin
@@ -44,7 +45,7 @@ import jax
 import jax.numpy as jnp
 from jax.custom_batching import custom_vmap
 
-from fedml_tpu.parallel.ring_attention import full_attention
+from fedml_tpu.ops.attention import attention
 
 LAYER_KINDS = ("full_attention", "sliding_attention")
 
@@ -271,7 +272,7 @@ class DecoderLayer(nn.Module):
             q, k = apply_rotary(q, cos, sin), apply_rotary(k, cos, sin)
         sliding = self.kind == "sliding_attention"
         with jax.named_scope("attention_sliding" if sliding else "attention_full"):
-            a = full_attention(
+            a = attention(
                 q, k, v, causal=True, window=self.sliding_window if sliding else None)
         with jax.named_scope("out"):
             x = x + jnp.dot(a.reshape(B, T, H * D), self.param("o_proj", init, (H * D, d)))
@@ -279,7 +280,7 @@ class DecoderLayer(nn.Module):
         # Recomputed in the backward pass, not kept: the tokens x top-k rows
         # of the sorted copies, of both hidden products and of the output are
         # a gigabyte a layer at 4 096 tokens of width 2 304 and top-8, as
-        # much again as the attention probabilities that have to stay.
+        # much again as the attention probabilities would take if kept.
         experts = jax.checkpoint(functools.partial(
             routed_experts, top_k=self.num_experts_per_tok,
             norm_topk_prob=self.norm_topk_prob, held_from=lo))

@@ -78,7 +78,7 @@ def create(
         # in-repo NLP ceiling is the 2-layer LSTM). num_classes = vocab
         # size; trains under task="nwp" like the RNNs, so every federated
         # algorithm (FedAvg/FedOpt/FedProx/...) runs it unchanged.
-        from fedml_tpu.models.transformer import TransformerLM
+        from fedml_tpu.models.transformer import TransformerLM, causal_attention
 
         if kw.get("moe_experts"):
             raise ValueError(
@@ -90,9 +90,11 @@ def create(
             )
         kw.setdefault("max_len", int(input_shape[0]))
         m = TransformerLM(vocab_size=num_classes, **kw)
+        site = (m.num_heads, m.num_heads, m.embed_dim // m.num_heads)
         return ModelDef(
             m, input_shape, num_classes, input_dtype=jnp.int32,
             name="transformer",
+            attention_sites=(site,) * m.num_layers if m.attn_fn is causal_attention else (),
         )
 
     if name == "decoder":
@@ -113,6 +115,9 @@ def create(
                 "hidden": m.hidden_size, "expert_width": m.moe_intermediate_size,
                 "layers": len(m.layer_types),
             },
+            attention_sites=(
+                (m.num_attention_heads, m.num_key_value_heads, m.head_dim),
+            ) * len(m.layer_types),
         )
 
     if name in ("resnet56", "resnet110"):

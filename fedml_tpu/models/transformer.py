@@ -4,7 +4,7 @@ LSTMs, fedml_api/model/nlp/rnn.py; SURVEY §5 marks sequence parallelism
 absent).
 
 The attention callable is injected so the SAME module runs single-chip
-(full causal attention) or sequence-parallel (ring attention inside
+(causal attention through ``ops/attention.py``) or sequence-parallel (ring attention inside
 shard_map — parallel/long_context.py). Pre-LN blocks, learned positional
 embeddings indexed by GLOBAL position (the seq-sharded path passes each
 shard's offset), GELU MLP. bfloat16-friendly: all matmuls keep bf16 inputs
@@ -21,9 +21,9 @@ from fedml_tpu.models.norms import fp32_layer_norm
 import jax
 import jax.numpy as jnp
 
-from fedml_tpu.parallel.ring_attention import full_attention
+from fedml_tpu.ops.attention import attention
 
-causal_full_attention = functools.partial(full_attention, causal=True)
+causal_attention = functools.partial(attention, causal=True)
 
 
 class MoEMLP(nn.Module):
@@ -75,7 +75,7 @@ class TransformerBlock(nn.Module):
 
     num_heads: int
     mlp_ratio: int = 4
-    attn_fn: Callable = causal_full_attention
+    attn_fn: Callable = causal_attention
     moe_experts: int = 0
     moe_stats_axis: Optional[str] = None
 
@@ -118,7 +118,7 @@ class TransformerLM(nn.Module):
     num_heads: int = 4
     embed_dim: int = 128
     max_len: int = 4096
-    attn_fn: Callable = causal_full_attention
+    attn_fn: Callable = causal_attention
     moe_experts: int = 0
     moe_stats_axis: Optional[str] = None
 

@@ -1,47 +1,65 @@
-"""Blockwise (flash) attention as Pallas TPU kernels.
+"""Blockwise (flash) attention as Pallas TPU kernels: the attention core of
+the transformer models' training step (``ops/attention.py`` chooses between
+it and the plain form by the shapes it is handed).
 
-Standard FlashAttention blocking (public algorithm: Dao et al. 2022; online
-softmax per Milakov & Gionis) written for the TPU memory hierarchy: Q/K/V
-blocks stream HBM→VMEM via the grid's BlockSpecs, scores/probabilities never
-materialise in HBM (the S×S matrix XLA would allocate), and every matmul is
-MXU-shaped. Forward saves the log-sum-exp rows; backward recomputes P
-blockwise and accumulates dQ/dK/dV in two passes (dQ over K blocks; dK/dV
-over Q blocks).
+The FlashAttention recipe (public algorithm: Dao et al. 2022) cut to the
+sequence lengths the models train at: scores and probabilities live in VMEM
+only (the T×T matrix the plain form writes to HBM and reads back), forward
+keeps the output and one log-sum-exp per query row, and ONE backward kernel
+recomputes P chunk by chunk and gives dQ, dK and dV from that one
+recomputation (in the transposed frame, scores [keys, queries], so that the
+row statistics broadcast as they are stored and only dQ's product needs a
+transpose, of dS).
 
-The reference has no attention op at all (its NLP models are LSTMs,
-rnn.py:5-38); this kernel exists for the framework's long-context leg —
-it is the per-shard compute core under sequence-parallel ring attention
-(parallel/ring_attention.py) and the transformer LM (models/transformer.py).
+Geometry. A program instance holds the whole sequence of one batch row and
+one lane tile of heads (``MAX_LENGTH`` bounds it by VMEM), and walks it in
+row chunks of ``CHUNK`` queries with everything static: for each row chunk
+the key chunks the mask empties are not there at all, the run of key chunks
+it leaves whole is ONE wide product with no mask arithmetic, and only the
+chunks the mask cuts compute it. A row chunk sees all its keys in one
+step, so the softmax is taken in one pass: no running maximum, no
+rescaling. (On one v5e a grid over key blocks with the online softmax took
+27–45 % longer at the same shapes: PERF.md section 6, PR 29.)
+
+Layout. The kernels read ``[B, T, H·D]`` — the ``[B, T, H, D]`` the models
+hold, reshaped for free — so nothing is transposed around the call. A
+program instance takes the fewest query heads whose lanes make whole
+128-lane tiles (one head of 128, two of 64): each head's scores come from a
+product over the whole tile with the other heads' lanes zeroed, which is
+what a contraction of 64 costs the 128-deep MXU anyway, and each head's
+output lanes are selected from a product that is a whole tile wide.
+Grouped-query attention maps a query tile to its K/V tile in the block
+index (K and V are not repeated H/KV times) and dK/dV sum over the group
+inside the kernel; where heads are narrower than a tile a K/V head is
+repeated to fill the tile its query heads read (twice at D = 64).
+
+Masks. Causal and sliding-window (``i - j < window``) masks have ONE
+definition (``_Cfg.valid``) for both kernels, and one list of live key
+pieces per row chunk (``_Cfg.pieces``).
+
+Precision. Scores, softmax statistics and every accumulator are float32;
+matmul operands stay in the dtype they arrive in (bfloat16 in the training
+cells, float32 in the tests): P and dS are rounded to it for their
+products, as the plain form rounds P.
 
 Interpret mode is the CPU TEST route only: ``interpret=None`` resolves
 from the backend, once, to "compiled" on a TPU and "interpret" elsewhere.
 There is no second path behind it — on a TPU a kernel that fails to lower
 raises (chip_smoke.py asserts the ``tpu_custom_call`` is in the program).
 
-Recorded before PR 8 on a v5e (bf16, causal, block 512; the shared chip
-showed ~2× bimodal throughput windows so only interleaved A/B differences
-were trusted) — NOT re-measured on today's code, see PERF.md:
-
-- FORWARD-only, the kernel is at parity with XLA's attention lowering —
-  XLA on TPU already avoids materialising the S×S scores (S=4096:
-  ~11 ms both in the round-3 measurement, which used D=128; the training
-  rows below use H=8 D=64, so the two sets of absolute numbers are not
-  comparable to each other).
-- The TRAINING step (fwd+bwd, H=8 D=64) is where the kernel wins:
-  reverse-mode AD of plain jnp attention saves the S×S probabilities as
-  a residual (H·S²·2 bytes — 2.1 GB at S=8192), while this kernel's
-  custom VJP recomputes P blockwise. Interleaved best-of-5, twice
-  reproduced: parity at S=4096, ~3× faster at S=8192 (116 vs 341 ms
-  wall incl. ~100 ms of host fetch), ~1.35× at S=16384 (where XLA
-  evidently switches to a rematerialising schedule itself).
-
-Small blocks (≤256) are pathological (revisit overhead); keep ≥512 on
-hardware."""
+Measured on one TPU v5e (PERF.md section 6, PR 29; forward + gradient,
+bfloat16): 2.18 ms at ``gpt2-124m.silo4``'s step (4 clients x [4, 1024, 12
+heads of 64]) against the plain form's 7.58 ms and 3.29 ms for the fastest
+kernel JAX ships (splash attention, fused backward, with the transposes it
+needs); 2.05 ms at ``mellum2-12b-a2.5b.silo2``'s window layer ([2, 2048,
+32/4 heads of 128], window 1 024) against 11.24 ms and 2.75 ms."""
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -49,28 +67,127 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
+_LANES = 128
+_STAT_ROWS = 8  # float32 sublanes of one tile: the statistics' block height
+_VMEM_LIMIT = 96 * 1024 * 1024  # of a v5e core's 128 MiB; the default scope is 16 MiB
+
+# Queries a row chunk, and the longest sequence a program instance holds (at
+# 8 192 the forward kernel's chunks want 123 MB of VMEM). 256 ran 4–8 %
+# faster than 512 and 21–25 % faster than 1 024 at both cells' shapes.
+CHUNK = 256
+MAX_LENGTH = 4096
 
 
 def _use_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _masked_scores(q, k, qi, ki, *, scale, causal, block_q, block_k):
-    """Scaled scores for one (Q block, K block) pair with the causal mask —
-    the ONE definition shared by forward and both backward kernels (a
-    divergence here is the classic silent fwd/bwd gradient mismatch)."""
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale  # [Bq, Bk]
-    if causal:
-        rows = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0
+def heads_per_tile(H: int, D: int) -> int:
+    """Query heads one program instance takes: the fewest whose ``D`` lanes
+    make whole 128-lane tiles, or all ``H`` (the block is then the array's
+    full width, which any width may be) where no divisor of ``H`` does."""
+    return next(
+        (hp for hp in range(1, H + 1) if H % hp == 0 and (hp * D) % _LANES == 0), H
+    )
+
+
+@dataclasses.dataclass(frozen=True)
+class _Cfg:
+    """What the two kernels share, all static."""
+
+    head_dim: int
+    heads: int        # query heads a program instance takes (one tile's)
+    group_tiles: int  # query tiles that read one K/V tile
+    causal: bool
+    window: Optional[int]
+    chunk_q: int
+    chunk_k: int
+    length_q: int
+    length_k: int
+    interpret: bool
+
+    @property
+    def scale(self) -> float:
+        return 1.0 / math.sqrt(self.head_dim)
+
+    def row_chunks(self):
+        return range(0, self.length_q, self.chunk_q)
+
+    def pieces(self, r0: int):
+        """The keys that queries ``[r0, r0 + chunk_q)`` see, as
+        ``(c0, c1, cut)`` column ranges: key chunks the mask empties are
+        left out, a run of chunks it leaves whole is one piece, and a chunk
+        it cuts is a piece of its own that needs the mask arithmetic."""
+        r1, out = r0 + self.chunk_q - 1, []
+        for c0 in range(0, self.length_k, self.chunk_k):
+            c1 = c0 + self.chunk_k - 1
+            live = whole = True
+            if self.causal:
+                live, whole = live and c0 <= r1, whole and c1 <= r0
+            if self.window is not None:
+                live, whole = live and r0 - c1 < self.window, whole and r1 - c0 < self.window
+            if not live:
+                continue
+            if whole and out and not out[-1][2] and out[-1][1] == c0:
+                out[-1] = (out[-1][0], c1 + 1, False)
+            else:
+                out.append((c0, c1 + 1, not whole))
+        return out
+
+    def valid(self, r0: int, c0: int, c1: int, q_axis: int):
+        """bool of the (query, key) pairs the mask keeps, queries from
+        ``r0`` and keys ``[c0, c1)`` — the ONE definition forward and
+        backward share (a divergence here is the classic silent fwd/bwd
+        gradient mismatch). ``q_axis`` is the axis the queries run along
+        (0; 1 in the backward kernel's transposed frame)."""
+        shape = (self.chunk_q, c1 - c0) if q_axis == 0 else (c1 - c0, self.chunk_q)
+        # query position minus key position
+        gap = (
+            jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+            - jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+            + (r0 - c0)
         )
-        cols = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
-        s = jnp.where(rows >= cols, s, _NEG_INF)
-    return s
+        ok = gap >= 0 if self.causal else None
+        if self.window is not None:
+            inside = gap < self.window
+            ok = inside if ok is None else ok & inside
+        return ok
+
+    def only(self, x, i):
+        """``x`` [rows, tile] with every lane but head ``i``'s zeroed."""
+        if self.heads == 1:
+            return x
+        return jnp.where(self._head_lanes(x.shape, i), x, jnp.zeros_like(x))
+
+    def pick(self, i, new, old):
+        """``new`` on head ``i``'s lanes and ``old`` on the others."""
+        if self.heads == 1 or old is None:
+            return new
+        return jnp.where(self._head_lanes(new.shape, i), new, old)
+
+    def _head_lanes(self, shape, i):
+        return jax.lax.broadcasted_iota(jnp.int32, shape, 1) // self.head_dim == i
+
+    def compiler_params(self, semantics):
+        if self.interpret:
+            return None
+        return pltpu.CompilerParams(
+            dimension_semantics=semantics, vmem_limit_bytes=_VMEM_LIMIT)
+
+
+_NT = (((1,), (1,)), ((), ()))  # [m, c] x [n, c] -> [m, n]
+
+
+def _nt(a, b):
+    return jax.lax.dot_general(a, b, _NT, preferred_element_type=jnp.float32)
+
+
+def _nn(a, b):
+    return jnp.dot(a, b, preferred_element_type=jnp.float32)
+
+
+def _stat_rows(heads: int) -> int:
+    return -(-heads // _STAT_ROWS) * _STAT_ROWS
 
 
 # ---------------------------------------------------------------------------
@@ -78,96 +195,57 @@ def _masked_scores(q, k, qi, ki, *, scale, causal, block_q, block_k):
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-                *, scale, causal, block_q, block_k):
-    qi, ki = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
-
-    @pl.when(ki == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-
-    def _step():
-        s = _masked_scores(
-            q_ref[0], k_ref[0], qi, ki, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k,
-        )
-        m_prev = m_ref[:, :1]  # [Bq, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)  # [Bq, Bk]
-        corr = jnp.exp(m_prev - m_new)  # [Bq, 1]
-        l_ref[:, :1] = l_ref[:, :1] * corr + jnp.sum(p, axis=1, keepdims=True)
-        m_ref[:, :1] = m_new
-        pv = jnp.dot(
-            p.astype(v_ref.dtype), v_ref[0], preferred_element_type=jnp.float32
-        )
-        acc_ref[:] = acc_ref[:] * corr + pv
-
-    if causal:
-        # a block is live unless every (row, col) pair has col > row
-        @pl.when(ki * block_k <= qi * block_q + block_q - 1)
-        def _():
-            _step()
-    else:
-        _step()
-
-    @pl.when(ki == nk - 1)
-    def _finish():
-        l = l_ref[:, :1]
-        safe_l = jnp.where(l > 0, l, 1.0)
-        o_ref[0] = (acc_ref[:] / safe_l).astype(o_ref.dtype)
-        # lse rides in a sublane-replicated [8, Bq] layout (TPU block
-        # shapes need the 2nd-to-last dim divisible by 8)
-        lse_row = (m_ref[:, :1] + jnp.log(safe_l))[:, 0]
-        lse_ref[0] = jnp.broadcast_to(lse_row[None, :], (8, lse_row.shape[0]))
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, cfg):
+    for r0 in cfg.row_chunks():
+        rows = slice(r0, r0 + cfg.chunk_q)
+        q, pieces, out = q_ref[0, rows, :], cfg.pieces(r0), None
+        keeps = [cfg.valid(r0, c0, c1, 0) if cut else None for c0, c1, cut in pieces]
+        for i in range(cfg.heads):
+            q_i = cfg.only(q, i)
+            scores = []  # one [chunk, keys of the piece] per piece
+            for (c0, c1, _), keep in zip(pieces, keeps):
+                s = _nt(q_i, k_ref[0, c0:c1, :]) * cfg.scale
+                scores.append(s if keep is None else jnp.where(keep, s, _NEG_INF))
+            m = functools.reduce(
+                jnp.maximum, [jnp.max(s, axis=1, keepdims=True) for s in scores])
+            l, o = 0.0, 0.0
+            for s, (c0, c1, _) in zip(scores, pieces):
+                p = jnp.exp(s - m)
+                l = l + jnp.sum(p, axis=1, keepdims=True)
+                # [chunk, tile]; head i's lanes are its P·V
+                o = o + _nn(p.astype(v_ref.dtype), v_ref[0, c0:c1, :])
+            out = cfg.pick(i, o / l, out)
+            # one row of the statistics' tile per head (TPU block shapes
+            # need the 2nd-to-last dim divisible by 8)
+            lse_ref[0, 0, i:i + 1, rows] = (m + jnp.log(l))[:, 0][None, :]
+        o_ref[0, rows, :] = out.astype(o_ref.dtype)
 
 
-def _flash_forward(q, k, v, scale, causal, block_q, block_k, interpret):
-    BH, S, d = q.shape
-    Sk = k.shape[1]
-    nq, nk = pl.cdiv(S, block_q), pl.cdiv(Sk, block_k)
-    grid = (BH, nq, nk)
-    kernel = functools.partial(
-        _fwd_kernel, scale=scale, causal=causal,
-        block_q=block_q, block_k=block_k,
-    )
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, 8, block_q), lambda b, i, j: (b, 0, i)),
-        ],
+# Both wrappers are jitted so that every layer of a model calls ONE traced and
+# lowered kernel: traced in place, the unrolled kernels of GPT-2's 12 layers
+# made the round program's first call 47–50 s on the v5e's host where this
+# makes it 23 s, the plain form's 21 (PERF.md section 6, PR 29).
+@functools.partial(jax.jit, static_argnames="cfg")
+def _flash_forward(q, k, v, cfg):
+    B, T, width = q.shape
+    tile = cfg.heads * cfg.head_dim
+    rows = _stat_rows(cfg.heads)
+    q_spec = pl.BlockSpec((1, T, tile), lambda b, h: (b, 0, h))
+    kv_spec = pl.BlockSpec(
+        (1, cfg.length_k, tile), lambda b, h: (b, 0, h // cfg.group_tiles))
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, cfg=cfg),
+        grid=(B, width // tile),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=[q_spec, pl.BlockSpec((1, 1, rows, T), lambda b, h: (b, h, 0, 0))],
         out_shape=[
-            jax.ShapeDtypeStruct((BH, S, d), q.dtype),
-            jax.ShapeDtypeStruct((BH, 8, S), jnp.float32),
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((B, width // tile, rows, T), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),   # acc
-            pltpu.VMEM((block_q, 128), jnp.float32),  # m (running max)
-            pltpu.VMEM((block_q, 128), jnp.float32),  # l (running sum)
-        ],
-        compiler_params=_compiler_params(interpret),
-        interpret=interpret,
+        compiler_params=cfg.compiler_params(("parallel", "parallel")),
+        interpret=cfg.interpret,
+        name="attention_fwd",
     )(q, k, v)
-    return out, lse
-
-
-def _compiler_params(interpret):
-    """BH and Q-block grid dims are parallel; the K-block dim carries the
-    online-softmax accumulator and must run in order."""
-    if interpret:
-        return None
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary")
-    )
 
 
 # ---------------------------------------------------------------------------
@@ -175,86 +253,84 @@ def _compiler_params(interpret):
 # ---------------------------------------------------------------------------
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   acc_ref, *, scale, causal, block_q, block_k):
-    qi, ki = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
+                dk_acc, dv_acc, *, cfg):
+    """Grid (batch, K/V tile, query tile of its group): dQ is this query
+    tile's own, dK and dV are summed over the group in the accumulators."""
+    g = pl.program_id(2)
 
-    @pl.when(ki == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    def _step():
-        k = k_ref[0]
-        s = _masked_scores(
-            q_ref[0], k, qi, ki, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k,
-        )
-        p = jnp.exp(s - lse_ref[0, 0][:, None])  # [Bq, Bk]
-        dov = jax.lax.dot_general(
-            do_ref[0].astype(jnp.float32), v_ref[0].astype(jnp.float32),
-            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
-        )  # [Bq, Bk]
-        ds = p * (dov - delta_ref[0, 0][:, None]) * scale
-        acc_ref[:] += jnp.dot(
-            ds.astype(k.dtype), k, preferred_element_type=jnp.float32
-        )
-
-    if causal:
-        @pl.when(ki * block_k <= qi * block_q + block_q - 1)
-        def _():
-            _step()
-    else:
-        _step()
-
-    @pl.when(ki == nk - 1)
-    def _finish():
-        dq_ref[0] = acc_ref[:].astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_acc, dv_acc,
-                    *, scale, causal, block_q, block_k):
-    ki, qi = pl.program_id(1), pl.program_id(2)
-    nq = pl.num_programs(2)
-
-    @pl.when(qi == 0)
+    @pl.when(g == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    def _step():
-        q = q_ref[0]
-        s = _masked_scores(
-            q, k_ref[0], qi, ki, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k,
-        )
-        p = jnp.exp(s - lse_ref[0, 0][:, None])  # [Bq, Bk]
-        do = do_ref[0].astype(jnp.float32)
-        dv_acc[:] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [Bk, d]
-        dov = jax.lax.dot_general(
-            do, v_ref[0].astype(jnp.float32),
-            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
-        )
-        ds = p * (dov - delta_ref[0, 0][:, None]) * scale  # [Bq, Bk]
-        dk_acc[:] += jax.lax.dot_general(
-            ds, q.astype(jnp.float32),
-            (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-        )  # [Bk, d]
+    def add(acc, cols, i, part):
+        acc[cols, :] = cfg.pick(i, acc[cols, :] + part, acc[cols, :])
 
-    if causal:
-        @pl.when(qi * block_q + block_q - 1 >= ki * block_k)
-        def _():
-            _step()
-    else:
-        _step()
+    for r0 in cfg.row_chunks():
+        rows = slice(r0, r0 + cfg.chunk_q)
+        q, do, dq, pieces = q_ref[0, rows, :], do_ref[0, rows, :], None, cfg.pieces(r0)
+        keeps = [cfg.valid(r0, c0, c1, 1) if cut else None for c0, c1, cut in pieces]
+        for i in range(cfg.heads):
+            # [1, chunk] rows: they broadcast down the [keys, chunk] scores
+            lse, delta = lse_ref[0, 0, i:i + 1, rows], delta_ref[0, 0, i:i + 1, rows]
+            dq_i = 0.0
+            for (c0, c1, _), keep in zip(pieces, keeps):
+                cols = slice(c0, c1)
+                k, v = k_ref[0, cols, :], v_ref[0, cols, :]
+                s = _nt(cfg.only(k, i), q) * cfg.scale  # [keys, chunk]
+                if keep is not None:
+                    s = jnp.where(keep, s, _NEG_INF)
+                p = jnp.exp(s - lse)
+                add(dv_acc, cols, i, _nn(p.astype(do.dtype), do))
+                ds = p * (_nt(cfg.only(v, i), do) - delta)
+                add(dk_acc, cols, i, _nn(ds.astype(q.dtype), q))
+                dq_i = dq_i + _nn(ds.T.astype(k.dtype), k)
+            dq = cfg.pick(i, dq_i, dq)
+        dq_ref[0, rows, :] = (dq * cfg.scale).astype(dq_ref.dtype)
 
-    @pl.when(qi == nq - 1)
+    @pl.when(g == cfg.group_tiles - 1)
     def _finish():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dk_ref[0] = (dk_acc[:] * cfg.scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames="cfg")
+def _flash_backward(q, k, v, out, lse, do, cfg):
+    B, T, width = q.shape
+    tile = cfg.heads * cfg.head_dim
+    rows, G = lse.shape[2], cfg.group_tiles
+    # delta = rowsum(dO * O) per head, in the layout of lse: [B, tiles, rows, T]
+    delta = jnp.sum(
+        (do.astype(jnp.float32) * out.astype(jnp.float32)).reshape(
+            B, T, width // tile, cfg.heads, cfg.head_dim),
+        axis=-1,
+    )
+    delta = jnp.pad(
+        jnp.transpose(delta, (0, 2, 3, 1)), ((0, 0), (0, 0), (0, rows - cfg.heads), (0, 0))
+    )
+    q_spec = pl.BlockSpec((1, T, tile), lambda b, c, g: (b, 0, c * G + g))
+    kv_spec = pl.BlockSpec((1, cfg.length_k, tile), lambda b, c, g: (b, 0, c))
+    stat_spec = pl.BlockSpec((1, 1, rows, T), lambda b, c, g: (b, c * G + g, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, cfg=cfg),
+        grid=(B, k.shape[2] // tile, G),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, stat_spec, stat_spec],
+        out_specs=[q_spec, kv_spec, kv_spec],
+        out_shape=[
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((cfg.length_k, tile), jnp.float32),
+            pltpu.VMEM((cfg.length_k, tile), jnp.float32),
+        ],
+        # dK and dV are summed over the group's query tiles
+        compiler_params=cfg.compiler_params(("parallel", "parallel", "arbitrary")),
+        interpret=cfg.interpret,
+        name="attention_bwd",
+    )(q, k, v, do, lse, delta)
 
 
 # ---------------------------------------------------------------------------
@@ -262,143 +338,86 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(
-    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6)
-)
-def _flash(q, k, v, causal, block_q, block_k, interpret):
-    out, _ = _flash_forward(
-        q, k, v, 1.0 / math.sqrt(q.shape[-1]), causal, block_q, block_k,
-        interpret,
-    )
-    return out
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _flash(q, k, v, cfg):
+    return _flash_forward(q, k, v, cfg)[0]
 
 
-def _flash_fwd(q, k, v, causal, block_q, block_k, interpret):
-    out, lse = _flash_forward(
-        q, k, v, 1.0 / math.sqrt(q.shape[-1]), causal, block_q, block_k,
-        interpret,
-    )
+def _flash_fwd(q, k, v, cfg):
+    out, lse = _flash_forward(q, k, v, cfg)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, block_q, block_k, interpret, res, do):
+def _flash_bwd(cfg, res, do):
     q, k, v, out, lse = res
-    BH, S, d = q.shape
-    Sk = k.shape[1]
-    scale = 1.0 / math.sqrt(d)
-    # delta in the same sublane-replicated [BH, 8, S] layout as lse
-    delta = jnp.sum(
-        do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
-    )
-    delta = jnp.broadcast_to(delta[:, None, :], (BH, 8, S))
-    nq, nk = pl.cdiv(S, block_q), pl.cdiv(Sk, block_k)
-
-    dq = pl.pallas_call(
-        functools.partial(
-            _bwd_dq_kernel, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k,
-        ),
-        grid=(BH, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, 8, block_q), lambda b, i, j: (b, 0, i)),
-            pl.BlockSpec((1, 8, block_q), lambda b, i, j: (b, 0, i)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_compiler_params(interpret),
-        interpret=interpret,
-    )(q, k, v, do, lse, delta)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(
-            _bwd_dkv_kernel, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k,
-        ),
-        grid=(BH, nk, nq),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, 8, block_q), lambda b, j, i: (b, 0, i)),
-            pl.BlockSpec((1, 8, block_q), lambda b, j, i: (b, 0, i)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(k.shape, k.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
-        ],
-        compiler_params=_compiler_params(interpret),
-        interpret=interpret,
-    )(q, k, v, do, lse, delta)
-    return dq, dk, dv
+    return _flash_backward(q, k, v, out, lse, do, cfg)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def flash_attention(
+def flash_attention_bthd(
     q,
     k,
     v,
     causal: bool = True,
-    block_q: int = 512,
-    block_k: int = 512,
-    interpret: bool | None = None,
+    window: Optional[int] = None,
+    chunk: int = CHUNK,
+    interpret: Optional[bool] = None,
 ):
-    """Blockwise attention: softmax(Q Kᵀ/√d [, causal]) V.
-
-    q/k/v: [..., S, d] with any leading batch/head dims (flattened
-    internally). Sequence lengths must be multiples of the block sizes
-    (callers pad; ring attention's shards already are). Differentiable via
-    the flash backward kernels."""
+    """Blockwise attention in the framework's layout, the signature of
+    ``parallel/ring_attention.full_attention``: q [B, T, H, D], k and v
+    [B, Tk, KV, D] with KV dividing H; ``window`` keeps, beside the causal
+    mask, only the keys with ``i - j < window``. Sequence lengths are whole
+    numbers of ``chunk`` (or shorter than one: the chunk is then the
+    sequence) and at most ``MAX_LENGTH``. Differentiable via the backward
+    kernel."""
+    B, T, H, D = q.shape
+    Tk, KV = k.shape[1], k.shape[2]
     if interpret is None:
         interpret = _use_interpret()
-    orig_shape = q.shape
-    S, d = q.shape[-2:]
-    Sk = k.shape[-2]
-    block_q = min(block_q, S)
-    block_k = min(block_k, Sk)
-    if S % block_q or Sk % block_k:
+    if k.shape != v.shape or k.shape[0] != B or k.shape[3] != D or H % KV:
         raise ValueError(
-            f"sequence lengths ({S}, {Sk}) must be multiples of the block "
-            f"sizes ({block_q}, {block_k})"
+            f"q {q.shape} needs k and v of one shape [B, Tk, KV, D] with KV "
+            f"dividing its heads, got k {k.shape}, v {v.shape}"
         )
-    if causal and S != Sk:
-        raise ValueError("causal attention requires matching Q/K lengths")
-    q3 = q.reshape((-1, S, d))
-    k3 = k.reshape((-1, Sk, d))
-    v3 = v.reshape((-1, Sk, d))
-    if q3.shape[0] != k3.shape[0] or k3.shape != v3.shape:
-        # the grid is sized from Q's batch*heads; a smaller K/V (e.g. MQA
-        # [B, 1, S, d]) would clamp block indices on TPU → silently wrong
+    chunk_q, chunk_k = min(chunk, T), min(chunk, Tk)
+    if T % chunk_q or Tk % chunk_k or max(T, Tk) > MAX_LENGTH:
+        raise ValueError(
+            f"sequence lengths ({T}, {Tk}) must be multiples of the chunk "
+            f"({chunk}) and at most {MAX_LENGTH}"
+        )
+    if (causal or window is not None) and T != Tk:
+        raise ValueError("a causal or window mask requires matching Q/K lengths")
+    heads = heads_per_tile(H, D)
+    if KV != H and heads > 1:
+        # heads narrower than a tile: a K/V head fills the tile that its
+        # query heads read, so those have to be heads of its own group
+        if (H // KV) % heads:
+            raise ValueError(
+                f"{H // KV} query heads a K/V head do not fill tiles of {heads} heads"
+            )
+        k, v = (jnp.repeat(a, heads, axis=2) for a in (k, v))
+    cfg = _Cfg(
+        head_dim=D, heads=heads, group_tiles=H // k.shape[2],
+        causal=bool(causal), window=None if window is None else int(window),
+        chunk_q=chunk_q, chunk_k=chunk_k, length_q=T, length_k=Tk,
+        interpret=bool(interpret),
+    )
+    out = _flash(
+        q.reshape(B, T, H * D), k.reshape(B, Tk, -1), v.reshape(B, Tk, -1), cfg
+    )
+    return out.reshape(B, T, H, D)
+
+
+def flash_attention(q, k, v, causal: bool = True, **kw):
+    """The same for heads laid out ahead of the sequence: q/k/v
+    [..., S, d] with any leading batch/head dims, each a head of its own."""
+    if q.shape[:-2] != k.shape[:-2] or k.shape != v.shape:
         raise ValueError(
             f"q/k/v leading (batch, heads) dims must match: q {q.shape}, "
             f"k {k.shape}, v {v.shape} (broadcast MQA/GQA heads first)"
         )
-    out = _flash(q3, k3, v3, causal, block_q, block_k, interpret)
-    return out.reshape(orig_shape)
-
-
-def flash_attention_bthd(q, k, v, causal: bool = True, **kw):
-    """[B, T, H, D]-layout adapter matching the framework's attention
-    callable convention (parallel/ring_attention.full_attention,
-    models/transformer.TransformerBlock.attn_fn): drop-in flash-backed
-    ``attn_fn`` for TransformerLM."""
-    qt = jnp.swapaxes(q, 1, 2)  # [B, H, T, D]
-    kt = jnp.swapaxes(k, 1, 2)
-    vt = jnp.swapaxes(v, 1, 2)
-    out = flash_attention(qt, kt, vt, causal=causal, **kw)
-    return jnp.swapaxes(out, 1, 2).astype(q.dtype)
+    one_head = lambda a: a.reshape((-1,) + a.shape[-2:])[:, :, None, :]
+    out = flash_attention_bthd(one_head(q), one_head(k), one_head(v), causal=causal, **kw)
+    return out.reshape(q.shape)

@@ -24,7 +24,7 @@ def test_compare_records_builds_delta_table_and_flags_regressions():
         "north_star": {"rounds_per_sec": 40.0},
         "north_star_bf16": {"rounds_per_sec": 30.0},
         "scale_1m": {"rounds_per_sec": 350.0},
-        "flash_attention_s8192": {"flash_over_xla_speedup": 3.0},  # no r/s
+        "flash_attention_s4096": {"flash_over_xla_speedup": 3.0},  # no r/s
         "process_cold_start": {"skipped": "no backend"},
     }
     baseline = {
@@ -43,7 +43,7 @@ def test_compare_records_builds_delta_table_and_flags_regressions():
     assert s["headline"]["delta_pct"] == -4.8
     # sections without comparable r/s on both sides appear without deltas
     # (flash has no rounds_per_sec; cold_start skipped this run)
-    assert "flash_attention_s8192" not in s
+    assert "flash_attention_s4096" not in s
     assert out["regressions"] and "north_star_bf16" in out["regressions"][0]
     assert out["regress_tol_pct"] == 10.0
     assert out["missing_sections"] == []

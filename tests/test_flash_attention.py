@@ -32,17 +32,17 @@ def _qkv(shape, seed=0):
 @pytest.mark.parametrize("causal", [False, True])
 def test_forward_matches_reference(causal):
     q, k, v = _qkv((2, 2, 128, 32))  # [B, H, S, d]
-    out = flash_attention(q, k, v, causal=causal, block_q=64, block_k=64)
+    out = flash_attention(q, k, v, causal=causal, chunk=64)
     ref = _ref_attention(q, k, v, causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
 def test_uneven_blocks_and_single_block():
     q, k, v = _qkv((1, 192, 16), seed=3)
-    out = flash_attention(q, k, v, causal=True, block_q=64, block_k=64)
+    out = flash_attention(q, k, v, causal=True, chunk=64)
     ref = _ref_attention(q, k, v, True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
-    # S smaller than the block: block clamps to S
+    # S smaller than the chunk: the chunk clamps to S
     q, k, v = _qkv((1, 32, 16), seed=4)
     out = flash_attention(q, k, v, causal=False)
     ref = _ref_attention(q, k, v, False)
@@ -54,7 +54,7 @@ def test_vjp_matches_reference(causal):
     q, k, v = _qkv((2, 128, 32), seed=7)
 
     def loss_flash(q, k, v):
-        out = flash_attention(q, k, v, causal=causal, block_q=64, block_k=64)
+        out = flash_attention(q, k, v, causal=causal, chunk=64)
         return jnp.sum(jnp.sin(out))
 
     def loss_ref(q, k, v):
@@ -89,7 +89,7 @@ def test_transformer_lm_with_flash_attention():
     ref_model = make()
     params = ref_model.init(jax.random.PRNGKey(0), tokens)
     flash_model = make(
-        lambda q, k, v: flash_attention_bthd(q, k, v, block_q=64, block_k=64)
+        lambda q, k, v: flash_attention_bthd(q, k, v, chunk=64)
     )
     ref_logits = ref_model.apply(params, tokens)
     flash_logits = flash_model.apply(params, tokens)
@@ -114,8 +114,8 @@ def test_transformer_lm_with_flash_attention():
 def test_shape_guards():
     q, k, v = _qkv((1, 100, 16))
     with pytest.raises(ValueError):
-        flash_attention(q, k, v, block_q=64, block_k=64)
+        flash_attention(q, k, v, chunk=64)
     q, k, v = _qkv((1, 128, 16))
     k2 = k[:, :64]
     with pytest.raises(ValueError):
-        flash_attention(q, k2, v[:, :64], causal=True, block_q=64, block_k=64)
+        flash_attention(q, k2, v[:, :64], causal=True, chunk=64)

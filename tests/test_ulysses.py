@@ -66,7 +66,7 @@ def test_ulysses_with_flash_core():
         mesh,
         causal=True,
         attn_fn=lambda q, k, v, causal: flash_attention_bthd(
-            q, k, v, causal=causal, block_q=64, block_k=64
+            q, k, v, causal=causal, chunk=64
         ),
     )(qs, ks, vs)
     ref = full_attention(q, k, v, causal=True)
