@@ -15,9 +15,6 @@ Round-3 changes:
   optimized HLO, fusing away most of the backward) — the r2 MFU numbers
   were deflated by exactly that factor. The XLA number is still reported
   for transparency;
-- the fused multi-round path is timed through the production train() loop
-  (class-aware chunking + pad-free scan schedule — the r2 fused feature
-  padded whole chunks to the chunk-max step count and LOST to eager);
 - ``hard_accuracy``: regimes that can FAIL (Missing #1): the FedProx-paper
   synthetic(1,1) with E=20 local epochs separates FedAvg/FedProx/FedOpt
   (FedAvg misses the 0.60 target in 100 rounds, the others cross it), and
@@ -63,7 +60,7 @@ def _timed_rounds(api, start: int, n: int, repeats: int = 5) -> float:
     window (same shape classes each pass; jit caches warm). A shared
     chip can show bimodal ~2× throughput windows — a single pass can land
     entirely in the slow mode and record a 2×-off number; min-of-blocks
-    is the same discipline the fused-vs-eager rows already use. Five
+    is the same discipline the train-loop rows use. Five
     windows because the mode persists for tens of seconds: three ~1s
     windows can ALL land slow."""
     best = float("inf")
@@ -211,8 +208,7 @@ def _throughput_row(api, warmup: int, timed: int, label: str,
     }
 
 
-def _north_star_api(compute_dtype="float32", comm_round=1, fused_rounds=1,
-                    fused_plan="static", pipeline="auto"):
+def _north_star_api(compute_dtype="float32", comm_round=1, pipeline="auto"):
     from fedml_tpu.algorithms.fedavg import FedAvgAPI
     from fedml_tpu.config import DataConfig, FedConfig, RunConfig, TrainConfig
     from fedml_tpu.data.femnist_synth import femnist_synthetic
@@ -225,8 +221,6 @@ def _north_star_api(compute_dtype="float32", comm_round=1, fused_rounds=1,
             client_num_per_round=10,
             comm_round=comm_round,
             epochs=1,
-            fused_rounds=fused_rounds,
-            fused_plan=fused_plan,
             pipeline=pipeline,
             frequency_of_the_test=10_000,
         ),
@@ -241,113 +235,24 @@ def _north_star_api(compute_dtype="float32", comm_round=1, fused_rounds=1,
     return FedAvgAPI(config, data, model)
 
 
-def _trainloop_rows(compute_dtype, total=64, chunk=16, repeats=3):
-    """Eager vs fused through the production train() loop (incl. logging),
-    timed as INTERLEAVED passes (E,F,E,F,...) with best-of per config —
-    chip throughput drifts several percent over minutes, more than the
-    eager-vs-fused difference, so back-to-back blocks of one config would
-    measure the drift, not the feature."""
-    apis = {
-        "eager": _north_star_api(compute_dtype, comm_round=total, fused_rounds=1),
-        "fused": _north_star_api(
-            compute_dtype, comm_round=total, fused_rounds=chunk
-        ),
-    }
-    if apis["fused"]._store is None:
-        apis.pop("fused")
-    best = {}
-    for name, api in apis.items():  # warm: compiles every shape in horizon
-        api.train()
-        best[name] = float("inf")
+def _trainloop_row(compute_dtype, total=64, repeats=3):
+    """The production train() loop (incl. logging), best of ``repeats``
+    passes over the same ``total`` rounds."""
+    api = _north_star_api(compute_dtype, comm_round=total)
+    api.train()  # warm: compiles every shape in horizon
+    best = float("inf")
     for _ in range(repeats):
-        for name, api in apis.items():
-            _reset(api)
-            t0 = time.perf_counter()
-            api.train()
-            best[name] = min(best[name], (time.perf_counter() - t0) / total)
-
-    def row(label, name, fused_rounds):
-        if name not in best:
-            return None
-        return {
-            "label": label,
-            "compute_dtype": compute_dtype,
-            "rounds_per_sec": round(1.0 / best[name], 4),
-            "round_ms_wall": round(best[name] * 1e3, 2),
-            "fused_rounds": fused_rounds,
-            "timed_via": (
-                f"production train() loop incl. logging, interleaved "
-                f"best of {repeats}"
-            ),
-        }
-
-    return (
-        row("north_star_eager_trainloop", "eager", 1),
-        row("north_star_fused", "fused", chunk),
-    )
-
-
-def _fused_vs_eager(total=32, chunk=8, repeats=2):
-    """ISSUE 14 gate row: BOTH schedules on the north-star config through
-    the production train() loop (interleaved best-of, like
-    _trainloop_rows), PLUS a measured-plan run whose planner must commit
-    to the winner from flight-recorder probes — the decision is recorded
-    here, and agreement with the interleaved measurement is reported
-    (the ci.sh CPU-proxy gate asserts it; on TPU this row is the record
-    for the next r0x pass)."""
-    apis = {
-        "eager": _north_star_api("float32", comm_round=total, fused_rounds=1),
-        "fused": _north_star_api(
-            "float32", comm_round=total, fused_rounds=chunk
-        ),
-    }
-    if apis["fused"]._store is None:
-        return {"skipped": "no device store — fused path unavailable"}
-    best = {}
-    for name, api in apis.items():  # warm: compiles every shape in horizon
+        _reset(api)
+        t0 = time.perf_counter()
         api.train()
-        best[name] = float("inf")
-    for _ in range(repeats):
-        for name, api in apis.items():
-            _reset(api)
-            t0 = time.perf_counter()
-            api.train()
-            best[name] = min(best[name], (time.perf_counter() - t0) / total)
-    eager_rps = round(1.0 / best["eager"], 4)
-    fused_rps = round(1.0 / best["fused"], 4)
-    # measured planner arm: fresh API, fused_plan="measured" — the
-    # planner probes both schedules off the flight recorder and commits
-    planner_api = _north_star_api(
-        "float32", comm_round=total, fused_rounds=chunk,
-        fused_plan="measured",
-    )
-    planner_api.train()
-    psum = (
-        planner_api.planner.summary_row()
-        if planner_api.planner is not None
-        else {}
-    )
-    decision = psum.get("flight/planner_schedule")
-    measured_winner = "fused" if fused_rps >= eager_rps else "eager"
+        best = min(best, (time.perf_counter() - t0) / total)
     return {
-        "label": "fused_vs_eager",
-        "compute_dtype": "float32",
-        "fused_rounds": chunk,
-        "eager_rounds_per_sec": eager_rps,
-        "fused_rounds_per_sec": fused_rps,
-        # the winner's rate IS the row's r/s — what --compare tracks
-        "rounds_per_sec": max(eager_rps, fused_rps),
-        "fused_over_eager": round(fused_rps / eager_rps, 3),
-        "planner_decision": decision,
-        "planner_probe": {
-            k: v for k, v in psum.items() if k.startswith("flight/probe_")
-        },
-        "planner_agrees_with_interleaved": (
-            decision == measured_winner if decision else None
-        ),
+        "label": "north_star_eager_trainloop",
+        "compute_dtype": compute_dtype,
+        "rounds_per_sec": round(1.0 / best, 4),
+        "round_ms_wall": round(best * 1e3, 2),
         "timed_via": (
-            f"production train() loop, interleaved best of {repeats}; "
-            "planner decision from a separate fused_plan=measured run"
+            f"production train() loop incl. logging, best of {repeats}"
         ),
     }
 
@@ -356,8 +261,10 @@ def _pipeline_rounds(total=32, repeats=2):
     """ISSUE 17 row: the round pipeline — host prepares round r+1
     (cohort selection, batch gather, placement) while round r's program
     runs on device, committing at the boundary — vs --pipeline off, both
-    through the production train() loop (interleaved best-of, like
-    _trainloop_rows). Measured overlap comes off a private flight
+    through the production train() loop, timed as INTERLEAVED passes with
+    best-of per config (chip throughput drifts several percent over
+    minutes, more than the difference measured: back-to-back blocks of one
+    config would measure the drift). Measured overlap comes off a private flight
     recorder's folded records, and byte parity of the final train loss
     is recorded alongside the rates (tests/test_pipeline.py pins the
     full-tree parity; this row is the throughput record)."""
@@ -1762,11 +1669,11 @@ class _Emitter:
 
     _SECTION_SLOTS = (
         "north_star", "north_star_bf16", "flagship_lm_bf16",
-        "north_star_eager_trainloop", "north_star_fused",
+        "north_star_eager_trainloop",
         "bf16_cross_silo_resnet56", "flash_attention_s4096",
         "mxu_validation", "scale_100k_clients", "scale_100k_stateful",
         "scale_1m", "fedbuff_async", "wire_fleet", "process_cold_start",
-        "fused_vs_eager", "pipeline", "uplink_bytes", "splitfed",
+        "pipeline", "uplink_bytes", "splitfed",
     )
 
     def __init__(self, t0: float, detail_path: str,
@@ -1853,7 +1760,6 @@ class _Emitter:
             "eager_fp32": rec.get("north_star"),
             "eager_bf16": rec.get("north_star_bf16"),
             "trainloop_eager_bf16": rec.get("north_star_eager_trainloop"),
-            "trainloop_fused_bf16": rec.get("north_star_fused"),
         }
         candidates = [
             (k, v) for k, v in rows.items()
@@ -1895,11 +1801,6 @@ def _sec_digest(key: str, v) -> str:
         return "?" if v is None else str(v)[:38]
     if "skipped" in v:
         return ("skip:" + str(v["skipped"]))[:38]
-    if "fused_over_eager" in v:
-        return (
-            f"{v['fused_over_eager']}x fused/eager "
-            f"({v.get('planner_decision') or 'no-commit'})"
-        )
     if "cut_x" in v:
         return f"{v['cut_x']}x uplink cut (int4)"
     if "activation_cut_x" in v:  # splitfed
@@ -2223,7 +2124,7 @@ def main():
     # a skipped/failed section must stamp the SAME record slots its body
     # would have filled — the degraded record self-describes per slot
     slot_map = {
-        "trainloop": ("north_star_eager_trainloop", "north_star_fused"),
+        "trainloop": ("north_star_eager_trainloop",),
         "bf16_cross_silo": ("bf16_cross_silo_resnet56",),
         "flash_attention": ("flash_attention_s4096",),
         "scale": ("scale_100k_clients",),
@@ -2347,31 +2248,9 @@ def main():
         }})
 
     def s_trainloop():
-        eager_loop, fused_loop = _trainloop_rows("bfloat16")
-        updates = {
-            "north_star_eager_trainloop": eager_loop,
-            "north_star_fused": fused_loop,
-            "fused_vs_eager_trainloop": (
-                round(
-                    fused_loop["rounds_per_sec"] / eager_loop["rounds_per_sec"],
-                    3,
-                )
-                if fused_loop
-                and "rounds_per_sec" in fused_loop
-                and "rounds_per_sec" in (eager_loop or {})
-                else None
-            ),
-        }
-        updates["fused_note"] = None if not (
-            fused_loop and "rounds_per_sec" in fused_loop
-        ) else (
-            "r2's 13% fused regression (chunk-max step padding) is "
-            "eliminated: across interleaved best-of passes the fused/eager "
-            "ratio measures 1.00-1.29, never below parity (both paths are "
-            "device-compute-bound at identical shapes; the chip's "
-            "bimodal throughput bounds resolution above that)."
+        emitter.update(
+            {"north_star_eager_trainloop": _trainloop_row("bfloat16")}
         )
-        emitter.update(updates)
 
     def s_bf16_cross_silo():
         emitter.update({"bf16_cross_silo_resnet56": _bf16_cross_silo(quick=True)})
@@ -2396,9 +2275,6 @@ def main():
 
     def s_cold_start():
         emitter.update({"process_cold_start": _process_cold_start()})
-
-    def s_fused_vs_eager():
-        emitter.update({"fused_vs_eager": _fused_vs_eager()})
 
     def s_uplink():
         emitter.update({"uplink_bytes": _uplink_bytes_rows()})
@@ -2465,7 +2341,6 @@ def main():
             ("synthetic11", s_synthetic11, 70, 300),
             ("femnist_lda", s_femnist_lda, 170, 500),
             ("trainloop", s_trainloop, 125, 300),
-            ("fused_vs_eager", s_fused_vs_eager, 150, 420),
             ("pipeline", s_pipeline, 60, 300),
             ("uplink_bytes", s_uplink, 40, 240),
             ("splitfed", s_splitfed, 60, 300),

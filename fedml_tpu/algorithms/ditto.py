@@ -389,8 +389,6 @@ class DittoAPI(FedAvgAPI):
     cross-device by nature, so the spill path is the
     one that scales it to the data layer's 100k-client regime."""
 
-    _supports_fused = False  # per-round personal-state exchange
-
     def __init__(
         self, config: RunConfig, data: FederatedDataset, model: ModelDef,
         lam: float = 0.1, **kw,

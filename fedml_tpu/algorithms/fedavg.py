@@ -350,121 +350,6 @@ def make_fedavg_round(
     return dispatch
 
 
-def make_fedavg_multiround(
-    model: ModelDef,
-    config: RunConfig,
-    steps: int,
-    bs: int,
-    feat_shape: tuple,
-    label_shape: tuple,
-    task: str = "classification",
-    local_train_fn: Optional[Callable] = None,
-    client_mode: Optional[str] = None,
-    may_pad: Optional[bool] = None,
-):
-    """Fused multi-round FedAvg: T rounds as ONE jitted ``lax.scan`` over the
-    HBM-resident data store — zero host round-trips inside the chunk.
-
-    Per-round host work in the eager path (sampling, index building, metric
-    fetch, dispatch) dominates small-model rounds, especially through a
-    remote-device transport. Here the host precomputes only the per-round
-    gather indices (a few KB each; sampling parity with
-    FedAVGAggregator.py:80-88 is preserved because sampling stays host-side)
-    and the device runs the whole chunk:
-
-        fn(global_vars, flat_x, flat_y, idx_next [T,C,cap],
-           mask_next [T,C,cap], num_samples [T,C], round_ids [T], base_rng)
-            -> (global_vars', stacked per-round metrics)
-
-    ``flat_x``/``flat_y`` are the store's lane-padded rows; ``feat_shape``
-    and ``label_shape`` (the store's) are restored on each gathered batch.
-
-    ``idx_next``/``mask_next`` arrive PRE-ROTATED by one round (host-side
-    ``roll(-1)`` in ``_fused_plan``): iteration t's xs row is round t+1's
-    gather — the double-buffer prefetch — and the last row wraps to round
-    0's indices, which the prologue reads back (``idx_next[-1]``) for the
-    first batch. Rotating on the host removes the two whole-chunk
-    ``jnp.roll`` copies the traced program used to execute per dispatch
-    (re-profile finding, ISSUE 14): the bytes shipped are identical, the
-    device-side copies are gone.
-
-    Per-round math is identical to :func:`make_fedavg_round` at the same
-    (steps, bs): the round body, the fold_in/split PRNG stream, and the
-    weighted average are the same code."""
-    from fedml_tpu.data.device_store import gather_batch
-    from fedml_tpu.compile import get_program_cache, model_fingerprint
-
-    mode = client_mode or resolve_client_parallelism(
-        config.fed.client_parallelism, model
-    )
-    local_train = local_train_fn or make_local_train(
-        model, config.train, config.fed.epochs, task=task,
-        skip_empty_steps=resolve_skip_empty_steps(mode, may_pad),
-    )
-    lifted = client_axis_map(local_train, mode)
-
-    def multi_fn(global_vars, flat_x, flat_y, idx_next, mask_next, num_samples, round_ids, base_rng):
-        C = idx_next.shape[1]
-
-        def gathered(idx_r, mask_r):
-            # shared gather-and-zero-padding contract with the eager path
-            return gather_batch(
-                flat_x, flat_y, idx_r, mask_r, steps, bs, feat_shape, label_shape
-            )
-
-        # Double-buffered: each iteration trains on the PRE-GATHERED batch
-        # in the carry while gathering the next round's — the gather has no
-        # data dependency on this round's result, so XLA is free to overlap
-        # it with the round's compute (the eager loop gets the same overlap
-        # from async dispatch; without this the fused scan serializes
-        # prepare-then-train every round).
-        def body(carry, per_round):
-            gv, cur = carry
-            idx_n, mask_n, ns_r, rid = per_round
-            x, y, m = cur
-            rng = jax.random.fold_in(base_rng, rid + 1)
-            keys = round_client_rngs(rng, C)
-            client_vars, metrics = lifted(gv, x, y, m, keys)
-            new_global = weighted_average(client_vars, ns_r)
-            nxt = gathered(idx_n, mask_n)
-            return (new_global, nxt), jax.tree_util.tree_map(
-                jnp.sum, metrics
-            )
-
-        # the host pre-rotated the index arrays (see docstring): row t is
-        # round t+1's gather, row T-1 wraps to round 0's — the prologue
-        # batch reads it back here, and the scan's xs rows are already
-        # the prefetch stream (no device-side roll copies)
-        first = gathered(idx_next[-1], mask_next[-1])
-        (gv, _), mets = jax.lax.scan(
-            body,
-            (global_vars, first),
-            (idx_next, mask_next, num_samples, round_ids),
-        )
-        return gv, mets
-
-    cache = get_program_cache()
-    if local_train_fn is not None:
-        return cache.wrap_uncached("fedavg_multiround", jax.jit(multi_fn, donate_argnums=(0,)))
-    return cache.get_or_build(
-        "fedavg_multiround",
-        {
-            "kind": "fedavg_multiround",
-            "model": model_fingerprint(model),
-            "train": config.train,
-            "epochs": config.fed.epochs,
-            "task": task,
-            "mode": mode,
-            "steps": steps,
-            "bs": bs,
-            "feat": tuple(feat_shape),
-            "lab": tuple(label_shape),
-            "may_pad": may_pad,
-        },
-        lambda: jax.jit(multi_fn, donate_argnums=(0,)),
-    )
-
-
 class FedAvgAPI:
     """Standalone FedAvg simulator (ref standalone/fedavg/fedavg_api.py:13-180).
 
@@ -479,11 +364,6 @@ class FedAvgAPI:
     # Subclasses with their own batch placement (the sharded API pads +
     # shards host arrays over the mesh) disable the HBM-resident store.
     _use_device_store = True
-    # Fused multi-round chunks (FedConfig.fused_rounds > 1) are only valid
-    # when the round is exactly the plain FedAvg body — subclasses that add
-    # per-round host-side work (server optimizer step, robust post hooks)
-    # set this False.
-    _supports_fused = True
     # Whether this API's round fn may return per-client loss vectors
     # (power_of_choice's true bias signal). Subclasses that combine metric
     # trees across cohorts of different sizes (hierarchical groups) or
@@ -515,9 +395,8 @@ class FedAvgAPI:
         self.rng = jax.random.PRNGKey(config.seed)
         self.global_vars = model.init(jax.random.fold_in(self.rng, 0))
         self._local_train_fn = local_train_fn
-        self._fused_fns: dict = {}  # (steps, bs, may_pad) -> jitted multi-round fn
         self._round_plans: dict = {}  # round_idx -> (sampled, steps, bs)
-        self._may_pad_cache: dict = {}  # (round_idx, force_steps) -> bool
+        self._may_pad_cache: dict = {}  # round_idx -> bool
         self._client_mode = resolve_client_parallelism(
             config.fed.client_parallelism, model
         )
@@ -604,34 +483,6 @@ class FedAvgAPI:
             )
         self._pipeline_overlap: dict = {}
         self.pipeline_rounds = 0
-        # (start_round, n_rounds) -> (fn, rest): same contract for the
-        # fused path — the chunk's gather-index/mask stacking and H2D
-        # transfer is paid once at warmup, not again at dispatch. Valid
-        # across the warmup->train gap because every rest component is
-        # deterministic in (round, config.seed) and self.rng is never
-        # reassigned after __init__.
-        self._warm_fused: dict = {}
-        # Measured fused-vs-eager planner (FedConfig.fused_plan =
-        # "measured", algorithms/round_planner.py): probes both schedules
-        # over the first rounds — costs read from flight-recorder folds,
-        # device-synced during the probe — and commits to the winner per
-        # (algorithm, shape-class, cohort). None = legacy static plan.
-        self.planner = None
-        if (
-            config.fed.fused_plan == "measured"
-            and self._supports_fused
-            and config.fed.fused_rounds > 1
-        ):
-            from fedml_tpu.algorithms.round_planner import SchedulePlanner
-
-            self.planner = SchedulePlanner(log_fn=self.log_fn).attach(
-                self._tracer, config=config
-            )
-        elif config.fed.fused_plan not in ("static", "measured"):
-            raise ValueError(
-                "fused_plan must be 'static' or 'measured'; got "
-                f"{config.fed.fused_plan!r}"
-            )
         self._store = None
         if self._use_device_store and config.data.device_cache:
             from fedml_tpu.data.device_store import DeviceDataStore, fits_on_device
@@ -688,14 +539,11 @@ class FedAvgAPI:
     def warmup(self, log_fn=None):
         """AOT-compile this run's programs before round 0
         (``jit(...).lower(...).compile()`` — fedml_tpu/compile/warmup.py):
-        the round program for ``start_round``'s cohort shapes (the fused
-        chunk program when the planner would fuse), EVERY other
+        the round program for ``start_round``'s cohort shapes, EVERY other
         (steps, bs) shape class the partition can produce (derived via
-        ``bucket_steps`` over all client sizes — EAGER rounds 1..R never
-        hit a lazy shape-bucket compile), the horizon's fused chunk
-        programs (every distinct program × [T, C, cap] signature the
-        structural chunk walk reaches, capped — classes/chunks past the
-        warmup caps still compile lazily, compile/warmup.py), the eval program, and the
+        ``bucket_steps`` over all client sizes — rounds 1..R never hit a
+        lazy shape-bucket compile; classes past the warmup cap still
+        compile lazily, compile/warmup.py), the eval program, and the
         server-optimizer step when present. When a persistent executable
         cache is installed, warmed programs load from / export to disk,
         so a fresh process warms with zero backend compiles. Emits
@@ -710,7 +558,7 @@ class FedAvgAPI:
 
     def train_round(self, round_idx: int):
         # _round_plan is the one derivation of "this round's cohort" —
-        # memoized, shared with the fused chunk planner and _round_may_pad
+        # memoized, shared with the pipeline's prepare and _round_may_pad
         sampled, _steps, _bs = self._round_plan(round_idx)
         # "broadcast" = ship the global model + cohort batch to the device
         # (the simulator's analog of the transport path's model broadcast)
@@ -786,15 +634,11 @@ class FedAvgAPI:
         whenever preparing ahead could change what the serial schedule
         would do:
 
-        - pipeline "off";
+        - pipeline "off", or a class that opts out (``_supports_pipeline``);
         - adaptive selection (power_of_choice / straggler_aware feed on
           round r's losses/straggler flags before selecting r+1);
         - an active fault plan with participation faults (cohorts shrink
-          per round; fault accounting must describe executed rounds);
-        - the next segment runs as a fused chunk (it amortizes dispatch
-          on device and stacks its own inputs);
-        - a planner probe round (its fold must measure the serial
-          schedule cost — round_planner.py).
+          per round; fault accounting must describe executed rounds).
 
         The ``prepare`` span's seconds land in ``_pipeline_overlap`` and
         ride the next round's span as ``overlap_s`` (flight records)."""
@@ -814,10 +658,6 @@ class FedAvgAPI:
             and self.faults.plan.has_participation_faults()
         ):
             return
-        if self._fused_chunk_len(next_round) != 1:
-            return
-        if self.planner is not None and self.planner.wants_sync(next_round):
-            return
         with self._tracer.span("prepare", round=next_round) as sp:
             sampled, _steps, _bs = self._round_plan(next_round)
             self._warm_placed[next_round] = self._build_placed(
@@ -832,8 +672,8 @@ class FedAvgAPI:
         mean the transport clients attach to their uploads
         (ARG_TRAIN_LOSS), so sim and transport power_of_choice bias on
         identical signals and select identical cohorts. The fetch blocks
-        on the round (adaptive policies already run eager, per-round —
-        _fused_chunk_len disables chunking for them)."""
+        on the round (adaptive policies run serial, per-round: the
+        pipeline prepares nothing ahead for them)."""
         losses = np.asarray(metrics["client_loss_sum"])[: len(sampled)]
         counts = np.asarray(metrics["client_count"])[: len(sampled)]
         for cid, s, c in zip(sampled, losses, counts):
@@ -846,26 +686,22 @@ class FedAvgAPI:
             return [int(self._store.counts[i]) for i in sampled]
         return [len(self.data.client_y[i]) for i in sampled]
 
-    def _round_may_pad(self, round_idx: int, force_steps: int = 0) -> bool:
-        """Memoized per-round _cohort_may_pad — the fused chunk planner
-        asks per round per candidate chunk, and recomputing the count
-        loop + bucket math each time would reintroduce the host overhead
-        _round_plans was added to remove."""
-        key = (round_idx, force_steps)
-        v = self._may_pad_cache.get(key)
+    def _round_may_pad(self, round_idx: int) -> bool:
+        """Memoized per-round _cohort_may_pad — the round, warm-up and
+        ``round_program`` all ask, and the count loop + bucket math is
+        host time in the loop."""
+        v = self._may_pad_cache.get(round_idx)
         if v is None:
-            v = self._may_pad_cache[key] = self._cohort_may_pad(
-                self._round_plan(round_idx)[0], force_steps
+            v = self._may_pad_cache[round_idx] = self._cohort_may_pad(
+                self._round_plan(round_idx)[0]
             )
         return v
 
-    def _cohort_may_pad(self, sampled, force_steps: int = 0) -> bool:
+    def _cohort_may_pad(self, sampled) -> bool:
         """True iff some sampled client has at least one ALL-padding local
         step — i.e. fewer full batches than the cohort's bucketed step
         count. Host-side static knowledge: picks the round variant with or
-        without the per-step cond skip (see resolve_skip_empty_steps).
-        ``force_steps`` overrides the bucket (the fused chunk's shared
-        step count)."""
+        without the per-step cond skip (see resolve_skip_empty_steps)."""
         from fedml_tpu.data.base import bucket_steps
 
         cfg = self.config
@@ -873,7 +709,6 @@ class FedAvgAPI:
         steps, bs, _ = bucket_steps(
             counts, cfg.data.batch_size, cfg.data.pad_bucket
         )
-        steps = max(steps, force_steps)
         return any(-(-int(n) // bs) < steps for n in counts)
 
     def _stack(self, client_indices, seed: int):
@@ -1011,11 +846,10 @@ class FedAvgAPI:
         )
 
     def _round_plan(self, round_idx: int):
-        """(sampled, steps, bs) of one round, memoized: the chunk planner
-        walks rounds ahead of execution and train_rounds_fused then visits
-        the same rounds — recomputing the round-seeded sampling and the
-        bucket math twice per round was the fused path's last measurable
-        overhead vs eager."""
+        """(sampled, steps, bs) of one round, memoized: the pipeline plans
+        a round while the one before it runs, and the round, its health
+        update and its logged row then ask again — the round-seeded
+        sampling and the bucket math are done once."""
         plan = self._round_plans.get(round_idx)
         if plan is None:
             from fedml_tpu.data.base import bucket_steps
@@ -1055,7 +889,7 @@ class FedAvgAPI:
     def _apply_participation_faults(self, selected, round_idx: int) -> np.ndarray:
         """Simulator fault semantics (scheduler/faults.py): dropout/crash
         remove the client from the cohort before batching. Memoized per
-        round — the chunk planner, train loop, and metric flush all
+        round — the pipeline, train loop, and metric flush all
         re-derive the cohort, and the injector's counters must count each
         fault once. At least one survivor is kept so the round's jitted
         shapes stay well-formed."""
@@ -1086,199 +920,6 @@ class FedAvgAPI:
         self._fault_cache[r] = out
         return out
 
-    def _round_steps_class(self, round_idx: int):
-        """(steps, bs) bucket of one round's sampled cohort — the jit-shape
-        class of that round."""
-        sampled, steps, bs = self._round_plan(round_idx)
-        return steps, bs
-
-    def _fused_chunk_len(self, round_idx: int, structural: bool = False) -> int:
-        """Rounds [round_idx, round_idx+L) that can run as one fused chunk:
-        bounded by fused_rounds, the horizon, the next eval round (eval
-        fires after rounds where r % frequency == 0), and — under vmap —
-        the first steps-class change (round-2's fused feature padded the
-        whole chunk to the chunk-max steps, which under vmap cost more in
-        padded conv compute than the amortized dispatch saved: round 2's
-        fused run was 13% slower than eager). Under the scan
-        schedule a chunk may span classes: padding steps are cond-skipped
-        (train_rounds_fused compiles the cond in whenever the chunk has
-        any), so spanned rounds pay only the ~3% cond tax, not padded
-        compute.
-
-        ``structural=True`` returns the structural answer WITHOUT
-        consulting the measured planner — the warmup chunk walk
-        enumerates every fusable program regardless of which schedule
-        the probe later commits (planning a probe segment for a round
-        warmup merely inspects would corrupt the probe)."""
-        cfg = self.config
-        if (
-            cfg.fed.fused_rounds <= 1
-            or not self._supports_fused
-            or self._store is None
-            # full-batch mode sets bs = max client size, which varies per
-            # round — chunks can't share one (steps, bs) shape
-            or cfg.data.batch_size == -1
-            # adaptive policies feed on per-round signals (reported losses,
-            # straggler flags): the chunk planner derives cohorts AHEAD of
-            # execution, which would freeze those signals at planning time
-            # and make selection depend on fused_rounds — eager rounds keep
-            # the feedback loop per-round (scheduler determinism contract)
-            or cfg.fed.selection in ("power_of_choice", "straggler_aware")
-            # participation faults shrink cohorts per round: rounds of size
-            # k and k-1 share a (steps, bs) class but not a client-axis
-            # size, and train_rounds_fused stacks per-round index matrices
-            # into one [T, C, cap] array — a ragged C would crash mid-run
-            or (
-                self.faults is not None
-                and self.faults.plan.has_participation_faults()
-            )
-        ):
-            return 1
-        L = min(cfg.fed.fused_rounds, cfg.fed.comm_round - round_idx)
-        # Under the scan client schedule, padded steps are skipped lax.cond
-        # branches (train/client.py step_body), so a chunk can pad every
-        # round to the chunk-max step count and span steps classes: the
-        # chunk's local train carries the cond whenever any padding exists
-        # (chunk_may_pad in train_rounds_fused), which makes the padding
-        # itself ~free at the cost of the cond tax (~3% of a round,
-        # interleaved-measured) on the chunk's pad-free rounds. Under vmap
-        # the padding runs real compute (the round-2 fused regression) —
-        # cut the chunk at the first class change
-        # instead.
-        pad_free = self._client_mode == "scan"
-        klass = self._round_steps_class(round_idx)
-        struct = None
-        for off in range(L):
-            r = round_idx + off
-            if (
-                not pad_free
-                and off > 0
-                and self._round_steps_class(r) != klass
-            ):
-                L = off
-                break
-            if r % cfg.fed.frequency_of_the_test == 0:
-                # an eval round must be the LAST round of its chunk (eval
-                # reads global_vars right after that round)
-                struct = off + 1
-                break
-        if struct is None:
-            # round down to a power of two: chunk length is part of the
-            # jit shape key, and run lengths are arbitrary — the cap
-            # bounds compiles to log2(fused_rounds) lengths per
-            # (steps, bs) class
-            struct = 1 << (L.bit_length() - 1)
-        if struct <= 1 or self.planner is None or structural:
-            return struct
-        # measured planning: the structural length says fusion is
-        # POSSIBLE here; whether it runs fused is the planner's measured
-        # decision (probe → commit; idempotent per round, so warmup and
-        # the train loop see one answer)
-        from fedml_tpu.algorithms.round_planner import PlanKey
-
-        steps, bs = self._round_steps_class(round_idx)
-        return self.planner.plan(
-            PlanKey(
-                algo=type(self).__name__,
-                steps=int(steps),
-                bs=int(bs),
-                cohort=len(self._round_plan(round_idx)[0]),
-            ),
-            round_idx,
-            struct,
-        )
-
-    def train_rounds_fused(self, start_round: int, n_rounds: int):
-        """Run rounds [start_round, start_round+n_rounds) as one on-device
-        scan (see :func:`make_fedavg_multiround`). Returns stacked per-round
-        metrics {loss_sum, correct, count, steps: [T]}."""
-        plan = self._warm_fused.pop((start_round, n_rounds), None)
-        fn, rest = plan if plan is not None else self._fused_plan(
-            start_round, n_rounds
-        )
-        self.global_vars, metrics = fn(self.global_vars, *rest)
-        return metrics
-
-    def _fused_plan(self, start_round: int, n_rounds: int):
-        """(fused program, its non-model args) for one chunk — the round
-        indices/masks/weights plus the jitted multi-round fn from the
-        per-shape cache. Split out of :meth:`train_rounds_fused` so the
-        AOT warmup path can lower/compile the exact chunk program round 0
-        will dispatch without executing it."""
-        cfg = self.config
-        store = self._store
-        if cfg.data.batch_size == -1:
-            raise ValueError(
-                "fused rounds do not support batch_size=-1 (full batch): "
-                "bs varies with each round's max client size"
-            )
-        per_round = []
-        max_steps = bs = 0
-        for off in range(n_rounds):
-            r = start_round + off
-            sampled, steps_r, bs = self._round_plan(r)
-            per_round.append((r, sampled))
-            if (
-                self._client_mode == "vmap"
-                and max_steps
-                and steps_r != max_steps
-            ):
-                # under vmap, padded steps run real compute — fusing across
-                # a class change would silently pay padded conv compute for
-                # every round in the chunk (the round-2 regression); the
-                # scan schedule skips padded steps, so there it's free
-                raise ValueError(
-                    f"rounds {start_round}..{start_round + n_rounds - 1} span "
-                    f"steps classes {max_steps} and {steps_r}; fuse only "
-                    "within one class under client_parallelism='vmap' "
-                    "(see _fused_chunk_len)"
-                )
-            max_steps = max(max_steps, steps_r)
-        idxs, masks, ns = [], [], []
-        for r, sampled in per_round:
-            idx, mask, _, _, ns_r = store.round_indices(
-                sampled, cfg.data.batch_size, seed=cfg.seed * 1_000_003 + r,
-                pad_bucket=cfg.data.pad_bucket, force_steps=max_steps,
-            )
-            idxs.append(idx)
-            masks.append(mask)
-            ns.append(ns_r)
-        # Only the default scan-mode local train can vary its cond on
-        # may_pad (make_fedavg_round's can_vary rule) — anywhere else the
-        # flag wouldn't change the compiled program, and keying the cache
-        # on it would duplicate whole-chunk compiles for nothing.
-        can_vary = self._client_mode == "scan" and self._local_train_fn is None
-        chunk_may_pad = can_vary and any(
-            self._round_may_pad(r, force_steps=max_steps)
-            for r, _ in per_round
-        )
-        key = (max_steps, bs, chunk_may_pad)
-        fn = self._fused_fns.get(key)
-        if fn is None:
-            fn = make_fedavg_multiround(
-                self.model, cfg, max_steps, bs,
-                store.feat_shape, store.label_shape, task=self.task,
-                local_train_fn=self._local_train_fn,
-                client_mode=self._client_mode,
-                may_pad=chunk_may_pad,
-            )
-            self._fused_fns[key] = fn
-        # rotate by one round on the HOST (row t = round t+1's indices,
-        # last row wraps to round 0's): the scan consumes the rotated
-        # stack directly as its prefetch stream and the prologue reads
-        # round 0's gather back from the last row — this replaced two
-        # whole-chunk device-side jnp.roll copies per dispatch (ISSUE 14
-        # re-profile). Same bytes over the wire, zero device copies.
-        return fn, (
-            store.flat_x,
-            store.flat_y,
-            jnp.asarray(np.stack(idxs[1:] + idxs[:1])),
-            jnp.asarray(np.stack(masks[1:] + masks[:1])),
-            jnp.asarray(np.asarray(ns, np.float32)),
-            jnp.arange(start_round, start_round + n_rounds, dtype=jnp.int32),
-            self.rng,
-        )
-
     def _log_round(self, round_idx: int, metrics) -> dict:
         cfg = self.config
         count = float(metrics["count"])
@@ -1290,7 +931,7 @@ class FedAvgAPI:
         # feed power_of_choice: rounds whose program emitted per-client
         # loss vectors already reported TRUE per-client losses
         # (_report_client_losses — sim/transport parity); everything else
-        # (fused chunks, mesh/hierarchical rounds) falls back to the
+        # (mesh/hierarchical rounds) falls back to the
         # cohort mean reported to every participant
         if round_idx not in self._client_loss_rounds:
             for cid in self._round_plan(round_idx)[0]:
@@ -1321,14 +962,13 @@ class FedAvgAPI:
 
     def _pack_metrics(self, metrics) -> "jnp.ndarray":
         """One round's metrics dict -> a [K] device vector (single dispatch,
-        issued while the round itself is still in flight), or a [T, K]
-        matrix for a fused chunk's stacked metrics."""
+        issued while the round itself is still in flight)."""
         # the model's device counters ride behind the fixed keys when the
         # local train reports them (a caller's own local train need not)
         keys = self._METRIC_KEYS + tuple(
             k for k in self.model.counters if k in metrics
         )
-        return jnp.stack([jnp.asarray(metrics[k]) for k in keys], axis=-1)
+        return jnp.stack([jnp.asarray(metrics[k]) for k in keys])
 
     def _flush_pending(self, pending) -> dict:
         """Fetch all deferred per-round metrics in ONE device->host transfer
@@ -1339,9 +979,7 @@ class FedAvgAPI:
         final = {}
         if not pending:
             return final
-        rounds = []
-        for r, v in pending:
-            rounds.extend(range(r, r + (v.shape[0] if v.ndim == 2 else 1)))
+        rounds, vectors = zip(*pending)
         with self._tracer.span(
             "flush", first_round=rounds[0], last_round=rounds[-1],
             rows=len(rounds),
@@ -1354,11 +992,7 @@ class FedAvgAPI:
             # part of it: its dispatches queue behind the rounds in flight
             # and wait with them (on the v5e 70 ms a flush of 20 rounds).
             with self._tracer.span("flush_wait", rows=len(rounds)):
-                host = np.asarray(
-                    jnp.concatenate(
-                        [v if v.ndim == 2 else v[None] for _, v in pending]
-                    )
-                )
+                host = np.asarray(jnp.stack(vectors))
             fixed = len(self._METRIC_KEYS)
             if host.shape[1] > fixed:
                 # the model's device counters, summed over the rounds flushed
@@ -1376,80 +1010,50 @@ class FedAvgAPI:
     def train(self) -> Dict[str, float]:
         cfg = self.config
         final = {}
-        round_idx = self.start_round
-        pending = []  # (first round_idx, device metrics [K] or [T, K])
-        while round_idx < cfg.fed.comm_round:
-            L = self._fused_chunk_len(round_idx)
-            # measured-probe segments sync on the device INSIDE the round
-            # span: async dispatch makes an unsynced span measure host
-            # dispatch only, and the planner's fused-vs-eager commitment
-            # must compare true schedule costs (round_planner.py). Zero
-            # rounds pay this after the probe commits.
-            probe = self.planner is not None and self.planner.wants_sync(
-                round_idx
-            )
-            if L > 1:
-                with self._tracer.span(
-                    "round", round=round_idx, fused_rounds=L
-                ) as sp:
-                    metrics = self.train_rounds_fused(round_idx, L)
-                    if probe:
-                        jax.block_until_ready(self.global_vars)
-                first_round, last_round = round_idx, round_idx + L - 1
-                round_idx += L
-            else:
-                # a round the pipeline prepared carries its measured
-                # hidden-host-time as span attrs — the flight recorder
-                # folds them into the round record (overlap_s), keeping
-                # the phase accounting honest under overlap: this span's
-                # broadcast phase is ~0 BECAUSE overlap_s was spent
-                # during the previous round's device execution
-                attrs = {}
-                ov = self._pipeline_overlap.pop(round_idx, None)
-                if ov is not None:
-                    attrs = {"overlap_s": round(ov, 6), "pipeline_depth": 1}
-                with self._tracer.span("round", round=round_idx, **attrs) as sp:
-                    _, metrics = self.train_round(round_idx)
-                    if probe:
-                        jax.block_until_ready(self.global_vars)
-                first_round = last_round = round_idx
-                round_idx += 1
+        pending = []  # (round_idx, device metrics [K])
+        for round_idx in range(self.start_round, cfg.fed.comm_round):
+            # a round the pipeline prepared carries its measured
+            # hidden-host-time as span attrs — the flight recorder
+            # folds them into the round record (overlap_s), keeping
+            # the phase accounting honest under overlap: this span's
+            # broadcast phase is ~0 BECAUSE overlap_s was spent
+            # during the previous round's device execution
+            attrs = {}
+            ov = self._pipeline_overlap.pop(round_idx, None)
+            if ov is not None:
+                attrs = {"overlap_s": round(ov, 6), "pipeline_depth": 1}
+            with self._tracer.span("round", round=round_idx, **attrs) as sp:
+                _, metrics = self.train_round(round_idx)
             # a handful of small dispatches that queue behind the round just
             # dispatched: where the device is the slower side, this is where
             # the host waits for a free slot in the device's queue
-            with self._tracer.span("pack", round=first_round):
-                pending.append((first_round, self._pack_metrics(metrics)))
-            # round pipeline: the dispatched rounds are still executing on
-            # device (async dispatch; probe segments already synced inside
-            # their span) — prepare the NEXT round's cohort/batch/placement
-            # now, so its broadcast phase is host time the device never
-            # waits for. Commit point: the _warm_placed stash popped at the
-            # round boundary; _pipeline_prepare degrades to serial for
-            # adaptive policies, fault plans, fused chunks and probe rounds.
-            self._pipeline_prepare(round_idx)
+            with self._tracer.span("pack", round=round_idx):
+                pending.append((round_idx, self._pack_metrics(metrics)))
+            # round pipeline: the dispatched round is still executing on
+            # device (async dispatch) — prepare the NEXT round's
+            # cohort/batch/placement now, so its broadcast phase is host
+            # time the device never waits for. Commit point: the
+            # _warm_placed stash popped at the round boundary;
+            # _pipeline_prepare degrades to serial for adaptive policies
+            # and fault plans.
+            self._pipeline_prepare(round_idx + 1)
             # health: the cohort trained as one program — every sampled
-            # client shares the round's wall time (the ``round`` span's,
-            # per round of a fused chunk); participation/last-seen are
-            # exact per client (_round_plan is memoized, so this costs no
-            # re-sampling)
-            dt = sp.dur_us / 1e6 / (last_round - first_round + 1)
-            cohorts = [
-                (r, self._round_plan(r)[0])
-                for r in range(first_round, last_round + 1)
-            ]
+            # client shares the round's wall time (the ``round`` span's);
+            # participation/last-seen are exact per client (_round_plan is
+            # memoized, so this costs no re-sampling)
+            dt = sp.dur_us / 1e6
+            cohort = self._round_plan(round_idx)[0]
             with self._tracer.span(
-                "health", first_round=first_round, last_round=last_round,
-                clients=sum(len(cohort) for _, cohort in cohorts),
+                "health", first_round=round_idx, last_round=round_idx,
+                clients=len(cohort),
             ):
-                for r, cohort in cohorts:
-                    for cid in cohort:
-                        self.health.observe_train(int(cid), r, dt)
-            # Flush when the LAST executed round is an eval round — eval
-            # must read global_vars exactly as of that round, and
-            # _fused_chunk_len guarantees eval rounds terminate their
-            # chunk. Also flush periodically so history never lags far
-            # behind the device.
-            if self._is_eval_round(last_round) or len(pending) >= 64:
+                for cid in cohort:
+                    self.health.observe_train(int(cid), round_idx, dt)
+            # Flush on an eval round — eval must read global_vars exactly
+            # as of that round, before the next one is dispatched. Also
+            # flush periodically so history never lags far behind the
+            # device.
+            if self._is_eval_round(round_idx) or len(pending) >= 64:
                 final = self._flush_pending(pending)
         final = self._flush_pending(pending) or final
         return final

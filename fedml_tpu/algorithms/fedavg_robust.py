@@ -76,7 +76,6 @@ def make_robust_fedavg_round(
 
 
 class RobustFedAvgAPI(FedAvgAPI):
-    _supports_fused = False  # per-round host-side work forbids chunk fusion
     """FedAvg simulator with robust aggregation."""
 
     def __init__(self, config, data, model, robust: RobustConfig = RobustConfig(), **kw):

@@ -152,7 +152,6 @@ def make_gan_local_train(train_config, epochs: int, z_dim: int = 100):
 
 
 class FedGANAPI(FedAvgAPI):
-    _supports_fused = False  # custom round bodies forbid chunk fusion
     """FedAvg round skeleton with the GAN local trainer (ref FedGanAPI.py)."""
 
     def __init__(self, config, data, model=None, z_dim: int = 100, **kw):

@@ -121,7 +121,6 @@ def make_fednova_round(model, config, task="classification", local_train_fn=None
 
 
 class FedNovaAPI(FedAvgAPI):
-    _supports_fused = False  # per-round host-side work forbids chunk fusion
     """FedNova simulator — FedAvg round skeleton with normalized averaging."""
 
     def _build_round_fn(self, local_train_fn):

@@ -96,7 +96,6 @@ def make_cached_server_step(config: RunConfig):
 
 
 class FedOptAPI(FedAvgAPI):
-    _supports_fused = False  # per-round host-side work forbids chunk fusion
     """FedOpt simulator: FedAvgAPI with a server-optimizer step appended to
     each round (ref standalone/fedopt/fedopt_api.py:34-109)."""
 

@@ -20,7 +20,6 @@ from fedml_tpu.utils.seg_metrics import Evaluator
 
 
 class FedSegAPI(FedAvgAPI):
-    _supports_fused = False  # custom round bodies forbid chunk fusion
     def __init__(self, config, data, model, checkpoint_path: Optional[str] = None, **kw):
         kw.setdefault("task", "segmentation")
         super().__init__(config, data, model, **kw)

@@ -43,7 +43,6 @@ def resolve_groups(groups, num_clients: int, group_num: int, seed: int) -> List[
 
 
 class HierarchicalFedAvgAPI(FedAvgAPI):
-    _supports_fused = False  # per-round host-side work forbids chunk fusion
     # train_round runs its own group loop and never consumes the
     # _round_placed stash — pipelining would leak prepared batches
     _supports_pipeline = False

@@ -153,8 +153,6 @@ def make_qfedavg_round(
 class QFedAvgAPI(FedAvgAPI):
     """q-FedAvg simulator on the FedAvg skeleton."""
 
-    _supports_fused = False  # bespoke aggregation, no chunked round fn
-
     def __init__(self, config, data, model, q: float = 1.0, **kw):
         if config.train.client_optimizer != "sgd" or config.train.momentum:
             raise ValueError(

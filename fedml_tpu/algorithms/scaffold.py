@@ -570,8 +570,6 @@ class ScaffoldAPI(FedAvgAPI):
     SPILLS to the disk tier beyond it (state_store.MmapClientState —
     cohort rows only ride to device; round 3 refused instead)."""
 
-    _supports_fused = False  # per-round control-variate state exchange
-
     def __init__(self, config: RunConfig, data: FederatedDataset, model: ModelDef, **kw):
         super().__init__(config, data, model, **kw)
         from fedml_tpu.algorithms.state_store import (

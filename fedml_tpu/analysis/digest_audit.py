@@ -271,7 +271,6 @@ _CHOICE_VALUES: Dict[str, Any] = {
     "train.compute_dtype": "bfloat16",
     "train.augment": "crop_flip",
     "fed.client_parallelism": "scan",
-    "fed.fused_plan": "measured",
     "fed.selection": "weighted",
     "fed.state_store": "mmap",
     "server.server_optimizer": "adam",
@@ -293,7 +292,7 @@ _CHOICE_VALUES: Dict[str, Any] = {
 # dedup tests), not by this leaf. Audited on the representative spec
 # each run (expected
 # status: merged-identical/rejected, never VIOLATION) instead of fanning
-# out over all ~14 factories, which bounds audit time. A leaf absent
+# out over every registered factory, which bounds audit time. A leaf absent
 # from BOTH this set and the tree is impossible; a NEW unclassified leaf
 # — e.g. the next CompileConfig knob — fans out over every factory by
 # default, which is the point.
@@ -306,11 +305,6 @@ KNOWN_BENIGN = frozenset({
     "fed.frequency_of_the_test", "fed.ci", "fed.group_num",
     "fed.group_comm_round", "fed.selection", "fed.overprovision_factor",
     "fed.fault_plan", "fed.deadline_s", "fed.min_clients",
-    # fused_plan steers WHICH schedule (fused chunk vs eager rounds) the
-    # host dispatches — both programs exist either way and their digests
-    # are unchanged; the planner (algorithms/round_planner.py) is pure
-    # host-side measurement
-    "fed.fused_rounds", "fed.fused_plan",
     "fed.eval_on_clients", "fed.async_buffer_k",
     "fed.async_staleness_exp", "fed.async_server_lr", "fed.state_store",
     "fed.state_budget_bytes", "fed.state_dir",
@@ -445,26 +439,6 @@ def default_specs() -> List[FactorySpec]:
 
     def fedavg_args(cfg, ctx, kw):
         return (_gv_shapes(_model(ctx)),) + _cohort(cfg, C)
-
-    def multiround_build(cfg, ctx, kw):
-        from fedml_tpu.algorithms.fedavg import make_fedavg_multiround
-
-        return make_fedavg_multiround(
-            _model(ctx), cfg, steps=S, bs=B, feat_shape=FEAT, label_shape=()
-        )
-
-    def multiround_args(cfg, ctx, kw):
-        T, cap, n = 2, S * B, 48
-        return (
-            _gv_shapes(_model(ctx)),
-            _sds((n, 128), np.float32),  # the store's lane-padded rows
-            _sds((n,), np.int32),
-            _sds((T, C, cap), np.int32),
-            _sds((T, C, cap), np.float32),
-            _sds((T, C), np.float32),
-            _sds((T,), np.int32),
-            _sds((2,), np.uint32),
-        )
 
     def fednova_build(cfg, ctx, kw):
         from fedml_tpu.algorithms.fednova import make_fednova_round
@@ -726,10 +700,6 @@ def default_specs() -> List[FactorySpec]:
         FactorySpec(
             "fedavg_round", fedavg_build, fedavg_args,
             _AUTO_FANOUT + _BENIGN_PERTURBS,
-        ),
-        FactorySpec(
-            "fedavg_multiround", multiround_build, multiround_args,
-            _AUTO_FANOUT,
         ),
         FactorySpec("fednova_round", fednova_build, fedavg_args, _AUTO_FANOUT),
         FactorySpec(

@@ -158,24 +158,15 @@ RUNTIMES = ("vmap", "mesh", "loopback", "mqtt", "shm", "grpc")
                    "(printed to stderr). Off by default.")
 @click.option("--no_device_cache", is_flag=True, default=False,
               help="Disable the HBM-resident data store (data/device_store.py)")
-@click.option("--fused_rounds", type=int, default=1,
-              help="Run up to N rounds as one on-device lax.scan chunk "
-                   "(fedavg/fedprox + vmap runtime; needs the device cache)")
-@click.option("--fused_plan", type=click.Choice(("static", "measured")),
-              default="static",
-              help="fused_rounds > 1: 'static' always fuses where possible "
-                   "(legacy); 'measured' probes BOTH schedules over the "
-                   "first rounds (flight-recorder phase costs) and commits "
-                   "to the measured winner (algorithms/round_planner.py)")
 @click.option("--pipeline", type=click.Choice(("off", "auto", "on")),
               default="auto",
               help="Round pipelining (sim runtimes): while round r runs on "
                    "device, prepare round r+1's cohort/batch/placement on "
                    "the host (algorithms/fedavg.py _pipeline_prepare). "
                    "Numerics are byte-identical to serial; adaptive "
-                   "selection policies, active fault plans, fused chunks "
-                   "and planner probe rounds degrade to serial "
-                   "automatically. 'on' is an explicit alias of 'auto'")
+                   "selection policies and active fault plans degrade to "
+                   "serial automatically. 'on' is an explicit alias of "
+                   "'auto'")
 @click.option("--client_parallelism", type=click.Choice(("auto", "vmap", "scan")),
               default="auto",
               help="How one chip runs the sampled clients: vmap (batched) "
@@ -617,8 +608,6 @@ def build_config(opt) -> RunConfig:
             ci=opt["ci"],
             group_num=opt["group_num"],
             group_comm_round=opt["group_comm_round"],
-            fused_rounds=opt.get("fused_rounds", 1),
-            fused_plan=opt.get("fused_plan", "static"),
             eval_on_clients=opt.get("eval_on_clients", False),
             deadline_s=opt.get("deadline_s", 0.0),
             min_clients=opt.get("min_clients", 1),
@@ -1120,11 +1109,6 @@ def run(**opt):
                 # vmap/mesh fault accounting into summary.json (the transport
                 # runners log their shared injector themselves)
                 log_fn(api.faults.summary_row())
-            if getattr(api, "planner", None) is not None:
-                # measured fused-vs-eager planner: committed schedule +
-                # both arms' probed per-round costs (flight/planner_*) —
-                # the ci.sh fused-vs-eager gate reads the winner here
-                log_fn(api.planner.summary_row())
             if getattr(api, "pipeline_rounds", 0):
                 # round pipeline: rounds whose host prep was hidden behind
                 # the previous round's device dispatch (FedConfig.pipeline;

@@ -18,9 +18,8 @@ touches no training state (pinned by tests/test_compile.py).
 
 Covered programs, matching what ``FedAvgAPI.train`` dispatches first:
 
-- the round program — the eager round-fn variant for round
-  ``start_round``'s cohort shapes, or the fused multi-round chunk
-  program when ``fused_rounds`` applies;
+- the round program — the round-fn variant for round
+  ``start_round``'s cohort shapes;
 - **every other (steps, bs) shape class the partition can produce**
   (:func:`fedml_tpu.data.base.partition_shape_classes` — the cohort
   bucket only reads the max member's count, so the reachable classes
@@ -36,14 +35,9 @@ With a persistent executable cache installed
 serialized to disk — so the NEXT process deserializes its whole warmup
 set instead of compiling it (zero-cold-start serving).
 
-Fused multi-round chunk programs are pre-enumerated too
-(:func:`_warm_fused_chunks`, ISSUE-14 satellite closing the PR-8
-leftover): the horizon's chunk schedule is walked at STRUCTURAL lengths
-and every distinct (program, [T, C, cap] signature) pair is warmed —
-bounded by ``_MAX_WARM_CHUNKS`` programs over ``_MAX_CHUNK_SEGMENTS``
-examined segments, skips logged. Remaining lazy compiles: chunk
-programs past those caps, and cohorts reshaped mid-run by participation
-faults (a fault-shrunk cohort is a different client-axis size)."""
+Remaining lazy compiles: shape classes past ``_MAX_WARM_CLASSES``, and
+cohorts reshaped mid-run by participation faults (a fault-shrunk cohort
+is a different client-axis size)."""
 
 from __future__ import annotations
 
@@ -96,19 +90,6 @@ def _warm_one(rows: dict, label: str, fn, args, tracer) -> None:
 # stall over shapes most runs never dispatch. Classes are warmed
 # most-populous first and the skip is LOGGED (never silent).
 _MAX_WARM_CLASSES = 32
-
-# Fused-chunk pre-enumeration bounds (PR-8 leftover closed here: chunk
-# programs beyond round ``start_round``'s used to compile lazily at
-# dispatch). Chunk program diversity comes from (shape class, power-of-2
-# length, chunk_may_pad) combinations, which recur with the eval/class
-# period — examining a bounded window of chunk segments sees them all;
-# the warm cap bounds compile time like the class cap above.
-_MAX_WARM_CHUNKS = 8
-_MAX_CHUNK_SEGMENTS = 64
-# ... and the walk itself is bounded in ROUNDS examined: chunk-free
-# schedules (eval every round) would otherwise call the per-round
-# planner across the whole horizon warming nothing.
-_MAX_WALK_ROUNDS = 1024
 
 
 def _classes_by_population(
@@ -177,7 +158,7 @@ def _class_may_pad_variants(fulls, st: int, bs: int, cohort: int):
 
 
 def _warm_partition_classes(api, rows: dict, tracer, r0: int) -> None:
-    """Pre-enumerate and AOT-compile the eager round program for EVERY
+    """Pre-enumerate and AOT-compile the round program for EVERY
     (steps, bs) shape class the partition can produce — not just round
     ``r0``'s — so later rounds whose cohorts bucket differently dispatch
     a warmed executable instead of paying a lazy compile (ROADMAP item 1:
@@ -261,92 +242,6 @@ def _warm_partition_classes(api, rows: dict, tracer, r0: int) -> None:
             )
 
 
-def _warm_fused_chunks(api, rows: dict, tracer, r0: int, skip=None) -> None:
-    """Walk the horizon's chunk schedule (STRUCTURAL lengths — the
-    measured planner is deliberately not consulted, see
-    ``_fused_chunk_len(structural=True)``) and AOT-compile every
-    DISTINCT fused program the run can dispatch: distinct (program
-    digest, [T, C, cap] signature) pairs — chunk lengths past
-    ``start_round``'s and classes the walk reaches. Each newly warmed
-    chunk's staged plan is memoized in ``api._warm_fused`` so its first
-    dispatch reuses the index/mask H2D paid here — except under the
-    measured planner, whose probe's eager segments shift every later
-    chunk's start round, so the structural (round, length) keys would
-    never be popped: there only the compiled programs are warmed (the
-    ProgramCache/executable store is keyed by digest + shapes, not
-    start round) and no staged arrays are retained. Bounded by
-    :data:`_MAX_WARM_CHUNKS` warms over :data:`_MAX_CHUNK_SEGMENTS`
-    examined segments and :data:`_MAX_WALK_ROUNDS` examined rounds
-    (a schedule that never forms a chunk — eval every round — must not
-    walk a 100k-round horizon for zero warms); skips logged, never
-    silent."""
-    cfg = api.config
-    if (
-        cfg.fed.fused_rounds <= 1
-        or not getattr(api, "_supports_fused", True)
-        or getattr(api, "_store", None) is None
-        or not hasattr(api, "_fused_plan")
-        or not hasattr(api, "_warm_fused")
-    ):
-        return
-    warmed = set(skip or ())
-    warms = segments = examined = 0
-    r = r0
-    while (
-        r < cfg.fed.comm_round
-        and segments < _MAX_CHUNK_SEGMENTS
-        and examined < _MAX_WALK_ROUNDS
-    ):
-        examined += 1
-        try:
-            L = api._fused_chunk_len(r, structural=True)
-        except Exception as e:  # noqa: BLE001 — planner guards vary by algo
-            logging.warning("fused-chunk walk stopped at round %d: %s", r, e)
-            break
-        if L > 1:
-            segments += 1
-            try:
-                fn, rest = (
-                    api._warm_fused.get((r, L)) or api._fused_plan(r, L)
-                )
-                idx_shape = tuple(getattr(rest[2], "shape", ()))
-                key = (getattr(fn, "digest", None) or id(fn), idx_shape)
-                if key not in warmed:
-                    if warms >= _MAX_WARM_CHUNKS:
-                        rows["compile/warm_chunks_skipped"] = (
-                            rows.get("compile/warm_chunks_skipped", 0) + 1
-                        )
-                        logging.warning(
-                            "fused-chunk pre-enumeration capped at %d "
-                            "programs; chunk at round %d compiles lazily",
-                            _MAX_WARM_CHUNKS, r,
-                        )
-                    else:
-                        warmed.add(key)
-                        warms += 1
-                        if getattr(api, "planner", None) is None:
-                            # static plan only: dispatch pops the exact
-                            # (start_round, L) key, so the staged H2D is
-                            # reused; the measured probe shifts starts
-                            # and would strand these device arrays
-                            api._warm_fused.setdefault((r, L), (fn, rest))
-                        _warm_one(
-                            rows,
-                            f"round_fused_r{r}x{L}",
-                            fn,
-                            (api.global_vars, *rest),
-                            tracer,
-                        )
-            except Exception as e:  # noqa: BLE001 — enumeration must not
-                logging.warning(  # kill the run
-                    "fused-chunk warm at round %d failed: %s", r, e
-                )
-                break
-        r += L
-    if warms:
-        rows["compile/warm_chunk_programs"] = warms
-
-
 def warmup_api(api, log_fn: Optional[Callable[[dict], None]] = None) -> dict:
     """Warm a FedAvgAPI-family simulator (vmap or mesh): round + eval +
     server-optimizer programs for ``api.start_round``'s shapes. Returns
@@ -370,69 +265,29 @@ def warmup_api(api, log_fn: Optional[Callable[[dict], None]] = None) -> dict:
             api.global_vars = jax.device_put(
                 api.global_vars, NamedSharding(mesh, PartitionSpec())
             )
-        # -- round program: fused chunk when the planner would fuse,
-        #    else the eager variant for round r0's cohort --
-        fused_len = 1
-        if hasattr(api, "_fused_chunk_len") and hasattr(api, "_fused_plan"):
-            try:
-                fused_len = api._fused_chunk_len(r0)
-            except Exception:  # noqa: BLE001 — planner guards vary by algo
-                fused_len = 1
-        if fused_len > 1:
-            fn, rest = api._fused_plan(r0, fused_len)
-            if hasattr(api, "_warm_fused"):
-                # hand the whole plan to train_rounds_fused so the chunk's
-                # index/mask stacking + H2D transfer is paid once, not twice
-                api._warm_fused[(r0, fused_len)] = (fn, rest)
-            _warm_one(
-                rows, "round_fused", fn, (api.global_vars, *rest), tracer
+        # -- round program: the variant for round r0's cohort --
+        sampled = api._round_plan(r0)[0]
+        batch = api._round_batch(sampled, r0)
+        rng = jax.random.fold_in(api.rng, r0 + 1)
+        placed = api._place_batch(batch, rng)
+        if hasattr(api, "_warm_placed"):
+            # hand the placed batch to train_round(r0) so the stack +
+            # host->device transfer is paid once, not twice
+            api._warm_placed[r0] = placed
+        fn = api.round_fn
+        variant_for = getattr(fn, "variant_for", None)
+        if variant_for is not None:
+            fn = variant_for(api._round_may_pad(r0))
+        _warm_one(rows, "round", fn, (api.global_vars, *placed), tracer)
+        # every OTHER shape class the partition can produce — rounds
+        # 1..R must never pay a lazy shape-bucket compile
+        try:
+            _warm_partition_classes(api, rows, tracer, r0)
+        except Exception as e:  # noqa: BLE001 — enumeration must not
+            logging.warning(  # kill the run; r0 is already warm
+                "shape-class pre-enumeration failed: %s", e
             )
-            # fused runs still dispatch EAGER rounds (single-round chunks
-            # at eval boundaries, class changes under vmap) — enumerate
-            # the partition's eager classes too
-            try:
-                _warm_partition_classes(api, rows, tracer, r0)
-            except Exception as e:  # noqa: BLE001
-                logging.warning(
-                    "shape-class pre-enumeration failed: %s", e
-                )
-                rows["compile/class_enum_error"] = f"{type(e).__name__}: {e}"
-            # ...and the horizon's OTHER chunk programs (lengths cut by
-            # eval boundaries / class changes beyond this first chunk's)
-            r0_key = (
-                getattr(fn, "digest", None) or id(fn),
-                tuple(getattr(rest[2], "shape", ())),
-            )
-            _warm_fused_chunks(
-                api, rows, tracer, r0 + fused_len, skip={r0_key}
-            )
-        else:
-            sampled = api._round_plan(r0)[0]
-            batch = api._round_batch(sampled, r0)
-            rng = jax.random.fold_in(api.rng, r0 + 1)
-            placed = api._place_batch(batch, rng)
-            if hasattr(api, "_warm_placed"):
-                # hand the placed batch to train_round(r0) so the stack +
-                # host->device transfer is paid once, not twice
-                api._warm_placed[r0] = placed
-            fn = api.round_fn
-            variant_for = getattr(fn, "variant_for", None)
-            if variant_for is not None:
-                fn = variant_for(api._round_may_pad(r0))
-            _warm_one(rows, "round", fn, (api.global_vars, *placed), tracer)
-            # every OTHER shape class the partition can produce — rounds
-            # 1..R must never pay a lazy shape-bucket compile
-            try:
-                _warm_partition_classes(api, rows, tracer, r0)
-            except Exception as e:  # noqa: BLE001 — enumeration must not
-                logging.warning(  # kill the run; r0 is already warm
-                    "shape-class pre-enumeration failed: %s", e
-                )
-                rows["compile/class_enum_error"] = f"{type(e).__name__}: {e}"
-            # a 1-length chunk at r0 (eval rounds terminate their chunk,
-            # and round 0 is always an eval round) does NOT mean the run
-            # is eager — fused chunks start at r0+1; enumerate them
-            _warm_fused_chunks(api, rows, tracer, r0 + 1)
+            rows["compile/class_enum_error"] = f"{type(e).__name__}: {e}"
         # -- eval program at the cached test-batch shapes --
         if getattr(api, "eval_fn", None) is not None and hasattr(
             api, "_eval_batches"

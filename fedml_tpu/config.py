@@ -79,22 +79,7 @@ class FedConfig:
     # and discards late round-tagged uploads. 0 = wait for all (ref parity).
     deadline_s: float = 0.0
     min_clients: int = 1
-    # Fused round chunks (vmap runtime + HBM data store only): run up to
-    # this many rounds as ONE jitted lax.scan — zero host round-trips inside
-    # the chunk. 1 = eager per-round dispatch. Chunks never span an eval
-    # round, so observed metrics are identical to the eager loop.
-    fused_rounds: int = 1
-    # How the round planner decides fused-vs-eager when fused_rounds > 1
-    # (algorithms/round_planner.py). "static": legacy — always fuse where
-    # structurally possible. "measured": probe BOTH schedules over the
-    # first rounds (costs read from the flight recorder's folded phase
-    # records, device-synced during the probe) and commit to the measured
-    # winner per (algorithm, shape-class, cohort) — no config heuristic
-    # decides the schedule, a measurement does. Numerics are identical
-    # either way (fused == eager is a test contract); only wall clock
-    # differs.
-    fused_plan: str = "static"
-    # Round pipeline (eager rounds): while round r's programs execute on
+    # Round pipeline: while round r's programs execute on
     # device (JAX dispatch is async), the host prepares round r+1 —
     # cohort selection, batch gather/stack, H2D placement — and stashes
     # the placed batch for the round boundary (the _warm_placed commit
@@ -103,10 +88,8 @@ class FedConfig:
     # serial schedule (tests/test_pipeline.py). "auto" (default)
     # pipelines wherever that purity holds and degrades to serial
     # automatically: adaptive selection (power_of_choice /
-    # straggler_aware need round r's signals before selecting r+1),
-    # active fault plans that shrink cohorts, fused chunks (the chunk
-    # already amortizes dispatch on device), and planner probe rounds
-    # (their folds must measure the serial schedule). "on" is an alias
+    # straggler_aware need round r's signals before selecting r+1) and
+    # active fault plans that shrink cohorts. "on" is an alias
     # of "auto" (the degradations are correctness rules, not
     # preferences); "off" forces the serial schedule. Overlap is
     # measured and folded per round as flight `overlap_s`.

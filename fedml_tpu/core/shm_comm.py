@@ -28,6 +28,7 @@ and has no such footgun."""
 from __future__ import annotations
 
 import os
+import sys
 import threading
 import traceback
 from multiprocessing import connection, shared_memory
@@ -37,6 +38,9 @@ from fedml_tpu.core.comm import BaseCommManager
 from fedml_tpu.core.message import Message, write_wire_parts
 
 _FAMILY = "AF_UNIX"
+# Bytes of a socket path that ``sockaddr_un.sun_path`` holds before its NUL
+# (108 on Linux, 104 on the BSDs and macOS).
+_SUN_PATH_MAX = 107 if sys.platform.startswith("linux") else 103
 
 
 def _addr(sock_dir: str, rank: int, namespace: str = "") -> str:
@@ -70,6 +74,14 @@ class ShmCommManager(BaseCommManager):
         self.zero_copy = zero_copy
         self.namespace = str(namespace)
         addr = _addr(sock_dir, self.rank, self.namespace)
+        n = len(os.fsencode(addr))
+        if n > _SUN_PATH_MAX:
+            # bind() would say only "AF_UNIX path too long"
+            raise ValueError(
+                f"shm socket path {addr!r} is {n} bytes, over the "
+                f"{_SUN_PATH_MAX} that a UNIX socket address holds: use a "
+                "shorter sock_dir or namespace"
+            )
         if os.path.exists(addr):  # stale socket from a crashed run
             os.unlink(addr)
         # backlog: the default (1) makes a K-client broadcast race the

@@ -94,9 +94,7 @@ def gather_batch(flat_x, flat_y, idx, mask, steps, bs, feat_shape, label_shape):
     them, zero the padded slots (padded indices point at row 0; zeroing
     keeps the result bit-identical to host stack_clients, which zero-pads),
     and only then restore the feature shape, on the cohort-sized result.
-    Plain traced function shared by the eager program
-    (:meth:`DeviceDataStore.gather_program`) and the fused multi-round
-    scan, which inlines it in its own program."""
+    The body of :meth:`DeviceDataStore.gather_program`."""
     with jax.named_scope("gather"):
         x = jnp.take(flat_x, idx, axis=0)
         y = jnp.take(flat_y, idx, axis=0)
@@ -134,7 +132,7 @@ class DeviceDataStore:
         )
 
     def gather_program(self, steps: int, bs: int):
-        """The eager round-batch program for one (steps, bs) shape class:
+        """The round-batch program for one (steps, bs) shape class:
         :func:`gather_batch` as ONE ProgramCache-routed jit, so that (a)
         the AOT warmup pre-enumeration can compile it per class up front
         and (b) it persists through the executable cache like every other
@@ -166,23 +164,13 @@ class DeviceDataStore:
         seed: int = 0,
         pad_bucket: int = 1,
         shuffle: bool = True,
-        force_steps: int = None,
     ):
         """Host-side index/mask matrices for one round's gather:
         (idx [C, cap] int32, mask [C, cap] float32, steps, bs, ns).
         ``ns`` is the per-client true sample count — the single source for
-        aggregation weights (eager and fused paths must not re-derive it).
-        ``force_steps`` overrides the bucketed step count so a fused
-        multi-round scan can use one uniform shape across rounds (the extra
-        all-padding steps are gated no-ops in the local-train scan)."""
+        aggregation weights."""
         ns = [int(self.counts[i]) for i in client_indices]
         steps, bs, cap = bucket_steps(ns, batch_size, pad_bucket)
-        if force_steps is not None:
-            if force_steps < steps:
-                raise ValueError(
-                    f"force_steps={force_steps} < required steps={steps}"
-                )
-            steps, cap = force_steps, force_steps * bs
 
         rng = np.random.default_rng(seed)
         C = len(client_indices)

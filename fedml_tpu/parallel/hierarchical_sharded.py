@@ -169,7 +169,6 @@ class HierarchicalShardedAPI(FedAvgAPI):
     device program with no host round-trips between sub-rounds."""
 
     _use_device_store = False
-    _supports_fused = False
     # group-loop train_round never consumes the _round_placed stash
     _supports_pipeline = False
     _donate = True

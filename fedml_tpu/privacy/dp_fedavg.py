@@ -205,7 +205,6 @@ class DPFedAvgAPI(FedAvgAPI):
     :func:`poisson_client_sampling`), padded to a bucketed static client
     axis so realized sizes don't multiply compiled shapes."""
 
-    _supports_fused = False  # the accountant steps on the host every round
     sampling = "poisson"
 
     def __init__(self, config, data, model, dp: DpConfig = DpConfig(), **kw):
@@ -304,12 +303,12 @@ class DPFedAvgAPI(FedAvgAPI):
             batch = super()._round_batch(sampled, round_idx)
         return pad_clients_to(batch, bucket_cohort(m))
 
-    def _round_may_pad(self, round_idx: int, force_steps: int = 0) -> bool:
+    def _round_may_pad(self, round_idx: int) -> bool:
         sampled = self._round_plan(round_idx)[0]
         m = len(sampled)
         if m == 0 or bucket_cohort(m) > m:
             return True  # dummy cohort rows are all-padding steps
-        return super()._round_may_pad(round_idx, force_steps)
+        return super()._round_may_pad(round_idx)
 
     def _build_round_fn(self, local_train_fn):
         post_train, aggregate_fn, post_aggregate = make_dp_hooks(
@@ -361,7 +360,16 @@ class DPFedAvgAPI(FedAvgAPI):
         self.accountant._rdp = [float(v) for v in np.asarray(tree["dp_rdp"])]
         self.accountant.rounds = int(np.asarray(tree["dp_rounds"]))
         if "dp_sample_secret" in tree:
-            self._sample_secret = _words_to_secret(tree["dp_sample_secret"])
+            secret = _words_to_secret(tree["dp_sample_secret"])
+            if secret != self._sample_secret:
+                # whatever was planned before the restore (a warm-up, a
+                # ``round_program`` probe) drew its cohorts from the
+                # discarded secret: running those plans would decouple the
+                # executed cohorts from the accounted participation stream
+                self._round_plans.clear()
+                self._may_pad_cache.clear()
+                self._warm_placed.clear()
+            self._sample_secret = secret
         else:
             warnings.warn(
                 "checkpoint predates dp_sample_secret: it was written by a "

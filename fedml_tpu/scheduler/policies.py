@@ -367,8 +367,8 @@ class ClientScheduler:
     """The per-run selection driver every runtime shares: policy + context
     + per-round memo + the telemetry/metrics fan-out.
 
-    - ``select(r)`` is memoized per round, so the fused-chunk planner's
-      lookahead, the round loop, and a checkpoint writer all see ONE
+    - ``select(r)`` is memoized per round, so the round pipeline's
+      look-ahead, the round loop, and a checkpoint writer all see ONE
       decision per round; the memo (plus the loss map feeding
       power_of_choice) is exactly the state ``state_dict`` persists so a
       resumed run re-selects its in-flight cohort byte-identically.
@@ -519,7 +519,7 @@ class ClientScheduler:
             # the growth class the population runtime removes. Evicted
             # rounds re-derive as pure functions of (seed, round) — the
             # same property state_dict's bound already relies on. The
-            # floor keeps the fused chunk planner's lookahead and the
+            # floor keeps the round pipeline's look-ahead and the
             # short-run test contracts (full-run selections()) intact.
             cap = max(self._memo_rounds, 64)
             while len(self._selections) > cap:

@@ -362,8 +362,6 @@ class FlightRecorder:
                 "stragglers": stragglers,
                 "clients_seen": fleet,
             }
-            if attrs.get("fused_rounds"):
-                rec["fused_rounds"] = int(attrs["fused_rounds"])
             if attrs.get("overlap_s") is not None:
                 # host prep for the NEXT round that hid behind this round's
                 # device work (FedConfig.pipeline) — recorded additively:

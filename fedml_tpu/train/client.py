@@ -217,9 +217,8 @@ def make_local_train(
             params, extra, opt_state = carry
             ep_rng = jax.random.fold_in(rng, epoch_idx)
             if reshuffle_each_epoch:
-                # masked_epoch_perm: the fused multi-round scan (uniform
-                # chunk shapes) and the eager per-round path see identical
-                # math — see its docstring
+                # masked_epoch_perm: minibatch composition does not depend
+                # on the padded capacity — see its docstring
                 perm = masked_epoch_perm(ep_rng, m_flat)
             else:
                 perm = jnp.arange(n_flat)

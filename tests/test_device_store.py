@@ -68,68 +68,6 @@ def test_store_batch_bitmatches_host_stacking(kind):
             np.testing.assert_array_equal(got, want)
 
 
-def _image_api(fused_rounds):
-    from fedml_tpu.algorithms.fedavg import FedAvgAPI
-    from fedml_tpu.models import ModelDef
-    from fedml_tpu.models.cnn import CNNOriginalFedAvg
-
-    data = _data(feat_shape=(28, 28, 1), num_clients=8, samples_per_client=12)
-    model = ModelDef(
-        module=CNNOriginalFedAvg(num_classes=5), input_shape=(28, 28, 1),
-        num_classes=5,
-    )
-    cfg = RunConfig(
-        data=DataConfig(batch_size=8),
-        fed=FedConfig(
-            client_num_in_total=8, client_num_per_round=3, comm_round=4,
-            fused_rounds=fused_rounds, frequency_of_the_test=100,
-        ),
-        train=TrainConfig(lr=0.05),
-    )
-    return FedAvgAPI(cfg, data, model, log_fn=lambda row: None)
-
-
-def test_fused_path_gathers_what_the_eager_path_gathers():
-    """The fused multi-round scan reads the same 2-D store through the same
-    gather: its chunk's batches are the eager rounds' (bit-equal to
-    stack_clients, at an image shape), and training through it ends where
-    the eager rounds end."""
-    import jax
-
-    from fedml_tpu.data.device_store import gather_batch
-
-    fused, eager = _image_api(fused_rounds=4), _image_api(fused_rounds=1)
-    store = fused._store
-    assert store.feat_shape == (28, 28, 1) and store.flat_x.shape[1] == 896
-    _, args = fused._fused_plan(0, 4)
-    flat_x, flat_y, idx_next, mask_next = args[:4]
-    assert flat_x is store.flat_x
-    # row t of the rotated stack is round t+1's gather; the last is round 0's
-    for r in range(4):
-        sampled = fused._round_plan(r)[0]
-        host = stack_clients(
-            fused.data, sampled, 8, seed=fused.config.seed * 1_000_003 + r,
-            pad_bucket=fused.config.data.pad_bucket,
-        )
-        steps = idx_next.shape[2] // 8
-        x, y, mask = gather_batch(
-            flat_x, flat_y, idx_next[(r - 1) % 4], mask_next[(r - 1) % 4],
-            steps, 8, store.feat_shape, store.label_shape,
-        )
-        s = host.x.shape[1]  # the chunk pads every round to its longest
-        np.testing.assert_array_equal(np.asarray(x)[:, :s], host.x)
-        np.testing.assert_array_equal(np.asarray(y)[:, :s], host.y)
-        np.testing.assert_array_equal(np.asarray(mask)[:, :s], host.mask)
-        assert not np.asarray(mask)[:, s:].any()
-    fused.train()
-    eager.train()
-    for a, b in zip(
-        jax.tree_util.tree_leaves(fused.global_vars),
-        jax.tree_util.tree_leaves(eager.global_vars),
-    ):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6)
-
-
 def test_no_op_of_the_gather_program_is_the_size_of_the_population():
     """The rule the copy broke: every instruction of the compiled gather
     program is the size of the cohort's batch; only its parameters have

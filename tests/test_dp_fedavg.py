@@ -206,6 +206,32 @@ def test_ledger_survives_checkpoint_roundtrip():
         assert b._sample_clients(r).tolist() == a._sample_clients(r).tolist()
 
 
+def test_restore_of_another_secret_redraws_planned_cohorts():
+    """A plan memoised before the restore (``round_program``, a warm-up's
+    stash) drew its cohort from the construction-time secret; after a
+    checkpoint brings another secret the same rounds must be drawn again
+    from it, or the executed cohorts leave the accounted stream."""
+    data, model = _data_model()
+    dp = DpConfig(clip_norm=1.0, noise_multiplier=0.8)
+    a = DPFedAvgAPI(_cfg(rounds=40), data, model, dp=dp)
+    b = DPFedAvgAPI(_cfg(rounds=40), data, model, dp=dp)
+    assert a._sample_secret != b._sample_secret
+    rounds = range(40)
+    stale = [b._round_plan(r)[0].tolist() for r in rounds]
+    b._round_may_pad(0)
+    b.warmup()  # stashes round 0's batch, placed for the stale cohort
+    assert 0 in b._warm_placed
+    want = [a._sample_clients(r).tolist() for r in rounds]
+    assert stale != want  # 40 Poisson draws of 8 clients from two secrets
+    b.restore_state(a.checkpoint_state())
+    assert not b._warm_placed and not b._may_pad_cache
+    assert [b._round_plan(r)[0].tolist() for r in rounds] == want
+    # the same secret again invalidates nothing
+    plans = dict(b._round_plans)
+    b.restore_state(a.checkpoint_state())
+    assert all(b._round_plans[r] is plans[r] for r in rounds)
+
+
 def test_dp_sampling_secret_is_os_entropy_not_config_seed():
     """Advisor r4 (medium): config.seed defaults to 0 and is public/reused
     (data shuffling, broadcast init), so the participation stream must

@@ -3,7 +3,7 @@ while round r's device dispatch is in flight must be byte-identical to the
 serial loop — the stash commit point is the same `_warm_placed` contract
 warmup uses — and must degrade to serial automatically whenever next
 round's inputs depend on this round's outcome (adaptive selection, active
-fault plans, fused chunks, planner probe rounds). Also covers the
+fault plans). Also covers the
 transport half: once-per-round broadcast encoding and the quantized int8
 downlink (CommConfig.downlink_compression)."""
 
@@ -158,24 +158,6 @@ def test_adaptive_selection_forces_serial():
     api = FedAvgAPI(_cfg("auto", selection="power_of_choice"), data, model)
     api.train()
     assert api.pipeline_rounds == 0
-
-
-def test_fused_chunks_pipeline_only_the_eager_gaps():
-    """Fused multi-round chunks place their whole chunk at dispatch — the
-    pipeline must never prepare a round that a chunk will consume (the
-    stash would leak), but the single eager rounds BETWEEN chunks (cut by
-    eval boundaries) are fair game. Byte parity either way."""
-    data, model = _data(), _model()
-    piped = FedAvgAPI(_cfg("auto", fused_rounds=4), data, model)
-    if piped._store is None:
-        pytest.skip("device store required for fusion")
-    piped.train()
-    serial = FedAvgAPI(_cfg("off", fused_rounds=4), data, model)
-    serial.train()
-    _tree_equal(serial.global_vars, piped.global_vars)
-    for rs, rp in zip(serial.history, piped.history):
-        assert rs["Train/Loss"] == rp["Train/Loss"]
-    assert not piped._warm_placed  # nothing prepared into a fused chunk
 
 
 def test_unsupported_subclasses_stay_serial():

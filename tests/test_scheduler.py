@@ -273,35 +273,6 @@ def test_sim_round_plan_memoizes_fault_decisions():
     assert api.faults.counters["dropout"] == 1
 
 
-def test_participation_faults_disable_fused_chunks():
-    """Rounds shrunk by faults have ragged client-axis sizes — the fused
-    multi-round stack would crash on them, so the chunk planner must fall
-    back to eager rounds whenever the plan can drop."""
-    from fedml_tpu.algorithms.fedavg import FedAvgAPI
-
-    data, model = _data(samples=16), _model()
-
-    def mk(fault_plan=""):
-        cfg = RunConfig(
-            data=DataConfig(batch_size=8),
-            fed=FedConfig(
-                client_num_in_total=6, client_num_per_round=3, comm_round=6,
-                epochs=1, frequency_of_the_test=6, fused_rounds=4,
-                fault_plan=fault_plan,
-            ),
-            train=TrainConfig(client_optimizer="sgd", lr=0.1),
-            seed=0,
-        )
-        return FedAvgAPI(cfg, data, model)
-
-    faulty = mk('{"clients": {"1": {"dropout_p": 1.0}}}')
-    assert faulty._fused_chunk_len(1) == 1
-    # slowdown-only plans have no participation faults — fusion stays on
-    slow = mk('{"default": {"slowdown_s": 0.5}}')
-    if slow._store is not None:  # device store required for fusion at all
-        assert slow._fused_chunk_len(1) > 1
-
-
 def test_fedbuff_fault_starvation_raises_instead_of_hanging():
     """A plan that crashes every client must terminate the async run with
     a loud error (decline/re-dispatch would otherwise spin forever with

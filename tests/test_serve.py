@@ -526,24 +526,29 @@ def test_concurrent_shm_sessions_share_one_sock_dir(tmp_path, monkeypatch):
     """End-to-end: two shm sessions running at once, both socket dirs
     forced to the SAME directory — only the per-session namespace keeps
     them apart."""
+    import shutil
     import tempfile
 
-    shared = str(tmp_path / "shared_socks")
-    os.makedirs(shared, exist_ok=True)
-    monkeypatch.setattr(tempfile, "mkdtemp", lambda **kw: shared)
-    data, model = _data(), _model()
-    srv = FederationServer()
-    a = srv.create_session(
-        "shm_a", _sync_cfg(comm_round=2), data, model, runtime="shm"
-    )
-    b = srv.create_session(
-        "shm_b", _sync_cfg(comm_round=2, seed=5), data, model, runtime="shm"
-    )
-    srv.start()
-    results = srv.wait()
-    assert results["shm_a"]["ok"] and results["shm_b"]["ok"], results
-    assert len(a.history) == 2 and len(b.history) == 2
-    srv.close()
+    # a short directory of its own: under xdist ``tmp_path`` is long enough
+    # to push the socket names past what a UNIX socket address holds
+    shared = tempfile.mkdtemp(prefix="fs", dir="/tmp")
+    try:
+        monkeypatch.setattr(tempfile, "mkdtemp", lambda **kw: shared)
+        data, model = _data(), _model()
+        srv = FederationServer()
+        a = srv.create_session(
+            "shm_a", _sync_cfg(comm_round=2), data, model, runtime="shm"
+        )
+        b = srv.create_session(
+            "shm_b", _sync_cfg(comm_round=2, seed=5), data, model, runtime="shm"
+        )
+        srv.start()
+        results = srv.wait()
+        assert results["shm_a"]["ok"] and results["shm_b"]["ok"], results
+        assert len(a.history) == 2 and len(b.history) == 2
+        srv.close()
+    finally:
+        shutil.rmtree(shared, ignore_errors=True)
 
 
 # ---------------------------------------------------------------------------

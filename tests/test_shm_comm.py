@@ -86,6 +86,27 @@ def test_shm_echo(zero_copy):
         assert not t.is_alive()
 
 
+def test_shm_refuses_a_socket_path_too_long_for_its_address(tmp_path):
+    """A ``sock_dir`` whose socket names do not fit a UNIX socket address is
+    refused by name, with the length and the limit, before ``bind`` can
+    answer with a bare ``OSError``; the longest path that fits is taken."""
+    from fedml_tpu.core.shm_comm import _SUN_PATH_MAX, _addr
+
+    deep = tmp_path / ("d" * 120)
+    deep.mkdir()
+    addr = _addr(str(deep), 0, "ns")
+    with pytest.raises(ValueError) as e:
+        ShmCommManager(0, str(deep), namespace="ns")
+    assert addr in str(e.value)
+    assert f"{len(addr)} bytes" in str(e.value) and str(_SUN_PATH_MAX) in str(e.value)
+    with tempfile.TemporaryDirectory(prefix="fs", dir="/tmp") as d:
+        pad = _SUN_PATH_MAX - len(_addr(d, 0, ""))
+        fits = ShmCommManager(0, d, namespace="n" * (pad - 1))
+        fits.stop_receive_message()
+        with pytest.raises(ValueError, match="bytes"):
+            ShmCommManager(0, d, namespace="n" * pad)
+
+
 def test_shm_handler_exception_not_masked():
     """A raising observer must propagate its own exception (not BufferError
     from closing a still-referenced segment) and must not leak the segment."""

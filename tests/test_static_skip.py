@@ -14,7 +14,6 @@ host-side sample counts. These tests pin:
 
 import jax
 import numpy as np
-import pytest
 
 from fedml_tpu.algorithms.fedavg import FedAvgAPI, client_sampling
 from fedml_tpu.config import DataConfig, FedConfig, RunConfig, TrainConfig
@@ -56,8 +55,6 @@ def test_cohort_may_pad_predicate():
     api = _api(samples_per_client=8, batch_size=4)  # 8 = 2 full steps, pow2
     sampled = client_sampling(0, 4, 4)
     assert api._cohort_may_pad(sampled) is False
-    # force_steps above the real step count introduces all-padding steps
-    assert api._cohort_may_pad(sampled, force_steps=4) is True
 
     ragged = _api(samples_per_client=8, partition="hetero", batch_size=4)
     sampled = client_sampling(0, 4, 4)
@@ -106,20 +103,3 @@ def test_variants_identical_math_on_padded_batch():
         np.testing.assert_allclose(
             float(met_skip[k]), float(met_gate[k]), rtol=1e-6
         )
-
-
-def test_fused_chunk_keys_carry_may_pad():
-    import dataclasses
-
-    api = _api(samples_per_client=8, batch_size=4)
-    api.config = dataclasses.replace(
-        api.config,
-        fed=dataclasses.replace(api.config.fed, fused_rounds=2),
-    )
-    if api._store is None:
-        pytest.skip("device store unavailable")
-    api.train_rounds_fused(0, 2)
-    keys = list(api._fused_fns)
-    assert keys and all(len(k) == 3 for k in keys)
-    # uniform 8-sample clients at bs=4: exactly 2 steps, no padding
-    assert keys[0][2] is False
