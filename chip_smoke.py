@@ -376,7 +376,7 @@ def phase_decoder(ctx):
     from fedml_tpu.config import DataConfig, FedConfig, RunConfig, TrainConfig
     from fedml_tpu.data.base import FederatedDataset
     from fedml_tpu.models import create_model
-    from fedml_tpu.models.decoder import grouped_dot
+    from fedml_tpu.models.decoder import grouped_dot, row_bound
     from fedml_tpu.telemetry import get_tracer
 
     m = DECODER_TINY if ctx.rehearse else DECODER
@@ -429,9 +429,14 @@ def phase_decoder(ctx):
     losses = train_losses(out)
     flushes = [e.attrs for e in tracer.events()
                if e.name == "flush" and e.ts_us >= t0 and "moe_pairs" in e.attrs]
-    pairs = sum(a["moe_pairs"] for a in flushes)
+    moe = {k: sum(a[k] for a in flushes) for k in (
+        "moe_pairs", "moe_rows", "moe_load_max", "moe_load_mean", "moe_calls", "moe_overflow")}
     tokens = 2 * m["clients"] * m["samples"] * m["seq"]
-    per_token = pairs / (tokens * len(spec["layer_types"]))
+    per_token = moe["moe_pairs"] / (tokens * len(spec["layer_types"]))
+    # every (token, slot) row of every layer and step, and the rows under the
+    # layer's bound (a quarter of them at the published share of 8 in 64)
+    all_rows = tokens * spec["num_experts_per_tok"] * len(spec["layer_types"])
+    bound = row_bound(rows, held, spec["num_experts"])
     return {
         "asserted": [
             check(err32 <= 1e-5 * scale,
@@ -442,6 +447,11 @@ def phase_decoder(ctx):
             check(bool(flushes) and sum(a["moe_dropped"] for a in flushes) == 0,
                   "the flush spans carry the expert counters and no pair was dropped"),
             check(0.5 < per_token < 2.0, f"held pairs per token and layer {per_token:.3f} near 1"),
+            check(bool(flushes) and moe["moe_overflow"] == 0
+                  and moe["moe_rows"] == moe["moe_calls"] * bound == all_rows * bound // rows
+                  and (ctx.rehearse or moe["moe_rows"] < all_rows),
+                  f"no call over its bound of {bound} rows; the grouped products ran over "
+                  f"{moe['moe_rows']:.0f} of {all_rows} (token, slot) rows"),
             check(platforms_of(api.global_vars) == {ctx.platform},
                   f"parameters live on {ctx.platform}"),
         ],
@@ -449,8 +459,7 @@ def phase_decoder(ctx):
         "grouped_product": {"rows": rows, "live": live, "err_f32": err32, "err_bf16": err16,
                             "scale": scale},
         "held_pairs_per_token": per_token,
-        "moe": {k: sum(a[k] for a in flushes) for k in (
-            "moe_pairs", "moe_rows", "moe_load_max", "moe_load_mean")},
+        "moe": moe,
     }
 
 

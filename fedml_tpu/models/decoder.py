@@ -22,7 +22,10 @@ models, written from the keys of a Hugging Face ``config.json``.
 - Every MLP is a routed expert layer (:func:`routed_experts`): softmax router
   over all ``num_experts``, top-``num_experts_per_tok``, renormalised when
   ``norm_topk_prob``, gated-SiLU experts of width ``moe_intermediate_size``,
-  no capacity and no dropped pair.
+  no capacity and no dropped pair. A chip that holds a share of the experts
+  moves only the rows its share is likely to own: the sorted (token, slot)
+  rows are taken ``row_bound`` at a time (twice the even share, from shapes
+  alone), and a step that routes more than that here takes them again.
 
 ``experts_held = (lo, hi)`` is the expert-parallel share of one chip: the
 layer holds the weights of experts ``lo..hi-1`` only, still routes over all
@@ -30,7 +33,7 @@ layer holds the weights of experts ``lo..hi-1`` only, still routes over all
 absent experts would add is left out (on a mesh it arrives by the exchange;
 on one chip there is none). The default holds every expert.
 
-In training the model sows five counters per expert layer into the
+In training the model sows seven counters per expert layer into the
 ``counters`` collection (:data:`COUNTERS`; ``ModelDef.apply(...,
 counters=True)`` sums them over the layers); it returns logits only."""
 
@@ -52,9 +55,12 @@ LAYER_KINDS = ("full_attention", "sliding_attention")
 # Per expert layer and call, as float32 (whole numbers below 2**24 a round):
 # (token, slot) pairs routed to a held expert; held pairs that the dispatch
 # left outside the grouped products (0 by construction: the check of the
-# sort); rows the grouped products run over; the largest and the mean number
-# of pairs of one held expert.
-COUNTERS = ("moe_pairs", "moe_dropped", "moe_rows", "moe_load_max", "moe_load_mean")
+# sort and of the passes taken); rows the grouped products ran over in this
+# call (the row bound x the passes taken, not tokens x top-k); the largest
+# and the mean number of pairs of one held expert; 1 for the call; 1 where
+# the call's held pairs exceeded the row bound, so that it took a second pass.
+COUNTERS = ("moe_pairs", "moe_dropped", "moe_rows", "moe_load_max", "moe_load_mean",
+            "moe_calls", "moe_overflow")
 
 
 def rotary_tables(rope: Mapping[str, Any], head_dim: int, length: int):
@@ -93,11 +99,33 @@ def apply_rotary(x, cos, sin):
     return (x32 * cos[None, :, None, :] + rotated * sin[None, :, None, :]).astype(x.dtype)
 
 
+def sum_readers(table, readers, weights=None):
+    """Row n of the result is the float32 sum over k of
+    ``table[readers[n, k]]`` (times ``weights[n, k]``); a reader of
+    ``len(table)`` (out of bounds) reads zero. One gather of N rows per
+    slot, added as it goes: XLA fuses each gather into the running sum, so
+    the [N, top_k, d] array of a single gather under a ``sum`` is never
+    written. On the v5e at 4 096 tokens of width 2 304, top-8 and 8 192
+    table rows, with the row gather beside it: 0.68 ms forward (weighted)
+    and 0.60 ms backward against 0.95 and 2.24 ms for the single gather,
+    and 1.29 and 0.60 ms for segment sums (PERF.md section 6, PR 31)."""
+    acc = None
+    for k in range(readers.shape[1]):
+        part = jnp.take(table, readers[:, k], axis=0, mode="fill", fill_value=0)
+        part = part.astype(jnp.float32)
+        if weights is not None:
+            part = part * weights[:, k, None]
+        acc = part if acc is None else acc + part
+    return acc
+
+
 @jax.custom_vjp
 def take_rows(x, idx, readers):
     """``x[idx]`` where the rows of the result that read row ``r`` of ``x``
-    are exactly ``readers[r]`` (a fixed number each): the backward pass is
-    then a gather too, not a scatter-add."""
+    are exactly ``readers[r]``, a fixed number of places each, the places
+    that hold no reader filled with ``len(idx)`` (out of bounds, read as
+    zero): the backward pass is then gathers too (:func:`sum_readers`), not
+    a scatter-add."""
     return x[idx]
 
 
@@ -106,10 +134,37 @@ def _take_rows_fwd(x, idx, readers):
 
 
 def _take_rows_bwd(readers, g):
-    return jnp.sum(g[readers], axis=1), None, None
+    return sum_readers(g, readers).astype(g.dtype), None, None
 
 
 take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+@jax.custom_vjp
+def weighted_rows(ys, top_w, readers, slot):
+    """Token n's sum of ``top_w[n, k] * ys[readers[n, k]]`` over its slots,
+    in float32 (:func:`sum_readers`). ``slot`` [len(ys)] is the flat
+    (token, slot) place that reads each row, so the backward pass runs over
+    the rows of ``ys``, not over tokens x top-k."""
+    return sum_readers(ys, readers, top_w)
+
+
+def _weighted_rows_fwd(ys, top_w, readers, slot):
+    return weighted_rows(ys, top_w, readers, slot), (ys, top_w, readers, slot)
+
+
+def _weighted_rows_bwd(res, g):
+    ys, top_w, readers, slot = res
+    g_rows = g[slot // top_w.shape[1]]
+    by_row = jnp.sum(ys.astype(jnp.float32) * g_rows, axis=1)
+    return (
+        (g_rows * top_w.reshape(-1)[slot][:, None]).astype(ys.dtype),
+        jnp.take(by_row, readers, mode="fill", fill_value=0),
+        None, None,
+    )
+
+
+weighted_rows.defvjp(_weighted_rows_fwd, _weighted_rows_bwd)
 
 
 # lhs [M, K] and rhs [M, N], both ragged over M: the groups' K x N products.
@@ -170,6 +225,93 @@ def _grouped_dot_bwd(res, g):
 grouped_dot.defvjp(_grouped_dot_fwd, _grouped_dot_bwd)
 
 
+# Sorted rows come in multiples of this many: a whole number of the MXU's 128
+# rows and of the (8, 128) / (16, 128) tiles that float32 / bfloat16 rows lie
+# in, so no chunk of rows starts inside a tile. Not tuned: the one cell that
+# holds a share of its experts has a bound of 8 192 rows whatever this is.
+ROW_TILE = 512
+
+
+def row_bound(rows: int, held: int, experts: int) -> int:
+    """The sorted (token, slot) rows one pass of the expert products takes:
+    twice the even share ``rows * held / experts`` of a chip that holds
+    ``held`` of ``experts`` experts, in whole ``ROW_TILE``s, and never more
+    than ``rows`` (which it is where every expert is held)."""
+    twice_even = -(-2 * rows * held // experts)
+    return min(rows, -(-twice_even // ROW_TILE) * ROW_TILE)
+
+
+@functools.partial(jax.jit, static_argnames="bound")
+def _held_rows(c, x, top_w, w_gate, w_up, w_down, order, inverse, group_sizes, *, bound):
+    """What the sorted rows ``[c * bound, (c + 1) * bound)`` add to every
+    token's sum: [N, d] float32. ``order`` (padded to whole chunks) and
+    ``inverse`` [N, top_k] are the sort and its inverse, ``group_sizes``
+    the held experts' pair counts over all rows. Jitted so that every layer
+    and both places that call it (inline and in the overflow's loop, forward
+    and backward) share one traced and lowered function: tracing and
+    lowering four layers' gradient takes 0.9 s so, 1.9 s without (host
+    seconds), and the compiled program is the same."""
+    top_k = top_w.shape[1]
+    start = c * bound
+    with jax.named_scope("dispatch"):
+        slot = jax.lax.dynamic_slice_in_dim(order, start, bound)
+        ends = jnp.cumsum(group_sizes)
+        sizes = jnp.maximum(
+            jnp.minimum(ends, start + bound) - jnp.maximum(ends - group_sizes, start), 0)
+        # sorted rows that carry a pair of a held expert; the grouped products
+        # leave whatever they find in the rows after them (on the chip: not
+        # zeros), so every result is cleared there
+        live = (jnp.arange(bound) < ends[-1] - start)[:, None]
+        at = inverse - start
+        readers = jnp.where((at >= 0) & (at < bound), at, bound)
+        xs = take_rows(x, slot // top_k, readers)
+        xs = jnp.where(live, xs, jnp.zeros((), xs.dtype))
+    with jax.named_scope("experts"):
+        gate = grouped_dot(xs, w_gate, sizes)
+        up = grouped_dot(xs, w_up, sizes)
+        hidden = jnp.where(live, jax.nn.silu(gate) * up, jnp.zeros((), up.dtype))
+        ys = grouped_dot(hidden, w_down, sizes)
+        ys = jnp.where(live, ys, jnp.zeros((), ys.dtype))
+    with jax.named_scope("combine"):
+        return weighted_rows(ys, top_w, readers, slot)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _sum_chunks(part, chunks, used, operands, index):
+    """``part(c, *operands, *index)`` summed over the chunks ``c < used``
+    (``1 <= used <= chunks``, ``chunks`` static): chunk 0 inline, the others
+    in a loop of ``used - 1`` trips, none on a step that stays under the
+    bound. The backward pass is written out, as the same loop over each
+    chunk's own vjp (which recomputes the chunk): neither a ``cond`` nor a
+    ``scan`` is differentiated, so no chunk that does not run writes zeros
+    for residuals, and nothing of a chunk is kept between the passes but
+    the arguments."""
+    y = part(jnp.int32(0), *operands, *index)
+    if chunks > 1:
+        y = jax.lax.fori_loop(1, used, lambda c, y: y + part(c, *operands, *index), y)
+    return y
+
+
+def _sum_chunks_fwd(part, chunks, used, operands, index):
+    return _sum_chunks(part, chunks, used, operands, index), (used, operands, index)
+
+
+def _sum_chunks_bwd(part, chunks, res, g):
+    used, operands, index = res
+
+    def pull(c):
+        return jax.vjp(lambda *ops: part(c, *ops, *index), *operands)[1](g)
+
+    grads = pull(jnp.int32(0))
+    if chunks > 1:
+        grads = jax.lax.fori_loop(
+            1, used, lambda c, acc: jax.tree_util.tree_map(jnp.add, acc, pull(c)), grads)
+    return None, grads, None
+
+
+_sum_chunks.defvjp(_sum_chunks_fwd, _sum_chunks_bwd)
+
+
 def routed_experts(x, router, w_gate, w_up, w_down, *, top_k: int,
                    norm_topk_prob: bool = True, held_from: int = 0):
     """The held experts' part of a routed expert layer.
@@ -181,9 +323,29 @@ def routed_experts(x, router, w_gate, w_up, w_down, *, top_k: int,
     experts last, runs the three products as grouped products
     (:func:`grouped_dot`) over the held experts' rows, and sums each
     token's held slots by its (renormalised) top-k weights. Returns
-    ``(y [N, d], counters [len(COUNTERS)] float32)``."""
+    ``(y [N, d], counters [len(COUNTERS)] float32)``.
+
+    The rows between the sort and the sum are bounded by shapes alone:
+    ``R = row_bound(N * top_k, Eh, E)``, twice the even share of this chip's
+    experts. The gather of ``x``, the products, the SiLU and the clears take
+    the first R sorted rows (the held pairs are sorted first). A step that
+    routes more than R pairs here runs the same R-row computation again on
+    the next R sorted rows, as often as it takes (:func:`_sum_chunks`): no
+    capacity, no dropped pair, the same function of the weights; only the
+    float32 sum of a token's slots may be taken in another order. Where
+    every expert is held R is N*top_k and there is no loop in the program.
+    Two passes stay indexed by token, N x top_k row reads each: the sum of
+    a token's slots (:func:`weighted_rows`) and the backward of the
+    dispatch gather (:func:`take_rows`), both :func:`sum_readers`.
+
+    Under a ``vmap`` (the clients of a round) the loop's trip count is
+    batched, and JAX runs as many trips as the member with the most pairs
+    needs, masking the others: the result is each member's own."""
     N, d = x.shape
     Eh = w_gate.shape[0]
+    rows = N * top_k
+    bound = row_bound(rows, Eh, router.shape[1])
+    chunks = -(-rows // bound)
     with jax.named_scope("router"):
         logits = jnp.dot(x, router, preferred_element_type=jnp.float32)
         probs = jax.nn.softmax(logits, axis=-1)
@@ -191,7 +353,6 @@ def routed_experts(x, router, w_gate, w_up, w_down, *, top_k: int,
         if norm_topk_prob:
             top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
     with jax.named_scope("dispatch"):
-        rows = N * top_k
         local = top_e.reshape(rows) - held_from
         held = (local >= 0) & (local < Eh)
         key = jnp.where(held, local, Eh)
@@ -200,28 +361,23 @@ def routed_experts(x, router, w_gate, w_up, w_down, *, top_k: int,
         group_sizes = jnp.sum(
             key[:, None] == jnp.arange(Eh, dtype=key.dtype)[None, :], axis=0, dtype=jnp.int32)
         pairs = jnp.sum(group_sizes)
-        # sorted rows that carry a pair of a held expert; the grouped products
-        # leave whatever they find in the rows after them (on the chip: not
-        # zeros), so every result is cleared there
-        live = (jnp.arange(rows) < pairs)[:, None]
-        xs = take_rows(x, order // top_k, inverse.reshape(N, top_k))
-        xs = jnp.where(live, xs, jnp.zeros((), xs.dtype))
-    with jax.named_scope("experts"):
-        gate = grouped_dot(xs, w_gate, group_sizes)
-        up = grouped_dot(xs, w_up, group_sizes)
-        hidden = jnp.where(live, jax.nn.silu(gate) * up, jnp.zeros((), up.dtype))
-        ys = grouped_dot(hidden, w_down, group_sizes)
-        ys = jnp.where(live, ys, jnp.zeros((), ys.dtype))
+        # chunks of `bound` sorted rows that hold a held pair; the first always runs
+        used = jnp.clip(-(-pairs // bound), 1, chunks)
+    y = _sum_chunks(
+        functools.partial(_held_rows, bound=bound), chunks, used,
+        (x, top_w, w_gate, w_up, w_down),
+        (jnp.pad(order, (0, chunks * bound - rows)), inverse.reshape(N, top_k), group_sizes))
     with jax.named_scope("combine"):
-        slots = take_rows(ys, inverse, order[:, None]).reshape(N, top_k, d)
-        y = jnp.sum(slots.astype(jnp.float32) * top_w[..., None], axis=1).astype(x.dtype)
+        y = y.astype(x.dtype)
         f32 = jnp.float32
         counters = jnp.stack([
             pairs.astype(f32),
-            jnp.sum(held[order] & ~live[:, 0]).astype(f32),
-            jnp.asarray(rows, f32),
+            jnp.sum(held[order] & (jnp.arange(rows) >= jnp.minimum(pairs, used * bound))).astype(f32),
+            (used * bound).astype(f32),
             jnp.max(group_sizes).astype(f32),
             pairs.astype(f32) / Eh,
+            jnp.ones((), f32),
+            (pairs > bound).astype(f32),
         ])
     return y, jax.lax.stop_gradient(counters)
 

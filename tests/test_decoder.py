@@ -1,10 +1,11 @@
 """The spec-driven decoder (models/decoder.py) against its plain reference
 (benchmarks/configs/mellum2-12b-a2.5b_ref.py), at small sizes on the CPU in
 float32 with seeded random weights: loss and every leaf's gradient, the
-grouped form of the expert layer against the dense masked form, the shares
-of an expert-parallel deployment against the uncut layer, and one federated
-round under both client schedules with the expert counters on the ``flush``
-span."""
+grouped form of the expert layer against the dense masked form (all rows,
+under the row bound, and over it), the shares of an expert-parallel
+deployment against the uncut layer, one federated round under both client
+schedules with the expert counters on the ``flush`` span, and the reader of
+``moe.bounded_call_pct`` on hand-made runs."""
 
 import copy
 import dataclasses
@@ -19,7 +20,7 @@ import pytest
 
 from fedml_tpu.compile import model_fingerprint
 from fedml_tpu.models import create_model
-from fedml_tpu.models.decoder import COUNTERS, grouped_dot, routed_experts
+from fedml_tpu.models.decoder import COUNTERS, grouped_dot, routed_experts, row_bound
 from fedml_tpu.parallel.ring_attention import full_attention
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
@@ -113,9 +114,9 @@ def test_loss_and_every_gradient_match_the_reference(case):
         assert gap <= 2e-5 * scale, (name, gap, scale)
 
 
-def expert_weights(key, d=16, f=24, experts=8):
+def expert_weights(key, d=16, f=24, experts=8, tokens=40):
     ks = jax.random.split(key, 5)
-    return (jax.random.normal(ks[0], (40, d)), jax.random.normal(ks[1], (d, experts)),
+    return (jax.random.normal(ks[0], (tokens, d)), jax.random.normal(ks[1], (d, experts)),
             0.3 * jax.random.normal(ks[2], (experts, d, f)),
             0.3 * jax.random.normal(ks[3], (experts, d, f)),
             0.3 * jax.random.normal(ks[4], (experts, f, d)))
@@ -134,21 +135,44 @@ def dense_masked(x, router, gate, up, down, top_k, lo, hi):
     return out
 
 
-@pytest.mark.parametrize("held", [(0, 8), (0, 4), (3, 5)])
-def test_grouped_form_matches_dense_masked_form(held):
+def towards_held(x, router, lo, hi, by=8.0):
+    """The same tokens and router with every token's first choices on the
+    held experts: feature 0 is 3 on every token and weighs ``by`` for them."""
+    return x.at[:, 0].set(3.0), router.at[0, lo:hi].add(by)
+
+
+# tokens, experts, top_k, held, router biased towards the held experts
+GROUPED = {
+    "all_8_held": (40, 8, 3, (0, 8), False),
+    "first_4_of_8": (40, 8, 3, (0, 4), False),
+    "middle_2_of_8": (40, 8, 3, (3, 5), False),
+    "under_the_bound_2_of_16": (512, 16, 4, (0, 2), False),
+    "over_the_bound_2_of_16": (512, 16, 4, (5, 7), True),
+    "over_it_twice_ragged_last_chunk": (700, 32, 4, (5, 7), True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(GROUPED))
+def test_grouped_form_matches_dense_masked_form(case):
     """Value and gradients towards the tokens, the router and the held
     experts' weights; float32 both ways, 1e-5 of the largest entry for the
-    different order of the sums."""
-    lo, hi = held
-    x, router, gate, up, down = expert_weights(jax.random.PRNGKey(3))
+    different order of the sums. Where the chip holds a small share the
+    sorted rows are bounded (``row_bound``) and the comparison is of the
+    bounded pass; with the router biased the held pairs exceed the bound and
+    the overflow's passes are compared too: nothing is dropped."""
+    tokens, experts, top_k, (lo, hi), biased = GROUPED[case]
+    x, router, gate, up, down = expert_weights(
+        jax.random.PRNGKey(3), experts=experts, tokens=tokens)
+    if biased:
+        x, router = towards_held(x, router, lo, hi)
 
     def grouped(x, router, gate, up, down):
         y, counters = routed_experts(x, router, gate[lo:hi], up[lo:hi], down[lo:hi],
-                                     top_k=3, held_from=lo)
+                                     top_k=top_k, held_from=lo)
         return jnp.sum(jnp.sin(y)), counters
 
     def dense(x, router, gate, up, down):
-        return jnp.sum(jnp.sin(dense_masked(x, router, gate, up, down, 3, lo, hi)))
+        return jnp.sum(jnp.sin(dense_masked(x, router, gate, up, down, top_k, lo, hi)))
 
     (vg, counters), gg = jax.value_and_grad(grouped, argnums=(0, 1, 2, 3, 4), has_aux=True)(
         x, router, gate, up, down)
@@ -157,9 +181,77 @@ def test_grouped_form_matches_dense_masked_form(held):
     for a, b in zip(gg, gd):
         assert float(jnp.max(jnp.abs(a - b))) <= 1e-5 * float(jnp.max(jnp.abs(b))) + 1e-7
     c = dict(zip(COUNTERS, np.asarray(counters)))
-    assert c["moe_dropped"] == 0 and c["moe_rows"] == 40 * 3
-    assert c["moe_pairs"] == 120 if held == (0, 8) else c["moe_pairs"] < 120
+    rows = tokens * top_k
+    bound = row_bound(rows, hi - lo, experts)
+    assert c["moe_dropped"] == 0 and c["moe_calls"] == 1
+    assert c["moe_pairs"] == rows if (lo, hi) == (0, experts) else c["moe_pairs"] < rows
     assert c["moe_load_mean"] == c["moe_pairs"] / (hi - lo) <= c["moe_load_max"]
+    # the rows the grouped products ran over: the bound, once for every
+    # `bound` held pairs or part of them
+    assert c["moe_rows"] == bound * max(1, -(-int(c["moe_pairs"]) // bound))
+    assert c["moe_overflow"] == (c["moe_pairs"] > bound) == biased
+    if tokens == 40:
+        assert bound == rows == c["moe_rows"] == 40 * 3  # every row, as before the bound
+    elif biased:
+        assert bound == 512 and c["moe_rows"] > bound
+    else:
+        assert bound == 512 == c["moe_rows"] < rows
+
+
+def test_the_row_bound_is_twice_the_even_share_in_whole_tiles_and_never_over_the_rows():
+    assert row_bound(32768, 8, 64) == 8192  # mellum2-12b-a2.5b.silo2's training step
+    assert row_bound(131072, 8, 64) == 32768  # and its evaluation batch
+    assert row_bound(2048, 2, 16) == 512
+    assert row_bound(2800, 2, 32) == 512  # 350 rows, rounded up to a tile
+    assert row_bound(120, 4, 8) == 120  # a tile is more than all rows
+    for rows, experts in [(120, 8), (32768, 64), (96, 4)]:
+        assert row_bound(rows, experts, experts) == rows  # every expert held: no bound
+
+
+@pytest.mark.parametrize("held,loops", [((0, 16), 0), ((4, 6), 2)])
+def test_only_a_held_share_puts_the_overflow_loop_in_the_program(held, loops):
+    """Every expert held: the bound is all rows and the program has no loop
+    or branch in it. A share of 2 in 16: one loop forward and one backward,
+    of as many trips as the step's pairs ask for."""
+    lo, hi = held
+    x, router, gate, up, down = expert_weights(jax.random.PRNGKey(3), experts=16, tokens=512)
+
+    def loss(x, router, gate, up, down):
+        return jnp.sum(routed_experts(x, router, gate, up, down, top_k=4, held_from=lo)[0])
+
+    text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 2)))(
+        x, router, gate[lo:hi], up[lo:hi], down[lo:hi]))
+    assert text.count("while[") == loops and "cond[" not in text
+
+
+def test_members_of_a_vmap_overflow_each_on_their_own():
+    """The clients' vmap batches the overflow loop's trip count: one member
+    over the bound and one under it give what each gives alone."""
+    lo, hi, top_k = 5, 7, 4
+    x, router, gate, up, down = expert_weights(jax.random.PRNGKey(6), experts=16, tokens=512)
+    x_over, router_over = towards_held(x, router, lo, hi)
+
+    def layer(x, router):
+        return routed_experts(x, router, gate[lo:hi], up[lo:hi], down[lo:hi],
+                              top_k=top_k, held_from=lo)
+
+    def loss(xs, routers):
+        y, counters = jax.vmap(layer)(xs, routers)
+        return jnp.sum(jnp.sin(y)), (y, counters)
+
+    xs, routers = jnp.stack([x, x_over]), jnp.stack([router, router_over])
+    (_, (ys, counters)), grads = jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(xs, routers)
+    overflow = np.asarray(counters)[:, COUNTERS.index("moe_overflow")]
+    assert list(overflow) == [0, 1]
+    def alone(x, router):
+        return jnp.sum(jnp.sin(layer(x, router)[0]))
+
+    for m in range(2):
+        y, _ = layer(xs[m], routers[m])
+        gx, gr = jax.grad(alone, argnums=(0, 1))(xs[m], routers[m])
+        assert jnp.allclose(ys[m], y, rtol=0, atol=1e-6)
+        assert jnp.allclose(grads[0][m], gx, rtol=0, atol=1e-6)
+        assert jnp.allclose(grads[1][m], gr, rtol=0, atol=1e-6)
 
 
 def test_the_eight_shares_add_up_to_the_uncut_layer():
@@ -266,22 +358,30 @@ def test_transformer_moe_message_points_to_the_decoder():
         create_model("transformer", "shakespeare", (80,), 90, moe_experts=4)
 
 
+# One step of 8 documents of 64 tokens, top-2 of 16 experts with 2 held:
+# 1 024 (token, slot) rows under a bound of 512, about 128 of them held.
+ROUND = dict(length=64, docs=8, clients=3, num_experts=16, experts_held=[2, 4])
+
+
 def one_round(mode):
     from fedml_tpu.algorithms import FedAvgAPI
     from fedml_tpu.config import DataConfig, FedConfig, RunConfig, TrainConfig
     from fedml_tpu.data.base import FederatedDataset
     from fedml_tpu.telemetry import get_tracer
 
-    docs = np.random.default_rng(0).integers(1, VOCAB, size=(3, 4, LENGTH + 1), dtype=np.int32)
+    length, per_client, clients = ROUND["length"], ROUND["docs"], ROUND["clients"]
+    docs = np.random.default_rng(0).integers(
+        1, VOCAB, size=(clients, per_client, length + 1), dtype=np.int32)
     data = FederatedDataset(
         name="random_tokens", client_x=list(docs[:, :, :-1]), client_y=list(docs[:, :, 1:]),
         test_x=docs[0, :2, :-1], test_y=docs[0, :2, 1:], num_classes=VOCAB)
-    model = create_model("decoder", "random_tokens", (LENGTH,), VOCAB,
-                         **dict(BASE, **CASES["top2_of_8_with_4_held"]))
+    model = create_model(
+        "decoder", "random_tokens", (length,), VOCAB,
+        **dict(BASE, num_experts=ROUND["num_experts"], experts_held=ROUND["experts_held"]))
     cfg = RunConfig(
-        data=DataConfig(batch_size=2, pad_bucket=1),
-        fed=FedConfig(client_num_in_total=3, client_num_per_round=3, comm_round=1, epochs=1,
-                      client_parallelism=mode),
+        data=DataConfig(batch_size=per_client, pad_bucket=1),
+        fed=FedConfig(client_num_in_total=clients, client_num_per_round=clients, comm_round=1,
+                      epochs=1, client_parallelism=mode),
         train=TrainConfig(client_optimizer="sgd", lr=0.05), model="decoder", seed=1,
     )
     tracer = get_tracer()
@@ -302,13 +402,42 @@ def test_a_federated_round_is_the_same_under_vmap_and_scan_and_counts_its_expert
         assert jnp.allclose(a, b, rtol=0, atol=1e-6)
     assert len(flushes) == 1
     attrs = flushes[0]
-    tokens, layers, top_k = 3 * 4 * LENGTH, 1, 2
+    calls, layers, top_k = ROUND["clients"], 1, 2  # one step a client, one layer
+    rows = ROUND["docs"] * ROUND["length"] * top_k
+    bound = row_bound(rows, 2, ROUND["num_experts"])
+    assert bound == 512 < rows
     assert attrs["moe_dropped"] == 0
-    assert attrs["moe_rows"] == tokens * layers * top_k
+    assert (attrs["moe_calls"], attrs["moe_overflow"]) == (calls * layers, 0)
+    assert attrs["moe_rows"] == calls * layers * bound  # not tokens x top-k: the bounded rows
     assert 0 < attrs["moe_pairs"] <= attrs["moe_rows"]
-    assert attrs["moe_load_mean"] * 4 == attrs["moe_pairs"]
+    assert attrs["moe_load_mean"] * 2 == attrs["moe_pairs"]
     assert attrs["moe_load_max"] >= attrs["moe_load_mean"]
     assert (attrs["hidden"], attrs["expert_width"], attrs["layers"]) == (32, 16, 1)
+
+
+def bounded_call_pct():
+    path = ROOT / "benchmarks" / "metrics" / "moe.bounded_call_pct.py"
+    spec = importlib.util.spec_from_file_location("moe_bounded_call_pct", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+@pytest.mark.parametrize("flushes,want", [
+    ([dict(moe_calls=64.0, moe_overflow=0.0), dict(moe_calls=320.0, moe_overflow=0.0)], 100.0),
+    ([dict(moe_calls=64.0, moe_overflow=16.0), dict(moe_calls=64.0, moe_overflow=48.0)], 50.0),
+    ([dict(moe_calls=8.0, moe_overflow=8.0)], 0.0),  # every call over its bound: a reading
+    ([dict(rows=5)], None),  # the parent's spans, and a model without routed experts
+    ([], None),
+])
+def test_bounded_call_share_reads_the_flush_spans_and_is_absent_without_them(flushes, want):
+    """The reader as ``benchmarks/run.py`` loads it, by path; other spans and
+    flushes without the counters are not its to read, and an absent share is
+    ``None``, never 0."""
+    spans = [("round", 0, 5, {"clients": 2})] + [("flush", 10 * i, 10 * i + 5, dict(a, rows=5))
+                                                for i, a in enumerate(flushes)]
+    got = bounded_call_pct()({"program_spans": spans})
+    assert got == want and (want is None or isinstance(got, float))
 
 
 def test_a_model_without_counters_keeps_its_metrics_and_flush_span():

@@ -111,6 +111,35 @@ def test_flash_attention_fwd_bwd_compiles_for_v5e(one_chip, clients, shape, wind
     assert compiled.as_text().count("tpu_custom_call") >= 2
 
 
+def test_expert_layer_at_mellum_share_compiles_for_v5e_over_bounded_rows(one_chip):
+    """models/decoder.routed_experts as DecoderLayer calls it (recomputed in
+    the backward pass), forward + gradient at mellum2-12b-a2.5b.silo2's
+    training step: 4 096 tokens of width 2 304, top-8 of 64 experts with 8
+    held. The arrays between the sort and the sum have the bound's 8 192
+    rows, not the 32 768 (token, slot) rows, and the overflow's loop is in
+    the program once forward and once backward."""
+    import functools
+
+    from fedml_tpu.models.decoder import routed_experts, row_bound
+
+    N, d, f, held, experts, top_k = 4096, 2304, 896, 8, 64, 8
+    assert row_bound(N * top_k, held, experts) == 8192
+    layer = jax.checkpoint(functools.partial(routed_experts, top_k=top_k))
+
+    def loss(*args):
+        y, counters = layer(*args)
+        return jnp.sum(y.astype(jnp.float32) ** 2), counters
+
+    shapes = [(N, d), (d, experts), (held, d, f), (held, d, f), (held, f, d)]
+    args = [jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip) for s in shapes]
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "[8192,2304]" in text and "[32768,2304]" not in text
+    assert text.count(" while(") == 2
+    # the parent's full-row program planned 0.547 GiB for this layer
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.45 * 2**30
+
+
 def test_femnist_cnn_round_program_compiles_for_one_v5e_chip(one_chip):
     """The production round program (``api.round_fn``) of the north star —
     FEMNIST CNN, 10 clients/round, batch 20 — lowered at its real round-0
