@@ -87,6 +87,36 @@ DECODER = dict(
         experts_held=[0, 8],
     ),
 )
+# The same decoder at Kanana-2-30B-A3B's published widths (latent attention
+# with heads of 128 | 64 and values of 128 out of a latent of 512, a dense
+# layer of 6144, 128 sigmoid-routed experts of 768 top-6 by a biased choice,
+# a shared expert of 2 x 768), one chip's share of sixteen (experts 0..7, an
+# eighth of the vocabulary), the dense layer and one expert layer, 2 silos x
+# 2 steps of one 2048-token document.
+LATENT = dict(
+    vocab=16032, seq=2048, clients=2, samples=2, batch=1, dtype="bfloat16",
+    spec=dict(
+        hidden_size=2048, num_attention_heads=32, num_hidden_layers=2,
+        kv_lora_rank=512, q_lora_rank=None, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, rope_theta=1000000, rope_interleave=True,
+        first_k_dense_replace=1, intermediate_size=6144,
+        n_routed_experts=128, n_shared_experts=2, num_experts_per_tok=6,
+        moe_intermediate_size=768, scoring_func="sigmoid", topk_method="noaux_tc",
+        norm_topk_prob=True, routed_scaling_factor=2.448, experts_held=[0, 8],
+    ),
+)
+LATENT_TINY = dict(
+    vocab=97, seq=32, clients=2, samples=4, batch=1, dtype="float32",
+    spec=dict(
+        hidden_size=64, num_attention_heads=4, num_hidden_layers=2,
+        kv_lora_rank=24, q_lora_rank=None, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=16, rope_theta=1000000, rope_interleave=True,
+        first_k_dense_replace=1, intermediate_size=96,
+        n_routed_experts=8, n_shared_experts=1, num_experts_per_tok=2,
+        moe_intermediate_size=32, scoring_func="sigmoid", topk_method="noaux_tc",
+        norm_topk_prob=True, routed_scaling_factor=2.448, experts_held=[0, 4],
+    ),
+)
 DECODER_TINY = dict(
     vocab=97, seq=32, clients=2, samples=4, batch=2, dtype="float32",
     spec=dict(
@@ -113,12 +143,17 @@ FLASH = {
                             dtype="bfloat16", tol=_BF16_TOL),
     "mellum2-12b-a2.5b.silo2": dict(clients=1, shape=(2, 2048, 32, 4, 128), window=1024,
                                     dtype="bfloat16", tol=_BF16_TOL),
+    # latent attention: a second score term of 64 against one shared key
+    "kanana-2-30b-a3b.silo2b1": dict(clients=1, shape=(1, 2048, 32, 32, 128), rope=64,
+                                     window=None, dtype="bfloat16", tol=_BF16_TOL),
 }
 FLASH_TINY = {
     "equal_heads": dict(clients=2, shape=(1, 256, 2, 2, 64), window=None,
                         dtype="float32", tol=_F32_TOL),
     "grouped_window": dict(clients=1, shape=(1, 256, 4, 1, 128), window=100,
                            dtype="float32", tol=_F32_TOL),
+    "two_terms": dict(clients=1, shape=(1, 256, 2, 2, 128), rope=64, window=None,
+                      dtype="float32", tol=_F32_TOL),
 }
 
 
@@ -372,12 +407,7 @@ def phase_decoder(ctx):
     import jax.numpy as jnp
     import numpy as np
 
-    from fedml_tpu.algorithms import FedAvgAPI
-    from fedml_tpu.config import DataConfig, FedConfig, RunConfig, TrainConfig
-    from fedml_tpu.data.base import FederatedDataset
-    from fedml_tpu.models import create_model
-    from fedml_tpu.models.decoder import grouped_dot, row_bound
-    from fedml_tpu.telemetry import get_tracer
+    from fedml_tpu.models.decoder import grouped_dot
 
     m = DECODER_TINY if ctx.rehearse else DECODER
     spec = m["spec"]
@@ -402,6 +432,35 @@ def phase_decoder(ctx):
     low = jax.jit(grouped_dot)(x.astype(jnp.bfloat16), w.astype(jnp.bfloat16), jnp.asarray(sizes))
     err16 = float(jnp.max(jnp.abs(low[:live].astype(jnp.float32) - want)))
 
+    mellum = _decoder_round(ctx, m, "grouped-query")
+    latent = _decoder_round(ctx, LATENT_TINY if ctx.rehearse else LATENT, "latent")
+    return {
+        "asserted": [
+            check(err32 <= 1e-5 * scale,
+                  f"grouped product in float32 at highest = dense form: {err32:.3g} of {scale:.3g}"),
+            check(err16 <= 2e-2 * scale, f"in bfloat16 within rounding: {err16:.3g} of {scale:.3g}"),
+        ] + mellum.pop("asserted") + latent.pop("asserted"),
+        "grouped_product": {"rows": rows, "live": live, "err_f32": err32, "err_bf16": err16,
+                            "scale": scale},
+        **mellum, "latent": latent,
+    }
+
+
+def _decoder_round(ctx, m, kind):
+    """Two rounds of one decoder spec through ``FedAvgAPI(...).train()``:
+    finite losses, the expert counters on the ``flush`` spans, no call over
+    its row bound, and on the chip the attention kernel in the round program."""
+    import jax
+    import numpy as np
+
+    from fedml_tpu.algorithms import FedAvgAPI
+    from fedml_tpu.config import DataConfig, FedConfig, RunConfig, TrainConfig
+    from fedml_tpu.data.base import FederatedDataset
+    from fedml_tpu.models import create_model
+    from fedml_tpu.models.decoder import row_bound
+    from fedml_tpu.telemetry import get_tracer
+
+    spec = m["spec"]
     cx, cy = [], []
     for c in range(m["clients"]):
         doc = np.random.default_rng([ctx.seed, c]).integers(
@@ -421,45 +480,59 @@ def phase_decoder(ctx):
         train=TrainConfig(client_optimizer="sgd", lr=0.01, compute_dtype=m["dtype"]),
         model="decoder", seed=ctx.seed,
     )
+    def round_programs():
+        return {e.hlo_modules()[0].to_string()
+                for e in jax.devices()[0].client.live_executables()
+                if e.hlo_modules() and e.hlo_modules()[0].name.startswith("jit_round_fn")}
+
     out = []
     tracer = get_tracer()
     t0 = tracer.now_us()
+    before = round_programs()
     api = FedAvgAPI(cfg, data, model, task="nwp", log_fn=out.append)
     api.train()
     losses = train_losses(out)
     flushes = [e.attrs for e in tracer.events()
                if e.name == "flush" and e.ts_us >= t0 and "moe_pairs" in e.attrs]
-    moe = {k: sum(a[k] for a in flushes) for k in (
-        "moe_pairs", "moe_rows", "moe_load_max", "moe_load_mean", "moe_calls", "moe_overflow")}
+    moe = {k: sum(a[k] for a in flushes) for k in model.counters if k != "moe_dropped"}
+    layers, top_k = model.counter_attrs["expert_layers"], model.counter_attrs["top_k"]
+    held = model.module.held()[1] - model.module.held()[0]
     tokens = 2 * m["clients"] * m["samples"] * m["seq"]
-    per_token = moe["moe_pairs"] / (tokens * len(spec["layer_types"]))
-    # every (token, slot) row of every layer and step, and the rows under the
-    # layer's bound (a quarter of them at the published share of 8 in 64)
-    all_rows = tokens * spec["num_experts_per_tok"] * len(spec["layer_types"])
-    bound = row_bound(rows, held, spec["num_experts"])
+    per_token = moe["moe_pairs"] / (tokens * layers)
+    # every (token, slot) row of every expert layer and step, and the rows
+    # under the layer's bound (a quarter of them at a share of 8 in 64)
+    rows = m["batch"] * m["seq"] * top_k
+    all_rows = tokens * top_k * layers
+    bound = row_bound(rows, held, model.module.experts())
+    even = top_k * held / model.module.experts()
+    asserted = [
+        check(len(losses) == 2 and all(math.isfinite(v) for v in losses),
+              f"{kind}: 2 rounds logged with finite losses: {losses}"),
+        check(bool(flushes) and sum(a["moe_dropped"] for a in flushes) == 0,
+              f"{kind}: the flush spans carry the expert counters and no pair was dropped"),
+        check(0.5 * even < per_token < 2.0 * even,
+              f"{kind}: held pairs per token and expert layer {per_token:.3f} near {even:.3f}"),
+        check(bool(flushes) and moe["moe_overflow"] == 0
+              and moe["moe_rows"] == moe["moe_calls"] * bound == all_rows * bound // rows
+              and (ctx.rehearse or moe["moe_rows"] < all_rows),
+              f"{kind}: no call over its bound of {bound} rows; the grouped products ran over "
+              f"{moe['moe_rows']:.0f} of {all_rows} (token, slot) rows"),
+        check(platforms_of(api.global_vars) == {ctx.platform},
+              f"{kind}: parameters live on {ctx.platform}"),
+    ]
+    if "moe_bias_moved" in moe:
+        asserted.append(check(
+            0 <= moe["moe_bias_moved"] < tokens * top_k * layers,
+            f"{kind}: the selection bias (zeros at init) moved "
+            f"{moe['moe_bias_moved']:.0f} chosen pairs"))
+    if ctx.platform == "tpu":
+        asserted.append(check(
+            any("tpu_custom_call" in t for t in round_programs() - before),
+            f"{kind}: the round program holds the attention kernel's tpu_custom_call"))
     return {
-        "asserted": [
-            check(err32 <= 1e-5 * scale,
-                  f"grouped product in float32 at highest = dense form: {err32:.3g} of {scale:.3g}"),
-            check(err16 <= 2e-2 * scale, f"in bfloat16 within rounding: {err16:.3g} of {scale:.3g}"),
-            check(len(losses) == 2 and all(math.isfinite(v) for v in losses),
-                  f"2 rounds logged with finite losses: {losses}"),
-            check(bool(flushes) and sum(a["moe_dropped"] for a in flushes) == 0,
-                  "the flush spans carry the expert counters and no pair was dropped"),
-            check(0.5 < per_token < 2.0, f"held pairs per token and layer {per_token:.3f} near 1"),
-            check(bool(flushes) and moe["moe_overflow"] == 0
-                  and moe["moe_rows"] == moe["moe_calls"] * bound == all_rows * bound // rows
-                  and (ctx.rehearse or moe["moe_rows"] < all_rows),
-                  f"no call over its bound of {bound} rows; the grouped products ran over "
-                  f"{moe['moe_rows']:.0f} of {all_rows} (token, slot) rows"),
-            check(platforms_of(api.global_vars) == {ctx.platform},
-                  f"parameters live on {ctx.platform}"),
-        ],
+        "asserted": asserted,
         "schedule": api._client_mode, "train_loss": [round(v, 4) for v in losses],
-        "grouped_product": {"rows": rows, "live": live, "err_f32": err32, "err_bf16": err16,
-                            "scale": scale},
-        "held_pairs_per_token": per_token,
-        "moe": moe,
+        "held_pairs_per_token": per_token, "moe": moe,
     }
 
 
@@ -481,38 +554,44 @@ def _flash_check(ctx):
         asserted.append(check(_use_interpret() is False, "flash interpret resolved to False"))
     for name, case in (FLASH_TINY if ctx.rehearse else FLASH).items():
         clients, (B, T, H, KV, D), window = case["clients"], case["shape"], case["window"]
+        R = case.get("rope", 0)
         dtype = jnp.dtype(case["dtype"])
-        asserted.append(check(takes_kernel(T, H, KV, D), f"{name}: the entry takes the kernel"))
-        keys = jax.random.split(jax.random.PRNGKey(ctx.seed), 3)
-        q, k, v = (jax.random.normal(kk, (clients, B, T, heads, D), dtype)
-                   for kk, heads in zip(keys, (H, KV, KV)))
+        asserted.append(check(takes_kernel(T, H, KV, D, R, D), f"{name}: the entry takes the kernel"))
+        # q, k, v, and with a second score term q_rope and the one k_rope
+        shapes = [(H, D), (KV, D), (KV, D)] + ([(H, R), (1, R)] if R else [])
+        keys = jax.random.split(jax.random.PRNGKey(ctx.seed), len(shapes))
+        operands = [jax.random.normal(kk, (clients, B, T) + tail, dtype)
+                    for kk, tail in zip(keys, shapes)]
 
-        def fwd_and_grads(fn, q, k, v):
-            def loss(q, k, v):
-                out = fn(q, k, v, causal=True, window=window)
+        def fwd_and_grads(fn, q, k, v, *rope):
+            def loss(q, k, v, *rope):
+                two = dict(q_rope=rope[0], k_rope=rope[1], scale=(D + R) ** -0.5) if rope else {}
+                out = fn(q, k, v, causal=True, window=window, **two)
                 return jnp.sum(jnp.sin(out.astype(jnp.float32))), out
 
-            (_, out), grads = jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+            (_, out), grads = jax.value_and_grad(
+                loss, argnums=tuple(range(3 + len(rope))), has_aux=True)(q, k, v, *rope)
             return (out,) + grads
 
         step = jax.jit(jax.vmap(functools.partial(fwd_and_grads, attention)))
-        compiled = step.lower(q, k, v).compile()
+        compiled = step.lower(*operands).compile()
         if ctx.platform == "tpu":
             asserted.append(check(
                 compiled.as_text().count("tpu_custom_call") >= 2,
                 f"{name}: fwd+grad program contains the forward and backward tpu_custom_call"))
-        got = compiled(q, k, v)
+        got = compiled(*operands)
         with jax.default_matmul_precision("highest"):
             want = jax.jit(jax.vmap(functools.partial(fwd_and_grads, full_attention)))(
-                *(a.astype(jnp.float32) for a in (q, k, v)))
+                *(a.astype(jnp.float32) for a in operands))
 
         errs = {}
-        for part, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        parts = ("out", "dq", "dk", "dv") + (("dq_rope", "dk_rope") if R else ())
+        for part, a, b in zip(parts, got, want):
             a = np.asarray(a.astype(jnp.float32))
             check(np.isfinite(a).all(), f"{name}: flash {part} finite")
             b = np.asarray(b)
             err = float(np.abs(a - b).max())
-            tol = case["tol"][part] * max(1.0, float(np.abs(b).max()))
+            tol = case["tol"][part.removesuffix("_rope")] * max(1.0, float(np.abs(b).max()))
             errs[part] = {"max_abs_err": err, "tol": tol, "ref_max": float(np.abs(b).max())}
             asserted.append(check(
                 err <= tol,

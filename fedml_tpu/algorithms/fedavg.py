@@ -422,7 +422,8 @@ class FedAvgAPI:
         self.health = ClientHealthRegistry.from_config(config)
         # How many of the round program's attention call sites take the
         # blockwise kernel at the training length, and all of them: host
-        # numbers from the shapes alone, carried by every ``flush`` span.
+        # numbers from the shapes alone, carried by every ``flush`` span. The
+        # sites of latent attention add what their core's FLOPs follow from.
         self._attention_attrs = {}
         if model.attention_sites:
             # imported here: a model without attention pays no Pallas import
@@ -434,6 +435,12 @@ class FedAvgAPI:
                     for site in model.attention_sites),
                 "attn_sites": len(model.attention_sites),
             }
+            latent = [site for site in model.attention_sites if len(site) == 5]
+            if latent:
+                heads, _, nope, rope, values = latent[0]
+                self._attention_attrs.update(
+                    attn_qk_width=nope + rope, attn_v_width=values, attn_heads=heads,
+                    attn_length=model.input_shape[0], attn_layers=len(latent))
         # Scheduler: policy-driven cohort selection (FedConfig.selection /
         # .overprovision_factor, scheduler/policies.py). It shares this
         # API's health registry (straggler_aware consults the straggler

@@ -36,9 +36,11 @@ class ModelDef:
     counters: Tuple[str, ...] = ()
     counter_attrs: dict = dataclasses.field(default_factory=dict)
     # One (query heads, key/value heads, head dim) per call of
-    # ``ops/attention.attention`` in a forward pass: with the sequence length
-    # it is what ``ops/attention.takes_kernel`` decides each call from.
-    attention_sites: Tuple[Tuple[int, int, int], ...] = ()
+    # ``ops/attention.attention`` in a forward pass, and for a site of latent
+    # attention two more widths (of the second score term, of the values):
+    # after the sequence length, the arguments ``ops/attention.takes_kernel``
+    # decides each call from.
+    attention_sites: Tuple[Tuple[int, ...], ...] = ()
 
     def init(self, rng) -> dict:
         dummy = jnp.zeros((1,) + tuple(self.input_shape), dtype=self.input_dtype)

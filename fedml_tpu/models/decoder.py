@@ -1,47 +1,78 @@
-"""Spec-driven decoder-only language model: the block shape of today's open
+"""Spec-driven decoder-only language model: the block shapes of today's open
 models, written from the keys of a Hugging Face ``config.json``.
 
     x0 = E[token]
-    h  = x + Wo . Attn(n Wq, n Wk, n Wv)        n = RMSNorm(x)
-    y  = h + MoE(RMSNorm(h))
+    h  = x + Wo . Attn(n)                        n = RMSNorm(x)
+    y  = h + FFN(RMSNorm(h))
     logits = RMSNorm(y_L) W_head                 (W_head = E^T when tied)
 
 - RMS norms (float32 statistics, learned scale), no biases, no learned
   positions.
-- Grouped-query attention: ``num_attention_heads`` query heads of
-  ``head_dim`` on ``num_key_value_heads`` key/value heads (the query width
-  need not equal ``hidden_size``), through the one attention core
+- Attention is of one of two kinds, both through the one attention core
   ``ops/attention.attention`` (the blockwise kernel at training lengths, the
-  plain form at short ones).
-- Rotate-half rotary embeddings on every dim of q and k, one table per layer
-  kind from ``rope_parameters[kind]``: ``rope_type`` ``default`` or ``yarn``
-  (as HF's ``_compute_yarn_parameters``: blended frequencies, cos and sin
-  scaled by ``attention_factor``).
+  plain form at short ones):
+  - grouped-query: ``num_attention_heads`` query heads of ``head_dim`` on
+    ``num_key_value_heads`` key/value heads (the query width need not equal
+    ``hidden_size``), rotate-half rotary on every dim of q and k, one table
+    per layer kind from ``rope_parameters[kind]``: ``rope_type`` ``default``
+    or ``yarn`` (as HF's ``_compute_yarn_parameters``: blended frequencies,
+    cos and sin scaled by ``attention_factor``);
+  - latent (MLA, HF's ``DeepseekV3Attention``), where ``kv_lora_rank`` is
+    given: ``q = n Wq`` holds per head ``qk_nope_head_dim`` dims without and
+    ``qk_rope_head_dim`` with rotary (``q_lora_rank`` null: no query
+    latent); ``n Wkva`` gives the ``kv_lora_rank`` latent and ONE rotary key
+    for all heads; the RMS-normed latent times ``Wkvb`` gives every head's
+    ``k_nope`` and its ``v`` of ``v_head_dim``; scores are ``(q_nope .
+    k_nope + q_rope . k_rope) / sqrt(nope + rope)``. Rotary is the default
+    type at ``rope_theta`` over the rope dims alone; with ``rope_interleave``
+    the pairs are HF's ``(2m, 2m + 1)``, rotated where they lie: the
+    de-interleaving HF applies to q and k alike is a permutation of the
+    dims a dot product sums over, and cancels in every score.
 - ``layer_types`` gives every layer's kind: ``full_attention`` (causal) or
-  ``sliding_attention`` (causal, and ``i - j < sliding_window``).
-- Every MLP is a routed expert layer (:func:`routed_experts`): softmax router
-  over all ``num_experts``, top-``num_experts_per_tok``, renormalised when
-  ``norm_topk_prob``, gated-SiLU experts of width ``moe_intermediate_size``,
-  no capacity and no dropped pair. A chip that holds a share of the experts
-  moves only the rows its share is likely to own: the sorted (token, slot)
-  rows are taken ``row_bound`` at a time (twice the even share, from shapes
-  alone), and a step that routes more than that here takes them again.
+  ``sliding_attention`` (causal, and ``i - j < sliding_window``); a spec
+  without it has ``num_hidden_layers`` full layers.
+- The first ``first_k_dense_replace`` layers' feed-forward is one gated-SiLU
+  MLP of ``intermediate_size``; every other layer's is a routed expert layer
+  (:func:`routed_experts`): a router over all experts (``num_experts`` or,
+  as DeepSeek-style configs name it, ``n_routed_experts``), softmax or
+  sigmoid scores (``scoring_func``), top-``num_experts_per_tok`` of the
+  scores or, with ``topk_method`` ``noaux_tc``, of the scores plus a
+  selection bias that enters the choice and not the weight, renormalised
+  when ``norm_topk_prob``, times ``routed_scaling_factor``; gated-SiLU
+  experts of width ``moe_intermediate_size``, no capacity and no dropped
+  pair; and beside them, where ``n_shared_experts``, one gated-SiLU MLP of
+  ``n_shared_experts x moe_intermediate_size`` that every token takes. A
+  chip that holds a share of the experts moves only the rows its share is
+  likely to own: the sorted (token, slot) rows are taken ``row_bound`` at a
+  time (twice the even share, from shapes alone), and a step that routes
+  more than that here takes them again.
 
 ``experts_held = (lo, hi)`` is the expert-parallel share of one chip: the
 layer holds the weights of experts ``lo..hi-1`` only, still routes over all
-``num_experts``, and returns its own experts' part of the sum. What the
-absent experts would add is left out (on a mesh it arrives by the exchange;
-on one chip there is none). The default holds every expert.
+of them, and returns its own experts' part of the sum (plus the shared
+expert, which every chip computes alike). What the absent experts would add
+is left out (on a mesh it arrives by the exchange; on one chip there is
+none). The default holds every expert.
 
-In training the model sows seven counters per expert layer into the
-``counters`` collection (:data:`COUNTERS`; ``ModelDef.apply(...,
+The selection bias (HF's ``e_score_correction_bias``, a buffer there) is a
+leaf of ``params`` whose gradient is stopped: local training and the average
+leave it as it came. Its update rule is a training recipe no ``config.json``
+gives, and a model cannot carry non-gradient state through the round here.
+
+Not expressed, and refused by name: a query latent (``q_lora_rank``),
+group-limited routing (``n_group`` / ``topk_group`` over 1), rotary scaling
+beside a latent (``rope_scaling``).
+
+In training the model sows its counters per expert layer into the
+``counters`` collection (:func:`counter_names`; ``ModelDef.apply(...,
 counters=True)`` sums them over the layers); it returns logits only."""
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
-from typing import Any, Mapping, Optional, Sequence
+from typing import Any, Mapping, Optional, Sequence, Tuple, Union
 
 import flax.linen as nn
 import jax
@@ -61,6 +92,15 @@ LAYER_KINDS = ("full_attention", "sliding_attention")
 # the call's held pairs exceeded the row bound, so that it took a second pass.
 COUNTERS = ("moe_pairs", "moe_dropped", "moe_rows", "moe_load_max", "moe_load_mean",
             "moe_calls", "moe_overflow")
+# Only where the router has a selection bias, after the others: the chosen
+# (token, slot) pairs that the top-k of the biased scores holds and the top-k
+# of the scores themselves does not (over all experts, held or not).
+BIAS_COUNTER = "moe_bias_moved"
+SCORING = ("softmax", "sigmoid")
+
+
+def counter_names(biased: bool):
+    return COUNTERS + ((BIAS_COUNTER,) if biased else ())
 
 
 def rotary_tables(rope: Mapping[str, Any], head_dim: int, length: int):
@@ -97,6 +137,16 @@ def apply_rotary(x, cos, sin):
     half = x.shape[-1] // 2
     rotated = jnp.concatenate([-x32[..., half:], x32[..., :half]], axis=-1)
     return (x32 * cos[None, :, None, :] + rotated * sin[None, :, None, :]).astype(x.dtype)
+
+
+def apply_rotary_pairs(x, cos, sin):
+    """Rotary on the adjacent pairs ``(2m, 2m + 1)`` of x [B, T, H, D] where
+    they lie (``cos`` and ``sin`` [T, D] hold each angle twice in a row), in
+    float32, back in x's dtype."""
+    x32 = x.astype(jnp.float32)
+    even = jnp.arange(x.shape[-1]) % 2 == 0
+    partner = jnp.where(even, -jnp.roll(x32, -1, axis=-1), jnp.roll(x32, 1, axis=-1))
+    return (x32 * cos[None, :, None, :] + partner * sin[None, :, None, :]).astype(x.dtype)
 
 
 def sum_readers(table, readers, weights=None):
@@ -312,18 +362,28 @@ def _sum_chunks_bwd(part, chunks, res, g):
 _sum_chunks.defvjp(_sum_chunks_fwd, _sum_chunks_bwd)
 
 
-def routed_experts(x, router, w_gate, w_up, w_down, *, top_k: int,
-                   norm_topk_prob: bool = True, held_from: int = 0):
+def routed_experts(x, router, w_gate, w_up, w_down, bias=None, *, top_k: int,
+                   norm_topk_prob: bool = True, held_from: int = 0,
+                   scoring: str = "softmax", scale: float = 1.0):
     """The held experts' part of a routed expert layer.
 
     x [N, d] tokens; router [d, E]; w_gate, w_up [Eh, d, f] and w_down
     [Eh, f, d], the weights of experts ``held_from .. held_from + Eh - 1``.
-    Routes every token over all E experts (logits and softmax in float32),
+    Routes every token over all E experts (logits and scores in float32),
     sorts the N*top_k (token, slot) pairs by expert with the pairs of absent
     experts last, runs the three products as grouped products
     (:func:`grouped_dot`) over the held experts' rows, and sums each
     token's held slots by its (renormalised) top-k weights. Returns
-    ``(y [N, d], counters [len(COUNTERS)] float32)``.
+    ``(y [N, d], counters [len(counter_names(bias is not None))] float32)``.
+
+    ``scoring`` is ``softmax`` over the experts or ``sigmoid`` of each logit.
+    ``bias`` [E] (HF's ``e_score_correction_bias``) is added to the scores
+    for the choice of the top-k alone: a slot's weight is its expert's own
+    score, and no gradient reaches the bias. Sigmoid weights are
+    renormalised over ``sum + 1e-20`` as HF's router does, and ``scale``
+    (``routed_scaling_factor``) multiplies the weights last. With softmax,
+    no bias and scale 1 the traced program is what it was before any of the
+    three existed.
 
     The rows between the sort and the sum are bounded by shapes alone:
     ``R = row_bound(N * top_k, Eh, E)``, twice the even share of this chip's
@@ -348,10 +408,28 @@ def routed_experts(x, router, w_gate, w_up, w_down, *, top_k: int,
     chunks = -(-rows // bound)
     with jax.named_scope("router"):
         logits = jnp.dot(x, router, preferred_element_type=jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)
-        top_w, top_e = jax.lax.top_k(probs, top_k)
+        if scoring == "softmax":
+            probs = jax.nn.softmax(logits, axis=-1)
+        elif scoring == "sigmoid":
+            probs = jax.nn.sigmoid(logits)
+        else:
+            raise ValueError(f"unknown scoring_func {scoring!r}; have {SCORING}")
+        if bias is None:
+            top_w, top_e = jax.lax.top_k(probs, top_k)
+        else:
+            _, top_e = jax.lax.top_k(probs + jax.lax.stop_gradient(bias.astype(probs.dtype)), top_k)
+            top_w = jnp.take_along_axis(probs, top_e, axis=-1)
+            # a chosen pair's rank among the scores themselves (ties to the
+            # lower index, as top_k breaks them): from top_k on, the bias chose it
+            ahead = (probs[:, None, :] > top_w[:, :, None]) | (
+                (probs[:, None, :] == top_w[:, :, None])
+                & (jnp.arange(probs.shape[1])[None, None, :] < top_e[:, :, None]))
+            moved = jnp.sum(jnp.sum(ahead, axis=-1) >= top_k)
         if norm_topk_prob:
-            top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+            total = jnp.sum(top_w, axis=-1, keepdims=True)
+            top_w = top_w / (total + 1e-20 if scoring == "sigmoid" else total)
+        if scale != 1.0:
+            top_w = top_w * scale
     with jax.named_scope("dispatch"):
         local = top_e.reshape(rows) - held_from
         held = (local >= 0) & (local < Eh)
@@ -378,7 +456,7 @@ def routed_experts(x, router, w_gate, w_up, w_down, *, top_k: int,
             pairs.astype(f32) / Eh,
             jnp.ones((), f32),
             (pairs > bound).astype(f32),
-        ])
+        ] + ([] if bias is None else [moved.astype(f32)]))
     return y, jax.lax.stop_gradient(counters)
 
 
@@ -393,33 +471,73 @@ class RMSNorm(nn.Module):
         return (x32 * jax.lax.rsqrt(var + self.eps) * scale).astype(x.dtype)
 
 
+@dataclasses.dataclass(frozen=True)
+class AttentionSpec:
+    """One layer's attention, in numbers. ``rope_dim`` 0 is grouped-query
+    attention (``head_dim`` for q, k and v, rotary on all of it); otherwise
+    latent attention: ``head_dim`` without rotary and ``rope_dim`` with it a
+    query head, values of ``v_dim``, keys and values out of a latent of
+    ``kv_lora_rank``."""
+
+    heads: int
+    kv_heads: int
+    head_dim: int
+    rope_dim: int = 0
+    v_dim: int = 0
+    kv_lora_rank: int = 0
+    interleave: bool = False
+
+    def site(self) -> Tuple[int, ...]:
+        """The shapes ``ops/attention.attention`` is called with, as
+        ``takes_kernel`` takes them after the length
+        (``ModelDef.attention_sites``): what the layer below computes from
+        and what the round's ``flush`` span reports cannot drift apart."""
+        latent = (self.rope_dim, self.v_dim) if self.rope_dim else ()
+        return (self.heads, self.kv_heads, self.head_dim) + latent
+
+
+@dataclasses.dataclass(frozen=True)
+class ExpertSpec:
+    """One expert layer, in numbers (:func:`routed_experts` has the rules)."""
+
+    experts: int
+    top_k: int
+    width: int
+    held: Tuple[int, int]
+    norm_topk_prob: bool = True
+    scoring: str = "softmax"
+    biased: bool = False
+    scale: float = 1.0
+    shared_width: int = 0
+
+
+def gated_mlp(x, w_gate, w_up, w_down):
+    return jnp.dot(jax.nn.silu(jnp.dot(x, w_gate)) * jnp.dot(x, w_up), w_down)
+
+
 class DecoderLayer(nn.Module):
-    """One layer. Every weight is a leaf of the layer itself, and every part
-    of the computation a ``jax.named_scope`` directly beneath it (``qkv``,
-    ``rope``, ``attention_full`` / ``attention_sliding``, ``out``,
-    ``router``, ``dispatch``, ``experts``, ``combine``), so a device trace
-    splits the layer by them."""
+    """One layer. Every weight is a leaf of the layer itself (the latent's
+    inner norm is a module), and every part of the computation a
+    ``jax.named_scope`` directly beneath it, so a device trace splits the
+    layer by them: ``qkv``, ``rope``, ``attention_full`` /
+    ``attention_sliding``, ``out`` (grouped-query), ``q_proj``,
+    ``kv_latent``, ``rope``, ``attention_mla``, ``out`` (latent), ``mlp``
+    (a dense layer), ``router``, ``dispatch``, ``experts``, ``combine``,
+    ``shared`` (an expert layer). The three helpers below are ``nowrap``:
+    a wrapped method would put a scope of its own (``layers_0.latent``)
+    between the layer and these. ``ffn`` is the dense MLP's width or the
+    expert layer's numbers."""
 
     kind: str
-    num_attention_heads: int
-    num_key_value_heads: int
-    head_dim: int
+    attn: AttentionSpec
+    ffn: Union[int, ExpertSpec]
     sliding_window: int
-    num_experts: int
-    num_experts_per_tok: int
-    moe_intermediate_size: int
-    norm_topk_prob: bool
     rms_norm_eps: float
-    experts_held: Sequence[int]
 
-    @nn.compact
-    def __call__(self, x, cos, sin):
-        B, T, d = x.shape
-        H, KV, D = self.num_attention_heads, self.num_key_value_heads, self.head_dim
-        lo, hi = self.experts_held
-        f = self.moe_intermediate_size
-        init = nn.initializers.normal(0.02)
-        n = RMSNorm(self.rms_norm_eps, name="input_layernorm")(x)
+    @nn.nowrap
+    def grouped_query(self, n, cos, sin, init):
+        B, T, d = n.shape
+        H, KV, D = self.attn.site()
         with jax.named_scope("qkv"):
             q = jnp.dot(n, self.param("q_proj", init, (d, H * D))).reshape(B, T, H, D)
             k = jnp.dot(n, self.param("k_proj", init, (d, KV * D))).reshape(B, T, KV, D)
@@ -428,40 +546,103 @@ class DecoderLayer(nn.Module):
             q, k = apply_rotary(q, cos, sin), apply_rotary(k, cos, sin)
         sliding = self.kind == "sliding_attention"
         with jax.named_scope("attention_sliding" if sliding else "attention_full"):
-            a = attention(
+            return attention(
                 q, k, v, causal=True, window=self.sliding_window if sliding else None)
-        with jax.named_scope("out"):
-            x = x + jnp.dot(a.reshape(B, T, H * D), self.param("o_proj", init, (H * D, d)))
-        n = RMSNorm(self.rms_norm_eps, name="post_attention_layernorm")(x)
+
+    @nn.nowrap
+    def latent(self, n, cos, sin, init):
+        B, T, d = n.shape
+        H, _, D, R, V = self.attn.site()
+        rank = self.attn.kv_lora_rank
+        with jax.named_scope("q_proj"):
+            q = jnp.dot(n, self.param("q_proj", init, (d, H * (D + R)))).reshape(B, T, H, D + R)
+        with jax.named_scope("kv_latent"):
+            down = jnp.dot(n, self.param("kv_a_proj", init, (d, rank + R)))
+            latent = RMSNorm(self.rms_norm_eps, name="kv_a_layernorm")(down[..., :rank])
+            kv = jnp.dot(latent, self.param("kv_b_proj", init, (rank, H * (D + V))))
+            kv = kv.reshape(B, T, H, D + V)
+        with jax.named_scope("rope"):
+            turn = apply_rotary_pairs if self.attn.interleave else apply_rotary
+            q_rope = turn(q[..., D:], cos, sin)
+            k_rope = turn(down[..., None, rank:], cos, sin)
+        with jax.named_scope("attention_mla"):
+            return attention(
+                q[..., :D], kv[..., :D], kv[..., D:], causal=True,
+                window=self.sliding_window if self.kind == "sliding_attention" else None,
+                q_rope=q_rope, k_rope=k_rope, scale=(D + R) ** -0.5)
+
+    @nn.nowrap
+    def expert_layer(self, n, init):
+        """The held routed experts' part plus the shared expert's, and the
+        layer's counters."""
+        d, e = n.shape[-1], self.ffn
+        lo, hi = e.held
+        weights = [
+            self.param("router", init, (d, e.experts)),
+            self.param("experts_gate", init, (hi - lo, d, e.width)),
+            self.param("experts_up", init, (hi - lo, d, e.width)),
+            self.param("experts_down", init, (hi - lo, e.width, d)),
+            self.param("router_bias", nn.initializers.zeros, (e.experts,)) if e.biased else None,
+        ]
         # Recomputed in the backward pass, not kept: the tokens x top-k rows
         # of the sorted copies, of both hidden products and of the output are
         # a gigabyte a layer at 4 096 tokens of width 2 304 and top-8, as
         # much again as the attention probabilities would take if kept.
-        experts = jax.checkpoint(functools.partial(
-            routed_experts, top_k=self.num_experts_per_tok,
-            norm_topk_prob=self.norm_topk_prob, held_from=lo))
-        y, counters = experts(
-            n.reshape(B * T, d),
-            self.param("router", init, (d, self.num_experts)),
-            self.param("experts_gate", init, (hi - lo, d, f)),
-            self.param("experts_up", init, (hi - lo, d, f)),
-            self.param("experts_down", init, (hi - lo, f, d)),
-        )
-        self.sow("counters", "moe", counters)
-        return x + y.reshape(B, T, d)
+        y, counters = jax.checkpoint(functools.partial(
+            routed_experts, top_k=e.top_k, norm_topk_prob=e.norm_topk_prob, held_from=lo,
+            scoring=e.scoring, scale=e.scale))(n, *weights)
+        if e.shared_width:
+            # Kept, not recomputed: its two hidden products are tokens x
+            # shared_width in the compute dtype (19 MB a layer at 2 048
+            # tokens of 1 536), a fiftieth of what the routed rows above
+            # would keep, and recomputing would run its three products again.
+            with jax.named_scope("shared"):
+                y = y + gated_mlp(
+                    n,
+                    self.param("shared_gate", init, (d, e.shared_width)),
+                    self.param("shared_up", init, (d, e.shared_width)),
+                    self.param("shared_down", init, (e.shared_width, d)),
+                )
+        return y, counters
+
+    @nn.compact
+    def __call__(self, x, cos, sin):
+        B, T, d = x.shape
+        init = nn.initializers.normal(0.02)
+        n = RMSNorm(self.rms_norm_eps, name="input_layernorm")(x)
+        a = (self.latent if self.attn.rope_dim else self.grouped_query)(n, cos, sin, init)
+        with jax.named_scope("out"):
+            o_proj = self.param("o_proj", init, (a.shape[2] * a.shape[3], d))
+            x = x + jnp.dot(a.reshape(B, T, -1), o_proj)
+        n = RMSNorm(self.rms_norm_eps, name="post_attention_layernorm")(x)
+        if isinstance(self.ffn, ExpertSpec):
+            y, counters = self.expert_layer(n.reshape(B * T, d), init)
+            self.sow("counters", "moe", counters)
+            return x + y.reshape(B, T, d)
+        with jax.named_scope("mlp"):
+            return x + gated_mlp(
+                n,
+                self.param("mlp_gate", init, (d, self.ffn)),
+                self.param("mlp_up", init, (d, self.ffn)),
+                self.param("mlp_down", init, (self.ffn, d)),
+            )
 
 
 class DecoderLM(nn.Module):
     """Arguments mirror the ``config.json`` keys of the source model (plus
-    ``experts_held``); the depth is ``len(layer_types)``. The defaults are a
-    small model for the CLI and tests, not a published one."""
+    ``experts_held``), in either family's vocabulary: ``layer_types`` or
+    ``num_hidden_layers`` for the depth, ``num_experts`` or
+    ``n_routed_experts``, ``rope_parameters`` (per layer kind) or, beside a
+    latent, ``rope_theta``. The defaults are a small model for the CLI and
+    tests, not a published one."""
 
     vocab_size: int
     hidden_size: int = 128
     num_attention_heads: int = 4
     num_key_value_heads: int = 2
     head_dim: int = 32
-    layer_types: Sequence[str] = ("sliding_attention", "full_attention")
+    layer_types: Optional[Sequence[str]] = None
+    num_hidden_layers: Optional[int] = None
     sliding_window: int = 64
     rope_parameters: Optional[Mapping[str, Any]] = None
     num_experts: int = 8
@@ -471,38 +652,124 @@ class DecoderLM(nn.Module):
     rms_norm_eps: float = 1e-6
     tie_word_embeddings: bool = False
     experts_held: Optional[Sequence[int]] = None
+    # latent attention (DeepseekV3Attention's keys)
+    kv_lora_rank: Optional[int] = None
+    q_lora_rank: Optional[int] = None
+    qk_nope_head_dim: Optional[int] = None
+    qk_rope_head_dim: Optional[int] = None
+    v_head_dim: Optional[int] = None
+    rope_theta: float = 10000.0
+    rope_interleave: bool = False
+    rope_scaling: Optional[Mapping[str, Any]] = None
+    # leading dense layers, the router's rules and the shared expert
+    first_k_dense_replace: int = 0
+    intermediate_size: Optional[int] = None
+    n_routed_experts: Optional[int] = None
+    n_shared_experts: int = 0
+    scoring_func: str = "softmax"
+    topk_method: str = "greedy"
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling_factor: float = 1.0
+
+    def kinds(self) -> Tuple[str, ...]:
+        """Every layer's kind; the depth is its length."""
+        if self.layer_types is not None:
+            kinds = tuple(self.layer_types)
+        elif self.num_hidden_layers is not None:
+            kinds = ("full_attention",) * int(self.num_hidden_layers)
+        else:
+            kinds = ("sliding_attention", "full_attention")
+        unknown = sorted(set(kinds) - set(LAYER_KINDS))
+        if unknown:
+            raise ValueError(f"unknown layer kinds {unknown}; have {LAYER_KINDS}")
+        return kinds
+
+    def experts(self) -> int:
+        return int(self.num_experts if self.n_routed_experts is None else self.n_routed_experts)
 
     def held(self):
-        lo, hi = self.experts_held or (0, self.num_experts)
-        if not 0 <= lo < hi <= self.num_experts:
-            raise ValueError(f"experts_held {(lo, hi)} is no range within {self.num_experts} experts")
+        lo, hi = self.experts_held or (0, self.experts())
+        if not 0 <= lo < hi <= self.experts():
+            raise ValueError(f"experts_held {(lo, hi)} is no range within {self.experts()} experts")
         return int(lo), int(hi)
+
+    def attention_spec(self) -> AttentionSpec:
+        if self.q_lora_rank is not None:
+            raise ValueError(
+                f"q_lora_rank {self.q_lora_rank}: a query latent is not expressed here (null only)")
+        if self.kv_lora_rank is None:
+            if self.num_attention_heads % self.num_key_value_heads:
+                raise ValueError("num_attention_heads must be a multiple of num_key_value_heads")
+            return AttentionSpec(self.num_attention_heads, self.num_key_value_heads, self.head_dim)
+        if self.rope_scaling is not None:
+            raise ValueError("rope_scaling beside a latent (kv_lora_rank) is not expressed here")
+        widths = (self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim)
+        if None in widths or self.qk_rope_head_dim % 2:
+            raise ValueError(
+                "latent attention needs qk_nope_head_dim, an even qk_rope_head_dim and "
+                f"v_head_dim, got {widths}")
+        return AttentionSpec(
+            self.num_attention_heads, self.num_attention_heads, int(self.qk_nope_head_dim),
+            int(self.qk_rope_head_dim), int(self.v_head_dim), int(self.kv_lora_rank),
+            bool(self.rope_interleave))
+
+    def expert_spec(self) -> ExpertSpec:
+        if self.n_group != 1 or self.topk_group != 1:
+            raise ValueError(
+                f"n_group {self.n_group}, topk_group {self.topk_group}: group-limited routing "
+                "is not expressed here (1 only)")
+        if self.scoring_func not in SCORING:
+            raise ValueError(f"unknown scoring_func {self.scoring_func!r}; have {SCORING}")
+        if self.topk_method not in ("greedy", "noaux_tc"):
+            raise ValueError(
+                f"unknown topk_method {self.topk_method!r}; have 'greedy' and 'noaux_tc'")
+        return ExpertSpec(
+            self.experts(), int(self.num_experts_per_tok), int(self.moe_intermediate_size),
+            self.held(), bool(self.norm_topk_prob), self.scoring_func,
+            self.topk_method == "noaux_tc", float(self.routed_scaling_factor),
+            int(self.n_shared_experts) * int(self.moe_intermediate_size))
+
+    def feed_forwards(self):
+        """Every layer's ``DecoderLayer.ffn``: the dense width in the leading
+        ``first_k_dense_replace`` layers, the expert layer's numbers after."""
+        dense = min(int(self.first_k_dense_replace), len(self.kinds()))
+        if dense and self.intermediate_size is None:
+            raise ValueError("first_k_dense_replace needs intermediate_size, the dense MLP's width")
+        return (int(self.intermediate_size or 0),) * dense + (
+            self.expert_spec(),) * (len(self.kinds()) - dense)
+
+    def attention_sites(self) -> Tuple[Tuple[int, ...], ...]:
+        """``ModelDef.attention_sites``: one site a layer."""
+        return (self.attention_spec().site(),) * len(self.kinds())
 
     @nn.compact
     def __call__(self, tokens, train: bool = False):
         B, T = tokens.shape
-        unknown = sorted(set(self.layer_types) - set(LAYER_KINDS))
-        if unknown:
-            raise ValueError(f"unknown layer kinds {unknown}; have {LAYER_KINDS}")
-        if self.num_attention_heads % self.num_key_value_heads:
-            raise ValueError("num_attention_heads must be a multiple of num_key_value_heads")
-        rope = self.rope_parameters or {
-            kind: {"rope_type": "default", "rope_theta": 10000.0} for kind in LAYER_KINDS}
+        kinds, attn = self.kinds(), self.attention_spec()
         with jax.named_scope("rope"):
-            tables = {kind: rotary_tables(rope[kind], self.head_dim, T)
-                      for kind in dict.fromkeys(self.layer_types)}
+            if attn.rope_dim:
+                cos, sin = rotary_tables(
+                    {"rope_type": "default", "rope_theta": self.rope_theta}, attn.rope_dim, T)
+                if attn.interleave:
+                    # each angle twice in a row: the pairs are (2m, 2m + 1)
+                    half = attn.rope_dim // 2
+                    cos, sin = (jnp.repeat(t[:, :half], 2, axis=-1) for t in (cos, sin))
+                tables = {kind: (cos, sin) for kind in kinds}
+            else:
+                rope = self.rope_parameters or {
+                    kind: {"rope_type": "default", "rope_theta": 10000.0} for kind in LAYER_KINDS}
+                tables = {kind: rotary_tables(rope[kind], self.head_dim, T)
+                          for kind in dict.fromkeys(kinds)}
         # unit-RMS embedding: under an RMS norm a 0.02 embedding is drowned by
         # the attention branch's mean over the context, which every position
         # shares, and a fresh router collapses onto a few experts
         embed = nn.Embed(self.vocab_size, self.hidden_size, name="embed_tokens",
                          embedding_init=nn.initializers.normal(1.0))
         x = embed(tokens)
-        for i, kind in enumerate(self.layer_types):
+        for i, (kind, ffn) in enumerate(zip(kinds, self.feed_forwards())):
             x = DecoderLayer(
-                kind, self.num_attention_heads, self.num_key_value_heads, self.head_dim,
-                self.sliding_window, self.num_experts, self.num_experts_per_tok,
-                self.moe_intermediate_size, self.norm_topk_prob, self.rms_norm_eps,
-                self.held(), name=f"layers_{i}",
+                kind, attn, ffn, self.sliding_window, self.rms_norm_eps, name=f"layers_{i}",
             )(x, *tables[kind])
         x = RMSNorm(self.rms_norm_eps, name="norm")(x)
         if self.tie_word_embeddings:

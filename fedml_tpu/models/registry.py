@@ -98,26 +98,32 @@ def create(
         )
 
     if name == "decoder":
-        # Spec-driven decoder (GQA, rotary/YaRN, window and full layers,
-        # routed experts); kw mirrors the source model's config.json keys,
-        # lists and nested mappings included (flax freezes them as given).
-        # Returns logits only and trains under task="nwp" like
+        # Spec-driven decoder (grouped-query or latent attention, rotary/YaRN,
+        # window and full layers, leading dense layers, routed experts with a
+        # shared one beside them); kw mirrors the source model's config.json
+        # keys, lists and nested mappings included (flax freezes them as
+        # given). Returns logits only and trains under task="nwp" like
         # "transformer"; its expert layers' counters ride with the round's
         # metrics (ModelDef.counters).
-        from fedml_tpu.models.decoder import COUNTERS, DecoderLM
+        from fedml_tpu.models.decoder import DecoderLM, ExpertSpec, counter_names
 
         m = DecoderLM(vocab_size=num_classes, **kw)
-        m.held()  # a share outside the experts fails here, not at first trace
+        # a spec that cannot be expressed fails here, by name, not at first trace
+        routed = [f for f in m.feed_forwards() if isinstance(f, ExpertSpec)]
+        attrs = {}
+        if routed:
+            # ``layers`` is what the experts' counters are summed over: the
+            # expert layers (every layer, where none is dense)
+            attrs = {"hidden": m.hidden_size, "expert_width": routed[0].width,
+                     "layers": len(routed), "expert_layers": len(routed),
+                     "top_k": routed[0].top_k}
+            if routed[0].shared_width:
+                attrs["shared_width"] = routed[0].shared_width
         return ModelDef(
             m, input_shape, num_classes, input_dtype=jnp.int32, name="decoder",
-            counters=COUNTERS,
-            counter_attrs={
-                "hidden": m.hidden_size, "expert_width": m.moe_intermediate_size,
-                "layers": len(m.layer_types),
-            },
-            attention_sites=(
-                (m.num_attention_heads, m.num_key_value_heads, m.head_dim),
-            ) * len(m.layer_types),
+            counters=counter_names(routed[0].biased) if routed else (),
+            counter_attrs=attrs,
+            attention_sites=m.attention_sites(),
         )
 
     if name in ("resnet56", "resnet110"):

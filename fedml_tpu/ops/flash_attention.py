@@ -33,6 +33,15 @@ index (K and V are not repeated H/KV times) and dK/dV sum over the group
 inside the kernel; where heads are narrower than a tile a K/V head is
 repeated to fill the tile its query heads read (twice at D = 64).
 
+Two-term scores (latent attention). With ``q_rope`` / ``k_rope`` a head's
+score is the SUM of two products, ``q . k + q_rope . k_rope``, where the
+second key is ONE head that every query head shares (the rotary key of MLA)
+and the values keep the first product's width. The two kernels take the
+second pair as two more operands, padded to whole lane tiles by the wrapper
+(a contraction of 64 costs the 128-deep MXU a whole pass either way); the
+shared key is not repeated per head in HBM, and its gradient sums over the
+heads in an accumulator of the backward kernel, as dK/dV of a group do.
+
 Masks. Causal and sliding-window (``i - j < window``) masks have ONE
 definition (``_Cfg.valid``) for both kernels, and one list of live key
 pieces per row chunk (``_Cfg.pieces``).
@@ -67,7 +76,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
-_LANES = 128
+LANES = 128
 _STAT_ROWS = 8  # float32 sublanes of one tile: the statistics' block height
 _VMEM_LIMIT = 96 * 1024 * 1024  # of a v5e core's 128 MiB; the default scope is 16 MiB
 
@@ -87,7 +96,7 @@ def heads_per_tile(H: int, D: int) -> int:
     make whole 128-lane tiles, or all ``H`` (the block is then the array's
     full width, which any width may be) where no divisor of ``H`` does."""
     return next(
-        (hp for hp in range(1, H + 1) if H % hp == 0 and (hp * D) % _LANES == 0), H
+        (hp for hp in range(1, H + 1) if H % hp == 0 and (hp * D) % LANES == 0), H
     )
 
 
@@ -98,6 +107,8 @@ class _Cfg:
     head_dim: int
     heads: int        # query heads a program instance takes (one tile's)
     group_tiles: int  # query tiles that read one K/V tile
+    scale: float      # of the scores: head_dim ** -0.5 unless the caller gives one
+    rope_tile: int    # lanes of the second score term's operands; 0: one term
     causal: bool
     window: Optional[int]
     chunk_q: int
@@ -105,10 +116,6 @@ class _Cfg:
     length_q: int
     length_k: int
     interpret: bool
-
-    @property
-    def scale(self) -> float:
-        return 1.0 / math.sqrt(self.head_dim)
 
     def row_chunks(self):
         return range(0, self.length_q, self.chunk_q)
@@ -195,7 +202,10 @@ def _stat_rows(heads: int) -> int:
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, cfg):
+def _fwd_kernel(q_ref, k_ref, v_ref, *rest, cfg):
+    """``rest``: the second score term's ``q_rope`` and ``k_rope`` where
+    there is one (``cfg.rope_tile``), then the two outputs."""
+    *rope, o_ref, lse_ref = rest
     for r0 in cfg.row_chunks():
         rows = slice(r0, r0 + cfg.chunk_q)
         q, pieces, out = q_ref[0, rows, :], cfg.pieces(r0), None
@@ -204,7 +214,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, cfg):
             q_i = cfg.only(q, i)
             scores = []  # one [chunk, keys of the piece] per piece
             for (c0, c1, _), keep in zip(pieces, keeps):
-                s = _nt(q_i, k_ref[0, c0:c1, :]) * cfg.scale
+                s = _nt(q_i, k_ref[0, c0:c1, :])
+                if rope:
+                    s = s + _nt(rope[0][0, rows, :], rope[1][0, c0:c1, :])
+                s = s * cfg.scale
                 scores.append(s if keep is None else jnp.where(keep, s, _NEG_INF))
             m = functools.reduce(
                 jnp.maximum, [jnp.max(s, axis=1, keepdims=True) for s in scores])
@@ -226,17 +239,22 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, cfg):
 # made the round program's first call 47–50 s on the v5e's host where this
 # makes it 23 s, the plain form's 21 (PERF.md section 6, PR 29).
 @functools.partial(jax.jit, static_argnames="cfg")
-def _flash_forward(q, k, v, cfg):
+def _flash_forward(q, k, v, rope, cfg):
     B, T, width = q.shape
     tile = cfg.heads * cfg.head_dim
     rows = _stat_rows(cfg.heads)
     q_spec = pl.BlockSpec((1, T, tile), lambda b, h: (b, 0, h))
     kv_spec = pl.BlockSpec(
         (1, cfg.length_k, tile), lambda b, h: (b, 0, h // cfg.group_tiles))
+    in_specs = [q_spec, kv_spec, kv_spec]
+    if rope:
+        # a head's own second query, and the one second key all heads share
+        in_specs += [pl.BlockSpec((1, T, cfg.rope_tile), lambda b, h: (b, 0, h)),
+                     pl.BlockSpec((1, cfg.length_k, cfg.rope_tile), lambda b, h: (b, 0, 0))]
     return pl.pallas_call(
         functools.partial(_fwd_kernel, cfg=cfg),
         grid=(B, width // tile),
-        in_specs=[q_spec, kv_spec, kv_spec],
+        in_specs=in_specs,
         out_specs=[q_spec, pl.BlockSpec((1, 1, rows, T), lambda b, h: (b, h, 0, 0))],
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
@@ -245,7 +263,7 @@ def _flash_forward(q, k, v, cfg):
         compiler_params=cfg.compiler_params(("parallel", "parallel")),
         interpret=cfg.interpret,
         name="attention_fwd",
-    )(q, k, v)
+    )(q, k, v, *rope)
 
 
 # ---------------------------------------------------------------------------
@@ -253,16 +271,29 @@ def _flash_forward(q, k, v, cfg):
 # ---------------------------------------------------------------------------
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
-                dk_acc, dv_acc, *, cfg):
+def _bwd_kernel(q_ref, k_ref, v_ref, *rest, cfg):
     """Grid (batch, K/V tile, query tile of its group): dQ is this query
-    tile's own, dK and dV are summed over the group in the accumulators."""
+    tile's own, dK and dV are summed over the group in the accumulators.
+    With a second score term (``cfg.rope_tile``) ``rest`` opens with
+    ``q_rope`` and ``k_rope`` and holds a third gradient pair: dQ_rope is
+    the query tile's own, dK_rope is summed over every head of the batch
+    row (both inner grid axes) in an accumulator of its own."""
+    n = 2 if cfg.rope_tile else 0
+    rope, (do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref) = rest[:n], rest[n:n + 6]
+    drope, (dk_acc, dv_acc, *dkr_acc) = rest[n + 6:2 * n + 6], rest[2 * n + 6:]
     g = pl.program_id(2)
 
     @pl.when(g == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    if rope:
+        c = pl.program_id(1)
+
+        @pl.when((c == 0) & (g == 0))
+        def _init_rope():
+            dkr_acc[0][:] = jnp.zeros_like(dkr_acc[0])
 
     def add(acc, cols, i, part):
         acc[cols, :] = cfg.pick(i, acc[cols, :] + part, acc[cols, :])
@@ -271,6 +302,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref,
         rows = slice(r0, r0 + cfg.chunk_q)
         q, do, dq, pieces = q_ref[0, rows, :], do_ref[0, rows, :], None, cfg.pieces(r0)
         keeps = [cfg.valid(r0, c0, c1, 1) if cut else None for c0, c1, cut in pieces]
+        if rope:
+            qr, dqr = rope[0][0, rows, :], 0.0
         for i in range(cfg.heads):
             # [1, chunk] rows: they broadcast down the [keys, chunk] scores
             lse, delta = lse_ref[0, 0, i:i + 1, rows], delta_ref[0, 0, i:i + 1, rows]
@@ -278,7 +311,11 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref,
             for (c0, c1, _), keep in zip(pieces, keeps):
                 cols = slice(c0, c1)
                 k, v = k_ref[0, cols, :], v_ref[0, cols, :]
-                s = _nt(cfg.only(k, i), q) * cfg.scale  # [keys, chunk]
+                s = _nt(cfg.only(k, i), q)  # [keys, chunk]
+                if rope:
+                    kr = rope[1][0, cols, :]
+                    s = s + _nt(kr, qr)
+                s = s * cfg.scale
                 if keep is not None:
                     s = jnp.where(keep, s, _NEG_INF)
                 p = jnp.exp(s - lse)
@@ -286,17 +323,27 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref,
                 ds = p * (_nt(cfg.only(v, i), do) - delta)
                 add(dk_acc, cols, i, _nn(ds.astype(q.dtype), q))
                 dq_i = dq_i + _nn(ds.T.astype(k.dtype), k)
+                if rope:
+                    dkr_acc[0][cols, :] += _nn(ds.astype(qr.dtype), qr)
+                    dqr = dqr + _nn(ds.T.astype(kr.dtype), kr)
             dq = cfg.pick(i, dq_i, dq)
         dq_ref[0, rows, :] = (dq * cfg.scale).astype(dq_ref.dtype)
+        if rope:
+            drope[0][0, rows, :] = (dqr * cfg.scale).astype(drope[0].dtype)
 
     @pl.when(g == cfg.group_tiles - 1)
     def _finish():
         dk_ref[0] = (dk_acc[:] * cfg.scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
+    if rope:
+        @pl.when((c == pl.num_programs(1) - 1) & (g == cfg.group_tiles - 1))
+        def _finish_rope():
+            drope[1][0] = (dkr_acc[0][:] * cfg.scale).astype(drope[1].dtype)
+
 
 @functools.partial(jax.jit, static_argnames="cfg")
-def _flash_backward(q, k, v, out, lse, do, cfg):
+def _flash_backward(q, k, v, rope, out, lse, do, cfg):
     B, T, width = q.shape
     tile = cfg.heads * cfg.head_dim
     rows, G = lse.shape[2], cfg.group_tiles
@@ -312,25 +359,35 @@ def _flash_backward(q, k, v, out, lse, do, cfg):
     q_spec = pl.BlockSpec((1, T, tile), lambda b, c, g: (b, 0, c * G + g))
     kv_spec = pl.BlockSpec((1, cfg.length_k, tile), lambda b, c, g: (b, 0, c))
     stat_spec = pl.BlockSpec((1, 1, rows, T), lambda b, c, g: (b, c * G + g, 0, 0))
-    return pl.pallas_call(
+    in_specs, out_specs = [q_spec, kv_spec, kv_spec], [q_spec, kv_spec, kv_spec]
+    out_shape = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in (q, k, v)]
+    scratch = [
+        pltpu.VMEM((cfg.length_k, tile), jnp.float32),
+        pltpu.VMEM((cfg.length_k, tile), jnp.float32),
+    ]
+    # dK and dV are summed over the group's query tiles
+    semantics = ("parallel", "parallel", "arbitrary")
+    if rope:
+        qr_spec = pl.BlockSpec((1, T, cfg.rope_tile), lambda b, c, g: (b, 0, c * G + g))
+        kr_spec = pl.BlockSpec((1, cfg.length_k, cfg.rope_tile), lambda b, c, g: (b, 0, 0))
+        in_specs += [qr_spec, kr_spec]
+        out_specs += [qr_spec, kr_spec]
+        out_shape += [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in rope]
+        scratch.append(pltpu.VMEM((cfg.length_k, cfg.rope_tile), jnp.float32))
+        # dK_rope is summed over every head of a batch row
+        semantics = ("parallel", "arbitrary", "arbitrary")
+    dq, dk, dv, *drope = pl.pallas_call(
         functools.partial(_bwd_kernel, cfg=cfg),
         grid=(B, k.shape[2] // tile, G),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, stat_spec, stat_spec],
-        out_specs=[q_spec, kv_spec, kv_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
-            jax.ShapeDtypeStruct(k.shape, k.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((cfg.length_k, tile), jnp.float32),
-            pltpu.VMEM((cfg.length_k, tile), jnp.float32),
-        ],
-        # dK and dV are summed over the group's query tiles
-        compiler_params=cfg.compiler_params(("parallel", "parallel", "arbitrary")),
+        in_specs=in_specs + [q_spec, stat_spec, stat_spec],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
+        compiler_params=cfg.compiler_params(semantics),
         interpret=cfg.interpret,
         name="attention_bwd",
-    )(q, k, v, do, lse, delta)
+    )(q, k, v, *rope, do, lse, delta)
+    return dq, dk, dv, tuple(drope)
 
 
 # ---------------------------------------------------------------------------
@@ -338,19 +395,19 @@ def _flash_backward(q, k, v, out, lse, do, cfg):
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _flash(q, k, v, cfg):
-    return _flash_forward(q, k, v, cfg)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _flash(q, k, v, rope, cfg):
+    return _flash_forward(q, k, v, rope, cfg)[0]
 
 
-def _flash_fwd(q, k, v, cfg):
-    out, lse = _flash_forward(q, k, v, cfg)
-    return out, (q, k, v, out, lse)
+def _flash_fwd(q, k, v, rope, cfg):
+    out, lse = _flash_forward(q, k, v, rope, cfg)
+    return out, (q, k, v, rope, out, lse)
 
 
 def _flash_bwd(cfg, res, do):
-    q, k, v, out, lse = res
-    return _flash_backward(q, k, v, out, lse, do, cfg)
+    q, k, v, rope, out, lse = res
+    return _flash_backward(q, k, v, rope, out, lse, do, cfg)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -364,6 +421,9 @@ def flash_attention_bthd(
     window: Optional[int] = None,
     chunk: int = CHUNK,
     interpret: Optional[bool] = None,
+    q_rope=None,
+    k_rope=None,
+    scale: Optional[float] = None,
 ):
     """Blockwise attention in the framework's layout, the signature of
     ``parallel/ring_attention.full_attention``: q [B, T, H, D], k and v
@@ -371,7 +431,14 @@ def flash_attention_bthd(
     mask, only the keys with ``i - j < window``. Sequence lengths are whole
     numbers of ``chunk`` (or shorter than one: the chunk is then the
     sequence) and at most ``MAX_LENGTH``. Differentiable via the backward
-    kernel."""
+    kernel.
+
+    ``q_rope`` [B, T, H, R] and ``k_rope`` [B, Tk, 1, R] add a second term
+    to every head's score, ``q_rope . k_rope`` against the ONE key that all
+    heads share (MLA's rotary part; its gradient is the sum over the heads);
+    it needs ``KV == H`` and heads of whole lane tiles. ``scale`` multiplies
+    the scores (default ``D ** -0.5``; with a second term the caller gives
+    ``(D + R) ** -0.5``)."""
     B, T, H, D = q.shape
     Tk, KV = k.shape[1], k.shape[2]
     if interpret is None:
@@ -398,14 +465,30 @@ def flash_attention_bthd(
                 f"{H // KV} query heads a K/V head do not fill tiles of {heads} heads"
             )
         k, v = (jnp.repeat(a, heads, axis=2) for a in (k, v))
+    rope, rope_tile = (), 0
+    if q_rope is not None:
+        R = q_rope.shape[-1]
+        if (q_rope.shape != (B, T, H, R) or k_rope.shape != (B, Tk, 1, R)
+                or KV != H or D % LANES):
+            raise ValueError(
+                f"a second score term needs q_rope [B, T, H, R], k_rope [B, Tk, 1, R] and "
+                f"as many key heads as query heads, each of whole {LANES}-lane tiles: got "
+                f"q {q.shape}, k {k.shape}, q_rope {q_rope.shape}, k_rope {k_rope.shape}"
+            )
+        # whole lane tiles, zeros beyond R: they add nothing to a product
+        rope_tile = -(-R // LANES) * LANES
+        pad = ((0, 0), (0, 0), (0, 0), (0, rope_tile - R))
+        rope = (jnp.pad(q_rope, pad).reshape(B, T, H * rope_tile),
+                jnp.pad(k_rope, pad).reshape(B, Tk, rope_tile))
     cfg = _Cfg(
         head_dim=D, heads=heads, group_tiles=H // k.shape[2],
+        scale=1.0 / math.sqrt(D) if scale is None else float(scale), rope_tile=rope_tile,
         causal=bool(causal), window=None if window is None else int(window),
         chunk_q=chunk_q, chunk_k=chunk_k, length_q=T, length_k=Tk,
         interpret=bool(interpret),
     )
     out = _flash(
-        q.reshape(B, T, H * D), k.reshape(B, Tk, -1), v.reshape(B, Tk, -1), cfg
+        q.reshape(B, T, H * D), k.reshape(B, Tk, -1), v.reshape(B, Tk, -1), rope, cfg
     )
     return out.reshape(B, T, H, D)
 

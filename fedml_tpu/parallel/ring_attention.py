@@ -105,16 +105,26 @@ def make_ring_attention(mesh: Mesh, axis_name: str = "seq", causal: bool = False
     return jax.jit(fn)  # fedlint: disable=uncached-jit -- bespoke ring-attention kernel wrapper closed over the mesh; built once per benchmark run
 
 
-def full_attention(q, k, v, causal: bool = False, window: Optional[int] = None):
+def full_attention(q, k, v, causal: bool = False, window: Optional[int] = None,
+                   q_rope=None, k_rope=None, scale: Optional[float] = None):
     """Reference O(T²) attention: q [B, T, H, D], k/v [B, T, KV, D].
 
     ``window`` keeps, beside the causal mask, only the keys with
     ``i - j < window`` (sliding-window layers). With fewer key/value heads
     than query heads (KV divides H) each key/value head serves H/KV query
     heads, and the scores are accumulated in float32. With equal head counts
-    and no window the traced program is what it was before either existed."""
+    and no window the traced program is what it was before either existed.
+
+    ``q_rope`` [B, T, H, R] and ``k_rope`` [B, T, 1, R] add a second term to
+    every head's score against the one key all heads share (latent
+    attention's rotary part), the sum taken in float32; ``scale`` multiplies
+    the scores (default ``D ** -0.5``)."""
     D = q.shape[-1]
     H, KV = q.shape[2], k.shape[2]
+    if q_rope is not None:
+        return _two_term_attention(q, k, v, q_rope, k_rope, causal, window, scale)
+    if scale is not None:
+        raise ValueError("scale is given with the second score term only")
     if H != KV:
         return _grouped_query_attention(q, k, v, causal, window)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(
@@ -133,6 +143,26 @@ def _attention_mask(T: int, causal: bool, window: Optional[int]):
         i = jax.lax.iota(jnp.int32, T)
         mask = mask & (i[:, None] - i[None, :] < window)
     return mask
+
+
+def _two_term_attention(q, k, v, q_rope, k_rope, causal, window, scale):
+    """Scores ``(q . k + q_rope . k_rope) * scale`` in float32, one key head
+    a query head and one ``k_rope`` for all; values of any width."""
+    B, T, H, D = q.shape
+    if k.shape[:3] != (B, T, H) or k_rope.shape[:3] != (B, T, 1):
+        raise ValueError(
+            f"a second score term needs a key head a query head and one k_rope: "
+            f"q {q.shape}, k {k.shape}, k_rope {k_rope.shape}")
+    if scale is None:
+        scale = 1.0 / (D + q_rope.shape[-1]) ** 0.5
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32)
+    s = s + jnp.einsum(
+        "bqhr,bkr->bhqk", q_rope, k_rope[:, :, 0], preferred_element_type=jnp.float32)
+    s = s * scale
+    if causal or window is not None:
+        s = jnp.where(_attention_mask(T, causal, window)[None, None], s, _NEG_INF)
+    p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v).astype(q.dtype)
 
 
 def _grouped_query_attention(q, k, v, causal: bool, window: Optional[int]):

@@ -111,6 +111,34 @@ def test_flash_attention_fwd_bwd_compiles_for_v5e(one_chip, clients, shape, wind
     assert compiled.as_text().count("tpu_custom_call") >= 2
 
 
+def test_latent_attention_fwd_bwd_compiles_for_v5e(one_chip):
+    """The two-term form of both kernels at kanana-2-30b-a3b.silo2b1's
+    training step: 32 heads of 128 | 64 with values of 128 over 2 048
+    positions, the one rotary key shared (chip_smoke.py's kernel check runs
+    it)."""
+    from fedml_tpu.ops.attention import attention, takes_kernel
+
+    B, T, H, D, R = 1, 2048, 32, 128, 64
+    assert takes_kernel(T, H, H, D, R, D)
+
+    def loss(q, k, v, q_rope, k_rope):
+        out = attention(q, k, v, causal=True, q_rope=q_rope, k_rope=k_rope,
+                        scale=(D + R) ** -0.5)
+        return jnp.sum(out.astype(jnp.float32))
+
+    shapes = [(B, T, H, D)] * 3 + [(B, T, H, R), (B, T, 1, R)]
+    args = [jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip) for s in shapes]
+    import importlib
+
+    module = importlib.import_module("fedml_tpu.ops.flash_attention")
+    saved, module._use_interpret = module._use_interpret, lambda: False
+    try:
+        compiled = jax.jit(jax.value_and_grad(loss, argnums=range(5))).lower(*args).compile()
+    finally:
+        module._use_interpret = saved
+    assert compiled.as_text().count("tpu_custom_call") >= 2
+
+
 def test_expert_layer_at_mellum_share_compiles_for_v5e_over_bounded_rows(one_chip):
     """models/decoder.routed_experts as DecoderLayer calls it (recomputed in
     the backward pass), forward + gradient at mellum2-12b-a2.5b.silo2's
