@@ -633,11 +633,47 @@ def _robust_stats_check(ctx):
     return {"C": 10, "D": D}, asserted
 
 
+def _pool_check(ctx):
+    """ops/pooling.max_pool at the FEMNIST CNN's first pool (post-ReLU
+    values, one plane constant so that every window in it is tied): value
+    and gradient equal flax's to the last bit, with no select_and_scatter
+    in the compiled gradient."""
+    import flax.linen as nn
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from fedml_tpu.ops.pooling import max_pool
+
+    shape = (4, 8, 8, 32) if ctx.rehearse else (20, 28, 28, 32)
+    k1, k2 = jax.random.split(jax.random.PRNGKey(ctx.seed + 2))
+    x = jnp.maximum(jax.random.normal(k1, shape) - 0.5, 0.0).at[:, :, :, 0].set(0.75)
+    dy = jax.random.normal(k2, (shape[0], shape[1] // 2, shape[2] // 2, shape[3]))
+
+    def both(pool):
+        fn = jax.jit(lambda x, dy: (pool(x), jax.vjp(pool, x)[1](dy)[0]))
+        return fn.lower(x, dy).compile().as_text(), fn(x, dy)
+
+    text, ours = both(lambda x: max_pool(x, (2, 2), strides=(2, 2)))
+    _, flax = both(lambda x: nn.max_pool(x, (2, 2), strides=(2, 2)))
+    asserted = []
+    for part, a, b in zip(("value", "gradient"), ours, flax):
+        asserted.append(check(
+            np.array_equal(np.asarray(a).view(np.uint32), np.asarray(b).view(np.uint32)),
+            f"max_pool {part} at f32{list(shape)} bit-equal to nn.max_pool, ties included"))
+    asserted.append(check("select-and-scatter" not in text,
+                          "max_pool's compiled gradient holds no select-and-scatter"))
+    return {"shape": list(shape)}, asserted
+
+
 def phase_kernels(ctx):
     """Both Pallas kernels, compiled, against their references — alone and
-    (robust stats) through the normal CLI path."""
+    (robust stats) through the normal CLI path — and the written-out pool
+    gradient against flax's."""
     flash, asserted = _flash_check(ctx)
     robust, more = _robust_stats_check(ctx)
+    asserted += more
+    pool, more = _pool_check(ctx)
     asserted += more
 
     api, rows, _ = run_cli(
@@ -657,7 +693,7 @@ def phase_kernels(ctx):
             "tpu_custom_call" in text,
             "robust round (aggregation) program contains tpu_custom_call",
         ))
-    return {"asserted": asserted, "flash": flash, "robust_stats": robust}
+    return {"asserted": asserted, "flash": flash, "robust_stats": robust, "max_pool": pool}
 
 
 def phase_multichip(ctx):

@@ -11,6 +11,8 @@ from __future__ import annotations
 import flax.linen as nn
 import jax.numpy as jnp
 
+from fedml_tpu.ops.pooling import max_pool
+
 
 class CNNOriginalFedAvg(nn.Module):
     """conv32(5×5) → pool → conv64(5×5) → pool → fc512 → fc#classes
@@ -22,10 +24,10 @@ class CNNOriginalFedAvg(nn.Module):
     def __call__(self, x, train: bool = False):
         x = nn.Conv(32, (5, 5), padding="SAME", name="conv2d_1")(x)
         x = nn.relu(x)
-        x = nn.max_pool(x, (2, 2), strides=(2, 2))
+        x = max_pool(x, (2, 2), strides=(2, 2))
         x = nn.Conv(64, (5, 5), padding="SAME", name="conv2d_2")(x)
         x = nn.relu(x)
-        x = nn.max_pool(x, (2, 2), strides=(2, 2))
+        x = max_pool(x, (2, 2), strides=(2, 2))
         x = x.reshape((x.shape[0], -1))
         x = nn.relu(nn.Dense(512, name="linear_1")(x))
         return nn.Dense(self.num_classes, name="linear_2")(x)
@@ -43,7 +45,7 @@ class CNNDropOut(nn.Module):
     def __call__(self, x, train: bool = False):
         x = nn.relu(nn.Conv(32, (3, 3), padding="VALID", name="conv2d_1")(x))
         x = nn.relu(nn.Conv(64, (3, 3), padding="VALID", name="conv2d_2")(x))
-        x = nn.max_pool(x, (2, 2), strides=(2, 2))
+        x = max_pool(x, (2, 2), strides=(2, 2))
         x = nn.Dropout(self.dropout1, deterministic=not train)(x)
         x = x.reshape((x.shape[0], -1))
         x = nn.relu(nn.Dense(128, name="linear_1")(x))

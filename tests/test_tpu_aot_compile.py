@@ -196,6 +196,9 @@ def test_femnist_cnn_round_program_compiles_for_one_v5e_chip(one_chip):
 
     text = compiled.as_text()
     assert "convolution" in text  # the CNN's convs are in the chip program
+    # the pools' gradient is ops/pooling's written-out rule (PR 33), not the
+    # transpose of reduce_window
+    assert "select-and-scatter" not in text and "reduce-window" in text
     mem = compiled.memory_analysis()
     need = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert 0 < need < 1 << 30, need  # ~160 MB; the chip holds 16 GB
