@@ -991,7 +991,8 @@ class FedAvgAPI:
             "flush", first_round=rounds[0], last_round=rounds[-1],
             rows=len(rounds),
         ) as flush:
-            for name, value in self._attention_attrs.items():
+            # with the model's own constants (empty where it has none to give)
+            for name, value in (*self._attention_attrs.items(), *self.model.counter_attrs.items()):
                 flush.set_attr(name, value)
             # the one device-to-host fetch: it returns when the device has
             # finished every round flushed here, so this is the wait, not
@@ -1002,13 +1003,10 @@ class FedAvgAPI:
                 host = np.asarray(jnp.stack(vectors))
             fixed = len(self._METRIC_KEYS)
             if host.shape[1] > fixed:
-                # the model's device counters, summed over the rounds flushed
-                # here, with the constants their readers need beside them
+                # the model's device counters, summed over the rounds flushed here
                 sums = host[:, fixed:].sum(axis=0, dtype=np.float64)
                 for name, total in zip(self.model.counters, sums):
                     flush.set_attr(name, float(total))
-                for name, value in self.model.counter_attrs.items():
-                    flush.set_attr(name, value)
             for r, vals in zip(rounds, host):
                 final = self._log_round(r, dict(zip(self._METRIC_KEYS, vals)))
         pending.clear()

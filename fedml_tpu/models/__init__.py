@@ -31,15 +31,18 @@ class ModelDef:
     # Device counters the module reports in training: it sows one float32
     # vector (an entry per name here) into the "counters" collection wherever
     # it counts, and ``apply(..., counters=True)`` hands back their sum. The
-    # local-train loop carries them with its metrics; ``counter_attrs`` are
-    # the model's constants a reader of the counters needs beside them.
+    # local-train loop carries them with its metrics. ``counter_attrs`` are
+    # the model's host constants that every ``flush`` span carries for the
+    # readers of its counters and of its layers' metrics (the expert layers'
+    # widths and counts, the conv layers' ``conv_layers`` and ``conv_width``).
     counters: Tuple[str, ...] = ()
     counter_attrs: dict = dataclasses.field(default_factory=dict)
     # One (query heads, key/value heads, head dim) per call of
     # ``ops/attention.attention`` in a forward pass, and for a site of latent
     # attention two more widths (of the second score term, of the values):
     # after the sequence length, the arguments ``ops/attention.takes_kernel``
-    # decides each call from.
+    # decides each call from. A layer whose token mixer is no attention has
+    # no site.
     attention_sites: Tuple[Tuple[int, ...], ...] = ()
 
     def init(self, rng) -> dict:

@@ -2,21 +2,35 @@
 models, written from the keys of a Hugging Face ``config.json``.
 
     x0 = E[token]
-    h  = x + Wo . Attn(n)                        n = RMSNorm(x)
+    h  = x + Mixer(n)                            n = RMSNorm(x)
     y  = h + FFN(RMSNorm(h))
     logits = RMSNorm(y_L) W_head                 (W_head = E^T when tied)
 
 - RMS norms (float32 statistics, learned scale), no biases, no learned
   positions.
+- ``layer_types`` gives every layer's kind, which is its token mixer:
+  ``full_attention`` (causal), ``sliding_attention`` (causal, and ``i - j <
+  sliding_window``) or ``conv``; a spec without it has ``num_hidden_layers``
+  full layers.
+- A ``conv`` layer mixes tokens by a gated short convolution and no
+  attention (HF's ``Lfm2MoeShortConv``; ``ops/short_conv.py``): ``[B, C, X] =
+  n W_in`` (d -> 3d, split in that order), ``u = B * X``, a causal depthwise
+  convolution of ``conv_L_cache`` taps a channel over ``u`` (zero before
+  position 0, no bias), ``Mixer = (C * c) W_out``. No activation, no
+  softmax, no positions; ``u`` and the sums over the taps are float32.
 - Attention is of one of two kinds, both through the one attention core
   ``ops/attention.attention`` (the blockwise kernel at training lengths, the
   plain form at short ones):
   - grouped-query: ``num_attention_heads`` query heads of ``head_dim`` on
     ``num_key_value_heads`` key/value heads (the query width need not equal
-    ``hidden_size``), rotate-half rotary on every dim of q and k, one table
-    per layer kind from ``rope_parameters[kind]``: ``rope_type`` ``default``
-    or ``yarn`` (as HF's ``_compute_yarn_parameters``: blended frequencies,
-    cos and sin scaled by ``attention_factor``);
+    ``hidden_size``); with ``use_qk_norm`` an RMS norm with a learned scale
+    of ``head_dim`` over each head's dims of q and of k (one scale for all
+    heads of q, one for k) ahead of rotary; rotate-half rotary on every dim
+    of q and k, one table per layer kind from ``rope_parameters[kind]``
+    (``rope_type`` ``default`` or ``yarn``, as HF's
+    ``_compute_yarn_parameters``: blended frequencies, cos and sin scaled by
+    ``attention_factor``) or, without ``rope_parameters``, the default type
+    at ``rope_theta`` for every layer;
   - latent (MLA, HF's ``DeepseekV3Attention``), where ``kv_lora_rank`` is
     given: ``q = n Wq`` holds per head ``qk_nope_head_dim`` dims without and
     ``qk_rope_head_dim`` with rotary (``q_lora_rank`` null: no query
@@ -28,9 +42,6 @@ models, written from the keys of a Hugging Face ``config.json``.
     the pairs are HF's ``(2m, 2m + 1)``, rotated where they lie: the
     de-interleaving HF applies to q and k alike is a permutation of the
     dims a dot product sums over, and cancels in every score.
-- ``layer_types`` gives every layer's kind: ``full_attention`` (causal) or
-  ``sliding_attention`` (causal, and ``i - j < sliding_window``); a spec
-  without it has ``num_hidden_layers`` full layers.
 - The first ``first_k_dense_replace`` layers' feed-forward is one gated-SiLU
   MLP of ``intermediate_size``; every other layer's is a routed expert layer
   (:func:`routed_experts`): a router over all experts (``num_experts`` or,
@@ -38,14 +49,24 @@ models, written from the keys of a Hugging Face ``config.json``.
   sigmoid scores (``scoring_func``), top-``num_experts_per_tok`` of the
   scores or, with ``topk_method`` ``noaux_tc``, of the scores plus a
   selection bias that enters the choice and not the weight, renormalised
-  when ``norm_topk_prob``, times ``routed_scaling_factor``; gated-SiLU
-  experts of width ``moe_intermediate_size``, no capacity and no dropped
-  pair; and beside them, where ``n_shared_experts``, one gated-SiLU MLP of
+  when ``norm_topk_prob`` by the chosen scores' sum plus ``renorm_eps``
+  (by default HF's ``DeepseekV3`` 1e-20 under sigmoid scores and nothing
+  under softmax; HF's ``lfm2_moe`` router adds 1e-6), times
+  ``routed_scaling_factor``; gated-SiLU experts of width
+  ``moe_intermediate_size``, no capacity and no dropped pair; and beside
+  them, where ``n_shared_experts``, one gated-SiLU MLP of
   ``n_shared_experts x moe_intermediate_size`` that every token takes. A
   chip that holds a share of the experts moves only the rows its share is
   likely to own: the sorted (token, slot) rows are taken ``row_bound`` at a
   time (twice the even share, from shapes alone), and a step that routes
   more than that here takes them again.
+
+The keys are the decoder's own where families spell one thing differently:
+a spec written from HF's ``lfm2_moe`` gives its ``num_dense_layers`` as
+``first_k_dense_replace``, its ``norm_eps`` as ``rms_norm_eps``, and its
+router (``use_expert_bias`` true: sigmoid scores always, the ``expert_bias``
+buffer in the choice, 1e-6 in the renormalisation) as ``scoring_func``
+``sigmoid``, ``topk_method`` ``noaux_tc``, ``renorm_eps`` 1e-6.
 
 ``experts_held = (lo, hi)`` is the expert-parallel share of one chip: the
 layer holds the weights of experts ``lo..hi-1`` only, still routes over all
@@ -54,14 +75,23 @@ expert, which every chip computes alike). What the absent experts would add
 is left out (on a mesh it arrives by the exchange; on one chip there is
 none). The default holds every expert.
 
-The selection bias (HF's ``e_score_correction_bias``, a buffer there) is a
-leaf of ``params`` whose gradient is stopped: local training and the average
-leave it as it came. Its update rule is a training recipe no ``config.json``
-gives, and a model cannot carry non-gradient state through the round here.
+The selection bias (HF's ``e_score_correction_bias`` or ``expert_bias``, a
+buffer there) is a leaf of ``params`` whose gradient is stopped: local
+training and the average leave it as it came. Its update rule is a training
+recipe no ``config.json`` gives, and a model cannot carry non-gradient state
+through the round here.
 
 Not expressed, and refused by name: a query latent (``q_lora_rank``),
 group-limited routing (``n_group`` / ``topk_group`` over 1), rotary scaling
-beside a latent (``rope_scaling``).
+beside a latent (``rope_scaling``), QK norms beside a latent
+(``use_qk_norm``), a ``conv`` layer without its filter's length
+(``conv_L_cache``). No key here gives a convolution a bias (HF's
+``conv_bias`` true), a tied head a scale of its own (the table is drawn at
+unit RMS, so ``tie_word_embeddings`` starts the logits at deviation
+``sqrt(hidden_size)`` where a source's 0.02 table starts them under 1),
+documents packed into one sequence (the convolution and attention would
+have to reset at their boundaries) or sequences over the attention kernel's
+``MAX_LENGTH``.
 
 In training the model sows its counters per expert layer into the
 ``counters`` collection (:func:`counter_names`; ``ModelDef.apply(...,
@@ -80,8 +110,9 @@ import jax.numpy as jnp
 from jax.custom_batching import custom_vmap
 
 from fedml_tpu.ops.attention import attention
+from fedml_tpu.ops.short_conv import gated_short_conv
 
-LAYER_KINDS = ("full_attention", "sliding_attention")
+LAYER_KINDS = ("full_attention", "sliding_attention", "conv")
 
 # Per expert layer and call, as float32 (whole numbers below 2**24 a round):
 # (token, slot) pairs routed to a held expert; held pairs that the dispatch
@@ -364,7 +395,7 @@ _sum_chunks.defvjp(_sum_chunks_fwd, _sum_chunks_bwd)
 
 def routed_experts(x, router, w_gate, w_up, w_down, bias=None, *, top_k: int,
                    norm_topk_prob: bool = True, held_from: int = 0,
-                   scoring: str = "softmax", scale: float = 1.0):
+                   scoring: str = "softmax", scale: float = 1.0, renorm_eps: float = 0.0):
     """The held experts' part of a routed expert layer.
 
     x [N, d] tokens; router [d, E]; w_gate, w_up [Eh, d, f] and w_down
@@ -379,11 +410,12 @@ def routed_experts(x, router, w_gate, w_up, w_down, bias=None, *, top_k: int,
     ``scoring`` is ``softmax`` over the experts or ``sigmoid`` of each logit.
     ``bias`` [E] (HF's ``e_score_correction_bias``) is added to the scores
     for the choice of the top-k alone: a slot's weight is its expert's own
-    score, and no gradient reaches the bias. Sigmoid weights are
-    renormalised over ``sum + 1e-20`` as HF's router does, and ``scale``
-    (``routed_scaling_factor``) multiplies the weights last. With softmax,
-    no bias and scale 1 the traced program is what it was before any of the
-    three existed.
+    score, and no gradient reaches the bias. The chosen weights are
+    renormalised over ``sum + renorm_eps`` (HF's sigmoid routers add 1e-20
+    or 1e-6; 0 adds nothing), and ``scale`` (``routed_scaling_factor``)
+    multiplies the weights last. With softmax, no bias, scale 1 and no
+    epsilon the traced program is what it was before any of the four
+    existed.
 
     The rows between the sort and the sum are bounded by shapes alone:
     ``R = row_bound(N * top_k, Eh, E)``, twice the even share of this chip's
@@ -427,7 +459,7 @@ def routed_experts(x, router, w_gate, w_up, w_down, bias=None, *, top_k: int,
             moved = jnp.sum(jnp.sum(ahead, axis=-1) >= top_k)
         if norm_topk_prob:
             total = jnp.sum(top_w, axis=-1, keepdims=True)
-            top_w = top_w / (total + 1e-20 if scoring == "sigmoid" else total)
+            top_w = top_w / (total + renorm_eps if renorm_eps else total)
         if scale != 1.0:
             top_w = top_w * scale
     with jax.named_scope("dispatch"):
@@ -477,7 +509,8 @@ class AttentionSpec:
     attention (``head_dim`` for q, k and v, rotary on all of it); otherwise
     latent attention: ``head_dim`` without rotary and ``rope_dim`` with it a
     query head, values of ``v_dim``, keys and values out of a latent of
-    ``kv_lora_rank``."""
+    ``kv_lora_rank``. ``qk_norm``: an RMS norm with a learned scale over
+    each head's dims of q and of k, ahead of rotary (grouped-query only)."""
 
     heads: int
     kv_heads: int
@@ -486,6 +519,7 @@ class AttentionSpec:
     v_dim: int = 0
     kv_lora_rank: int = 0
     interleave: bool = False
+    qk_norm: bool = False
 
     def site(self) -> Tuple[int, ...]:
         """The shapes ``ops/attention.attention`` is called with, as
@@ -509,6 +543,7 @@ class ExpertSpec:
     biased: bool = False
     scale: float = 1.0
     shared_width: int = 0
+    renorm_eps: float = 0.0
 
 
 def gated_mlp(x, w_gate, w_up, w_down):
@@ -519,20 +554,24 @@ class DecoderLayer(nn.Module):
     """One layer. Every weight is a leaf of the layer itself (the latent's
     inner norm is a module), and every part of the computation a
     ``jax.named_scope`` directly beneath it, so a device trace splits the
-    layer by them: ``qkv``, ``rope``, ``attention_full`` /
-    ``attention_sliding``, ``out`` (grouped-query), ``q_proj``,
-    ``kv_latent``, ``rope``, ``attention_mla``, ``out`` (latent), ``mlp``
-    (a dense layer), ``router``, ``dispatch``, ``experts``, ``combine``,
-    ``shared`` (an expert layer). The three helpers below are ``nowrap``:
-    a wrapped method would put a scope of its own (``layers_0.latent``)
-    between the layer and these. ``ffn`` is the dense MLP's width or the
-    expert layer's numbers."""
+    layer by them: ``qkv``, ``qk_norm`` (where the spec has it), ``rope``,
+    ``attention_full`` / ``attention_sliding``, ``out`` (grouped-query),
+    ``q_proj``, ``kv_latent``, ``rope``, ``attention_mla``, ``out``
+    (latent), ``in_proj``, ``short_conv``, ``out`` (a ``conv`` layer),
+    ``mlp`` (a dense layer), ``router``, ``dispatch``, ``experts``,
+    ``combine``, ``shared`` (an expert layer). The helpers below are
+    ``nowrap``: a wrapped method would put a scope of its own
+    (``layers_0.latent``) between the layer and these. ``ffn`` is the dense
+    MLP's width or the expert layer's numbers; ``conv_taps`` the filter's
+    length in a ``conv`` layer, whose mixer needs neither ``attn`` nor the
+    rotary tables."""
 
     kind: str
     attn: AttentionSpec
     ffn: Union[int, ExpertSpec]
     sliding_window: int
     rms_norm_eps: float
+    conv_taps: int = 0
 
     @nn.nowrap
     def grouped_query(self, n, cos, sin, init):
@@ -542,6 +581,11 @@ class DecoderLayer(nn.Module):
             q = jnp.dot(n, self.param("q_proj", init, (d, H * D))).reshape(B, T, H, D)
             k = jnp.dot(n, self.param("k_proj", init, (d, KV * D))).reshape(B, T, KV, D)
             v = jnp.dot(n, self.param("v_proj", init, (d, KV * D))).reshape(B, T, KV, D)
+        if self.attn.qk_norm:
+            # one scale of D for all heads of q, one for k
+            with jax.named_scope("qk_norm"):
+                q = RMSNorm(self.rms_norm_eps, name="q_layernorm")(q)
+                k = RMSNorm(self.rms_norm_eps, name="k_layernorm")(k)
         with jax.named_scope("rope"):
             q, k = apply_rotary(q, cos, sin), apply_rotary(k, cos, sin)
         sliding = self.kind == "sliding_attention"
@@ -572,6 +616,19 @@ class DecoderLayer(nn.Module):
                 q_rope=q_rope, k_rope=k_rope, scale=(D + R) ** -0.5)
 
     @nn.nowrap
+    def short_conv(self, n, init):
+        """The gated short convolution between its two projections'
+        products (``ops/short_conv.py``): [B, T, d]. The filter starts at
+        deviation ``1 / sqrt(taps)``, so that the sum over the taps keeps its
+        input's scale."""
+        d, L = n.shape[-1], self.conv_taps
+        with jax.named_scope("in_proj"):
+            bcx = jnp.dot(n, self.param("in_proj", init, (d, 3 * d)))
+        with jax.named_scope("short_conv"):
+            return gated_short_conv(
+                bcx, self.param("conv", nn.initializers.normal(L ** -0.5), (d, L)))
+
+    @nn.nowrap
     def expert_layer(self, n, init):
         """The held routed experts' part plus the shared expert's, and the
         layer's counters."""
@@ -590,7 +647,7 @@ class DecoderLayer(nn.Module):
         # much again as the attention probabilities would take if kept.
         y, counters = jax.checkpoint(functools.partial(
             routed_experts, top_k=e.top_k, norm_topk_prob=e.norm_topk_prob, held_from=lo,
-            scoring=e.scoring, scale=e.scale))(n, *weights)
+            scoring=e.scoring, scale=e.scale, renorm_eps=e.renorm_eps))(n, *weights)
         if e.shared_width:
             # Kept, not recomputed: its two hidden products are tokens x
             # shared_width in the compute dtype (19 MB a layer at 2 048
@@ -610,10 +667,13 @@ class DecoderLayer(nn.Module):
         B, T, d = x.shape
         init = nn.initializers.normal(0.02)
         n = RMSNorm(self.rms_norm_eps, name="input_layernorm")(x)
-        a = (self.latent if self.attn.rope_dim else self.grouped_query)(n, cos, sin, init)
+        if self.kind == "conv":
+            a, leaf = self.short_conv(n, init), "out_proj"
+        else:
+            a = (self.latent if self.attn.rope_dim else self.grouped_query)(n, cos, sin, init)
+            a, leaf = a.reshape(B, T, -1), "o_proj"
         with jax.named_scope("out"):
-            o_proj = self.param("o_proj", init, (a.shape[2] * a.shape[3], d))
-            x = x + jnp.dot(a.reshape(B, T, -1), o_proj)
+            x = x + jnp.dot(a, self.param(leaf, init, (a.shape[-1], d)))
         n = RMSNorm(self.rms_norm_eps, name="post_attention_layernorm")(x)
         if isinstance(self.ffn, ExpertSpec):
             y, counters = self.expert_layer(n.reshape(B * T, d), init)
@@ -632,9 +692,9 @@ class DecoderLM(nn.Module):
     """Arguments mirror the ``config.json`` keys of the source model (plus
     ``experts_held``), in either family's vocabulary: ``layer_types`` or
     ``num_hidden_layers`` for the depth, ``num_experts`` or
-    ``n_routed_experts``, ``rope_parameters`` (per layer kind) or, beside a
-    latent, ``rope_theta``. The defaults are a small model for the CLI and
-    tests, not a published one."""
+    ``n_routed_experts``, ``rope_parameters`` (per layer kind) or one
+    ``rope_theta`` for every layer. The defaults are a small model for the
+    CLI and tests, not a published one."""
 
     vocab_size: int
     hidden_size: int = 128
@@ -671,6 +731,10 @@ class DecoderLM(nn.Module):
     n_group: int = 1
     topk_group: int = 1
     routed_scaling_factor: float = 1.0
+    renorm_eps: Optional[float] = None
+    # conv layers and QK norms
+    conv_L_cache: Optional[int] = None
+    use_qk_norm: bool = False
 
     def kinds(self) -> Tuple[str, ...]:
         """Every layer's kind; the depth is its length."""
@@ -684,6 +748,15 @@ class DecoderLM(nn.Module):
         if unknown:
             raise ValueError(f"unknown layer kinds {unknown}; have {LAYER_KINDS}")
         return kinds
+
+    def conv_taps(self) -> int:
+        """The conv layers' filter length; 0 where no layer is one."""
+        if "conv" not in self.kinds():
+            return 0
+        if not self.conv_L_cache or int(self.conv_L_cache) < 1:
+            raise ValueError(
+                f"a 'conv' layer needs conv_L_cache, its filter's length, got {self.conv_L_cache}")
+        return int(self.conv_L_cache)
 
     def experts(self) -> int:
         return int(self.num_experts if self.n_routed_experts is None else self.n_routed_experts)
@@ -701,7 +774,10 @@ class DecoderLM(nn.Module):
         if self.kv_lora_rank is None:
             if self.num_attention_heads % self.num_key_value_heads:
                 raise ValueError("num_attention_heads must be a multiple of num_key_value_heads")
-            return AttentionSpec(self.num_attention_heads, self.num_key_value_heads, self.head_dim)
+            return AttentionSpec(self.num_attention_heads, self.num_key_value_heads, self.head_dim,
+                                 qk_norm=bool(self.use_qk_norm))
+        if self.use_qk_norm:
+            raise ValueError("use_qk_norm beside a latent (kv_lora_rank) is not expressed here")
         if self.rope_scaling is not None:
             raise ValueError("rope_scaling beside a latent (kv_lora_rank) is not expressed here")
         widths = (self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim)
@@ -724,11 +800,15 @@ class DecoderLM(nn.Module):
         if self.topk_method not in ("greedy", "noaux_tc"):
             raise ValueError(
                 f"unknown topk_method {self.topk_method!r}; have 'greedy' and 'noaux_tc'")
+        eps = self.renorm_eps
+        if eps is None:
+            # HF's DeepseekV3 router adds 1e-20 to the sum it renormalises by
+            eps = 1e-20 if self.scoring_func == "sigmoid" else 0.0
         return ExpertSpec(
             self.experts(), int(self.num_experts_per_tok), int(self.moe_intermediate_size),
             self.held(), bool(self.norm_topk_prob), self.scoring_func,
             self.topk_method == "noaux_tc", float(self.routed_scaling_factor),
-            int(self.n_shared_experts) * int(self.moe_intermediate_size))
+            int(self.n_shared_experts) * int(self.moe_intermediate_size), float(eps))
 
     def feed_forwards(self):
         """Every layer's ``DecoderLayer.ffn``: the dense width in the leading
@@ -740,13 +820,16 @@ class DecoderLM(nn.Module):
             self.expert_spec(),) * (len(self.kinds()) - dense)
 
     def attention_sites(self) -> Tuple[Tuple[int, ...], ...]:
-        """``ModelDef.attention_sites``: one site a layer."""
-        return (self.attention_spec().site(),) * len(self.kinds())
+        """``ModelDef.attention_sites``: one site a layer that has attention
+        (a ``conv`` layer calls no attention)."""
+        return (self.attention_spec().site(),) * sum(kind != "conv" for kind in self.kinds())
 
     @nn.compact
     def __call__(self, tokens, train: bool = False):
         B, T = tokens.shape
-        kinds, attn = self.kinds(), self.attention_spec()
+        kinds, attn, taps = self.kinds(), self.attention_spec(), self.conv_taps()
+        # one pair of tables a kind of attention; a conv layer has no positions
+        turning = [kind for kind in dict.fromkeys(kinds) if kind != "conv"]
         with jax.named_scope("rope"):
             if attn.rope_dim:
                 cos, sin = rotary_tables(
@@ -755,12 +838,12 @@ class DecoderLM(nn.Module):
                     # each angle twice in a row: the pairs are (2m, 2m + 1)
                     half = attn.rope_dim // 2
                     cos, sin = (jnp.repeat(t[:, :half], 2, axis=-1) for t in (cos, sin))
-                tables = {kind: (cos, sin) for kind in kinds}
+                tables = {kind: (cos, sin) for kind in turning}
             else:
                 rope = self.rope_parameters or {
-                    kind: {"rope_type": "default", "rope_theta": 10000.0} for kind in LAYER_KINDS}
-                tables = {kind: rotary_tables(rope[kind], self.head_dim, T)
-                          for kind in dict.fromkeys(kinds)}
+                    kind: {"rope_type": "default", "rope_theta": self.rope_theta}
+                    for kind in LAYER_KINDS}
+                tables = {kind: rotary_tables(rope[kind], self.head_dim, T) for kind in turning}
         # unit-RMS embedding: under an RMS norm a 0.02 embedding is drowned by
         # the attention branch's mean over the context, which every position
         # shares, and a fresh router collapses onto a few experts
@@ -769,8 +852,8 @@ class DecoderLM(nn.Module):
         x = embed(tokens)
         for i, (kind, ffn) in enumerate(zip(kinds, self.feed_forwards())):
             x = DecoderLayer(
-                kind, attn, ffn, self.sliding_window, self.rms_norm_eps, name=f"layers_{i}",
-            )(x, *tables[kind])
+                kind, attn, ffn, self.sliding_window, self.rms_norm_eps, taps, name=f"layers_{i}",
+            )(x, *tables.get(kind, (None, None)))
         x = RMSNorm(self.rms_norm_eps, name="norm")(x)
         if self.tie_word_embeddings:
             return embed.attend(x)

@@ -99,8 +99,9 @@ def create(
 
     if name == "decoder":
         # Spec-driven decoder (grouped-query or latent attention, rotary/YaRN,
-        # window and full layers, leading dense layers, routed experts with a
-        # shared one beside them); kw mirrors the source model's config.json
+        # window and full layers, layers of a gated short convolution, leading
+        # dense layers, routed experts with a shared one beside them); kw
+        # mirrors the source model's config.json
         # keys, lists and nested mappings included (flax freezes them as
         # given). Returns logits only and trains under task="nwp" like
         # "transformer"; its expert layers' counters ride with the round's
@@ -119,6 +120,8 @@ def create(
                      "top_k": routed[0].top_k}
             if routed[0].shared_width:
                 attrs["shared_width"] = routed[0].shared_width
+        if m.conv_taps():
+            attrs.update(conv_layers=m.kinds().count("conv"), conv_width=m.hidden_size)
         return ModelDef(
             m, input_shape, num_classes, input_dtype=jnp.int32, name="decoder",
             counters=counter_names(routed[0].biased) if routed else (),

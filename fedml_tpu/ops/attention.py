@@ -6,8 +6,11 @@ and computes the same thing. It takes the blockwise Pallas kernel
 or backward) when the shapes it is handed allow that, and the plain form
 otherwise; ``takes_kernel`` is that decision, a pure function of the shapes
 and of nothing else — no option, no model name, no backend (off the TPU the
-kernel runs interpreted, which the tests use). The training shapes of both
-language-model cells qualify (T = 1 024 and 2 048); their 64-token
+kernel runs interpreted, which the tests use). The training shapes of all
+four language-model cells qualify: T = 1 024, 2 048 and, since
+``lfm2-8b-a1b.silo2t4k``, 4 096 = ``MAX_LENGTH``, the longest sequence the
+kernel holds and now a trained length (32 query heads on 8 key/value heads
+of 64: the two heads of a lane tile share a K/V head). Their 64-token
 evaluation documents and the small sequences of the CPU tests do not.
 
 Both forms also compute latent attention's two-term score: with ``q_rope``

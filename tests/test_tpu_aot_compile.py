@@ -84,6 +84,11 @@ def test_robust_stats_kernel_compiles_for_v5e(one_chip, C, D):
         # cells' training steps (chip_smoke.py's kernel check runs them)
         (4, (4, 1024, 12, 12, 64), None, jnp.bfloat16),    # gpt2-124m.silo4, vmap
         (1, (2, 2048, 32, 4, 128), 1024, jnp.bfloat16),    # mellum2-12b-a2.5b.silo2
+        # lfm2-8b-a1b.silo2t4k: MAX_LENGTH, two heads of 64 a tile sharing a
+        # K/V head. Outside tier 1 (102 s here beside five busy workers, more
+        # than the rest of this file together): run it with ``-m slow`` before
+        # chip time goes on this shape; the cell compiles it on the chip.
+        pytest.param(1, (1, 4096, 32, 8, 64), None, jnp.bfloat16, marks=pytest.mark.slow),
         (1, (8, 4096, 1, 1, 64), None, jnp.bfloat16),      # bench.py's row: a head a batch row
         (1, (1, 2048, 8, 2, 128), 512, jnp.float32),
     ],
