@@ -357,36 +357,43 @@ def _held_rows(c, x, top_w, w_gate, w_up, w_down, order, inverse, group_sizes, *
         return weighted_rows(ys, top_w, readers, slot)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _sum_chunks(part, chunks, used, operands, index):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _sum_chunks(part, used, operands, index):
     """``part(c, *operands, *index)`` summed over the chunks ``c < used``
-    (``1 <= used <= chunks``, ``chunks`` static): chunk 0 inline, the others
-    in a loop of ``used - 1`` trips, none on a step that stays under the
-    bound. The backward pass is written out, as the same loop over each
-    chunk's own vjp (which recomputes the chunk): neither a ``cond`` nor a
-    ``scan`` is differentiated, so no chunk that does not run writes zeros
-    for residuals, and nothing of a chunk is kept between the passes but
-    the arguments."""
+    (``used >= 1``): chunk 0 inline, the others in a loop of ``used - 1``
+    trips, none on a step that stays under the bound.
+
+    What a chunk keeps for the backward pass follows from the one thing the
+    program knows of it. Chunk 0 runs on every step, so it is differentiated
+    as ordinary JAX code and keeps its residuals: of :func:`_held_rows` the
+    sorted rows ``xs`` and ``ys`` [bound, d], ``gate``, ``up`` and ``hidden``
+    [bound, f], ``bound * (2 d + 3 f)`` numbers of the compute dtype a layer
+    (151 MB at 8 192 rows of 2 304 and 896 in bfloat16), beside the readers
+    and ``live``; its backward pass is the six grouped products and the row
+    passes of the gradient alone. The overflow's chunks run a number of
+    times that is data, so their residuals have no shape to be kept in: the
+    backward pass is written out as the same loop over each chunk's own vjp,
+    which runs the chunk again. Neither a ``cond`` nor a ``scan`` is
+    differentiated, so no chunk that does not run writes zeros for
+    residuals, and both passes add the chunks up in the same order."""
     y = part(jnp.int32(0), *operands, *index)
-    if chunks > 1:
-        y = jax.lax.fori_loop(1, used, lambda c, y: y + part(c, *operands, *index), y)
-    return y
+    return jax.lax.fori_loop(1, used, lambda c, y: y + part(c, *operands, *index), y)
 
 
-def _sum_chunks_fwd(part, chunks, used, operands, index):
-    return _sum_chunks(part, chunks, used, operands, index), (used, operands, index)
+def _sum_chunks_fwd(part, used, operands, index):
+    y, pull_first = jax.vjp(lambda *ops: part(jnp.int32(0), *ops, *index), *operands)
+    y = jax.lax.fori_loop(1, used, lambda c, y: y + part(c, *operands, *index), y)
+    return y, (pull_first, used, operands, index)
 
 
-def _sum_chunks_bwd(part, chunks, res, g):
-    used, operands, index = res
+def _sum_chunks_bwd(part, res, g):
+    pull_first, used, operands, index = res
 
     def pull(c):
         return jax.vjp(lambda *ops: part(c, *ops, *index), *operands)[1](g)
 
-    grads = pull(jnp.int32(0))
-    if chunks > 1:
-        grads = jax.lax.fori_loop(
-            1, used, lambda c, acc: jax.tree_util.tree_map(jnp.add, acc, pull(c)), grads)
+    grads = jax.lax.fori_loop(
+        1, used, lambda c, acc: jax.tree_util.tree_map(jnp.add, acc, pull(c)), pull_first(g))
     return None, grads, None
 
 
@@ -424,8 +431,15 @@ def routed_experts(x, router, w_gate, w_up, w_down, bias=None, *, top_k: int,
     routes more than R pairs here runs the same R-row computation again on
     the next R sorted rows, as often as it takes (:func:`_sum_chunks`): no
     capacity, no dropped pair, the same function of the weights; only the
-    float32 sum of a token's slots may be taken in another order. Where
-    every expert is held R is N*top_k and there is no loop in the program.
+    float32 sum of a token's slots may be taken in another order. The first
+    R rows, which every step runs, keep what their backward pass reads:
+    ``R * (2 d + 3 f) * itemsize`` bytes a layer (``xs``, ``ys``; ``gate``,
+    ``up``, ``hidden``) beside the [N, E] float32 scores and the sort's
+    [N*top_k] int32 vectors, so a gradient holds nine grouped products a
+    layer outside the overflow's loops, whose passes alone are run again.
+    Where every expert is held R is N*top_k, those bytes are
+    ``N * top_k * (2 d + 3 f) * itemsize``, and there is neither a loop nor
+    a ``custom_vjp`` around the pass in the program.
     Two passes stay indexed by token, N x top_k row reads each: the sum of
     a token's slots (:func:`weighted_rows`) and the backward of the
     dispatch gather (:func:`take_rows`), both :func:`sum_readers`.
@@ -473,10 +487,13 @@ def routed_experts(x, router, w_gate, w_up, w_down, bias=None, *, top_k: int,
         pairs = jnp.sum(group_sizes)
         # chunks of `bound` sorted rows that hold a held pair; the first always runs
         used = jnp.clip(-(-pairs // bound), 1, chunks)
-    y = _sum_chunks(
-        functools.partial(_held_rows, bound=bound), chunks, used,
-        (x, top_w, w_gate, w_up, w_down),
-        (jnp.pad(order, (0, chunks * bound - rows)), inverse.reshape(N, top_k), group_sizes))
+    part = functools.partial(_held_rows, bound=bound)
+    operands = (x, top_w, w_gate, w_up, w_down)
+    index = (jnp.pad(order, (0, chunks * bound - rows)), inverse.reshape(N, top_k), group_sizes)
+    if chunks == 1:
+        y = part(jnp.int32(0), *operands, *index)
+    else:
+        y = _sum_chunks(part, used, operands, index)
     with jax.named_scope("combine"):
         y = y.astype(x.dtype)
         f32 = jnp.float32
@@ -641,18 +658,14 @@ class DecoderLayer(nn.Module):
             self.param("experts_down", init, (hi - lo, e.width, d)),
             self.param("router_bias", nn.initializers.zeros, (e.experts,)) if e.biased else None,
         ]
-        # Recomputed in the backward pass, not kept: the tokens x top-k rows
-        # of the sorted copies, of both hidden products and of the output are
-        # a gigabyte a layer at 4 096 tokens of width 2 304 and top-8, as
-        # much again as the attention probabilities would take if kept.
-        y, counters = jax.checkpoint(functools.partial(
-            routed_experts, top_k=e.top_k, norm_topk_prob=e.norm_topk_prob, held_from=lo,
-            scoring=e.scoring, scale=e.scale, renorm_eps=e.renorm_eps))(n, *weights)
+        # No jax.checkpoint: what the backward pass reads is bounded by shapes
+        # (routed_experts' docstring; 151 MB a layer at 4 096 tokens of 2 304,
+        # top-8, 8 of 64 experts of width 896 held), and recomputing it runs
+        # three grouped products, the sort and the dispatch gather again.
+        y, counters = routed_experts(
+            n, *weights, top_k=e.top_k, norm_topk_prob=e.norm_topk_prob, held_from=lo,
+            scoring=e.scoring, scale=e.scale, renorm_eps=e.renorm_eps)
         if e.shared_width:
-            # Kept, not recomputed: its two hidden products are tokens x
-            # shared_width in the compute dtype (19 MB a layer at 2 048
-            # tokens of 1 536), a fiftieth of what the routed rows above
-            # would keep, and recomputing would run its three products again.
             with jax.named_scope("shared"):
                 y = y + gated_mlp(
                     n,

@@ -13,8 +13,8 @@ both products stay with the caller.
 The core is bound by bandwidth. Written as ``L`` shifted multiply-adds, XLA
 fuses the split, both gates and the taps into one elementwise pass that
 reads ``bcx`` and writes ``y``: 4 x width numbers a token. The function is a
-``jax.checkpoint``, as the expert layer is: nothing but ``bcx`` and the
-filter is kept between the passes, and the backward pass recomputes ``u``
+``jax.checkpoint``: nothing but ``bcx`` and the filter is kept between the
+passes, and the backward pass recomputes ``u``
 and ``c`` (two multiplies and ``L`` multiply-adds a number, against a
 [.., T, d] float32 residual each that autodiff would keep), reads ``dy`` and
 ``bcx`` and writes the three gradients, 7 x width numbers a token, plus the

@@ -372,8 +372,8 @@ def test_every_part_of_a_conv_layer_is_a_scope_directly_under_it():
         variables, jax.ShapeDtypeStruct((2, LENGTH), jnp.int32)).as_text(debug_info=True)
     under = {i: set(re.findall(rf"layers_{i}/([\w.]+)", text)) for i in (0, 1, 2)}
     assert {"in_proj", "short_conv", "out", "mlp"} <= under[0]
-    assert {"qkv", "qk_norm", "rope", "attention_full", "out", "checkpoint"} <= under[1]
-    assert {"in_proj", "short_conv", "out", "checkpoint"} <= under[2]
+    assert {"qkv", "qk_norm", "rope", "attention_full", "out", "router", "dispatch"} <= under[1]
+    assert {"in_proj", "short_conv", "out", "router", "dispatch"} <= under[2]
     assert not {"qkv", "rope", "attention_full", "qk_norm"} & (under[0] | under[2])
     assert not {"in_proj", "short_conv"} & under[1]
     assert not any("." in name for names in under.values() for name in names)

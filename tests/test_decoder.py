@@ -2,10 +2,11 @@
 (benchmarks/configs/mellum2-12b-a2.5b_ref.py), at small sizes on the CPU in
 float32 with seeded random weights: loss and every leaf's gradient, the
 grouped form of the expert layer against the dense masked form (all rows,
-under the row bound, and over it), the shares of an expert-parallel
-deployment against the uncut layer, one federated round under both client
-schedules with the expert counters on the ``flush`` span, and the reader of
-``moe.bounded_call_pct`` on hand-made runs."""
+under the row bound, and over it once and twice, under both client
+schedules), the grouped products a layer's gradient holds, the shares of an
+expert-parallel deployment against the uncut layer, one federated round under
+both client schedules with the expert counters on the ``flush`` span, and the
+reader of ``moe.bounded_call_pct`` on hand-made runs."""
 
 import copy
 import dataclasses
@@ -222,6 +223,84 @@ def test_only_a_held_share_puts_the_overflow_loop_in_the_program(held, loops):
     text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 2)))(
         x, router, gate[lo:hi], up[lo:hi], down[lo:hi]))
     assert text.count("while[") == loops and "cond[" not in text
+
+
+def grouped_products(jaxpr, in_loop=False, found=None):
+    """``ragged_dot*`` equations of a jaxpr and of every jaxpr inside it, those
+    beneath a ``while`` or ``scan`` apart, and the loops themselves."""
+    found = {"outside": 0, "in_loops": 0, "loops": 0} if found is None else found
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        loop = name in ("while", "scan")
+        found["loops"] += loop
+        if name.startswith("ragged_dot"):
+            found["in_loops" if in_loop else "outside"] += 1
+        for inner in jax.tree_util.tree_leaves(
+                list(eqn.params.values()), is_leaf=lambda v: hasattr(v, "eqns") or hasattr(v, "jaxpr")):
+            inner = getattr(inner, "jaxpr", inner)
+            if hasattr(inner, "eqns"):
+                grouped_products(inner, in_loop or loop, found)
+    return found
+
+
+@pytest.mark.parametrize("held,in_loops,loops", [((0, 16), 0, 0), ((4, 8), 12, 2)])
+def test_a_layers_gradient_holds_nine_grouped_products_outside_the_overflow_loops(
+        held, in_loops, loops):
+    """Three forward and six backward: the pass every step runs keeps its
+    rows, so its backward runs no product a second time (twelve before PR
+    35). The overflow's loops hold what they held: three forward, and three
+    run again with six backward."""
+    lo, hi = held
+    x, router, gate, up, down = expert_weights(jax.random.PRNGKey(3), experts=16, tokens=512)
+
+    def loss(x, router, gate, up, down):
+        return jnp.sum(routed_experts(x, router, gate, up, down, top_k=4, held_from=lo)[0])
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))(
+        x, router, gate[lo:hi], up[lo:hi], down[lo:hi])
+    assert grouped_products(jaxpr.jaxpr) == {"outside": 9, "in_loops": in_loops, "loops": loops}
+
+
+@pytest.mark.parametrize("schedule", ["scan", "vmap"])
+def test_members_that_take_one_two_and_three_passes_match_the_dense_form(schedule):
+    """Three clients on shared expert weights, under both client schedules:
+    the first stays under the bound (its gradient is the kept rows' alone),
+    the second runs the overflow's loop once, the third twice. Value and all
+    five gradients of each against the dense masked form, as
+    ``test_grouped_form_matches_dense_masked_form`` holds them."""
+    tokens, experts, top_k, lo, hi = 700, 32, 4, 5, 7
+    x, router, gate, up, down = expert_weights(
+        jax.random.PRNGKey(3), experts=experts, tokens=tokens)
+    x_all, router_all = towards_held(x, router, lo, hi)
+    # feature 0 draws a token to the held experts: on no token, on half, on all
+    xs = jnp.stack([x.at[:, 0].set(0.0), x_all.at[tokens // 2:, 0].set(0.0), x_all])
+    routers = jnp.stack([router_all] * 3)
+
+    def grouped(x, router, gate, up, down):
+        y, counters = routed_experts(x, router, gate[lo:hi], up[lo:hi], down[lo:hi],
+                                     top_k=top_k, held_from=lo)
+        return jnp.sum(jnp.sin(y)), counters
+
+    def dense(x, router, gate, up, down):
+        return jnp.sum(jnp.sin(dense_masked(x, router, gate, up, down, top_k, lo, hi)))
+
+    member = jax.value_and_grad(grouped, argnums=(0, 1, 2, 3, 4), has_aux=True)
+    if schedule == "vmap":
+        (values, counters), grads = jax.vmap(member, in_axes=(0, 0, None, None, None))(
+            xs, routers, gate, up, down)
+    else:
+        _, ((values, counters), grads) = jax.lax.scan(
+            lambda _, m: (None, member(m[0], m[1], gate, up, down)), None, (xs, routers))
+    bound = row_bound(tokens * top_k, hi - lo, experts)
+    passes = np.asarray(counters)[:, COUNTERS.index("moe_rows")] / bound
+    assert list(passes) == [1, 2, 3]
+    assert not np.asarray(counters)[:, COUNTERS.index("moe_dropped")].any()
+    for m in range(3):
+        vd, gd = jax.value_and_grad(dense, argnums=(0, 1, 2, 3, 4))(
+            xs[m], routers[m], gate, up, down)
+        assert abs(float(values[m] - vd)) <= 1e-5 * abs(float(vd))
+        for a, b in zip(grads, gd):
+            assert float(jnp.max(jnp.abs(a[m] - b))) <= 1e-5 * float(jnp.max(jnp.abs(b))) + 1e-7
 
 
 def test_members_of_a_vmap_overflow_each_on_their_own():

@@ -145,22 +145,22 @@ def test_latent_attention_fwd_bwd_compiles_for_v5e(one_chip):
 
 
 def test_expert_layer_at_mellum_share_compiles_for_v5e_over_bounded_rows(one_chip):
-    """models/decoder.routed_experts as DecoderLayer calls it (recomputed in
-    the backward pass), forward + gradient at mellum2-12b-a2.5b.silo2's
-    training step: 4 096 tokens of width 2 304, top-8 of 64 experts with 8
-    held. The arrays between the sort and the sum have the bound's 8 192
-    rows, not the 32 768 (token, slot) rows, and the overflow's loop is in
-    the program once forward and once backward."""
-    import functools
+    """models/decoder.routed_experts as DecoderLayer calls it, forward +
+    gradient at mellum2-12b-a2.5b.silo2's training step: 4 096 tokens of
+    width 2 304, top-8 of 64 experts with 8 held. The arrays between the
+    sort and the sum have the bound's 8 192 rows, not the 32 768 (token,
+    slot) rows; the overflow's loop is in the program once forward and once
+    backward; and the pass every step runs keeps its rows, so nine grouped
+    kernels lie outside the loops (twelve with the forward run again)."""
+    import re
 
     from fedml_tpu.models.decoder import routed_experts, row_bound
 
     N, d, f, held, experts, top_k = 4096, 2304, 896, 8, 64, 8
     assert row_bound(N * top_k, held, experts) == 8192
-    layer = jax.checkpoint(functools.partial(routed_experts, top_k=top_k))
 
     def loss(*args):
-        y, counters = layer(*args)
+        y, counters = routed_experts(*args, top_k=top_k)
         return jnp.sum(y.astype(jnp.float32) ** 2), counters
 
     shapes = [(N, d), (d, experts), (held, d, f), (held, d, f), (held, f, d)]
@@ -169,7 +169,10 @@ def test_expert_layer_at_mellum_share_compiles_for_v5e_over_bounded_rows(one_chi
     text = compiled.as_text()
     assert "[8192,2304]" in text and "[32768,2304]" not in text
     assert text.count(" while(") == 2
-    # the parent's full-row program planned 0.547 GiB for this layer
+    entry = text[text.index("\nENTRY "):]
+    assert len(re.findall(r"^\s*%?ragged-dot-none[\w.]* = ", entry, re.M)) == 9
+    # the full-row program planned 0.547 GiB for this layer, the bounded rows
+    # 0.33, kept or recomputed (one layer alone holds its rows either way)
     assert compiled.memory_analysis().temp_size_in_bytes < 0.45 * 2**30
 
 
