@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from fedml_tpu.analysis.sentinel import ensure_backend_listener
 from fedml_tpu.config import RunConfig
 from fedml_tpu.data.base import FederatedDataset, stack_clients
 from fedml_tpu.models import ModelDef
@@ -387,6 +388,23 @@ class FedAvgAPI:
         local_train_fn: Optional[Callable] = None,
         log_fn: Optional[Callable[[dict], None]] = None,
     ):
+        self._tracer = get_tracer()
+        # every span is mirrored into a running jax profile as a
+        # ``fedml.<name>`` annotation, on the profiler's clock
+        self._tracer.annotate = span_annotation
+        # and every program that jax traces, lowers and compiles or loads
+        # from here on is a ``jit_*`` span under the span that paid for it
+        ensure_backend_listener()
+        # set-up, outside any round: parent of ``store_upload`` and of
+        # whatever ``model.init`` and the store make jax compile
+        with self._tracer.span("api_init") as sp:
+            self._init_body(config, data, model, task, local_train_fn, log_fn)
+            leaves = jax.tree_util.tree_leaves(self.global_vars)
+            sp.set_attr("params", sum(int(x.size) for x in leaves))
+            sp.set_attr("param_bytes", sum(int(x.nbytes) for x in leaves))
+            sp.set_attr("client_mode", self._client_mode)
+
+    def _init_body(self, config, data, model, task, local_train_fn, log_fn):
         self.config = config
         self.data = data
         self.model = model
@@ -415,10 +433,6 @@ class FedAvgAPI:
         # jitted program, so per-client "train time" here is the cohort's
         # shared round wall time — participation/last-seen stay exact, and
         # the transport runtimes refine timing per client.
-        self._tracer = get_tracer()
-        # every span is mirrored into a running jax profile as a
-        # ``fedml.<name>`` annotation, on the profiler's clock
-        self._tracer.annotate = span_annotation
         self.health = ClientHealthRegistry.from_config(config)
         # How many of the round program's attention call sites take the
         # blockwise kernel at the training length, and all of them: host

@@ -20,6 +20,12 @@ Two event streams feed it:
   :class:`fedml_tpu.compile.ProgramCache` listeners, recorded with their
   program labels so a budget violation names WHICH programs compiled.
 
+The same listener pair also RECORDS what it hears, as finished spans on
+the compiling thread's tracer (``jit_trace``, ``jit_lower``,
+``jit_backend``: see :func:`_record_span`), so that a trace shows which
+program a span's first call traced, lowered and compiled or loaded, and
+under which round. The counters above do not depend on it.
+
 ``--recompile_budget N`` on the CLI runs the whole federation under a
 sentinel and raises :class:`RecompileBudgetExceeded` at the end when
 more than N backend compiles happened — the per-run compile-storm tripwire
@@ -49,6 +55,30 @@ _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 # compilation); it still shows as climbing compile/program_builds in the
 # same summary row — docs/ANALYSIS.md "what counts as a compile".
 
+# What jax 0.9 times on the way from a Python function to an executable,
+# each as one duration event with the program's ``fun_name``, fired on the
+# thread that called the program: the jaxpr trace (inner jits fire inside
+# the outer trace's interval: sum the UNION of ``jit_trace`` spans, never
+# their durations), the lowering to MLIR, and ``compile_or_get_cached``.
+_SPAN_OF_EVENT = {
+    "/jax/core/compile/jaxpr_trace_duration": "jit_trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit_lower",
+    "/jax/core/compile/" + _BACKEND_EVENT_SUFFIX: "jit_backend",
+}
+# The persistent cache's verdict on the program being acquired arrives on
+# the same thread just BEFORE its backend_compile_duration: ``cache_hits``
+# with the two durations below, or ``cache_misses`` when the compiled
+# program is written (one whose compile is under the persistence threshold
+# is never written and fires neither: it reads ``off``, like a process
+# with no cache at all, and like there it will compile again next time).
+_CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+_CACHE_ATTR_OF_EVENT = {
+    "/jax/compilation_cache/cache_retrieval_time_sec": "retrieval_s",
+    "/jax/compilation_cache/compile_time_saved_sec": "saved_s",
+}
+# per thread: what the cache said since the last ``jit_backend``
+_cache_said = threading.local()
+
 _lock = threading.Lock()
 _backend_compiles = 0
 _cache_hits = 0
@@ -59,8 +89,34 @@ class RecompileBudgetExceeded(RuntimeError):
     """More XLA compiles happened than the declared budget allows."""
 
 
+def _record_span(span: str, secs: float, program) -> None:
+    """One jax compile-path event as a finished span on the calling
+    thread's tracer: it ends at the callback and started ``secs`` earlier,
+    under whatever span is open there (``local_train`` of round 0 on the
+    lazy path, ``api_init``, a ``compile`` span of ``--warmup``).
+    ``jit_backend`` carries the persistent cache's verdict: ``hit`` (with
+    the retrieval's seconds and the compile seconds it saved), ``miss``
+    (compiled and written) or ``off`` (compiled, nothing read or written)."""
+    from fedml_tpu.telemetry import get_tracer
+
+    attrs = {"program": program}
+    if span == "jit_backend":
+        attrs["cache"] = "off"
+        attrs.update(_cache_said.__dict__)
+        _cache_said.__dict__.clear()
+    get_tracer().record_child_event(span, secs, **attrs)
+
+
 def _on_jax_event(name: str, secs: float, **kw) -> None:
     global _backend_compiles
+    span = _SPAN_OF_EVENT.get(name)
+    try:
+        if span is not None:
+            _record_span(span, secs, kw.get("fun_name"))
+        elif name in _CACHE_ATTR_OF_EVENT:
+            setattr(_cache_said, _CACHE_ATTR_OF_EVENT[name], float(secs))
+    except Exception:  # noqa: BLE001 — telemetry must not break compiles
+        pass
     if not name.endswith(_BACKEND_EVENT_SUFFIX):
         return
     # per-tenant attribution (fedml_tpu/serve/): jax.monitoring fires on
@@ -90,8 +146,12 @@ def _on_jax_event(name: str, secs: float, **kw) -> None:
 
 def _on_jax_plain_event(name: str, **kw) -> None:
     global _cache_hits
+    if name == _CACHE_MISS_EVENT:
+        _cache_said.cache = "miss"
+        return
     if name != _CACHE_HIT_EVENT:
         return
+    _cache_said.cache = "hit"
     from fedml_tpu.telemetry.scope import current_scope
 
     sc = current_scope()

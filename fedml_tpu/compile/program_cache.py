@@ -130,7 +130,9 @@ class CachedProgram:
         dispatch map warmup fills, so a warm-from-disk run takes exactly
         the dispatch path a warm-in-process run takes (byte-identical
         numerics — the executable IS the one a compile would build,
-        pinned by tests/test_compile.py)."""
+        pinned by tests/test_compile.py). The load that replaced a compile
+        is a ``compile`` span (``deserialized=True``) of its true length,
+        on the warmup and the lazy-probe path alike; a miss leaves none."""
         cache = self._exec_cache()
         if cache is None:
             return None
@@ -139,6 +141,9 @@ class CachedProgram:
         if exe is None:
             return None
         dt = time.perf_counter() - t0
+        (tracer or get_tracer()).record_child_event(
+            "compile", dt, program=self.label, aot=True, deserialized=True
+        )
         flops = bytes_accessed = None
         try:
             ca = exe.cost_analysis()
@@ -160,12 +165,6 @@ class CachedProgram:
         self._aot_stats[sig] = st
         if self._cache is not None:
             self._cache._note_deserialize(dt, label=self.label, digest=self.digest)
-        if tracer is not None:
-            # zero-duration marker span: the deserialize replaced a compile
-            with tracer.span(
-                "compile", program=self.label, aot=True, deserialized=True
-            ):
-                pass
         return st
 
     def __call__(self, *args, **kwargs):

@@ -235,6 +235,22 @@ class Tracer:
         self._record(ev)
         return ev
 
+    def record_child_event(self, name: str, dur_s: float, **attrs) -> SpanEvent:
+        """Record an interval that ENDS NOW on the calling thread and was
+        timed by someone else (jax's compile events reach their listener
+        only once over: analysis/sentinel.py), attributed as ``_push``
+        attributes a context-manager span: ``parent``, ``depth`` and ``round``
+        of the innermost open span. Outside any span it carries none of the
+        three, so that it is never read as a depth-0 span of the loop."""
+        st = getattr(self._local, "stack", None)
+        if st:
+            attrs["parent"] = st[-1].name
+            attrs["depth"] = len(st)
+            if "round" in st[-1].attrs:
+                attrs["round"] = st[-1].attrs["round"]
+        dur_us = float(dur_s) * 1e6
+        return self.record_event(name, self._now_us() - dur_us, dur_us, **attrs)
+
     def _record(self, ev: SpanEvent) -> None:
         with self._lock:
             if len(self._events) < self.max_events:

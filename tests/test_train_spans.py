@@ -164,7 +164,7 @@ def test_depth0_spans_are_the_loop_and_do_not_overlap(piped):
     top = sorted((e for e in piped.events if e.attrs.get("depth") == 0),
                  key=lambda e: e.ts_us)
     # set-up's one span comes first and is no part of the loop
-    assert top[0].name == "store_upload"
+    assert top[0].name == "api_init"
     top = top[1:]
     assert {e.name for e in top} == {"round", "pack", "prepare", "health", "flush"}
     for a, b in zip(top, top[1:]):
@@ -235,8 +235,11 @@ def test_pipelined_and_serial_runs_log_the_same_rows(piped, serial):
 
 
 def test_annotation_entered_and_left_once_per_span(piped):
-    # set-up's store_upload ran before this test put its hook in
-    assert len(piped.annotations) == len(piped.events) - 1
+    # set-up's api_init and store_upload ran before this test put its hook
+    # in; what jax's compile events leave (jit_*) is recorded when it is
+    # over, and never entered
+    entered = [e for e in piped.events if not e.name.startswith("jit_")]
+    assert len(piped.annotations) == len(entered) - 2
     assert all(entered == 1 and left == 1 for _, _, entered, left in piped.annotations)
     # the identifier is the round the span works for, where it has one
     seen = {(name, r) for name, r, *_ in piped.annotations}
