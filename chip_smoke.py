@@ -666,14 +666,63 @@ def _pool_check(ctx):
     return {"shape": list(shape)}, asserted
 
 
+def _rotary_check(ctx):
+    """ops/rotary.rotary at the two cells' q and k (heads of 128 under YaRN's
+    scale, heads of 64), bfloat16: value and gradient equal the plain form's
+    to the last bit (float32 products and sum, one rounding: the vector unit
+    contracts nothing), through the kernel."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from fedml_tpu.models.decoder import rotary_tables
+    from fedml_tpu.ops import rotary as op
+
+    yarn = {"rope_type": "yarn", "rope_theta": 500000, "factor": 16,
+            "original_max_position_embeddings": 8192}
+    cases = {"mellum_q": ((2, 2048, 32, 128), yarn), "mellum_k": ((2, 2048, 4, 128), yarn),
+             "lfm2_q": ((1, 4096, 32, 64), {"rope_theta": 1000000}),
+             "lfm2_k": ((1, 4096, 8, 64), {"rope_theta": 1000000})}
+    if ctx.rehearse:
+        cases = {"heads_of_128": ((1, 256, 2, 128), yarn), "heads_of_64": ((1, 256, 2, 64), yarn)}
+    asserted = []
+    for name, (shape, rope) in cases.items():
+        cos, sin = rotary_tables(rope, shape[3], shape[1])
+        x, dy = (jax.random.normal(k, shape, jnp.bfloat16)
+                 for k in jax.random.split(jax.random.PRNGKey(ctx.seed + 3)))
+        if ctx.rehearse:
+            # the CPU contracts the plain form's sum into a multiply-add: keep
+            # the products exact (tests/test_rotary.py has the argument)
+            cos, sin = (t.astype(jnp.bfloat16).astype(jnp.float32) for t in (cos, sin))
+
+        def both(form):
+            fn = jax.jit(lambda x, dy: (form(x, cos, sin),
+                                        jax.vjp(lambda x: form(x, cos, sin), x)[1](dy)[0]))
+            return fn.lower(x, dy).compile().as_text(), fn(x, dy)
+
+        text, ours = both(op.rotary)
+        _, plain = both(op.plain)
+        asserted.append(check(op.takes_kernel(*shape[1:]), f"rotary {name} {list(shape)} takes the kernel"))
+        if ctx.platform == "tpu":
+            asserted.append(check("rotary_fwd" in text and "rotary_bwd" in text,
+                                  f"rotary {name}: both kernels are in the compiled program"))
+        for part, a, b in zip(("value", "gradient"), ours, plain):
+            asserted.append(check(
+                np.array_equal(np.asarray(a).view(np.uint16), np.asarray(b).view(np.uint16)),
+                f"rotary {name} {part} at bf16{list(shape)} bit-equal to the plain form"))
+    return {"cases": {k: list(v[0]) for k, v in cases.items()}}, asserted
+
+
 def phase_kernels(ctx):
-    """Both Pallas kernels, compiled, against their references — alone and
+    """The Pallas kernels, compiled, against their references — alone and
     (robust stats) through the normal CLI path — and the written-out pool
     gradient against flax's."""
     flash, asserted = _flash_check(ctx)
     robust, more = _robust_stats_check(ctx)
     asserted += more
     pool, more = _pool_check(ctx)
+    asserted += more
+    rotary, more = _rotary_check(ctx)
     asserted += more
 
     api, rows, _ = run_cli(
@@ -693,7 +742,8 @@ def phase_kernels(ctx):
             "tpu_custom_call" in text,
             "robust round (aggregation) program contains tpu_custom_call",
         ))
-    return {"asserted": asserted, "flash": flash, "robust_stats": robust, "max_pool": pool}
+    return {"asserted": asserted, "flash": flash, "robust_stats": robust, "max_pool": pool,
+            "rotary": rotary}
 
 
 def phase_multichip(ctx):

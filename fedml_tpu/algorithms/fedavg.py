@@ -437,7 +437,8 @@ class FedAvgAPI:
         # How many of the round program's attention call sites take the
         # blockwise kernel at the training length, and all of them: host
         # numbers from the shapes alone, carried by every ``flush`` span. The
-        # sites of latent attention add what their core's FLOPs follow from.
+        # sites of latent attention add what their core's FLOPs follow from;
+        # the calls of the rotate-half operator are counted the same way.
         self._attention_attrs = {}
         if model.attention_sites:
             # imported here: a model without attention pays no Pallas import
@@ -455,6 +456,14 @@ class FedAvgAPI:
                 self._attention_attrs.update(
                     attn_qk_width=nope + rope, attn_v_width=values, attn_heads=heads,
                     attn_length=model.input_shape[0], attn_layers=len(latent))
+            if model.rope_sites:
+                from fedml_tpu.ops import rotary
+
+                self._attention_attrs.update(
+                    rope_kernel_sites=sum(
+                        rotary.takes_kernel(model.input_shape[0], *site)
+                        for site in model.rope_sites),
+                    rope_sites=len(model.rope_sites))
         # Scheduler: policy-driven cohort selection (FedConfig.selection /
         # .overprovision_factor, scheduler/policies.py). It shares this
         # API's health registry (straggler_aware consults the straggler

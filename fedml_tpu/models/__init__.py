@@ -44,6 +44,9 @@ class ModelDef:
     # decides each call from. A layer whose token mixer is no attention has
     # no site.
     attention_sites: Tuple[Tuple[int, ...], ...] = ()
+    # One (heads, dims a head) per call of ``ops/rotary.rotary`` in a forward
+    # pass: after the sequence length, what its ``takes_kernel`` decides from.
+    rope_sites: Tuple[Tuple[int, int], ...] = ()
 
     def init(self, rng) -> dict:
         dummy = jnp.zeros((1,) + tuple(self.input_shape), dtype=self.input_dtype)

@@ -110,6 +110,7 @@ import jax.numpy as jnp
 from jax.custom_batching import custom_vmap
 
 from fedml_tpu.ops.attention import attention
+from fedml_tpu.ops.rotary import rotary
 from fedml_tpu.ops.short_conv import gated_short_conv
 
 LAYER_KINDS = ("full_attention", "sliding_attention", "conv")
@@ -160,14 +161,6 @@ def rotary_tables(rope: Mapping[str, Any], head_dim: int, length: int):
     angles = jnp.arange(length, dtype=jnp.float32)[:, None] * inv_freq[None, :]
     angles = jnp.concatenate([angles, angles], axis=-1)
     return jnp.cos(angles) * scale, jnp.sin(angles) * scale
-
-
-def apply_rotary(x, cos, sin):
-    """Rotate-half rotary on x [B, T, H, D], in float32, back in x's dtype."""
-    x32 = x.astype(jnp.float32)
-    half = x.shape[-1] // 2
-    rotated = jnp.concatenate([-x32[..., half:], x32[..., :half]], axis=-1)
-    return (x32 * cos[None, :, None, :] + rotated * sin[None, :, None, :]).astype(x.dtype)
 
 
 def apply_rotary_pairs(x, cos, sin):
@@ -546,6 +539,16 @@ class AttentionSpec:
         latent = (self.rope_dim, self.v_dim) if self.rope_dim else ()
         return (self.heads, self.kv_heads, self.head_dim) + latent
 
+    def rope_sites(self) -> Tuple[Tuple[int, int], ...]:
+        """The (heads, dims a head) of each call of ``ops/rotary.rotary`` in
+        a layer, as its ``takes_kernel`` takes them after the length
+        (``ModelDef.rope_sites``); the pairs form is no such call."""
+        if self.interleave:
+            return ()
+        if self.rope_dim:
+            return ((self.heads, self.rope_dim), (1, self.rope_dim))
+        return ((self.heads, self.head_dim), (self.kv_heads, self.head_dim))
+
 
 @dataclasses.dataclass(frozen=True)
 class ExpertSpec:
@@ -604,7 +607,7 @@ class DecoderLayer(nn.Module):
                 q = RMSNorm(self.rms_norm_eps, name="q_layernorm")(q)
                 k = RMSNorm(self.rms_norm_eps, name="k_layernorm")(k)
         with jax.named_scope("rope"):
-            q, k = apply_rotary(q, cos, sin), apply_rotary(k, cos, sin)
+            q, k = rotary(q, cos, sin), rotary(k, cos, sin)
         sliding = self.kind == "sliding_attention"
         with jax.named_scope("attention_sliding" if sliding else "attention_full"):
             return attention(
@@ -623,7 +626,7 @@ class DecoderLayer(nn.Module):
             kv = jnp.dot(latent, self.param("kv_b_proj", init, (rank, H * (D + V))))
             kv = kv.reshape(B, T, H, D + V)
         with jax.named_scope("rope"):
-            turn = apply_rotary_pairs if self.attn.interleave else apply_rotary
+            turn = apply_rotary_pairs if self.attn.interleave else rotary
             q_rope = turn(q[..., D:], cos, sin)
             k_rope = turn(down[..., None, rank:], cos, sin)
         with jax.named_scope("attention_mla"):
@@ -836,6 +839,11 @@ class DecoderLM(nn.Module):
         """``ModelDef.attention_sites``: one site a layer that has attention
         (a ``conv`` layer calls no attention)."""
         return (self.attention_spec().site(),) * sum(kind != "conv" for kind in self.kinds())
+
+    def rope_sites(self) -> Tuple[Tuple[int, int], ...]:
+        """``ModelDef.rope_sites``: the attention layers' calls of the
+        rotate-half operator."""
+        return self.attention_spec().rope_sites() * sum(kind != "conv" for kind in self.kinds())
 
     @nn.compact
     def __call__(self, tokens, train: bool = False):

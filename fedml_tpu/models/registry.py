@@ -127,6 +127,7 @@ def create(
             counters=counter_names(routed[0].biased) if routed else (),
             counter_attrs=attrs,
             attention_sites=m.attention_sites(),
+            rope_sites=m.rope_sites(),
         )
 
     if name in ("resnet56", "resnet110"):

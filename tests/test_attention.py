@@ -90,11 +90,14 @@ def test_kernel_under_the_client_schedules(schedule, heads, head_dim, mask):
     _assert_close(over_clients(_kernel), over_clients(full_attention))
 
 
-def _pallas_calls(fn, *args):
+def _pallas_calls(fn, *args, kernel="attention_"):
+    """The calls of the kernels named ``kernel...`` in the traced program
+    (``ops/rotary.py``'s carry another name)."""
+
     def count(jaxpr):
         total = 0
         for eqn in jaxpr.eqns:
-            total += eqn.primitive.name == "pallas_call"
+            total += eqn.primitive.name == "pallas_call" and eqn.params["name"].startswith(kernel)
             for sub in jax.core.jaxprs_in_params(eqn.params):
                 total += count(sub)
         return total
@@ -185,15 +188,20 @@ def _api(name, length):
     return FedAvgAPI(cfg, data, _model(name, length), task="nwp", log_fn=lambda row: None)
 
 
-@pytest.mark.parametrize("name,length,kernel_sites", [
-    ("transformer", 256, 2), ("decoder", 256, 2), ("transformer", 64, 0), ("decoder", 48, 0),
+@pytest.mark.parametrize("name,length,kernel_sites,rope", [
+    ("transformer", 256, 2, {}), ("transformer", 64, 0, {}),
+    # q and k of both layers go through the rotate-half operator
+    ("decoder", 256, 2, {"rope_kernel_sites": 4, "rope_sites": 4}),
+    ("decoder", 48, 0, {"rope_kernel_sites": 0, "rope_sites": 4}),
 ])
-def test_what_the_api_reports_is_what_the_traced_program_contains(name, length, kernel_sites):
+def test_what_the_api_reports_is_what_the_traced_program_contains(name, length, kernel_sites, rope):
     api = _api(name, length)
-    assert api._attention_attrs == {"attn_kernel_sites": kernel_sites, "attn_sites": 2}
+    assert api._attention_attrs == {"attn_kernel_sites": kernel_sites, "attn_sites": 2, **rope}
     tokens = jax.ShapeDtypeStruct((2, length), jnp.int32)
     forward = lambda variables, x: api.model.apply(variables, x, train=True)[0]
     assert _pallas_calls(forward, api.global_vars, tokens) == kernel_sites
+    assert _pallas_calls(forward, api.global_vars, tokens, kernel="rotary_") == rope.get(
+        "rope_kernel_sites", 0)
 
 
 def test_flush_span_carries_the_two_attributes_and_a_model_without_attention_none():

@@ -373,11 +373,17 @@ def test_the_sites_are_what_the_traced_layers_hand_the_attention_core(monkeypatc
 # them; one that means to change them computes them anew (run this file's
 # ``digest`` on the new tree) and says so. PR 35 meant to change Mellum's two:
 # the expert layer keeps its bounded rows and is no ``jax.checkpoint`` (at
-# its parent 30933ff they read 79cdf5cea5de352f and fbc1060337a8a788).
+# its parent 30933ff they read 79cdf5cea5de352f and fbc1060337a8a788). PR 37
+# meant to change Mellum's again: q and k of every layer go through
+# ``ops/rotary.rotary``. At the cell's size that is the kernel and its own
+# backward rule, and the pin is computed anew (at its parent a1e2524 it read
+# 8308e4dd86564cb3); at the rehearsal's 32 positions the operator IS the plain
+# three-line form, traced as before, so that pin was recomputed and came out
+# as it was (2ef7c050eeac5d0a). Both attention steps and both GPT-2 pins hold.
 PINS = {
     "attention.silo4": "940131b509805ea9",
     "attention.silo2": "50842107702d88df",
-    "mellum2-12b-a2.5b.full": "8308e4dd86564cb3",
+    "mellum2-12b-a2.5b.full": "ff35ffb062b3311d",
     "mellum2-12b-a2.5b.rehearse": "2ef7c050eeac5d0a",
     "gpt2-124m.full": "28bcf5bfcdd2a422",
     "gpt2-124m.rehearse": "8ce4b5786b6eb411",

@@ -144,6 +144,36 @@ def test_latent_attention_fwd_bwd_compiles_for_v5e(one_chip):
     assert compiled.as_text().count("tpu_custom_call") >= 2
 
 
+@pytest.mark.parametrize("clients,shape,dtype", [
+    (1, (2, 2048, 32, 128), jnp.bfloat16),  # mellum2-12b-a2.5b.silo2's q: a head a lane tile
+    (1, (2, 2048, 4, 128), jnp.bfloat16),   # and its k
+    (1, (1, 4096, 32, 64), jnp.bfloat16),   # lfm2-8b-a1b.silo2t4k's q: two heads a tile
+    (2, (1, 4096, 8, 64), jnp.bfloat16),    # its k, under a client vmap
+    (1, (1, 512, 4, 32), jnp.float32),      # four heads a tile, float32
+], ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else getattr(v, "__name__", str(v)))
+def test_rotary_fwd_bwd_compiles_for_v5e(one_chip, clients, shape, dtype):
+    """ops/rotary's kernel, forward and backward (the lane rotations, the
+    select where heads share a tile, blocks within the scoped VMEM), at the
+    two cells' q and k (chip_smoke.py's kernel check runs them)."""
+    from fedml_tpu.ops import rotary as op
+
+    B, T, H, D = shape
+    assert op.takes_kernel(T, H, D)
+
+    def loss(x, cos, sin):
+        out = jax.vmap(lambda x: op.rotary(x, cos, sin))(x)
+        return jnp.sum(out.astype(jnp.float32))
+
+    args = [jax.ShapeDtypeStruct((clients,) + shape, dtype, sharding=one_chip)] + [
+        jax.ShapeDtypeStruct((T, D), jnp.float32, sharding=one_chip)] * 2
+    saved, op._use_interpret = op._use_interpret, lambda: False
+    try:
+        text = jax.jit(jax.value_and_grad(loss)).lower(*args).compile().as_text()
+    finally:
+        op._use_interpret = saved
+    assert "rotary_fwd" in text and "rotary_bwd" in text and text.count("tpu_custom_call") >= 2
+
+
 def test_expert_layer_at_mellum_share_compiles_for_v5e_over_bounded_rows(one_chip):
     """models/decoder.routed_experts as DecoderLayer calls it, forward +
     gradient at mellum2-12b-a2.5b.silo2's training step: 4 096 tokens of
