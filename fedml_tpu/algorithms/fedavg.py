@@ -1014,7 +1014,9 @@ class FedAvgAPI:
             "flush", first_round=rounds[0], last_round=rounds[-1],
             rows=len(rounds),
         ) as flush:
-            # with the model's own constants (empty where it has none to give)
+            # with the model's own constants (empty where it has none to give:
+            # the expert layers' numbers and products a pair, the conv layers',
+            # the state-space layers' ``ssm_*``)
             for name, value in (*self._attention_attrs.items(), *self.model.counter_attrs.items()):
                 flush.set_attr(name, value)
             # the one device-to-host fetch: it returns when the device has

@@ -34,7 +34,10 @@ class ModelDef:
     # local-train loop carries them with its metrics. ``counter_attrs`` are
     # the model's host constants that every ``flush`` span carries for the
     # readers of its counters and of its layers' metrics (the expert layers'
-    # widths and counts, the conv layers' ``conv_layers`` and ``conv_width``).
+    # widths, counts and ``expert_products``, the grouped products a held pair
+    # runs forward; the conv layers' ``conv_layers`` and ``conv_width``; the
+    # state-space layers' ``ssm_layers``, ``ssm_heads``, ``ssm_head_dim``,
+    # ``ssm_state``, ``ssm_groups`` and ``ssm_chunk``).
     counters: Tuple[str, ...] = ()
     counter_attrs: dict = dataclasses.field(default_factory=dict)
     # One (query heads, key/value heads, head dim) per call of

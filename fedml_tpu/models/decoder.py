@@ -6,6 +6,11 @@ models, written from the keys of a Hugging Face ``config.json``.
     y  = h + FFN(RMSNorm(h))
     logits = RMSNorm(y_L) W_head                 (W_head = E^T when tied)
 
+or, where the spec has a ``hybrid_override_pattern`` (HF's ``nemotron_h``), a
+stack of ONE part a layer under ONE norm:
+
+    x  = x + Part_i(RMSNorm_i(x))                Part_i named by character i
+
 - RMS norms (float32 statistics, learned scale), no biases, no learned
   positions.
 - ``layer_types`` gives every layer's kind, which is its token mixer:
@@ -61,12 +66,48 @@ models, written from the keys of a Hugging Face ``config.json``.
   time (twice the even share, from shapes alone), and a step that routes
   more than that here takes them again.
 
+A one-part stack (``hybrid_override_pattern``, a string over ``M``, ``E``,
+``*`` and ``-``; the leaf of a layer's one norm is ``norm``):
+
+- ``M``, a Mamba-2 state-space mixer (HF's ``NemotronHMamba2Mixer``;
+  :meth:`DecoderLayer.mamba`): ``H = mamba_num_heads`` heads of ``P =
+  mamba_head_dim`` (inner width ``H P``; HF's ``expand`` sets no shape),
+  ``G = n_groups`` groups, state ``N = ssm_state_size``. ``[z | xBC | dt] = n
+  W_in`` (d -> H P + (H P + 2 G N) + H, no bias); ``xBC <- SiLU(conv(xBC) +
+  b)``, a causal depthwise convolution of ``conv_kernel`` taps a channel
+  (``ops/short_conv.silu_short_conv``; ``use_conv_bias``); ``[x | B | C] =
+  xBC``, head ``h`` reading the B, C of group ``h // (H / G)``; ``step =
+  softplus(dt + dt_bias)`` with no clamp, ``A = -exp(A_log)``; per head, with
+  a state ``S`` [P, N] from zero, ``S_t = exp(step_t A) S_{t-1} + step_t x_t
+  B_t^T``, ``y_t = S_t C_t + D x_t`` (``ops/ssd.ssd``: the chunked scan,
+  ``chunk_size`` positions a chunk, which changes no number); ``y <-
+  GroupRMSNorm(y * SiLU(z)) * w`` over groups of ``H P / G``, the gate
+  first (:func:`gated_group_norm`); ``Part = y W_out``. The step, the
+  decays, their cumulative sums and the states are float32. The leaves
+  ``A_log`` (``log`` of a uniform draw in [1, 16]), ``dt_bias`` (the inverse
+  softplus of a step drawn log-uniformly in [``time_step_min``,
+  ``time_step_max``], floored at ``time_step_floor``), ``D`` (ones), ``conv``
+  (deviation ``1 / sqrt(taps)``) and ``conv_bias`` (zeros) are parameters
+  like any other: FedAvg averages them as they are (``A_log`` as a
+  logarithm).
+- ``*``, grouped-query attention WITHOUT positions (``NemotronHAttention``):
+  no rotary, no tables built, ``rope_sites`` empty; ``rope_theta`` is read
+  by nothing. ``use_qk_norm`` still norms where a spec asks for it.
+- ``E``, the routed expert layer above with UNGATED experts: ``relu(x
+  W_up)**2 W_down``, two matrices an expert (``mlp_hidden_act`` ``relu2``),
+  and one shared expert of the same form at
+  ``moe_shared_expert_intermediate_size`` where ``n_shared_experts``.
+- ``-``, a dense ``relu(x W_up)**2 W_down`` of ``intermediate_size`` alone.
+
 The keys are the decoder's own where families spell one thing differently:
 a spec written from HF's ``lfm2_moe`` gives its ``num_dense_layers`` as
 ``first_k_dense_replace``, its ``norm_eps`` as ``rms_norm_eps``, and its
 router (``use_expert_bias`` true: sigmoid scores always, the ``expert_bias``
 buffer in the choice, 1e-6 in the renormalisation) as ``scoring_func``
-``sigmoid``, ``topk_method`` ``noaux_tc``, ``renorm_eps`` 1e-6.
+``sigmoid``, ``topk_method`` ``noaux_tc``, ``renorm_eps`` 1e-6; one written
+from ``nemotron_h`` gives its ``layer_norm_epsilon`` as ``rms_norm_eps`` and
+its router (sigmoid scores, ``e_score_correction_bias`` in the choice) as
+``scoring_func`` ``sigmoid``, ``topk_method`` ``noaux_tc``.
 
 ``experts_held = (lo, hi)`` is the expert-parallel share of one chip: the
 layer holds the weights of experts ``lo..hi-1`` only, still routes over all
@@ -85,13 +126,26 @@ Not expressed, and refused by name: a query latent (``q_lora_rank``),
 group-limited routing (``n_group`` / ``topk_group`` over 1), rotary scaling
 beside a latent (``rope_scaling``), QK norms beside a latent
 (``use_qk_norm``), a ``conv`` layer without its filter's length
-(``conv_L_cache``). No key here gives a convolution a bias (HF's
+(``conv_L_cache``); a character of ``hybrid_override_pattern`` other than
+``M``, ``E``, ``*``, ``-``; the pattern beside ``layer_types``,
+``first_k_dense_replace`` or a latent (``kv_lora_rank``), or at another
+length than ``num_hidden_layers``; ``n_groups`` that does not divide
+``mamba_num_heads``; an ``M`` layer without its sizes; a clamp of the step
+(``time_step_limit`` with a finite end or a positive start);
+``mamba_hidden_act`` other than ``silu``; ``mlp_hidden_act`` other than
+``relu2`` in a one-part stack, or other than ``silu`` in a stack of
+``layer_types``; a ``-`` layer without ``intermediate_size``. No key here
+gives a projection a bias (HF's ``attention_bias``, ``mlp_bias``,
+``mamba_proj_bias``: an unknown key is refused by its name), a gated
+short convolution a bias (HF's
 ``conv_bias`` true), a tied head a scale of its own (the table is drawn at
 unit RMS, so ``tie_word_embeddings`` starts the logits at deviation
 ``sqrt(hidden_size)`` where a source's 0.02 table starts them under 1),
-documents packed into one sequence (the convolution and attention would
-have to reset at their boundaries) or sequences over the attention kernel's
-``MAX_LENGTH``.
+documents packed into one sequence (the scan, the convolutions and attention
+would have to reset at their boundaries) or sequences over the attention
+kernel's ``MAX_LENGTH``. A second tower conditioned on this one and decoding
+by diffusion over blocks have no key in any ``config.json`` read here and
+no module: the decoder is one causal stack trained by next-token loss.
 
 In training the model sows its counters per expert layer into the
 ``counters`` collection (:func:`counter_names`; ``ModelDef.apply(...,
@@ -111,9 +165,12 @@ from jax.custom_batching import custom_vmap
 
 from fedml_tpu.ops.attention import attention
 from fedml_tpu.ops.rotary import rotary
-from fedml_tpu.ops.short_conv import gated_short_conv
+from fedml_tpu.ops.short_conv import gated_short_conv, silu_short_conv
+from fedml_tpu.ops.ssd import ssd
 
 LAYER_KINDS = ("full_attention", "sliding_attention", "conv")
+# The characters of ``hybrid_override_pattern`` and the one part each names.
+PARTS = {"M": "mamba", "E": "experts", "*": "attention", "-": "mlp"}
 
 # Per expert layer and call, as float32 (whole numbers below 2**24 a round):
 # (token, slot) pairs routed to a held expert; held pairs that the dispatch
@@ -316,15 +373,18 @@ def row_bound(rows: int, held: int, experts: int) -> int:
 
 
 @functools.partial(jax.jit, static_argnames="bound")
-def _held_rows(c, x, top_w, w_gate, w_up, w_down, order, inverse, group_sizes, *, bound):
+def _held_rows(c, x, top_w, *rest, bound):
     """What the sorted rows ``[c * bound, (c + 1) * bound)`` add to every
-    token's sum: [N, d] float32. ``order`` (padded to whole chunks) and
-    ``inverse`` [N, top_k] are the sort and its inverse, ``group_sizes``
-    the held experts' pair counts over all rows. Jitted so that every layer
+    token's sum: [N, d] float32. ``rest`` is the experts' weights (``w_gate,
+    w_up, w_down`` of gated-SiLU experts, three grouped products; ``w_up,
+    w_down`` of ungated ReLU-squared ones, two) and then ``order`` (padded
+    to whole chunks) and ``inverse`` [N, top_k], the sort and its inverse,
+    and ``group_sizes``, the held experts' pair counts over all rows. Jitted so that every layer
     and both places that call it (inline and in the overflow's loop, forward
     and backward) share one traced and lowered function: tracing and
     lowering four layers' gradient takes 0.9 s so, 1.9 s without (host
     seconds), and the compiled program is the same."""
+    *weights, order, inverse, group_sizes = rest
     top_k = top_w.shape[1]
     start = c * bound
     with jax.named_scope("dispatch"):
@@ -341,9 +401,15 @@ def _held_rows(c, x, top_w, w_gate, w_up, w_down, order, inverse, group_sizes, *
         xs = take_rows(x, slot // top_k, readers)
         xs = jnp.where(live, xs, jnp.zeros((), xs.dtype))
     with jax.named_scope("experts"):
-        gate = grouped_dot(xs, w_gate, sizes)
-        up = grouped_dot(xs, w_up, sizes)
-        hidden = jnp.where(live, jax.nn.silu(gate) * up, jnp.zeros((), up.dtype))
+        if len(weights) == 3:
+            w_gate, w_up, w_down = weights
+            gate = grouped_dot(xs, w_gate, sizes)
+            up = grouped_dot(xs, w_up, sizes)
+            hidden = jnp.where(live, jax.nn.silu(gate) * up, jnp.zeros((), up.dtype))
+        else:
+            w_up, w_down = weights
+            up = grouped_dot(xs, w_up, sizes)
+            hidden = jnp.where(live, jnp.square(jax.nn.relu(up)), jnp.zeros((), up.dtype))
         ys = grouped_dot(hidden, w_down, sizes)
         ys = jnp.where(live, ys, jnp.zeros((), ys.dtype))
     with jax.named_scope("combine"):
@@ -404,7 +470,11 @@ def routed_experts(x, router, w_gate, w_up, w_down, bias=None, *, top_k: int,
     sorts the N*top_k (token, slot) pairs by expert with the pairs of absent
     experts last, runs the three products as grouped products
     (:func:`grouped_dot`) over the held experts' rows, and sums each
-    token's held slots by its (renormalised) top-k weights. Returns
+    token's held slots by its (renormalised) top-k weights. With ``w_gate``
+    ``None`` the experts are ungated, ``relu(x W_up)**2 W_down``: two grouped
+    products forward and four backward where a gated expert runs three and
+    six, and ``R * (2 d + 2 f)`` numbers kept (``xs``, ``ys``; ``up``,
+    ``hidden``); everything around the products is the same code. Returns
     ``(y [N, d], counters [len(counter_names(bias is not None))] float32)``.
 
     ``scoring`` is ``softmax`` over the experts or ``sigmoid`` of each logit.
@@ -441,7 +511,7 @@ def routed_experts(x, router, w_gate, w_up, w_down, bias=None, *, top_k: int,
     batched, and JAX runs as many trips as the member with the most pairs
     needs, masking the others: the result is each member's own."""
     N, d = x.shape
-    Eh = w_gate.shape[0]
+    Eh = w_up.shape[0]
     rows = N * top_k
     bound = row_bound(rows, Eh, router.shape[1])
     chunks = -(-rows // bound)
@@ -481,7 +551,7 @@ def routed_experts(x, router, w_gate, w_up, w_down, bias=None, *, top_k: int,
         # chunks of `bound` sorted rows that hold a held pair; the first always runs
         used = jnp.clip(-(-pairs // bound), 1, chunks)
     part = functools.partial(_held_rows, bound=bound)
-    operands = (x, top_w, w_gate, w_up, w_down)
+    operands = (x, top_w) + (() if w_gate is None else (w_gate,)) + (w_up, w_down)
     index = (jnp.pad(order, (0, chunks * bound - rows)), inverse.reshape(N, top_k), group_sizes)
     if chunks == 1:
         y = part(jnp.int32(0), *operands, *index)
@@ -520,7 +590,10 @@ class AttentionSpec:
     latent attention: ``head_dim`` without rotary and ``rope_dim`` with it a
     query head, values of ``v_dim``, keys and values out of a latent of
     ``kv_lora_rank``. ``qk_norm``: an RMS norm with a learned scale over
-    each head's dims of q and of k, ahead of rotary (grouped-query only)."""
+    each head's dims of q and of k, ahead of rotary (grouped-query only).
+    ``rotary`` false: q and k go to the scores as projected, no positions
+    (the attention of a one-part stack, whose state-space parts carry the
+    order; grouped-query only)."""
 
     heads: int
     kv_heads: int
@@ -530,6 +603,7 @@ class AttentionSpec:
     kv_lora_rank: int = 0
     interleave: bool = False
     qk_norm: bool = False
+    rotary: bool = True
 
     def site(self) -> Tuple[int, ...]:
         """The shapes ``ops/attention.attention`` is called with, as
@@ -543,7 +617,7 @@ class AttentionSpec:
         """The (heads, dims a head) of each call of ``ops/rotary.rotary`` in
         a layer, as its ``takes_kernel`` takes them after the length
         (``ModelDef.rope_sites``); the pairs form is no such call."""
-        if self.interleave:
+        if self.interleave or not self.rotary:
             return ()
         if self.rope_dim:
             return ((self.heads, self.rope_dim), (1, self.rope_dim))
@@ -564,10 +638,81 @@ class ExpertSpec:
     scale: float = 1.0
     shared_width: int = 0
     renorm_eps: float = 0.0
+    gated: bool = True
+
+    def products(self) -> int:
+        """Grouped products a held pair runs forward: the span constant
+        ``expert_products`` that a reader's FLOPs a pair follow from."""
+        return 3 if self.gated else 2
+
+
+@dataclasses.dataclass(frozen=True)
+class MambaSpec:
+    """One Mamba-2 mixer, in numbers (:meth:`DecoderLayer.mamba` has the
+    equations): ``heads`` of ``head_dim`` (their product is the inner
+    width), ``groups`` that share B and C of ``state`` numbers, a
+    convolution of ``taps`` over ``x | B | C`` with or without a bias, the
+    scan's ``chunk``, and what the step's bias is drawn from."""
+
+    heads: int
+    head_dim: int
+    groups: int
+    state: int
+    taps: int
+    chunk: int = 128
+    conv_bias: bool = True
+    dt_min: float = 0.001
+    dt_max: float = 0.1
+    dt_floor: float = 1e-4
+
+    @property
+    def inner(self) -> int:
+        return self.heads * self.head_dim
+
+    @property
+    def conv_width(self) -> int:
+        return self.inner + 2 * self.groups * self.state
 
 
 def gated_mlp(x, w_gate, w_up, w_down):
     return jnp.dot(jax.nn.silu(jnp.dot(x, w_gate)) * jnp.dot(x, w_up), w_down)
+
+
+def relu2_mlp(x, w_up, w_down):
+    return jnp.dot(jnp.square(jax.nn.relu(jnp.dot(x, w_up))), w_down)
+
+
+@functools.partial(jax.checkpoint, static_argnums=(3, 4))
+def gated_group_norm(y, z, scale, groups: int, eps: float):
+    """``GroupRMSNorm(y * SiLU(z)) * scale`` over ``groups`` equal runs of the
+    last axis, the gate first (HF's ``MambaRMSNormGated``): product,
+    statistics and scale in float32, one rounding to ``y``'s dtype. A
+    ``jax.checkpoint``: between the passes it keeps ``y`` and ``z`` as they
+    came (bfloat16 in the training cells) where autodiff would keep four
+    float32 arrays of their size (0.27 GB a layer at 4 096 tokens of 4 096),
+    and recomputes a SiLU, two products and a mean."""
+    g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    g = g.reshape(*g.shape[:-1], groups, g.shape[-1] // groups)
+    g = g * jax.lax.rsqrt(jnp.mean(jnp.square(g), axis=-1, keepdims=True) + eps)
+    return (g.reshape(y.shape) * scale).astype(y.dtype)
+
+
+def a_log_init(key, shape, dtype=jnp.float32):
+    """``log(a)``, ``a`` uniform in [1, 16]: the source's own draw."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+def dt_bias_init(dt_min: float, dt_max: float, dt_floor: float):
+    """The inverse softplus of a step drawn log-uniformly in [dt_min, dt_max]
+    and floored at dt_floor, so that ``softplus(dt_bias)`` is that step."""
+
+    def init(key, shape, dtype=jnp.float32):
+        u = jax.random.uniform(key, shape, dtype)
+        dt = jnp.exp(u * (math.log(dt_max) - math.log(dt_min)) + math.log(dt_min))
+        dt = jnp.maximum(dt, dt_floor)
+        return dt + jnp.log(-jnp.expm1(-dt))
+
+    return init
 
 
 class DecoderLayer(nn.Module):
@@ -584,14 +729,23 @@ class DecoderLayer(nn.Module):
     (``layers_0.latent``) between the layer and these. ``ffn`` is the dense
     MLP's width or the expert layer's numbers; ``conv_taps`` the filter's
     length in a ``conv`` layer, whose mixer needs neither ``attn`` nor the
-    rotary tables."""
+    rotary tables.
+
+    A ``kind`` of :data:`PARTS` is a layer of ONE part under ONE norm (the
+    leaf ``norm``), ``x + Part(RMSNorm(x))``: ``mamba`` (``in_proj``,
+    ``conv``, ``ssd`` with the operator's ``ssd_chunk``, ``ssd_state`` and
+    ``ssd_out`` beneath it, ``gated_norm``, ``out``; numbers in ``ssm``),
+    ``attention`` (``qkv``, ``attention_full``, ``out`` and, where the spec
+    has no rotary, no ``rope``), ``experts`` (the five scopes above) or
+    ``mlp``. Its ``ffn`` is ``None`` where the part is a mixer."""
 
     kind: str
     attn: AttentionSpec
-    ffn: Union[int, ExpertSpec]
+    ffn: Union[int, ExpertSpec, None]
     sliding_window: int
     rms_norm_eps: float
     conv_taps: int = 0
+    ssm: Optional[MambaSpec] = None
 
     @nn.nowrap
     def grouped_query(self, n, cos, sin, init):
@@ -606,8 +760,9 @@ class DecoderLayer(nn.Module):
             with jax.named_scope("qk_norm"):
                 q = RMSNorm(self.rms_norm_eps, name="q_layernorm")(q)
                 k = RMSNorm(self.rms_norm_eps, name="k_layernorm")(k)
-        with jax.named_scope("rope"):
-            q, k = rotary(q, cos, sin), rotary(k, cos, sin)
+        if self.attn.rotary:
+            with jax.named_scope("rope"):
+                q, k = rotary(q, cos, sin), rotary(k, cos, sin)
         sliding = self.kind == "sliding_attention"
         with jax.named_scope("attention_sliding" if sliding else "attention_full"):
             return attention(
@@ -649,14 +804,52 @@ class DecoderLayer(nn.Module):
                 bcx, self.param("conv", nn.initializers.normal(L ** -0.5), (d, L)))
 
     @nn.nowrap
+    def mamba(self, n, init):
+        """The Mamba-2 mixer (HF's ``NemotronHMamba2Mixer``) ahead of its
+        output projection: [B, T, heads x head_dim].
+
+            [z | xBC | dt] = n W_in        d -> inner + (inner + 2 G N) + H
+            xBC = SiLU(conv(xBC) + b)      causal, depthwise, ``taps`` a channel
+            [x | B | C] = xBC              inner | G x N | G x N
+            step = softplus(dt + dt_bias), A = -exp(A_log)          float32
+            y = ssd(x, step, A, B, C, D)   ops/ssd.py: the scan over positions
+            y = GroupRMSNorm(y * SiLU(z)) * w      groups of inner / G, gate first
+
+        The step has no clamp (``time_step_limit`` [0, inf)). The gated norm's
+        product, statistics and scale are float32, rounded once."""
+        B_, T, d = n.shape
+        m = self.ssm
+        H, P, G, N = m.heads, m.head_dim, m.groups, m.state
+        with jax.named_scope("in_proj"):
+            zxbcdt = jnp.dot(n, self.param("in_proj", init, (d, m.inner + m.conv_width + H)))
+            z, xbc, dt = jnp.split(zxbcdt, [m.inner, m.inner + m.conv_width], axis=-1)
+        with jax.named_scope("conv"):
+            xbc = silu_short_conv(
+                xbc, self.param("conv", nn.initializers.normal(m.taps ** -0.5), (m.conv_width, m.taps)),
+                self.param("conv_bias", nn.initializers.zeros, (m.conv_width,)) if m.conv_bias
+                else None)
+        with jax.named_scope("ssd"):
+            step = jax.nn.softplus(dt.astype(jnp.float32) + self.param(
+                "dt_bias", dt_bias_init(m.dt_min, m.dt_max, m.dt_floor), (H,)).astype(jnp.float32))
+            A = -jnp.exp(self.param("A_log", a_log_init, (H,)).astype(jnp.float32))
+            x, Bm, Cm = jnp.split(xbc, [m.inner, m.inner + G * N], axis=-1)
+            y = ssd(x.reshape(B_, T, H, P), step, A, Bm.reshape(B_, T, G, N),
+                    Cm.reshape(B_, T, G, N), self.param("D", nn.initializers.ones, (H,)), m.chunk)
+        with jax.named_scope("gated_norm"):
+            return gated_group_norm(
+                y.reshape(B_, T, m.inner), z, self.param("gated_norm", nn.initializers.ones, (m.inner,)),
+                G, self.rms_norm_eps)
+
+    @nn.nowrap
     def expert_layer(self, n, init):
         """The held routed experts' part plus the shared expert's, and the
-        layer's counters."""
+        layer's counters. Ungated experts (and their shared expert) have no
+        ``experts_gate`` / ``shared_gate`` leaf."""
         d, e = n.shape[-1], self.ffn
         lo, hi = e.held
         weights = [
             self.param("router", init, (d, e.experts)),
-            self.param("experts_gate", init, (hi - lo, d, e.width)),
+            self.param("experts_gate", init, (hi - lo, d, e.width)) if e.gated else None,
             self.param("experts_up", init, (hi - lo, d, e.width)),
             self.param("experts_down", init, (hi - lo, e.width, d)),
             self.param("router_bias", nn.initializers.zeros, (e.experts,)) if e.biased else None,
@@ -670,18 +863,44 @@ class DecoderLayer(nn.Module):
             scoring=e.scoring, scale=e.scale, renorm_eps=e.renorm_eps)
         if e.shared_width:
             with jax.named_scope("shared"):
-                y = y + gated_mlp(
+                y = y + (gated_mlp if e.gated else relu2_mlp)(
                     n,
-                    self.param("shared_gate", init, (d, e.shared_width)),
+                    *([self.param("shared_gate", init, (d, e.shared_width))] if e.gated else []),
                     self.param("shared_up", init, (d, e.shared_width)),
                     self.param("shared_down", init, (e.shared_width, d)),
                 )
         return y, counters
 
+    @nn.nowrap
+    def one_part(self, x, init):
+        """``x + Part(RMSNorm(x))`` of a layer whose kind is one of
+        :data:`PARTS`."""
+        B, T, d = x.shape
+        n = RMSNorm(self.rms_norm_eps, name="norm")(x)
+        if self.kind == "experts":
+            y, counters = self.expert_layer(n.reshape(B * T, d), init)
+            self.sow("counters", "moe", counters)
+            return x + y.reshape(B, T, d)
+        if self.kind == "mlp":
+            with jax.named_scope("mlp"):
+                return x + relu2_mlp(
+                    n,
+                    self.param("mlp_up", init, (d, self.ffn)),
+                    self.param("mlp_down", init, (self.ffn, d)),
+                )
+        if self.kind == "mamba":
+            a, leaf = self.mamba(n, init), "out_proj"
+        else:
+            a, leaf = self.grouped_query(n, None, None, init).reshape(B, T, -1), "o_proj"
+        with jax.named_scope("out"):
+            return x + jnp.dot(a, self.param(leaf, init, (a.shape[-1], d)))
+
     @nn.compact
     def __call__(self, x, cos, sin):
         B, T, d = x.shape
         init = nn.initializers.normal(0.02)
+        if self.kind in PARTS.values():
+            return self.one_part(x, init)
         n = RMSNorm(self.rms_norm_eps, name="input_layernorm")(x)
         if self.kind == "conv":
             a, leaf = self.short_conv(n, init), "out_proj"
@@ -751,9 +970,60 @@ class DecoderLM(nn.Module):
     # conv layers and QK norms
     conv_L_cache: Optional[int] = None
     use_qk_norm: bool = False
+    # a one-part stack (HF's nemotron_h keys): the pattern, the Mamba-2
+    # mixer, the experts' activation and the shared expert's own width
+    hybrid_override_pattern: Optional[str] = None
+    mamba_num_heads: Optional[int] = None
+    mamba_head_dim: Optional[int] = None
+    n_groups: int = 1
+    ssm_state_size: Optional[int] = None
+    conv_kernel: Optional[int] = None
+    chunk_size: int = 128
+    use_conv_bias: bool = True
+    mamba_hidden_act: str = "silu"
+    time_step_limit: Optional[Sequence[Optional[float]]] = None
+    time_step_min: float = 0.001
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
+    mlp_hidden_act: str = "silu"
+    moe_shared_expert_intermediate_size: Optional[int] = None
+
+    def one_part(self) -> bool:
+        """Whether the stack is told by ``hybrid_override_pattern``: every
+        layer one part under one norm, attention without rotary, ungated
+        ReLU-squared experts and MLPs (what HF's ``nemotron_h`` classes fix)."""
+        return self.hybrid_override_pattern is not None
 
     def kinds(self) -> Tuple[str, ...]:
         """Every layer's kind; the depth is its length."""
+        if self.one_part():
+            pattern = str(self.hybrid_override_pattern)
+            if self.layer_types is not None:
+                raise ValueError(
+                    "hybrid_override_pattern beside layer_types: a stack is one part a layer "
+                    "or a mixer and a feed-forward part a layer, not both")
+            if self.first_k_dense_replace:
+                raise ValueError(
+                    "first_k_dense_replace beside hybrid_override_pattern: a one-part stack "
+                    "spells a dense MLP as '-' in its pattern")
+            unknown = sorted(set(pattern) - set(PARTS))
+            if unknown or not pattern:
+                raise ValueError(
+                    f"hybrid_override_pattern {pattern!r}: unknown parts {unknown}; "
+                    f"have {sorted(PARTS)}")
+            if self.num_hidden_layers is not None and int(self.num_hidden_layers) != len(pattern):
+                raise ValueError(
+                    f"num_hidden_layers {self.num_hidden_layers} is not the length of "
+                    f"hybrid_override_pattern {pattern!r}")
+            if self.mlp_hidden_act != "relu2":
+                raise ValueError(
+                    f"mlp_hidden_act {self.mlp_hidden_act!r}: a one-part stack's experts and "
+                    "MLPs are ungated ReLU-squared ('relu2' only)")
+            return tuple(PARTS[c] for c in pattern)
+        if self.mlp_hidden_act != "silu":
+            raise ValueError(
+                f"mlp_hidden_act {self.mlp_hidden_act!r}: the layers of layer_types have "
+                "gated-SiLU MLPs and experts ('silu' only)")
         if self.layer_types is not None:
             kinds = tuple(self.layer_types)
         elif self.num_hidden_layers is not None:
@@ -774,6 +1044,34 @@ class DecoderLM(nn.Module):
                 f"a 'conv' layer needs conv_L_cache, its filter's length, got {self.conv_L_cache}")
         return int(self.conv_L_cache)
 
+    def mamba_spec(self) -> Optional[MambaSpec]:
+        """The ``mamba`` layers' numbers; ``None`` where no layer is one."""
+        if "mamba" not in self.kinds():
+            return None
+        sizes = {k: getattr(self, k) for k in (
+            "mamba_num_heads", "mamba_head_dim", "ssm_state_size", "conv_kernel")}
+        missing = [k for k, v in sizes.items() if not v or int(v) < 1]
+        if missing:
+            raise ValueError(f"an 'M' layer needs {missing}, got {sizes}")
+        if int(self.n_groups) < 1 or int(self.mamba_num_heads) % int(self.n_groups):
+            raise ValueError(
+                f"n_groups {self.n_groups} does not divide mamba_num_heads {self.mamba_num_heads}")
+        if self.mamba_hidden_act != "silu":
+            raise ValueError(
+                f"mamba_hidden_act {self.mamba_hidden_act!r}: the convolution and the gate "
+                "are SiLU here ('silu' only)")
+        limit = tuple(self.time_step_limit or (0.0, None))
+        if len(limit) != 2 or float(limit[0] or 0.0) > 0 or (
+                limit[1] is not None and math.isfinite(float(limit[1]))):
+            raise ValueError(
+                f"time_step_limit {limit}: a clamp of the step is not expressed here "
+                "([0, null] only)")
+        return MambaSpec(
+            int(self.mamba_num_heads), int(self.mamba_head_dim), int(self.n_groups),
+            int(self.ssm_state_size), int(self.conv_kernel), int(self.chunk_size),
+            bool(self.use_conv_bias), float(self.time_step_min), float(self.time_step_max),
+            float(self.time_step_floor))
+
     def experts(self) -> int:
         return int(self.num_experts if self.n_routed_experts is None else self.n_routed_experts)
 
@@ -791,7 +1089,11 @@ class DecoderLM(nn.Module):
             if self.num_attention_heads % self.num_key_value_heads:
                 raise ValueError("num_attention_heads must be a multiple of num_key_value_heads")
             return AttentionSpec(self.num_attention_heads, self.num_key_value_heads, self.head_dim,
-                                 qk_norm=bool(self.use_qk_norm))
+                                 qk_norm=bool(self.use_qk_norm), rotary=not self.one_part())
+        if self.one_part():
+            raise ValueError(
+                "kv_lora_rank beside hybrid_override_pattern: a one-part stack's attention "
+                "is grouped-query without rotary")
         if self.use_qk_norm:
             raise ValueError("use_qk_norm beside a latent (kv_lora_rank) is not expressed here")
         if self.rope_scaling is not None:
@@ -820,15 +1122,29 @@ class DecoderLM(nn.Module):
         if eps is None:
             # HF's DeepseekV3 router adds 1e-20 to the sum it renormalises by
             eps = 1e-20 if self.scoring_func == "sigmoid" else 0.0
+        shared = int(self.n_shared_experts) * int(self.moe_intermediate_size)
+        if self.moe_shared_expert_intermediate_size is not None:
+            # one shared expert at a width of its own (HF's nemotron_h)
+            shared = int(self.moe_shared_expert_intermediate_size) if self.n_shared_experts else 0
         return ExpertSpec(
             self.experts(), int(self.num_experts_per_tok), int(self.moe_intermediate_size),
             self.held(), bool(self.norm_topk_prob), self.scoring_func,
             self.topk_method == "noaux_tc", float(self.routed_scaling_factor),
-            int(self.n_shared_experts) * int(self.moe_intermediate_size), float(eps))
+            shared, float(eps), gated=not self.one_part())
 
     def feed_forwards(self):
         """Every layer's ``DecoderLayer.ffn``: the dense width in the leading
-        ``first_k_dense_replace`` layers, the expert layer's numbers after."""
+        ``first_k_dense_replace`` layers, the expert layer's numbers after; in
+        a one-part stack the expert layer's numbers in an ``E`` layer, the
+        dense width in a ``-`` layer and ``None`` in a mixer's."""
+        if self.one_part():
+            kinds = self.kinds()
+            if "mlp" in kinds and self.intermediate_size is None:
+                raise ValueError("a '-' layer needs intermediate_size, the dense MLP's width")
+            by_kind = {"mlp": int(self.intermediate_size or 0)}
+            if "experts" in kinds:
+                by_kind["experts"] = self.expert_spec()
+            return tuple(by_kind.get(kind) for kind in kinds)
         dense = min(int(self.first_k_dense_replace), len(self.kinds()))
         if dense and self.intermediate_size is None:
             raise ValueError("first_k_dense_replace needs intermediate_size, the dense MLP's width")
@@ -838,19 +1154,25 @@ class DecoderLM(nn.Module):
     def attention_sites(self) -> Tuple[Tuple[int, ...], ...]:
         """``ModelDef.attention_sites``: one site a layer that has attention
         (a ``conv`` layer calls no attention)."""
-        return (self.attention_spec().site(),) * sum(kind != "conv" for kind in self.kinds())
+        return (self.attention_spec().site(),) * self.attention_layers()
+
+    def attention_layers(self) -> int:
+        return sum(kind in ("attention", "full_attention", "sliding_attention")
+                   for kind in self.kinds())
 
     def rope_sites(self) -> Tuple[Tuple[int, int], ...]:
         """``ModelDef.rope_sites``: the attention layers' calls of the
         rotate-half operator."""
-        return self.attention_spec().rope_sites() * sum(kind != "conv" for kind in self.kinds())
+        return self.attention_spec().rope_sites() * self.attention_layers()
 
     @nn.compact
     def __call__(self, tokens, train: bool = False):
         B, T = tokens.shape
         kinds, attn, taps = self.kinds(), self.attention_spec(), self.conv_taps()
-        # one pair of tables a kind of attention; a conv layer has no positions
-        turning = [kind for kind in dict.fromkeys(kinds) if kind != "conv"]
+        ssm = self.mamba_spec()
+        # one pair of tables a kind of attention; a conv layer has no positions,
+        # and no layer of a one-part stack
+        turning = [kind for kind in dict.fromkeys(kinds) if kind in LAYER_KINDS and kind != "conv"]
         with jax.named_scope("rope"):
             if attn.rope_dim:
                 cos, sin = rotary_tables(
@@ -873,7 +1195,8 @@ class DecoderLM(nn.Module):
         x = embed(tokens)
         for i, (kind, ffn) in enumerate(zip(kinds, self.feed_forwards())):
             x = DecoderLayer(
-                kind, attn, ffn, self.sliding_window, self.rms_norm_eps, taps, name=f"layers_{i}",
+                kind, attn, ffn, self.sliding_window, self.rms_norm_eps, taps, ssm,
+                name=f"layers_{i}",
             )(x, *tables.get(kind, (None, None)))
         x = RMSNorm(self.rms_norm_eps, name="norm")(x)
         if self.tie_word_embeddings:

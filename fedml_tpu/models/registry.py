@@ -100,7 +100,9 @@ def create(
     if name == "decoder":
         # Spec-driven decoder (grouped-query or latent attention, rotary/YaRN,
         # window and full layers, layers of a gated short convolution, leading
-        # dense layers, routed experts with a shared one beside them); kw
+        # dense layers, routed experts with a shared one beside them; or a
+        # stack of one part a layer: Mamba-2 state-space mixers, attention
+        # without positions, ungated experts, dense MLPs); kw
         # mirrors the source model's config.json
         # keys, lists and nested mappings included (flax freezes them as
         # given). Returns logits only and trains under task="nwp" like
@@ -117,11 +119,18 @@ def create(
             # expert layers (every layer, where none is dense)
             attrs = {"hidden": m.hidden_size, "expert_width": routed[0].width,
                      "layers": len(routed), "expert_layers": len(routed),
-                     "top_k": routed[0].top_k}
+                     "top_k": routed[0].top_k,
+                     # grouped products a held pair runs forward: 3 gated, 2 ungated
+                     "expert_products": routed[0].products()}
             if routed[0].shared_width:
                 attrs["shared_width"] = routed[0].shared_width
         if m.conv_taps():
             attrs.update(conv_layers=m.kinds().count("conv"), conv_width=m.hidden_size)
+        ssm = m.mamba_spec()
+        if ssm is not None:
+            attrs.update(ssm_layers=m.kinds().count("mamba"), ssm_heads=ssm.heads,
+                         ssm_head_dim=ssm.head_dim, ssm_state=ssm.state,
+                         ssm_groups=ssm.groups, ssm_chunk=ssm.chunk)
         return ModelDef(
             m, input_shape, num_classes, input_dtype=jnp.int32, name="decoder",
             counters=counter_names(routed[0].biased) if routed else (),

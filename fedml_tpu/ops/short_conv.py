@@ -26,7 +26,19 @@ cells); the results are rounded to the operands' dtypes once.
 
 Pure ``jax.numpy``: any leading axes, any length (shorter than the filter
 too), and it batches and scans like any elementwise function, which is what
-the client ``vmap`` and ``scan`` schedules need."""
+the client ``vmap`` and ``scan`` schedules need.
+
+The second convolution, :func:`silu_short_conv`, is the one ahead of a
+state-space scan (a Mamba-2 mixer's ``conv1d`` over ``x | B | C``), with a
+bias and an activation and no gate:
+
+    c_t = sum_{j < L} w[:, j] * x_{t-(L-1)+j} + b        x zero before position 0
+    y   = c * sigmoid(c)
+
+the same taps over the same shifted copies (:func:`_taps`, ``_past``), the
+sums, the bias and the SiLU in float32, one rounding to the operand's dtype,
+and a ``jax.checkpoint`` for the same reason: only ``x``, the filter and the
+bias are kept, 2 x width numbers a token forward and 3 x width backward."""
 
 from __future__ import annotations
 
@@ -42,6 +54,17 @@ def _past(x, k: int):
     return jnp.pad(x, pad)[..., :x.shape[-2], :]
 
 
+def _taps(u, w):
+    """The causal depthwise sum over the filter's taps of float32 ``u``
+    [..., T, d]: tap j of ``w`` [d, L] reads L-1-j positions back."""
+    L = w.shape[1]
+    w32 = w.astype(jnp.float32)
+    conv = w32[:, L - 1] * u
+    for j in range(L - 1):
+        conv = conv + w32[:, j] * _past(u, L - 1 - j)
+    return conv
+
+
 @jax.checkpoint
 def gated_short_conv(bcx, w):
     """bcx [..., T, 3d] (``B | C | X``), w [d, L] -> y [..., T, d] in bcx's
@@ -52,9 +75,18 @@ def gated_short_conv(bcx, w):
             f"gated_short_conv: a filter of {d} channels takes a last axis of {3 * d}, "
             f"got {bcx.shape}")
     b, c, x = (bcx[..., i * d:(i + 1) * d].astype(jnp.float32) for i in range(3))
-    w32, u = w.astype(jnp.float32), b * x
-    # tap j reads L-1-j positions back
-    conv = w32[:, L - 1] * u
-    for j in range(L - 1):
-        conv = conv + w32[:, j] * _past(u, L - 1 - j)
-    return (c * conv).astype(bcx.dtype)
+    return (c * _taps(b * x, w)).astype(bcx.dtype)
+
+
+@jax.checkpoint
+def silu_short_conv(x, w, bias=None):
+    """x [..., T, d], w [d, L], bias [d] or None -> SiLU(conv(x) + bias)
+    [..., T, d] in x's dtype; the second convolution at the top of this file."""
+    if x.shape[-1] != w.shape[0]:
+        raise ValueError(
+            f"silu_short_conv: a filter of {w.shape[0]} channels takes that last axis, "
+            f"got {x.shape}")
+    conv = _taps(x.astype(jnp.float32), w)
+    if bias is not None:
+        conv = conv + bias.astype(jnp.float32)
+    return jax.nn.silu(conv).astype(x.dtype)

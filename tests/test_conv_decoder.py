@@ -324,7 +324,7 @@ def test_create_model_reports_sites_for_attention_layers_only_and_the_conv_const
     assert model.attention_sites == ((4, 2, 8),)
     assert model.counters == COUNTERS + (BIAS_COUNTER,)
     assert model.counter_attrs == {"hidden": 32, "expert_width": 12, "layers": 2,
-                                   "expert_layers": 2, "top_k": 2,
+                                   "expert_layers": 2, "top_k": 2, "expert_products": 3,
                                    "conv_layers": 2, "conv_width": 32}
     only_conv = build(dict(SPEC, layer_types=["conv", "conv"], first_k_dense_replace=2))
     assert only_conv.attention_sites == () and only_conv.counters == ()

@@ -337,7 +337,8 @@ def test_create_model_reports_the_latent_sites_the_counters_and_the_constants():
     assert model.attention_sites == ((2, 2, 16, 8, 16),) * 3
     assert model.counters == COUNTERS + (BIAS_COUNTER,)
     assert model.counter_attrs == {"hidden": 32, "expert_width": 12, "layers": 2,
-                                   "expert_layers": 2, "top_k": 2, "shared_width": 24}
+                                   "expert_layers": 2, "top_k": 2, "expert_products": 3,
+                                   "shared_width": 24}
     # grouped-query specs keep their sites, counters and constants
     mellum = create_model("decoder", "random_tokens", (LENGTH,), VOCAB, num_experts=4)
     assert mellum.attention_sites == ((4, 2, 32),) * 2 and mellum.counters == COUNTERS
@@ -380,6 +381,9 @@ def test_the_sites_are_what_the_traced_layers_hand_the_attention_core(monkeypatc
 # 8308e4dd86564cb3); at the rehearsal's 32 positions the operator IS the plain
 # three-line form, traced as before, so that pin was recomputed and came out
 # as it was (2ef7c050eeac5d0a). Both attention steps and both GPT-2 pins hold.
+# PR 38 left all six as they were (the one-part stack is a branch that no
+# accepted spec takes) and added the two of the configuration it brought,
+# computed on its own tree: the program its chip readings are of.
 PINS = {
     "attention.silo4": "940131b509805ea9",
     "attention.silo2": "50842107702d88df",
@@ -387,6 +391,8 @@ PINS = {
     "mellum2-12b-a2.5b.rehearse": "2ef7c050eeac5d0a",
     "gpt2-124m.full": "28bcf5bfcdd2a422",
     "gpt2-124m.rehearse": "8ce4b5786b6eb411",
+    "nemotron-twotower-30b-a3b.full": "888d98eb5abd1cfe",
+    "nemotron-twotower-30b-a3b.rehearse": "e7137c8985465d0e",
 }
 ATTENTION_STEPS = {"attention.silo4": ((4, 1024, 12, 12, 64), None),
                    "attention.silo2": ((2, 2048, 32, 4, 128), 1024)}

@@ -206,6 +206,29 @@ def test_expert_layer_at_mellum_share_compiles_for_v5e_over_bounded_rows(one_chi
     assert compiled.memory_analysis().temp_size_in_bytes < 0.45 * 2**30
 
 
+def test_state_space_scan_fwd_bwd_compiles_for_v5e_within_its_plan(one_chip):
+    """``ops/ssd.ssd`` forward + gradient at nemotron-twotower-30b-a3b
+    .silo2t4k-ssm's training step: one document of 4 096 positions, 64 heads
+    of 64, 8 groups, state 128, chunks of 128. Plain ``jax.numpy`` that XLA
+    lowers (no custom call of ours); what this guards is the plan: the decay
+    matrix [32, 64, 128, 128] float32 is 134 MB and autodiff of the recomputed
+    forward holds several arrays of that size, and the cell's round program
+    has a fifth of a GiB to spare. 0.30 GiB when written (PR 38), about 7 s."""
+    from fedml_tpu.ops.ssd import ssd
+
+    T, H, P, G, N = 4096, 64, 64, 8, 128
+
+    def loss(x, dt, A, B, C, D):
+        return jnp.sum(ssd(x, dt, A, B, C, D, 128).astype(jnp.float32))
+
+    shapes = [((1, T, H, P), jnp.bfloat16), ((1, T, H), jnp.float32), ((H,), jnp.float32),
+              ((1, T, G, N), jnp.bfloat16), ((1, T, G, N), jnp.bfloat16), ((H,), jnp.float32)]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(*args).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.45 * 2**30
+
+
 def test_femnist_cnn_round_program_compiles_for_one_v5e_chip(one_chip):
     """The production round program (``api.round_fn``) of the north star —
     FEMNIST CNN, 10 clients/round, batch 20 — lowered at its real round-0
