@@ -37,7 +37,9 @@ class ModelDef:
     # widths, counts and ``expert_products``, the grouped products a held pair
     # runs forward; the conv layers' ``conv_layers`` and ``conv_width``; the
     # state-space layers' ``ssm_layers``, ``ssm_heads``, ``ssm_head_dim``,
-    # ``ssm_state``, ``ssm_groups`` and ``ssm_chunk``).
+    # ``ssm_state``, ``ssm_groups`` and ``ssm_chunk``; where attention layers
+    # are of more than one shape, ``attn_length``, ``attn_window`` and the
+    # per-kind ``attn_<kind>_*`` of ``DecoderLM.attention_constants``).
     counters: Tuple[str, ...] = ()
     counter_attrs: dict = dataclasses.field(default_factory=dict)
     # One (query heads, key/value heads, head dim) per call of
@@ -48,8 +50,9 @@ class ModelDef:
     # no site.
     attention_sites: Tuple[Tuple[int, ...], ...] = ()
     # One (heads, dims a head) per call of ``ops/rotary.rotary`` in a forward
-    # pass: after the sequence length, what its ``takes_kernel`` decides from.
-    rope_sites: Tuple[Tuple[int, int], ...] = ()
+    # pass, and the dims it turns where that is not the whole head: after the
+    # sequence length, what its ``takes_kernel`` decides from.
+    rope_sites: Tuple[Tuple[int, ...], ...] = ()
     # From the tokens a step trains on, one (M, K, N, G) per grouped product
     # that a forward pass runs outside the overflow loops
     # (``models/decoder._held_rows``), each with two more in the backward

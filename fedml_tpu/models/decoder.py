@@ -28,14 +28,18 @@ stack of ONE part a layer under ONE norm:
   plain form at short ones):
   - grouped-query: ``num_attention_heads`` query heads of ``head_dim`` on
     ``num_key_value_heads`` key/value heads (the query width need not equal
-    ``hidden_size``); with ``use_qk_norm`` an RMS norm with a learned scale
-    of ``head_dim`` over each head's dims of q and of k (one scale for all
-    heads of q, one for k) ahead of rotary; rotate-half rotary on every dim
-    of q and k, one table per layer kind from ``rope_parameters[kind]``
-    (``rope_type`` ``default`` or ``yarn``, as HF's
-    ``_compute_yarn_parameters``: blended frequencies, cos and sin scaled by
-    ``attention_factor``) or, without ``rope_parameters``, the default type
-    at ``rope_theta`` for every layer;
+    ``hidden_size``), or layer i's own ``num_attention_heads_per_layer[i]``
+    on the same key/value heads (HF's ``laguna``); with ``use_qk_norm`` an
+    RMS norm with a learned scale of ``head_dim`` over each head's dims of q
+    and of k (one scale for all heads of q, one for k) ahead of rotary;
+    rotate-half rotary on the first ``R = head_dim x partial_rotary_factor``
+    dims of q and k (every dim by default; the others pass unchanged, HF's
+    ``cat(rotated, pass)``), one table per layer kind from
+    ``rope_parameters[kind]`` (``rope_type`` ``default`` or ``yarn``, as HF's
+    ``_compute_yarn_parameters`` over the ``R`` rotated dims: blended
+    frequencies, cos and sin scaled by ``attention_factor``, which so scales
+    the rotated dims alone) or, without ``rope_parameters``, the default
+    type at ``rope_theta`` over every dim of every layer;
   - latent (MLA, HF's ``DeepseekV3Attention``), where ``kv_lora_rank`` is
     given: ``q = n Wq`` holds per head ``qk_nope_head_dim`` dims without and
     ``qk_rope_head_dim`` with rotary (``q_lora_rank`` null: no query
@@ -124,8 +128,14 @@ through the round here.
 
 Not expressed, and refused by name: a query latent (``q_lora_rank``),
 group-limited routing (``n_group`` / ``topk_group`` over 1), rotary scaling
-beside a latent (``rope_scaling``), QK norms beside a latent
-(``use_qk_norm``), a ``conv`` layer without its filter's length
+beside a latent (``rope_scaling``, a ``partial_rotary_factor`` other than 1
+in ``rope_parameters``), QK norms beside a latent (``use_qk_norm``), head
+counts per layer beside a latent or a one-part stack
+(``num_attention_heads_per_layer``), or at another length than the stack's,
+or not a multiple of ``num_key_value_heads``; a key of
+``rope_parameters[kind]`` other than :data:`ROPE_KEYS`, or beside the kinds
+other than ``original_max_position_embeddings`` (the fallback of a kind's
+``yarn`` that gives none of its own); a ``conv`` layer without its filter's length
 (``conv_L_cache``); a character of ``hybrid_override_pattern`` other than
 ``M``, ``E``, ``*``, ``-``; the pattern beside ``layer_types``,
 ``first_k_dense_replace`` or a latent (``kv_lora_rank``), or at another
@@ -170,6 +180,11 @@ from fedml_tpu.ops.short_conv import gated_short_conv, silu_short_conv
 from fedml_tpu.ops.ssd import ssd
 
 LAYER_KINDS = ("full_attention", "sliding_attention", "conv")
+# The kinds of layer that call attention (``attention`` is a one-part stack's).
+ATTENTION_KINDS = ("attention", "full_attention", "sliding_attention")
+# What a layer kind's rotary parameters (``rope_parameters[kind]``) may hold.
+ROPE_KEYS = ("rope_type", "rope_theta", "factor", "original_max_position_embeddings",
+             "beta_fast", "beta_slow", "attention_factor", "partial_rotary_factor")
 # The characters of ``hybrid_override_pattern`` and the one part each names.
 PARTS = {"M": "mamba", "E": "experts", "*": "attention", "-": "mlp"}
 
@@ -193,8 +208,26 @@ def counter_names(biased: bool):
     return COUNTERS + ((BIAS_COUNTER,) if biased else ())
 
 
+def rotated_dims(rope: Mapping[str, Any], head_dim: int) -> int:
+    """``R``, the dims of a head that a layer kind turns: ``head_dim x
+    partial_rotary_factor`` (1 by default), as HF takes it (``int``), even
+    and at least 2."""
+    factor = float(rope.get("partial_rotary_factor", 1.0))
+    R = int(head_dim * factor)
+    if R % 2 or not 0 < R <= head_dim:
+        raise ValueError(
+            f"partial_rotary_factor {factor}: head_dim {head_dim} x factor has to be an even "
+            f"number of dims in (0, {head_dim}], got {R}")
+    return R
+
+
 def rotary_tables(rope: Mapping[str, Any], head_dim: int, length: int):
-    """(cos, sin), each [length, head_dim] float32, of one layer kind."""
+    """(cos, sin), each [length, R] float32, of one layer kind: the angles
+    of its ``R = rotated_dims(rope, head_dim)`` turned dims, frequencies and
+    YaRN's correction range over ``R`` (HF's ``_compute_yarn_parameters``
+    over ``head_dim x partial_rotary_factor``), ``attention_factor`` folded
+    into both, so that it scales the turned dims and no other."""
+    head_dim = rotated_dims(rope, head_dim)
     theta = float(rope["rope_theta"])
     m = jnp.arange(0, head_dim, 2, dtype=jnp.float32)
     inv_freq = theta ** (-m / head_dim)
@@ -622,7 +655,9 @@ class AttentionSpec:
     each head's dims of q and of k, ahead of rotary (grouped-query only).
     ``rotary`` false: q and k go to the scores as projected, no positions
     (the attention of a one-part stack, whose state-space parts carry the
-    order; grouped-query only)."""
+    order; grouped-query only). ``rotary_dim``: the first dims of a head
+    that rotary turns, the others passing (grouped-query only); 0 turns the
+    whole head."""
 
     heads: int
     kv_heads: int
@@ -633,6 +668,7 @@ class AttentionSpec:
     interleave: bool = False
     qk_norm: bool = False
     rotary: bool = True
+    rotary_dim: int = 0
 
     def site(self) -> Tuple[int, ...]:
         """The shapes ``ops/attention.attention`` is called with, as
@@ -642,15 +678,17 @@ class AttentionSpec:
         latent = (self.rope_dim, self.v_dim) if self.rope_dim else ()
         return (self.heads, self.kv_heads, self.head_dim) + latent
 
-    def rope_sites(self) -> Tuple[Tuple[int, int], ...]:
+    def rope_sites(self) -> Tuple[Tuple[int, ...], ...]:
         """The (heads, dims a head) of each call of ``ops/rotary.rotary`` in
-        a layer, as its ``takes_kernel`` takes them after the length
+        a layer, and the dims it turns where that is not the whole head, as
+        its ``takes_kernel`` takes them after the length
         (``ModelDef.rope_sites``); the pairs form is no such call."""
         if self.interleave or not self.rotary:
             return ()
         if self.rope_dim:
             return ((self.heads, self.rope_dim), (1, self.rope_dim))
-        return ((self.heads, self.head_dim), (self.kv_heads, self.head_dim))
+        dims = (self.head_dim,) + ((self.rotary_dim,) if self.rotary_dim else ())
+        return ((self.heads,) + dims, (self.kv_heads,) + dims)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -985,6 +1023,8 @@ class DecoderLM(nn.Module):
     rms_norm_eps: float = 1e-6
     tie_word_embeddings: bool = False
     experts_held: Optional[Sequence[int]] = None
+    # query heads of each layer, on num_key_value_heads (HF's laguna)
+    num_attention_heads_per_layer: Optional[Sequence[int]] = None
     # latent attention (DeepseekV3Attention's keys)
     kv_lora_rank: Optional[int] = None
     q_lora_rank: Optional[int] = None
@@ -1136,6 +1176,11 @@ class DecoderLM(nn.Module):
             raise ValueError("use_qk_norm beside a latent (kv_lora_rank) is not expressed here")
         if self.rope_scaling is not None:
             raise ValueError("rope_scaling beside a latent (kv_lora_rank) is not expressed here")
+        if any(float(rope.get("partial_rotary_factor", 1.0)) != 1.0
+               for rope in (self.rope_parameters or {}).values() if isinstance(rope, Mapping)):
+            raise ValueError(
+                "partial_rotary_factor beside a latent (kv_lora_rank) is not expressed here: "
+                "its rope dims are qk_rope_head_dim")
         widths = (self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim)
         if None in widths or self.qk_rope_head_dim % 2:
             raise ValueError(
@@ -1145,6 +1190,89 @@ class DecoderLM(nn.Module):
             self.num_attention_heads, self.num_attention_heads, int(self.qk_nope_head_dim),
             int(self.qk_rope_head_dim), int(self.v_head_dim), int(self.kv_lora_rank),
             bool(self.rope_interleave))
+
+    def rope_by_kind(self) -> Mapping[str, Mapping[str, Any]]:
+        """Each layer kind's rotary parameters: ``rope_parameters[kind]``
+        (a ``yarn`` kind without its own ``original_max_position_embeddings``
+        takes the one beside the kinds), or the default type at
+        ``rope_theta`` for every kind."""
+        if self.rope_parameters is None:
+            return {kind: {"rope_type": "default", "rope_theta": self.rope_theta}
+                    for kind in LAYER_KINDS}
+        around = {k: v for k, v in self.rope_parameters.items() if k not in LAYER_KINDS}
+        unknown = sorted(set(around) - {"original_max_position_embeddings"})
+        if unknown:
+            raise ValueError(
+                f"rope_parameters: unknown keys {unknown} beside the layer kinds {LAYER_KINDS}")
+        out = {}
+        for kind in LAYER_KINDS:
+            if kind not in self.rope_parameters:
+                continue
+            rope = dict(self.rope_parameters[kind])
+            unknown = sorted(set(rope) - set(ROPE_KEYS))
+            if unknown:
+                raise ValueError(f"rope_parameters[{kind!r}]: unknown keys {unknown}; have {ROPE_KEYS}")
+            if "original_max_position_embeddings" in around:
+                rope.setdefault("original_max_position_embeddings",
+                                around["original_max_position_embeddings"])
+            out[kind] = rope
+        return out
+
+    def attention_specs(self) -> Tuple[AttentionSpec, ...]:
+        """Every layer's attention numbers (whatever its kind):
+        :meth:`attention_spec` with the layer's own query heads where
+        ``num_attention_heads_per_layer`` gives them, and the dims its kind
+        turns where that is not the whole head."""
+        base = self.attention_spec()
+        kinds = self.kinds()
+        heads = self.num_attention_heads_per_layer
+        if heads is not None:
+            if base.rope_dim or self.one_part():
+                raise ValueError(
+                    "num_attention_heads_per_layer beside a latent (kv_lora_rank) or "
+                    "hybrid_override_pattern is not expressed here")
+            heads = tuple(int(h) for h in heads)
+            if len(heads) != len(kinds) or any(h < 1 or h % base.kv_heads for h in heads):
+                raise ValueError(
+                    f"num_attention_heads_per_layer {heads}: one count a layer ({len(kinds)}), "
+                    f"each a multiple of num_key_value_heads {base.kv_heads}")
+        turned = {}
+        if not base.rope_dim and base.rotary:
+            turned = {kind: rotated_dims(rope, base.head_dim)
+                      for kind, rope in self.rope_by_kind().items()}
+        return tuple(
+            dataclasses.replace(
+                base, heads=heads[i] if heads else base.heads,
+                rotary_dim=0 if turned.get(kind, base.head_dim) == base.head_dim else turned[kind])
+            for i, kind in enumerate(kinds))
+
+    def attention_constants(self, length: int) -> dict:
+        """The ``flush`` span's constants of a stack whose grouped-query
+        attention layers are of more than one shape, which
+        ``attention.gqa_core_peak_pct`` reads: the trained ``attn_length``,
+        ``attn_window``, and per kind (``full``, ``sliding``) its layers,
+        query heads, key/value heads, head dim and turned dims
+        (``attn_full_layers``, ``attn_full_heads``, ``attn_full_kv_heads``,
+        ``attn_full_head_dim``, ``attn_full_rotary_dim``, ...). Empty where
+        every attention layer has one shape (every accepted configuration
+        but one), and where the layers of one kind differ among themselves."""
+        by_kind = {}
+        for kind, spec in zip(self.kinds(), self.attention_specs()):
+            if kind in ("full_attention", "sliding_attention"):
+                by_kind.setdefault(kind, set()).add(spec)
+        if len({spec.site() for specs in by_kind.values() for spec in specs}) < 2 or any(
+                len(specs) > 1 for specs in by_kind.values()):
+            return {}
+        out = {"attn_length": int(length), "attn_window": int(self.sliding_window)}
+        kinds = self.kinds()
+        for kind, (spec,) in by_kind.items():
+            name = kind.split("_")[0]
+            out.update({
+                f"attn_{name}_layers": kinds.count(kind), f"attn_{name}_heads": spec.heads,
+                f"attn_{name}_kv_heads": spec.kv_heads, f"attn_{name}_head_dim": spec.head_dim,
+                f"attn_{name}_rotary_dim": spec.rotary_dim or spec.head_dim,
+            })
+        return out
 
     def expert_spec(self) -> ExpertSpec:
         if self.n_group != 1 or self.topk_group != 1:
@@ -1191,17 +1319,18 @@ class DecoderLM(nn.Module):
 
     def attention_sites(self) -> Tuple[Tuple[int, ...], ...]:
         """``ModelDef.attention_sites``: one site a layer that has attention
-        (a ``conv`` layer calls no attention)."""
-        return (self.attention_spec().site(),) * self.attention_layers()
+        (a ``conv`` layer calls no attention), that layer's own."""
+        return tuple(spec.site() for kind, spec in zip(self.kinds(), self.attention_specs())
+                     if kind in ATTENTION_KINDS)
 
     def attention_layers(self) -> int:
-        return sum(kind in ("attention", "full_attention", "sliding_attention")
-                   for kind in self.kinds())
+        return sum(kind in ATTENTION_KINDS for kind in self.kinds())
 
-    def rope_sites(self) -> Tuple[Tuple[int, int], ...]:
+    def rope_sites(self) -> Tuple[Tuple[int, ...], ...]:
         """``ModelDef.rope_sites``: the attention layers' calls of the
         rotate-half operator."""
-        return self.attention_spec().rope_sites() * self.attention_layers()
+        return tuple(site for kind, spec in zip(self.kinds(), self.attention_specs())
+                     if kind in ATTENTION_KINDS for site in spec.rope_sites())
 
     def grouped_sites(self, tokens: int) -> Tuple[Tuple[int, int, int, int], ...]:
         """``ModelDef.grouped_sites``: the expert layers' grouped products
@@ -1212,8 +1341,8 @@ class DecoderLM(nn.Module):
     @nn.compact
     def __call__(self, tokens, train: bool = False):
         B, T = tokens.shape
-        kinds, attn, taps = self.kinds(), self.attention_spec(), self.conv_taps()
-        ssm = self.mamba_spec()
+        kinds, specs, taps = self.kinds(), self.attention_specs(), self.conv_taps()
+        attn, ssm = self.attention_spec(), self.mamba_spec()
         # one pair of tables a kind of attention; a conv layer has no positions,
         # and no layer of a one-part stack
         turning = [kind for kind in dict.fromkeys(kinds) if kind in LAYER_KINDS and kind != "conv"]
@@ -1227,9 +1356,7 @@ class DecoderLM(nn.Module):
                     cos, sin = (jnp.repeat(t[:, :half], 2, axis=-1) for t in (cos, sin))
                 tables = {kind: (cos, sin) for kind in turning}
             else:
-                rope = self.rope_parameters or {
-                    kind: {"rope_type": "default", "rope_theta": self.rope_theta}
-                    for kind in LAYER_KINDS}
+                rope = self.rope_by_kind()
                 tables = {kind: rotary_tables(rope[kind], self.head_dim, T) for kind in turning}
         # unit-RMS embedding: under an RMS norm a 0.02 embedding is drowned by
         # the attention branch's mean over the context, which every position
@@ -1237,9 +1364,9 @@ class DecoderLM(nn.Module):
         embed = nn.Embed(self.vocab_size, self.hidden_size, name="embed_tokens",
                          embedding_init=nn.initializers.normal(1.0))
         x = embed(tokens)
-        for i, (kind, ffn) in enumerate(zip(kinds, self.feed_forwards())):
+        for i, (kind, spec, ffn) in enumerate(zip(kinds, specs, self.feed_forwards())):
             x = DecoderLayer(
-                kind, attn, ffn, self.sliding_window, self.rms_norm_eps, taps, ssm,
+                kind, spec, ffn, self.sliding_window, self.rms_norm_eps, taps, ssm,
                 name=f"layers_{i}",
             )(x, *tables.get(kind, (None, None)))
         x = RMSNorm(self.rms_norm_eps, name="norm")(x)

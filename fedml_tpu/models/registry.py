@@ -126,6 +126,8 @@ def create(
                 attrs["shared_width"] = routed[0].shared_width
         if m.conv_taps():
             attrs.update(conv_layers=m.kinds().count("conv"), conv_width=m.hidden_size)
+        # attention layers of more than one shape: the per-kind numbers
+        attrs.update(m.attention_constants(input_shape[0]))
         ssm = m.mamba_spec()
         if ssm is not None:
             attrs.update(ssm_layers=m.kinds().count("mamba"), ssm_heads=ssm.heads,

@@ -391,7 +391,10 @@ def test_the_sites_are_what_the_traced_layers_hand_the_attention_core(monkeypatc
 # to change the two full-size programs with routed experts: their grouped
 # products take ``ops/grouped_matmul``'s kernels (at its parent 4cc8847 they
 # read ff35ffb062b3311d and 888d98eb5abd1cfe); the rehearsals' widths keep
-# ``ragged_dot`` and their pins hold, as do the other four.
+# ``ragged_dot`` and their pins hold, as do the other four. PR 40 left all
+# eight as they were (per-layer attention specs and the partial rotary's
+# lanes trace as before where every layer has one shape and turns the whole
+# head) and added the two of the configuration it brought.
 PINS = {
     "attention.silo4": "940131b509805ea9",
     "attention.silo2": "50842107702d88df",
@@ -401,6 +404,8 @@ PINS = {
     "gpt2-124m.rehearse": "8ce4b5786b6eb411",
     "nemotron-twotower-30b-a3b.full": "eaa14cfb8b04d242",
     "nemotron-twotower-30b-a3b.rehearse": "e7137c8985465d0e",
+    "laguna-xs.2.full": "37291c2624a2a267",
+    "laguna-xs.2.rehearse": "23cb7c783d5d8684",
 }
 ATTENTION_STEPS = {"attention.silo4": ((4, 1024, 12, 12, 64), None),
                    "attention.silo2": ((2, 2048, 32, 4, 128), 1024)}

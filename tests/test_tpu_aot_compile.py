@@ -89,6 +89,10 @@ def test_robust_stats_kernel_compiles_for_v5e(one_chip, C, D):
         # than the rest of this file together): run it with ``-m slow`` before
         # chip time goes on this shape; the cell compiles it on the chip.
         pytest.param(1, (1, 4096, 32, 8, 64), None, jnp.bfloat16, marks=pytest.mark.slow),
+        # laguna-xs.2.silo2t4k-swa: six query heads a K/V head on its full
+        # layers, eight under the window of 512 on its sliding ones (slow too)
+        pytest.param(1, (1, 4096, 48, 8, 128), None, jnp.bfloat16, marks=pytest.mark.slow),
+        pytest.param(1, (1, 4096, 64, 8, 128), 512, jnp.bfloat16, marks=pytest.mark.slow),
         (1, (8, 4096, 1, 1, 64), None, jnp.bfloat16),      # bench.py's row: a head a batch row
         (1, (1, 2048, 8, 2, 128), 512, jnp.float32),
     ],
@@ -144,28 +148,31 @@ def test_latent_attention_fwd_bwd_compiles_for_v5e(one_chip):
     assert compiled.as_text().count("tpu_custom_call") >= 2
 
 
-@pytest.mark.parametrize("clients,shape,dtype", [
-    (1, (2, 2048, 32, 128), jnp.bfloat16),  # mellum2-12b-a2.5b.silo2's q: a head a lane tile
-    (1, (2, 2048, 4, 128), jnp.bfloat16),   # and its k
-    (1, (1, 4096, 32, 64), jnp.bfloat16),   # lfm2-8b-a1b.silo2t4k's q: two heads a tile
-    (2, (1, 4096, 8, 64), jnp.bfloat16),    # its k, under a client vmap
-    (1, (1, 512, 4, 32), jnp.float32),      # four heads a tile, float32
+@pytest.mark.parametrize("clients,shape,turned,dtype", [
+    (1, (2, 2048, 32, 128), 128, jnp.bfloat16),  # mellum2-12b-a2.5b.silo2's q: a head a lane tile
+    (1, (2, 2048, 4, 128), 128, jnp.bfloat16),   # and its k
+    (1, (1, 4096, 32, 64), 64, jnp.bfloat16),    # lfm2-8b-a1b.silo2t4k's q: two heads a tile
+    (2, (1, 4096, 8, 64), 64, jnp.bfloat16),     # its k, under a client vmap
+    (1, (1, 512, 4, 32), 32, jnp.float32),       # four heads a tile, float32
+    (1, (1, 4096, 48, 128), 64, jnp.bfloat16),   # laguna-xs.2.silo2t4k-swa's full q: half turned
+    (2, (1, 4096, 8, 128), 64, jnp.bfloat16),    # and its k, under a client vmap
 ], ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else getattr(v, "__name__", str(v)))
-def test_rotary_fwd_bwd_compiles_for_v5e(one_chip, clients, shape, dtype):
+def test_rotary_fwd_bwd_compiles_for_v5e(one_chip, clients, shape, turned, dtype):
     """ops/rotary's kernel, forward and backward (the lane rotations, the
-    select where heads share a tile, blocks within the scoped VMEM), at the
-    two cells' q and k (chip_smoke.py's kernel check runs them)."""
+    select where heads share a tile or half a head turns, blocks within the
+    scoped VMEM), at the three cells' q and k (chip_smoke.py's kernel check
+    runs the first four)."""
     from fedml_tpu.ops import rotary as op
 
     B, T, H, D = shape
-    assert op.takes_kernel(T, H, D)
+    assert op.takes_kernel(T, H, D, turned)
 
     def loss(x, cos, sin):
         out = jax.vmap(lambda x: op.rotary(x, cos, sin))(x)
         return jnp.sum(out.astype(jnp.float32))
 
     args = [jax.ShapeDtypeStruct((clients,) + shape, dtype, sharding=one_chip)] + [
-        jax.ShapeDtypeStruct((T, D), jnp.float32, sharding=one_chip)] * 2
+        jax.ShapeDtypeStruct((T, turned), jnp.float32, sharding=one_chip)] * 2
     saved, op._use_interpret = op._use_interpret, lambda: False
     try:
         text = jax.jit(jax.value_and_grad(loss)).lower(*args).compile().as_text()
