@@ -474,6 +474,16 @@ class FedAvgAPI:
             self._site_attrs.update(
                 moe_kernel_sites=3 * sum(grouped_matmul.takes_kernel(*site) for site in sites),
                 moe_grouped_sites=3 * len(sites))
+        ssm = model.counter_attrs
+        if "ssm_layers" in ssm:
+            # one scan a state-space layer, at the training length
+            from fedml_tpu.ops import ssd
+
+            takes = ssd.takes_kernel(
+                model.input_shape[0], ssm["ssm_heads"], ssm["ssm_head_dim"], ssm["ssm_groups"],
+                ssm["ssm_state"], ssm["ssm_chunk"])
+            self._site_attrs.update(ssd_kernel_sites=ssm["ssm_layers"] * takes,
+                                    ssd_sites=ssm["ssm_layers"])
         # Scheduler: policy-driven cohort selection (FedConfig.selection /
         # .overprovision_factor, scheduler/policies.py). It shares this
         # API's health registry (straggler_aware consults the straggler

@@ -216,4 +216,6 @@ def test_flush_span_carries_the_two_attributes_and_a_model_without_attention_non
     flushes = [e.attrs for e in tracer.events() if e.name == "flush" and e.ts_us >= t0]
     assert flushes and all(
         (a["attn_kernel_sites"], a["attn_sites"]) == (0, 2) for a in flushes)
+    # no state-space layer, no scan sites
+    assert not any(k.startswith("ssd_") for a in flushes for k in a)
     assert create_model("lr", "synthetic", (6,), 3).attention_sites == ()

@@ -1,16 +1,19 @@
-"""``ops/ssd.ssd`` (the chunked state-space scan, a ``jax.checkpoint``) and
-its gradient in every operand against ``jax.grad`` of the recurrence written
-position by position, in float32 and bfloat16 operands, under ``vmap`` and
-inside a ``scan``, at lengths equal to, under and not a multiple of the
-chunk; causality and the state's reach; and the second short convolution
-(``ops/short_conv.silu_short_conv``: a bias and a SiLU, no gate) against
-plain numpy."""
+"""``ops/ssd.ssd`` (the chunked state-space scan, a ``jax.checkpoint``, and
+at shapes ``takes_kernel`` admits the Pallas kernels ``ssd_fwd`` / ``ssd_bwd``,
+interpreted here) and its gradient in every operand against ``jax.grad`` of
+the recurrence written position by position, in float32 and bfloat16
+operands, under ``vmap`` and inside a ``scan``, at lengths equal to, under
+and not a multiple of the chunk; the kernels against the chunked products
+too; causality and the state's reach; the kernels' shape rule; and the
+second short convolution (``ops/short_conv.silu_short_conv``: a bias and a
+SiLU, no gate) against plain numpy."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from fedml_tpu.ops import ssd as op
 from fedml_tpu.ops.short_conv import silu_short_conv
 from fedml_tpu.ops.ssd import ssd
 
@@ -84,24 +87,47 @@ def close(got, want, tol, what):
 TOLERANCE = {jnp.float32: 5e-5, jnp.bfloat16: 3e-2}
 
 
+def chunked(x, dt, A, B, C, D, chunk):
+    """The chunked products whatever the shape: what ``ssd`` runs where the
+    kernels do not take the call."""
+    *lead, T, H, P = x.shape
+    G, N = B.shape[-2:]
+    y = jax.checkpoint(op._chunked, static_argnums=6)(
+        x.reshape(-1, T, H, P), dt.reshape(-1, T, H), A,
+        B.astype(x.dtype).reshape(-1, T, G, N), C.astype(x.dtype).reshape(-1, T, G, N), D, chunk)
+    return y.reshape(*lead, T, H, P)
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("lead,T,H,P,G,N", [
-    ((2,), 16, 4, 8, 2, 8),     # one chunk exactly
-    ((1,), 48, 4, 8, 2, 8),     # three chunks
-    ((2,), 5, 4, 8, 2, 8),      # under the chunk: one chunk of 5
-    ((1,), 37, 4, 8, 4, 8),     # no multiple of the chunk: padded; a group a head
-    ((), 32, 6, 4, 1, 16),      # no leading axis; one group for all heads
-    ((2, 2), 20, 4, 8, 2, 8),   # two leading axes
-], ids=["one_chunk", "three_chunks", "under_the_chunk", "no_multiple", "no_lead", "two_leads"])
-def test_value_and_every_gradient_match_the_recurrence(lead, T, H, P, G, N, dtype):
+@pytest.mark.parametrize("lead,T,H,P,G,N,chunk", [
+    ((2,), 16, 4, 8, 2, 8, CHUNK),     # one chunk exactly
+    ((1,), 48, 4, 8, 2, 8, CHUNK),     # three chunks
+    ((2,), 5, 4, 8, 2, 8, CHUNK),      # under the chunk: one chunk of 5
+    ((1,), 37, 4, 8, 4, 8, CHUNK),     # no multiple of the chunk: padded; a group a head
+    ((), 32, 6, 4, 1, 16, CHUNK),      # no leading axis; one group for all heads
+    ((2, 2), 20, 4, 8, 2, 8, CHUNK),   # two leading axes
+    # the kernels: two chunks of 128, two heads of 64 a group sharing a lane tile
+    ((), 256, 4, 64, 2, 128, 128),
+    ((2,), 256, 4, 64, 2, 128, 128),
+    # a head a lane tile, four groups, three chunks
+    ((1,), 384, 4, 128, 4, 128, 128),
+], ids=["one_chunk", "three_chunks", "under_the_chunk", "no_multiple", "no_lead", "two_leads",
+        "kernels_no_lead", "kernels_lead", "kernels_head_a_tile"])
+def test_value_and_every_gradient_match_the_recurrence(lead, T, H, P, G, N, chunk, dtype):
     operands, cot = case(lead, T, H, P, G, N, dtype)
-    got = value_and_grads(ssd, operands, cot)
+    got = value_and_grads(ssd, operands, cot, chunk)
     want = value_and_grads(plain, operands, cot)
     assert got[0].dtype == dtype and got[0].shape == lead + (T, H, P)
     for name, a, b, operand in zip(("y",) + tuple("d_" + n for n in NAMES), got, want,
                                    (operands[0],) + operands):
         assert a.shape == b.shape and (name == "y" or a.dtype == operand.dtype), name
         close(a, b, TOLERANCE[dtype], (name, lead, T))
+    if op.takes_kernel(T, H, P, G, N, chunk):
+        # and the kernels against the products they stand in for, which round
+        # at the same places: only the order of the sums differs
+        assert "pallas_call" in str(jax.make_jaxpr(lambda *a: ssd(*a, chunk))(*operands))
+        for name, a, b in zip(("y",) + NAMES, got, value_and_grads(chunked, operands, cot, chunk)):
+            close(a, b, TOLERANCE[dtype], (name, "chunked", lead, T))
 
 
 def test_the_chunk_changes_no_number_beyond_the_order_of_sums():
@@ -158,6 +184,82 @@ def test_causal_and_the_state_reaches_past_the_chunk():
         late = np.abs(np.asarray(moved[0, t + 2 * CHUNK:]) - np.asarray(base[0, t + 2 * CHUNK:]))
         assert float(np.max(np.abs(np.asarray(moved[0, t]) - np.asarray(base[0, t])))) > 1e-3
         assert float(late.max()) > 1e-4
+
+
+def test_the_kernels_under_vmap_with_weights_of_their_own_and_inside_a_scan():
+    """The client ``vmap`` around both kernels (each client's own A and D:
+    the batched ``pallas_call`` takes them as operands of their own) and the
+    local-step scan around their gradient, against the chunked products."""
+    operands, cot = case((1,), 256, 4, 64, 2, 128, jnp.float32)
+    x, dt, A, B, C, D = operands
+    assert op.takes_kernel(256, 4, 64, 2, 128, 128)
+    As, Ds, xs = jnp.stack([A, 0.5 * A]), jnp.stack([D, -D]), jnp.stack([x, -2 * x])
+
+    def clients(fn):
+        def one(x, A, D):
+            return jnp.sum(fn(x, dt, A, B, C, D, 128) * cot)
+        return jax.jit(jax.vmap(jax.value_and_grad(one, argnums=(0, 1, 2))))(xs, As, Ds)
+
+    (lk, gk), (lc, gc) = clients(ssd), clients(chunked)
+    close(lk, lc, 5e-5, "vmap loss")
+    for a, b in zip(gk, gc):
+        close(a, b, 5e-5, "vmap grads")
+
+    def steps(fn):
+        def step(A, x_t):
+            value, dA = jax.value_and_grad(lambda A: jnp.sum(fn(x_t, dt, A, B, C, D, 128) * cot))(A)
+            return A - 1e-3 * dA, value
+        return jax.jit(lambda: jax.lax.scan(step, A, xs))()
+
+    (A_k, values_k), (A_c, values_c) = steps(ssd), steps(chunked)
+    close(values_k, values_c, 5e-5, "scan values")
+    close(A_k, A_c, 5e-5, "scan carry")
+
+
+def test_the_kernels_carry_the_state_across_chunks():
+    """With a slow decay a token's input reaches outputs chunks later through
+    the state the kernels carry, and its gradient reaches back the same way;
+    outputs before it are untouched to the bit; all of it as the recurrence
+    has it."""
+    (x, dt, A, B, C, D), cot = case((1,), 384, 4, 64, 2, 128, jnp.float32)
+    A = -jnp.full((4,), 0.05)
+    fn = jax.jit(lambda x, B: ssd(x, dt, A, B, C, D, 128))
+    base = fn(x, B)
+    t = 100                                   # the first chunk: its state enters chunks 1 and 2
+    for moved in (fn(x.at[0, t].add(1.0), B), fn(x, B.at[0, t].add(1.0))):
+        assert np.array_equal(np.asarray(moved[0, :t]), np.asarray(base[0, :t]))
+        late = np.abs(np.asarray(moved[0, 256:]) - np.asarray(base[0, 256:]))
+        assert float(late.max()) > 1e-4
+    close(base, plain(x, dt, A, B, C, D), 5e-5, "value")
+    # the last chunk's cotangent alone: what reaches x in the first chunk went
+    # through the state's gradient carried back over a whole chunk
+    late_cot = cot.at[:, :256].set(0.0)
+    dx = [jax.jit(jax.grad(lambda x: jnp.sum(fn_(x, dt, A, B, C, D) * late_cot)))(x)
+          for fn_ in (lambda *a: ssd(*a, 128), plain)]
+    assert float(jnp.max(jnp.abs(dx[0][0, :128]))) > 1e-4
+    close(dx[0], dx[1], 5e-5, "gradient across chunks")
+
+
+@pytest.mark.parametrize("shape,takes", [
+    ((4096, 64, 64, 8, 128, 128), True),    # nemotron-twotower-30b-a3b.silo2t4k-ssm's training step
+    ((64, 64, 64, 8, 128, 128), False),     # its 64-token evaluation: one chunk of 64
+    ((4096, 64, 64, 8, 128, 64), False),    # another chunk
+    ((4160, 64, 64, 8, 128, 128), False),   # no whole number of chunks
+    ((4096, 64, 64, 8, 64, 128), False),    # a state under a lane tile
+    ((4096, 6, 48, 2, 128, 128), False),    # a group's heads (3 of 48) no whole lane tiles
+    ((4096, 8, 8, 2, 16, 128), False),      # the tests' and rehearsals' small widths
+    ((4096, 64, 64, 6, 128, 128), False),   # groups that do not divide the heads
+    ((32768, 64, 64, 8, 128, 128), False),  # ssd_bwd's chunk states over their fast memory
+    ((256, 4, 64, 2, 128, 128), True),      # the interpreted cases above
+    ((384, 4, 128, 4, 128, 128), True),
+])
+def test_the_kernels_take_whole_chunks_of_128_in_whole_lane_tiles(shape, takes):
+    assert op.takes_kernel(*shape) is takes
+    T, H, P, G, N, chunk = shape
+    if T <= 4096 and H % G == 0:
+        operands, _ = case((1,), T, H, P, G, N, jnp.bfloat16)
+        jaxpr = str(jax.make_jaxpr(lambda *a: ssd(*a, chunk))(*operands))
+        assert ("pallas_call" in jaxpr) is takes
 
 
 def test_shapes_that_do_not_belong_together_are_refused():

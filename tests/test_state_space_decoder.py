@@ -24,6 +24,7 @@ import pytest
 from fedml_tpu.models import create_model
 from fedml_tpu.models.decoder import (
     BIAS_COUNTER, COUNTERS, PARTS, DecoderLayer, MambaSpec, routed_experts)
+from fedml_tpu.ops import ssd
 from fedml_tpu.ops.attention import takes_kernel
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
@@ -52,6 +53,7 @@ VOCAB, LENGTH = 61, 40
 KERNEL_SPEC = dict(SPEC, hidden_size=64, head_dim=128, num_attention_heads=2,
                    num_key_value_heads=1, hybrid_override_pattern="M*", chunk_size=128)
 KERNEL_LENGTH = 256
+SCAN_KERNEL_SPEC = dict(KERNEL_SPEC, mamba_num_heads=4, mamba_head_dim=64, ssm_state_size=128)
 
 
 def reference():
@@ -95,6 +97,8 @@ ROUTES = {
              experts_held=None), LENGTH, 2, False),
     "under_the_chunk_and_the_filter": (SPEC, 3, 4, False),
     "kernel_route_interpreted": (KERNEL_SPEC, KERNEL_LENGTH, 1, True),
+    # the scan's kernels too: 4 state-space heads of 64 in 2 groups, state 128
+    "scan_kernels_interpreted": (SCAN_KERNEL_SPEC, KERNEL_LENGTH, 1, True),
 }
 
 
@@ -113,6 +117,9 @@ def test_loss_and_every_gradient_match_the_reference(route):
     model = build(spec, length)
     assert len(model.attention_sites) == spec["hybrid_override_pattern"].count("*")
     assert all(takes_kernel(length, *site) is kernel for site in model.attention_sites)
+    scan = model.counter_attrs
+    assert ssd.takes_kernel(length, *(scan["ssm_" + k] for k in (
+        "heads", "head_dim", "groups", "state", "chunk"))) is (spec is SCAN_KERNEL_SPEC)
     flat = ref.init_params(5, cfg)
     have = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     assert set(have) == {"params"}
@@ -463,6 +470,8 @@ def test_a_federated_round_is_the_same_under_vmap_and_scan_and_keeps_the_bias():
                                "ssm_groups", "ssm_chunk")] == [2, 8, 8, 16, 2, 16]
         assert (a["attn_sites"], a["attn_kernel_sites"]) == (1, 0)
         assert "rope_sites" not in a and "rope_kernel_sites" not in a and "conv_layers" not in a
+        # two scans a step, at widths and chunks the scan's kernels do not take
+        assert (a["ssd_sites"], a["ssd_kernel_sites"]) == (2, 0)
         assert a["layers"] == a["expert_layers"] == 2 and a["expert_products"] == 2
         # up and down, each with its two gradients, under a lane tile's width
         assert (a["moe_grouped_sites"], a["moe_kernel_sites"]) == (12, 0)

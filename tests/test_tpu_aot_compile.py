@@ -228,27 +228,54 @@ def test_expert_layer_at_mellum_share_compiles_for_v5e_over_bounded_rows(one_chi
     assert compiled.memory_analysis().temp_size_in_bytes < 0.45 * 2**30
 
 
-def test_state_space_scan_fwd_bwd_compiles_for_v5e_within_its_plan(one_chip):
+def _scan_compiled(one_chip, clients):
     """``ops/ssd.ssd`` forward + gradient at nemotron-twotower-30b-a3b
-    .silo2t4k-ssm's training step: one document of 4 096 positions, 64 heads
-    of 64, 8 groups, state 128, chunks of 128. Plain ``jax.numpy`` that XLA
-    lowers (no custom call of ours); what this guards is the plan: the decay
-    matrix [32, 64, 128, 128] float32 is 134 MB and autodiff of the recomputed
-    forward holds several arrays of that size, and the cell's round program
-    has a fifth of a GiB to spare. 0.30 GiB when written (PR 38), about 7 s."""
-    from fedml_tpu.ops.ssd import ssd
+    .silo2t4k-ssm's training step (one document of 4 096 positions, 64 heads
+    of 64, 8 groups, state 128, chunks of 128), for ``clients`` under a
+    client vmap or none, compiled with the kernels."""
+    from fedml_tpu.ops import ssd as op
 
     T, H, P, G, N = 4096, 64, 64, 8, 128
+    assert op.takes_kernel(T, H, P, G, N, 128)
 
     def loss(x, dt, A, B, C, D):
-        return jnp.sum(ssd(x, dt, A, B, C, D, 128).astype(jnp.float32))
+        scan = lambda *a: op.ssd(*a, 128)
+        if clients:
+            scan = jax.vmap(scan)
+        return jnp.sum(scan(x, dt, A, B, C, D).astype(jnp.float32))
 
+    lead = (clients,) if clients else ()
     shapes = [((1, T, H, P), jnp.bfloat16), ((1, T, H), jnp.float32), ((H,), jnp.float32),
               ((1, T, G, N), jnp.bfloat16), ((1, T, G, N), jnp.bfloat16), ((H,), jnp.float32)]
-    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
-    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(*args).compile()
-    assert "tpu_custom_call" not in compiled.as_text()
-    assert compiled.memory_analysis().temp_size_in_bytes < 0.45 * 2**30
+    args = [jax.ShapeDtypeStruct(lead + s, d, sharding=one_chip) for s, d in shapes]
+    saved, op._use_interpret = op._use_interpret, lambda: False
+    try:
+        return jax.jit(jax.value_and_grad(loss, argnums=tuple(range(6)))).lower(*args).compile()
+    finally:
+        op._use_interpret = saved
+
+
+def test_state_space_scan_fwd_bwd_compiles_for_v5e_within_its_plan(one_chip):
+    """The scan goes to its two kernels (``ssd_fwd``, ``ssd_bwd``: the
+    broadcasts, transposes and lane selects, and ``ssd_bwd``'s 8 MiB of chunk
+    states within the VMEM it asks for), and its plan is the kernels' blocks
+    and the small float32 arrays of the decays around them: 0.04 GiB, where
+    the chunked products that XLA lowers (decay matrix [32, 64, 128, 128]
+    float32, 134 MB a layer) planned 0.30 GiB for autodiff of their recomputed
+    forward. About 2 s."""
+    compiled = _scan_compiled(one_chip, 0)
+    text = compiled.as_text()
+    assert "ssd_fwd" in text and "ssd_bwd" in text and text.count("tpu_custom_call") == 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.1 * 2**30
+
+
+def test_state_space_scan_kernels_compile_for_v5e_under_a_client_vmap(one_chip):
+    """The same under the ``vmap`` client schedule, each client with its own
+    A and D: the batched kernels take the clients as a grid axis of their own."""
+    compiled = _scan_compiled(one_chip, 2)
+    text = compiled.as_text()
+    assert "ssd_fwd" in text and "ssd_bwd" in text and text.count("tpu_custom_call") == 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.2 * 2**30
 
 
 def test_femnist_cnn_round_program_compiles_for_one_v5e_chip(one_chip):
