@@ -713,6 +713,50 @@ def _rotary_check(ctx):
     return {"cases": {k: list(v[0]) for k, v in cases.items()}}, asserted
 
 
+def _slot_sum_check(ctx):
+    """ops/slot_sum.slot_sum at two expert cells' training steps (top-8 and
+    top-6 slots), over a bfloat16 table of the row bound and readers as the
+    routing's sort makes them (a held pair's row, odd or even; every other
+    slot empty): weighted with float32 weights and unweighted rounded to
+    bfloat16, equal to ``sum_readers`` to the last bit through the kernel."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from fedml_tpu.models.decoder import row_bound, sum_readers
+    from fedml_tpu.ops import slot_sum as op
+
+    # tokens, top_k, width, experts, held
+    cases = {"mellum2_silo2": (4096, 8, 2304, 64, 8), "kanana2_silo2b1": (2048, 6, 2048, 128, 8)}
+    if ctx.rehearse:
+        cases = {"top_8": (256, 8, 128, 64, 8), "top_6": (128, 6, 128, 32, 4)}
+    asserted = []
+    for name, (N, top_k, d, experts, held) in cases.items():
+        R = row_bound(N * top_k, held, experts)
+        k1, k2, k3 = jax.random.split(jax.random.PRNGKey(ctx.seed + 4), 3)
+        expert = jax.random.randint(k1, (N * top_k,), 0, experts)
+        inverse = jnp.argsort(jnp.argsort(jnp.minimum(expert, held), stable=True))
+        pairs = jnp.sum(expert < held)
+        readers = jnp.where(inverse < jnp.minimum(pairs, R), inverse, R).reshape(N, top_k)
+        table = jax.random.normal(k2, (R, d), jnp.bfloat16)
+        weights = jax.random.uniform(k3, (N, top_k))
+        if ctx.rehearse:
+            # the CPU contracts the interpreted multiply and add: exact products
+            weights = weights.astype(jnp.bfloat16).astype(jnp.float32)
+        asserted.append(check(op.takes_kernel(N, top_k, d, R) or ctx.rehearse,
+                              f"slot_sum {name} [{N}, {top_k}] over {R} rows takes the kernel"))
+        for part, ours, plain in (
+                ("weighted", jax.jit(op.slot_sum)(table, readers, weights),
+                 jax.jit(sum_readers)(table, readers, weights)),
+                ("unweighted", jax.jit(lambda t, r: op.slot_sum(t, r, out_dtype=t.dtype))(table, readers),
+                 jax.jit(lambda t, r: sum_readers(t, r).astype(t.dtype))(table, readers))):
+            asserted.append(check(
+                np.array_equal(np.asarray(ours.astype(jnp.float32)).view(np.uint32),
+                               np.asarray(plain.astype(jnp.float32)).view(np.uint32)),
+                f"slot_sum {name} {part} ({int(pairs)} live slots) bit-equal to sum_readers"))
+    return {"cases": {k: list(v) for k, v in cases.items()}}, asserted
+
+
 def phase_kernels(ctx):
     """The Pallas kernels, compiled, against their references — alone and
     (robust stats) through the normal CLI path — and the written-out pool
@@ -723,6 +767,8 @@ def phase_kernels(ctx):
     pool, more = _pool_check(ctx)
     asserted += more
     rotary, more = _rotary_check(ctx)
+    asserted += more
+    slots, more = _slot_sum_check(ctx)
     asserted += more
 
     api, rows, _ = run_cli(
@@ -743,7 +789,7 @@ def phase_kernels(ctx):
             "robust round (aggregation) program contains tpu_custom_call",
         ))
     return {"asserted": asserted, "flash": flash, "robust_stats": robust, "max_pool": pool,
-            "rotary": rotary}
+            "rotary": rotary, "slot_sum": slots}
 
 
 def phase_multichip(ctx):

@@ -474,6 +474,14 @@ class FedAvgAPI:
             self._site_attrs.update(
                 moe_kernel_sites=3 * sum(grouped_matmul.takes_kernel(*site) for site in sites),
                 moe_grouped_sites=3 * len(sites))
+        if model.slot_sites is not None:
+            # the token side's two sums a layer, at the same tokens
+            from fedml_tpu.ops import slot_sum
+
+            sites = model.slot_sites(self._step_batch() * model.input_shape[0])
+            self._site_attrs.update(
+                moe_slot_kernel_sites=sum(slot_sum.takes_kernel(*site) for site in sites),
+                moe_slot_sites=len(sites))
         ssm = model.counter_attrs
         if "ssm_layers" in ssm:
             # one scan a state-space layer, at the training length

@@ -59,6 +59,12 @@ class ModelDef:
     # pass: the arguments ``ops/grouped_matmul.takes_kernel`` decides the
     # three from. None for a model without routed experts.
     grouped_sites: Optional[Callable[[int], Tuple[Tuple[int, int, int, int], ...]]] = None
+    # From the tokens a step trains on, one (N, top_k, d, R) per sum over a
+    # token's slots that a step runs outside the overflow loops (the forward
+    # of ``models/decoder.weighted_rows``, the backward of ``take_rows``):
+    # the arguments ``ops/slot_sum.takes_kernel`` decides each from. None for
+    # a model without routed experts.
+    slot_sites: Optional[Callable[[int], Tuple[Tuple[int, int, int, int], ...]]] = None
 
     def init(self, rng) -> dict:
         dummy = jnp.zeros((1,) + tuple(self.input_shape), dtype=self.input_dtype)

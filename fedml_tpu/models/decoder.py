@@ -173,7 +173,7 @@ import jax
 import jax.numpy as jnp
 from jax.custom_batching import custom_vmap
 
-from fedml_tpu.ops import grouped_matmul
+from fedml_tpu.ops import grouped_matmul, slot_sum
 from fedml_tpu.ops.attention import attention
 from fedml_tpu.ops.rotary import rotary
 from fedml_tpu.ops.short_conv import gated_short_conv, silu_short_conv
@@ -264,6 +264,25 @@ def apply_rotary_pairs(x, cos, sin):
     return (x32 * cos[None, :, None, :] + partner * sin[None, :, None, :]).astype(x.dtype)
 
 
+def _any_batched(fn):
+    """``fn`` with a vmap rule of its own: one call per member of the batch
+    (the clients of a round; a static handful), each on its own slice.
+    ``ragged_dot``'s rule wants every argument batched at dim 0, which the
+    first pass over a scan's body under the clients' vmap does not give
+    (the weights are not batched yet), the TPU compiler's grouped matmul
+    takes no batch dimension at all, and ``ops/slot_sum.py``'s kernel loads
+    its table by hand."""
+    wrapped = custom_vmap(fn)
+
+    @wrapped.def_vmap
+    def rule(axis_size, in_batched, *args):
+        outs = [fn(*(a[i] if b else a for a, b in zip(args, in_batched)))
+                for i in range(axis_size)]
+        return jnp.stack(outs), True
+
+    return wrapped
+
+
 def sum_readers(table, readers, weights=None):
     """Row n of the result is the float32 sum over k of
     ``table[readers[n, k]]`` (times ``weights[n, k]``); a reader of
@@ -284,13 +303,40 @@ def sum_readers(table, readers, weights=None):
     return acc
 
 
+_kernel_slots = _any_batched(
+    lambda table, readers: slot_sum.slot_sum(table, readers, out_dtype=table.dtype))
+_kernel_slots_weighted = _any_batched(
+    lambda table, readers, weights: slot_sum.slot_sum(table, readers, weights))
+
+
+def sum_slots(table, readers, weights=None):
+    """:func:`sum_readers`'s sums, in float32 where weighted (the sum of a
+    token's slots, which the overflow's chunks add to in float32) and
+    rounded once to the table's dtype where not (the dispatch gather's
+    backward): by ``ops/slot_sum.py``'s Pallas kernel, which reads only the
+    rows of live slots, where its ``takes_kernel`` holds for the shapes
+    (6 slots a token or more, a table of at most three eighths as many rows
+    as slots: the expert cells' training steps but
+    ``lfm2-8b-a1b.silo2t4k``'s), by ``sum_readers`` elsewhere."""
+    N, top_k = readers.shape
+    R, d = table.shape
+    if not slot_sum.takes_kernel(N, top_k, d, R):
+        total = sum_readers(table, readers, weights)
+        return total if weights is not None else total.astype(table.dtype)
+    if weights is None:
+        return _kernel_slots(table, readers)
+    return _kernel_slots_weighted(table, readers, weights)
+
+
 @jax.custom_vjp
 def take_rows(x, idx, readers):
     """``x[idx]`` where the rows of the result that read row ``r`` of ``x``
-    are exactly ``readers[r]``, a fixed number of places each, the places
-    that hold no reader filled with ``len(idx)`` (out of bounds, read as
-    zero): the backward pass is then gathers too (:func:`sum_readers`), not
-    a scatter-add."""
+    and whose cotangent may be non-zero are exactly ``readers[r]``, a fixed
+    number of places each, the places that hold no reader filled with
+    ``len(idx)`` (out of bounds, read as zero): the backward pass is then
+    gathers too (:func:`sum_slots`), not a scatter-add. A row that no
+    reader names must have a zero cotangent (``_held_rows`` clears those
+    rows)."""
     return x[idx]
 
 
@@ -299,7 +345,7 @@ def _take_rows_fwd(x, idx, readers):
 
 
 def _take_rows_bwd(readers, g):
-    return sum_readers(g, readers).astype(g.dtype), None, None
+    return sum_slots(g, readers), None, None
 
 
 take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
@@ -308,10 +354,10 @@ take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 @jax.custom_vjp
 def weighted_rows(ys, top_w, readers, slot):
     """Token n's sum of ``top_w[n, k] * ys[readers[n, k]]`` over its slots,
-    in float32 (:func:`sum_readers`). ``slot`` [len(ys)] is the flat
+    in float32 (:func:`sum_slots`). ``slot`` [len(ys)] is the flat
     (token, slot) place that reads each row, so the backward pass runs over
     the rows of ``ys``, not over tokens x top-k."""
-    return sum_readers(ys, readers, top_w)
+    return sum_slots(ys, readers, top_w)
 
 
 def _weighted_rows_fwd(ys, top_w, readers, slot):
@@ -337,24 +383,6 @@ _TO_WEIGHTS = jax.lax.RaggedDotDimensionNumbers(
     dot_dimension_numbers=(([0], [0]), ([], [])),
     lhs_ragged_dimensions=[0], rhs_group_dimensions=[],
 )
-
-
-def _any_batched(fn):
-    """``fn`` with a vmap rule of its own: one call per member of the batch
-    (the clients of a round; a static handful), each on its own slice.
-    ``ragged_dot``'s rule wants every argument batched at dim 0, which the
-    first pass over a scan's body under the clients' vmap does not give
-    (the weights are not batched yet), and the TPU compiler's grouped
-    matmul takes no batch dimension at all."""
-    wrapped = custom_vmap(fn)
-
-    @wrapped.def_vmap
-    def rule(axis_size, in_batched, *args):
-        outs = [fn(*(a[i] if b else a for a, b in zip(args, in_batched)))
-                for i in range(axis_size)]
-        return jnp.stack(outs), True
-
-    return wrapped
 
 
 _rows_by_group = _any_batched(
@@ -458,8 +486,12 @@ def _held_rows(c, x, top_w, *rest, bound):
         # leave whatever they find in the rows after them (on the chip: not
         # zeros), so every result is cleared there
         live = (jnp.arange(bound) < ends[-1] - start)[:, None]
+        # a slot reads its row only where the row is live: the bound's other
+        # rows hold pairs of experts on other chips, which read zero (a
+        # cleared row forward, a cleared row's zero cotangent backward), so
+        # the token-side sums need not visit them
         at = inverse - start
-        readers = jnp.where((at >= 0) & (at < bound), at, bound)
+        readers = jnp.where((at >= 0) & (at < jnp.minimum(bound, ends[-1] - start)), at, bound)
         xs = take_rows(x, slot // top_k, readers)
         xs = jnp.where(live, xs, jnp.zeros((), xs.dtype))
     with jax.named_scope("experts"):
@@ -565,9 +597,11 @@ def routed_experts(x, router, w_gate, w_up, w_down, bias=None, *, top_k: int,
     Where every expert is held R is N*top_k, those bytes are
     ``N * top_k * (2 d + 3 f) * itemsize``, and there is neither a loop nor
     a ``custom_vjp`` around the pass in the program.
-    Two passes stay indexed by token, N x top_k row reads each: the sum of
-    a token's slots (:func:`weighted_rows`) and the backward of the
-    dispatch gather (:func:`take_rows`), both :func:`sum_readers`.
+    Two passes are indexed by token: the sum of a token's slots
+    (:func:`weighted_rows`) and the backward of the dispatch gather
+    (:func:`take_rows`), both :func:`sum_slots`, which reads only the rows
+    of live slots where ``ops/slot_sum.takes_kernel`` holds and N x top_k
+    rows (:func:`sum_readers`) elsewhere.
 
     Under a ``vmap`` (the clients of a round) the loop's trip count is
     batched, and JAX runs as many trips as the member with the most pairs
@@ -720,6 +754,14 @@ class ExpertSpec:
         rows = row_bound(tokens * self.top_k, held, self.experts)
         return ((rows, hidden, self.width, held),) * (self.products() - 1) + (
             (rows, self.width, hidden, held),)
+
+    def slot_sites(self, hidden: int, tokens: int) -> Tuple[Tuple[int, int, int, int], ...]:
+        """``ModelDef.slot_sites`` of one layer over ``tokens``: the sum of a
+        token's slots and the backward of the dispatch gather, each over the
+        bounded rows."""
+        held = self.held[1] - self.held[0]
+        rows = row_bound(tokens * self.top_k, held, self.experts)
+        return ((tokens, self.top_k, hidden, rows),) * 2
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1337,6 +1379,12 @@ class DecoderLM(nn.Module):
         in a step of ``tokens``."""
         return tuple(site for ffn in self.feed_forwards() if isinstance(ffn, ExpertSpec)
                      for site in ffn.grouped_sites(self.hidden_size, tokens))
+
+    def slot_sites(self, tokens: int) -> Tuple[Tuple[int, int, int, int], ...]:
+        """``ModelDef.slot_sites``: the expert layers' token-side sums in a
+        step of ``tokens``."""
+        return tuple(site for ffn in self.feed_forwards() if isinstance(ffn, ExpertSpec)
+                     for site in ffn.slot_sites(self.hidden_size, tokens))
 
     @nn.compact
     def __call__(self, tokens, train: bool = False):

@@ -140,6 +140,7 @@ def create(
             attention_sites=m.attention_sites(),
             rope_sites=m.rope_sites(),
             grouped_sites=m.grouped_sites if routed else None,
+            slot_sites=m.slot_sites if routed else None,
         )
 
     if name in ("resnet56", "resnet110"):

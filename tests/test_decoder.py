@@ -229,7 +229,8 @@ def grouped_products(jaxpr, in_loop=False, found=None):
     """Grouped products of a jaxpr and of every jaxpr inside it, those beneath
     a ``while`` or ``scan`` apart, and the loops themselves: ``ragged_dot*``
     equations and ``ops/grouped_matmul``'s ``pallas_call``s (``gmm_*``),
-    which ``kernels`` counts again."""
+    which ``kernels`` counts again. A kernel's body is not the program's:
+    its own loops are not counted."""
     found = ({"outside": 0, "in_loops": 0, "loops": 0, "kernels": 0}
              if found is None else found)
     for eqn in jaxpr.eqns:
@@ -240,6 +241,8 @@ def grouped_products(jaxpr, in_loop=False, found=None):
         found["kernels"] += kernel
         if name.startswith("ragged_dot") or kernel:
             found["in_loops" if in_loop else "outside"] += 1
+        if name == "pallas_call":
+            continue
         for inner in jax.tree_util.tree_leaves(
                 list(eqn.params.values()), is_leaf=lambda v: hasattr(v, "eqns") or hasattr(v, "jaxpr")):
             inner = getattr(inner, "jaxpr", inner)

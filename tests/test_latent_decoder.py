@@ -399,18 +399,23 @@ def test_the_sites_are_what_the_traced_layers_hand_the_attention_core(monkeypatc
 # program: its three scans at 4 096 positions go to ``ssd_fwd`` / ``ssd_bwd``
 # under a ``jax.custom_vjp`` (at the parent d2b87b9 it read eaa14cfb8b04d242);
 # the rehearsal's chunks of 16 keep the chunked products and its pin holds,
-# as do the other seven.
+# as do the other seven. The token-side sums meant to change every program
+# with routed experts but GPT-2's: a slot reads its sorted row only where the
+# row holds a held pair, and Mellum's 4 096 tokens a step take
+# ``ops/slot_sum``'s kernel (at the parent 7ed97b7 the six read
+# 7807aa87a686398f, 2ef7c050eeac5d0a, ca0b95984ccd4fb7, e7137c8985465d0e,
+# 37291c2624a2a267 and 23cb7c783d5d8684); both GPT-2 pins hold.
 PINS = {
     "attention.silo4": "940131b509805ea9",
     "attention.silo2": "50842107702d88df",
-    "mellum2-12b-a2.5b.full": "7807aa87a686398f",
-    "mellum2-12b-a2.5b.rehearse": "2ef7c050eeac5d0a",
+    "mellum2-12b-a2.5b.full": "047105c6ae2b0a8e",
+    "mellum2-12b-a2.5b.rehearse": "e5325920fefbc11d",
     "gpt2-124m.full": "28bcf5bfcdd2a422",
     "gpt2-124m.rehearse": "8ce4b5786b6eb411",
-    "nemotron-twotower-30b-a3b.full": "ca0b95984ccd4fb7",
-    "nemotron-twotower-30b-a3b.rehearse": "e7137c8985465d0e",
-    "laguna-xs.2.full": "37291c2624a2a267",
-    "laguna-xs.2.rehearse": "23cb7c783d5d8684",
+    "nemotron-twotower-30b-a3b.full": "0c922e0526eee6ac",
+    "nemotron-twotower-30b-a3b.rehearse": "897a09af8bb81a0f",
+    "laguna-xs.2.full": "2bd3a81119a8c809",
+    "laguna-xs.2.rehearse": "8fa8ca783b6b41b4",
 }
 ATTENTION_STEPS = {"attention.silo4": ((4, 1024, 12, 12, 64), None),
                    "attention.silo2": ((2, 2048, 32, 4, 128), 1024)}

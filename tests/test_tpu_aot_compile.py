@@ -190,11 +190,13 @@ def test_expert_layer_at_mellum_share_compiles_for_v5e_over_bounded_rows(one_chi
     backward; and the pass every step runs keeps its rows, so nine grouped
     kernels lie outside the loops (twelve with the forward run again): since
     PR 39 ``ops/grouped_matmul``'s ``gmm_fwd``, ``gmm_dx`` and ``gmm_dw``,
-    and no ``ragged_dot`` is left."""
+    and no ``ragged_dot`` is left. The token side's two sums outside the
+    loops are ``ops/slot_sum``'s kernel."""
     import re
 
     from fedml_tpu.models.decoder import routed_experts, row_bound
     from fedml_tpu.ops import grouped_matmul as op
+    from fedml_tpu.ops import slot_sum
 
     N, d, f, held, experts, top_k = 4096, 2304, 896, 8, 64, 8
     assert row_bound(N * top_k, held, experts) == 8192
@@ -209,12 +211,13 @@ def test_expert_layer_at_mellum_share_compiles_for_v5e_over_bounded_rows(one_chi
     # at these shapes (tests/test_decoder.py makes one) must not be compiled
     # for the chip, nor this one run on the CPU after it
     jax.clear_caches()
-    saved, op._use_interpret = op._use_interpret, lambda: False
+    saved = op._use_interpret, slot_sum._use_interpret
+    op._use_interpret = slot_sum._use_interpret = lambda: False
     try:
         compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True)).lower(
             *args).compile()
     finally:
-        op._use_interpret = saved
+        op._use_interpret, slot_sum._use_interpret = saved
         jax.clear_caches()
     text = compiled.as_text()
     assert "[8192,2304]" in text and "[32768,2304]" not in text
@@ -222,10 +225,52 @@ def test_expert_layer_at_mellum_share_compiles_for_v5e_over_bounded_rows(one_chi
     entry = text[text.index("\nENTRY "):]
     kernels = re.findall(r'custom_call_target="tpu_custom_call".*op_name="[^"]*/(gmm_\w+)/', entry)
     assert sorted(kernels) == ["gmm_dw"] * 3 + ["gmm_dx"] * 3 + ["gmm_fwd"] * 3
+    # the weighted sum forward and the dispatch gather's backward
+    assert len(re.findall(r'custom_call_target="tpu_custom_call".*op_name="[^"]*/slot_sum/',
+                          entry)) == 2
     # the full-row program planned 0.547 GiB for this layer, the bounded rows
     # 0.33, kept or recomputed (one layer alone holds its rows either way);
     # 0.24 with the kernels, which copy no weights transposed
     assert compiled.memory_analysis().temp_size_in_bytes < 0.45 * 2**30
+
+
+# cell -> (tokens, top_k, width, held experts, experts) of a training step;
+# evaluation routes 16 384 tokens
+SLOT_CELLS = {
+    "mellum2-12b-a2.5b.silo2": (4096, 8, 2304, 8, 64),
+    "lfm2-8b-a1b.silo2t4k": (4096, 4, 2048, 8, 32),
+    "kanana-2-30b-a3b.silo2b1": (2048, 6, 2048, 8, 128),
+    "nemotron-twotower-30b-a3b.silo2t4k-ssm": (4096, 6, 2688, 8, 128),
+    "laguna-xs.2.silo2t4k-swa": (4096, 8, 2048, 16, 256),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(SLOT_CELLS))
+def test_slot_sum_compiles_for_v5e_at_the_expert_cells_shapes(one_chip, cell):
+    """``ops/slot_sum``'s kernel, weighted and not, over a bfloat16 table of
+    the bound's rows at each expert cell's training step: the table in VMEM,
+    its rows loaded one at a time, the readers and weights in SMEM; at
+    ``lfm2-8b-a1b.silo2t4k``'s shape too, which the rule sends to
+    ``sum_readers`` (4 slots a token, the table's rows half its slots). The
+    evaluation's tables (16 384 tokens) outgrow the kernel's VMEM and keep
+    ``sum_readers``."""
+    from fedml_tpu.models.decoder import row_bound
+    from fedml_tpu.ops import slot_sum as op
+
+    N, top_k, d, held, experts = SLOT_CELLS[cell]
+    R = row_bound(N * top_k, held, experts)
+    assert op.takes_kernel(N, top_k, d, R) is (top_k >= 6)
+    assert not op.takes_kernel(16384, top_k, d, row_bound(16384 * top_k, held, experts))
+    table = jax.ShapeDtypeStruct((R, d), jnp.bfloat16, sharding=one_chip)
+    readers = jax.ShapeDtypeStruct((N, top_k), jnp.int32, sharding=one_chip)
+    weights = jax.ShapeDtypeStruct((N, top_k), jnp.float32, sharding=one_chip)
+    saved, op._use_interpret = op._use_interpret, lambda: False
+    try:
+        for args in ((table, readers, weights), (table, readers)):
+            text = jax.jit(op.slot_sum).lower(*args).compile().as_text()
+            assert text.count("tpu_custom_call") == 1 and " while(" not in text
+    finally:
+        op._use_interpret = saved
 
 
 def _scan_compiled(one_chip, clients):
