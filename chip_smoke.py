@@ -52,7 +52,7 @@ NORTH_STAR = [
     "--epochs", "1", "--comm_round", "5",
 ]
 
-# The flagship LM (bench.py _flagship_bf16): d768 / L6 / H8, vocab 1024,
+# The flagship LM: d768 / L6 / H8, vocab 1024,
 # seq 256, batch 32, 8 clients x 512 sequences, bf16, Adam clients.
 FLAGSHIP = dict(
     vocab=1024, seq=256, layers=6, heads=8, dim=768, clients=8,
@@ -495,7 +495,8 @@ def _decoder_round(ctx, m, kind):
     flushes = [e.attrs for e in tracer.events()
                if e.name == "flush" and e.ts_us >= t0 and "moe_pairs" in e.attrs]
     moe = {k: sum(a[k] for a in flushes) for k in model.counters if k != "moe_dropped"}
-    layers, top_k = model.counter_attrs["expert_layers"], model.counter_attrs["top_k"]
+    consts = model.flush_attrs(m["batch"])
+    layers, top_k = consts["expert_layers"], consts["top_k"]
     held = model.module.held()[1] - model.module.held()[0]
     tokens = 2 * m["clients"] * m["samples"] * m["seq"]
     per_token = moe["moe_pairs"] / (tokens * layers)

@@ -11,7 +11,7 @@ cd "$(dirname "$0")/.."
 # syntax gate only — pyflakes isn't in this image; the ref's pyflakes gate
 # (CI-script-*.sh:6) additionally catches undefined names/unused imports
 echo "== syntax gate =="
-python -m compileall -q fedml_tpu tests bench.py __graft_entry__.py
+python -m compileall -q fedml_tpu tests __graft_entry__.py
 
 # fedlint JIT-hazard gate (docs/ANALYSIS.md) — stdlib-only, runs before
 # jax starts: zero unsuppressed findings or the gate is red

@@ -450,9 +450,6 @@ class DittoAPI(FedAvgAPI):
     def _build_round_fn(self, local_train_fn):
         return None  # unused — train_round is fully overridden
 
-    def round_flops(self, round_idx: int = 0):
-        return None  # bespoke round fn; XLA cost analysis not wired
-
     def checkpoint_state(self):
         """Personal models are round state — a resume that dropped them
         would silently reset every client's personalization. Spilled-

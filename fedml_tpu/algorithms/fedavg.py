@@ -70,11 +70,10 @@ def resolve_client_parallelism(mode: str, model: ModelDef) -> str:
     """Resolve FedConfig.client_parallelism="auto" for a model.
 
     "scan" wins when per-client weights make vmap's convs grouped convs
-    whose small channel dims tile the 128-lane MXU badly (measured on v5e,
-    examples/probe_resnet_bf16.py: cross-silo
-    ResNet-56 bf16 round 350 -> 190 ms under scan; the flagship femnist
-    CNN is a wash, 34.0 -> 33.1 ms, because its dense head runs at the
-    same tiny per-client M either way). Models without under-tiled convs
+    whose small channel dims tile the 128-lane MXU badly (measured on v5e:
+    cross-silo ResNet-56 bf16 round 350 -> 190 ms under scan; the flagship
+    femnist CNN is a wash, 34.0 -> 33.1 ms, because its dense head runs at
+    the same tiny per-client M either way). Models without under-tiled convs
     or with sub-MB param copies keep "vmap": their per-step time is
     overhead-dominated and one big program wins. The heuristic: any 4-D
     conv kernel with <= 64 output channels (under-tiled on the MXU) and a
@@ -434,64 +433,9 @@ class FedAvgAPI:
         # shared round wall time — participation/last-seen stay exact, and
         # the transport runtimes refine timing per client.
         self.health = ClientHealthRegistry.from_config(config)
-        # How many of the round program's attention call sites take the
-        # blockwise kernel at the training length, and all of them: host
-        # numbers from the shapes alone, carried by every ``flush`` span. The
-        # sites of latent attention add what their core's FLOPs follow from;
-        # the calls of the rotate-half operator and the routed experts'
-        # grouped products are counted the same way.
-        self._site_attrs = {}
-        if model.attention_sites:
-            # imported here: a model without attention pays no Pallas import
-            from fedml_tpu.ops.attention import takes_kernel
-
-            self._site_attrs = {
-                "attn_kernel_sites": sum(
-                    takes_kernel(model.input_shape[0], *site)
-                    for site in model.attention_sites),
-                "attn_sites": len(model.attention_sites),
-            }
-            latent = [site for site in model.attention_sites if len(site) == 5]
-            if latent:
-                heads, _, nope, rope, values = latent[0]
-                self._site_attrs.update(
-                    attn_qk_width=nope + rope, attn_v_width=values, attn_heads=heads,
-                    attn_length=model.input_shape[0], attn_layers=len(latent))
-            if model.rope_sites:
-                from fedml_tpu.ops import rotary
-
-                self._site_attrs.update(
-                    rope_kernel_sites=sum(
-                        rotary.takes_kernel(model.input_shape[0], *site)
-                        for site in model.rope_sites),
-                    rope_sites=len(model.rope_sites))
-        if model.grouped_sites is not None:
-            # at the tokens of a training step; three products a site, which
-            # take one path
-            from fedml_tpu.ops import grouped_matmul
-
-            sites = model.grouped_sites(self._step_batch() * model.input_shape[0])
-            self._site_attrs.update(
-                moe_kernel_sites=3 * sum(grouped_matmul.takes_kernel(*site) for site in sites),
-                moe_grouped_sites=3 * len(sites))
-        if model.slot_sites is not None:
-            # the token side's two sums a layer, at the same tokens
-            from fedml_tpu.ops import slot_sum
-
-            sites = model.slot_sites(self._step_batch() * model.input_shape[0])
-            self._site_attrs.update(
-                moe_slot_kernel_sites=sum(slot_sum.takes_kernel(*site) for site in sites),
-                moe_slot_sites=len(sites))
-        ssm = model.counter_attrs
-        if "ssm_layers" in ssm:
-            # one scan a state-space layer, at the training length
-            from fedml_tpu.ops import ssd
-
-            takes = ssd.takes_kernel(
-                model.input_shape[0], ssm["ssm_heads"], ssm["ssm_head_dim"], ssm["ssm_groups"],
-                ssm["ssm_state"], ssm["ssm_chunk"])
-            self._site_attrs.update(ssd_kernel_sites=ssm["ssm_layers"] * takes,
-                                    ssd_sites=ssm["ssm_layers"])
+        # the model's host constants that every ``flush`` span carries, at
+        # the samples of a local step (ModelDef.flush_attrs)
+        self._flush_attrs = model.flush_attrs(self._step_batch())
         # Scheduler: policy-driven cohort selection (FedConfig.selection /
         # .overprovision_factor, scheduler/policies.py). It shares this
         # API's health registry (straggler_aware consults the straggler
@@ -877,15 +821,6 @@ class FedAvgAPI:
             fn = fn.variant_for(self._round_may_pad(round_idx))
         return fn, (self.global_vars, *self._place_batch(batch, rng))
 
-    def round_flops(self, round_idx: int = 0):
-        """XLA-costed FLOPs of one round call at this round's batch shapes
-        (None if the backend exposes no cost model). Lowering reuses the
-        jit cache, so this is cheap after the first round has compiled."""
-        from fedml_tpu.utils.profiling import compiled_flops
-
-        fn, args = self.round_program(round_idx)
-        return compiled_flops(fn, *args)
-
     def _spill_pad_ids(self, sampled):
         """(store-gather ids, real count) for the stateful algorithms'
         SPILLED state tier. Defined on the common root so the mesh
@@ -1048,10 +983,8 @@ class FedAvgAPI:
             "flush", first_round=rounds[0], last_round=rounds[-1],
             rows=len(rounds),
         ) as flush:
-            # with the model's own constants (empty where it has none to give:
-            # the expert layers' numbers and products a pair, the conv layers',
-            # the state-space layers' ``ssm_*``)
-            for name, value in (*self._site_attrs.items(), *self.model.counter_attrs.items()):
+            # with the model's own constants (empty where it has none to give)
+            for name, value in self._flush_attrs.items():
                 flush.set_attr(name, value)
             # the one device-to-host fetch: it returns when the device has
             # finished every round flushed here, so this is the wait, not

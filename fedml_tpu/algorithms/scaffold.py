@@ -2,8 +2,7 @@
 
 BEYOND the reference's inventory (it ships FedAvg/FedProx/FedOpt/FedNova;
 SURVEY §2b) — included because it is the canonical answer to the client
--drift problem the hard-accuracy benchmark demonstrates (bench.py
-``hard_accuracy``: FedAvg misses the synthetic(1,1) target that
+-drift problem (on synthetic(1,1) FedAvg misses the accuracy target that
 FedProx/FedOpt reach), and because it exercises the one capability the
 other algorithms don't: PERSISTENT per-client state (SURVEY §7 names the
 client-state store as a hard part).
@@ -638,9 +637,6 @@ class ScaffoldAPI(FedAvgAPI):
 
     def _build_round_fn(self, local_train_fn):
         return None  # unused — train_round is fully overridden
-
-    def round_flops(self, round_idx: int = 0):
-        return None  # bespoke round fn; XLA cost analysis not wired
 
     def checkpoint_state(self):
         """Control-variate state for checkpoint/resume — without this a

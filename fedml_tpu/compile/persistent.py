@@ -35,7 +35,7 @@ compilation cache and applies the cache-dir/threshold config in one
 place. WHERE the cache lives is decided by :func:`resolve_cache_dir`
 alone — ``$JAX_COMPILATION_CACHE_DIR`` when set, else an explicitly
 requested directory, else ``<checkout>/.jax_cache`` — and the CLI,
-chip_smoke.py, bench.py and tests/conftest.py all go through it."""
+chip_smoke.py and tests/conftest.py all go through it."""
 
 from __future__ import annotations
 
@@ -313,9 +313,9 @@ def _resolve(requested: str, subdir: str) -> pathlib.Path:
 
 
 def resolve_cache_dir(requested: str = "") -> pathlib.Path:
-    """THE compile-cache directory of this process (CLI, chip_smoke.py,
-    bench.py and tests/conftest.py all ask here): ``$JAX_COMPILATION_
-    CACHE_DIR`` when set, else ``requested`` (a deployment's explicit
+    """THE compile-cache directory of this process (CLI, chip_smoke.py
+    and tests/conftest.py all ask here): ``$JAX_COMPILATION_CACHE_DIR``
+    when set, else ``requested`` (a deployment's explicit
     ``--compile_cache_dir``), else ``<checkout>/.jax_cache``."""
     return _resolve(requested, "")
 

@@ -118,7 +118,7 @@ class FedConfig:
     # runs them sequentially (each client's convs keep full MXU tiling —
     # measured 1.8x faster for conv models whose channel dims are small
     # relative to the 128-lane MXU, e.g. the cross-silo ResNet-56 round:
-    # 339 ms -> 190 ms bf16 on v5e, examples/probe_resnet_bf16.py).
+    # 339 ms -> 190 ms bf16 on v5e).
     # "auto" picks scan for conv models with a client param copy >= 1 MB.
     client_parallelism: str = "auto"
     # Where stateful algorithms (SCAFFOLD control variates, Ditto personal

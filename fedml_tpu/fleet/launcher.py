@@ -165,7 +165,7 @@ class FleetLauncher:
                 # the server ran in THIS process, so the fleet digests
                 # (per-tier train_s/rtt_s percentiles fed by client
                 # beacons) are in the process-global aggregator — persist
-                # them so out-of-process consumers (bench.py, CI) can read
+                # them so out-of-process consumers (CI) can read
                 # latency percentiles without scraping the /fleet route.
                 # cli-mode servers own their aggregator and publish it via
                 # their own ops port instead.

@@ -11,12 +11,17 @@ full state_dict, which includes BN stats)."""
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Optional, Tuple
+from typing import Any, Callable, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from flax.core import FrozenDict
+
+
+def no_flush_attrs(batch: int) -> dict:
+    """``ModelDef.flush_attrs`` of a model without kernel sites."""
+    return {}
 
 
 @dataclasses.dataclass
@@ -31,40 +36,15 @@ class ModelDef:
     # Device counters the module reports in training: it sows one float32
     # vector (an entry per name here) into the "counters" collection wherever
     # it counts, and ``apply(..., counters=True)`` hands back their sum. The
-    # local-train loop carries them with its metrics. ``counter_attrs`` are
-    # the model's host constants that every ``flush`` span carries for the
-    # readers of its counters and of its layers' metrics (the expert layers'
-    # widths, counts and ``expert_products``, the grouped products a held pair
-    # runs forward; the conv layers' ``conv_layers`` and ``conv_width``; the
-    # state-space layers' ``ssm_layers``, ``ssm_heads``, ``ssm_head_dim``,
-    # ``ssm_state``, ``ssm_groups`` and ``ssm_chunk``; where attention layers
-    # are of more than one shape, ``attn_length``, ``attn_window`` and the
-    # per-kind ``attn_<kind>_*`` of ``DecoderLM.attention_constants``).
+    # local-train loop carries them with its metrics.
     counters: Tuple[str, ...] = ()
-    counter_attrs: dict = dataclasses.field(default_factory=dict)
-    # One (query heads, key/value heads, head dim) per call of
-    # ``ops/attention.attention`` in a forward pass, and for a site of latent
-    # attention two more widths (of the second score term, of the values):
-    # after the sequence length, the arguments ``ops/attention.takes_kernel``
-    # decides each call from. A layer whose token mixer is no attention has
-    # no site.
-    attention_sites: Tuple[Tuple[int, ...], ...] = ()
-    # One (heads, dims a head) per call of ``ops/rotary.rotary`` in a forward
-    # pass, and the dims it turns where that is not the whole head: after the
-    # sequence length, what its ``takes_kernel`` decides from.
-    rope_sites: Tuple[Tuple[int, ...], ...] = ()
-    # From the tokens a step trains on, one (M, K, N, G) per grouped product
-    # that a forward pass runs outside the overflow loops
-    # (``models/decoder._held_rows``), each with two more in the backward
-    # pass: the arguments ``ops/grouped_matmul.takes_kernel`` decides the
-    # three from. None for a model without routed experts.
-    grouped_sites: Optional[Callable[[int], Tuple[Tuple[int, int, int, int], ...]]] = None
-    # From the tokens a step trains on, one (N, top_k, d, R) per sum over a
-    # token's slots that a step runs outside the overflow loops (the forward
-    # of ``models/decoder.weighted_rows``, the backward of ``take_rows``):
-    # the arguments ``ops/slot_sum.takes_kernel`` decides each from. None for
-    # a model without routed experts.
-    slot_sites: Optional[Callable[[int], Tuple[Tuple[int, int, int, int], ...]]] = None
+    # From the samples a local step trains on, the model's host constants
+    # that every ``flush`` span carries for the per-layer metrics: how many
+    # calls of each kernel-backed op take its kernel, by the op's own
+    # ``takes_kernel`` (``*_kernel_sites`` of ``*_sites``), and the widths and
+    # counts those metrics' FLOPs and bytes follow from. The model builds
+    # them (``DecoderLM.flush_attrs``, ``TransformerLM.flush_attrs``).
+    flush_attrs: Callable[[int], dict] = no_flush_attrs
 
     def init(self, rng) -> dict:
         dummy = jnp.zeros((1,) + tuple(self.input_shape), dtype=self.input_dtype)

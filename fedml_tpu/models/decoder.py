@@ -173,7 +173,8 @@ import jax
 import jax.numpy as jnp
 from jax.custom_batching import custom_vmap
 
-from fedml_tpu.ops import grouped_matmul, slot_sum
+from fedml_tpu.ops import attention as attention_op, grouped_matmul, slot_sum
+from fedml_tpu.ops import rotary as rotary_op, ssd as ssd_op
 from fedml_tpu.ops.attention import attention
 from fedml_tpu.ops.rotary import rotary
 from fedml_tpu.ops.short_conv import gated_short_conv, silu_short_conv
@@ -707,7 +708,7 @@ class AttentionSpec:
     def site(self) -> Tuple[int, ...]:
         """The shapes ``ops/attention.attention`` is called with, as
         ``takes_kernel`` takes them after the length
-        (``ModelDef.attention_sites``): what the layer below computes from
+        (``DecoderLM.attention_sites``): what the layer below computes from
         and what the round's ``flush`` span reports cannot drift apart."""
         latent = (self.rope_dim, self.v_dim) if self.rope_dim else ()
         return (self.heads, self.kv_heads, self.head_dim) + latent
@@ -716,7 +717,7 @@ class AttentionSpec:
         """The (heads, dims a head) of each call of ``ops/rotary.rotary`` in
         a layer, and the dims it turns where that is not the whole head, as
         its ``takes_kernel`` takes them after the length
-        (``ModelDef.rope_sites``); the pairs form is no such call."""
+        (``DecoderLM.rope_sites``); the pairs form is no such call."""
         if self.interleave or not self.rotary:
             return ()
         if self.rope_dim:
@@ -747,7 +748,7 @@ class ExpertSpec:
         return 3 if self.gated else 2
 
     def grouped_sites(self, hidden: int, tokens: int) -> Tuple[Tuple[int, int, int, int], ...]:
-        """``ModelDef.grouped_sites`` of one layer over ``tokens``: gate and
+        """``DecoderLM.grouped_sites`` of one layer over ``tokens``: gate and
         up ``d -> f`` (up alone where ungated), down ``f -> d``, each on the
         bounded rows of its held experts."""
         held = self.held[1] - self.held[0]
@@ -756,7 +757,7 @@ class ExpertSpec:
             (rows, self.width, hidden, held),)
 
     def slot_sites(self, hidden: int, tokens: int) -> Tuple[Tuple[int, int, int, int], ...]:
-        """``ModelDef.slot_sites`` of one layer over ``tokens``: the sum of a
+        """``DecoderLM.slot_sites`` of one layer over ``tokens``: the sum of a
         token's slots and the backward of the dispatch gather, each over the
         bounded rows."""
         held = self.held[1] - self.held[0]
@@ -1360,8 +1361,9 @@ class DecoderLM(nn.Module):
             self.expert_spec(),) * (len(self.kinds()) - dense)
 
     def attention_sites(self) -> Tuple[Tuple[int, ...], ...]:
-        """``ModelDef.attention_sites``: one site a layer that has attention
-        (a ``conv`` layer calls no attention), that layer's own."""
+        """The calls of ``ops/attention.attention`` in a forward pass: one
+        site a layer that has attention (a ``conv`` layer calls no
+        attention), that layer's own."""
         return tuple(spec.site() for kind, spec in zip(self.kinds(), self.attention_specs())
                      if kind in ATTENTION_KINDS)
 
@@ -1369,22 +1371,78 @@ class DecoderLM(nn.Module):
         return sum(kind in ATTENTION_KINDS for kind in self.kinds())
 
     def rope_sites(self) -> Tuple[Tuple[int, ...], ...]:
-        """``ModelDef.rope_sites``: the attention layers' calls of the
-        rotate-half operator."""
+        """The attention layers' calls of the rotate-half operator
+        (``AttentionSpec.rope_sites``)."""
         return tuple(site for kind, spec in zip(self.kinds(), self.attention_specs())
                      if kind in ATTENTION_KINDS for site in spec.rope_sites())
 
     def grouped_sites(self, tokens: int) -> Tuple[Tuple[int, int, int, int], ...]:
-        """``ModelDef.grouped_sites``: the expert layers' grouped products
-        in a step of ``tokens``."""
+        """The expert layers' grouped products that a forward pass over
+        ``tokens`` runs outside the overflow loops (``_held_rows``), each with
+        two more in the backward pass."""
         return tuple(site for ffn in self.feed_forwards() if isinstance(ffn, ExpertSpec)
                      for site in ffn.grouped_sites(self.hidden_size, tokens))
 
     def slot_sites(self, tokens: int) -> Tuple[Tuple[int, int, int, int], ...]:
-        """``ModelDef.slot_sites``: the expert layers' token-side sums in a
-        step of ``tokens``."""
+        """The expert layers' token-side sums in a step of ``tokens`` (the
+        forward of ``weighted_rows``, the backward of ``take_rows``)."""
         return tuple(site for ffn in self.feed_forwards() if isinstance(ffn, ExpertSpec)
                      for site in ffn.slot_sites(self.hidden_size, tokens))
+
+    def flush_attrs(self, length: int, batch: int) -> dict:
+        """``ModelDef.flush_attrs`` for a step of ``batch`` sequences of
+        ``length``: of each kernel-backed op, the calls that take its kernel
+        (by the op's own ``takes_kernel``) and all of them; the numbers the
+        per-layer metrics' FLOPs and bytes follow from: latent attention's
+        widths, the expert layers' (``layers`` is what their counters are
+        summed over, ``expert_products`` the grouped products a held pair
+        runs forward), the conv layers', the state-space layers' ``ssm_*``
+        and ``attention_constants``."""
+        out = {}
+        kinds, tokens = self.kinds(), batch * length
+        sites = self.attention_sites()
+        if sites:
+            out.update(attn_kernel_sites=sum(attention_op.takes_kernel(length, *site)
+                                             for site in sites),
+                       attn_sites=len(sites))
+        rope = self.rope_sites()
+        if rope:
+            out.update(rope_kernel_sites=sum(rotary_op.takes_kernel(length, *site)
+                                             for site in rope),
+                       rope_sites=len(rope))
+        attn = self.attention_spec()
+        if sites and attn.rope_dim:
+            # what latent attention's core FLOPs follow from
+            out.update(attn_qk_width=attn.head_dim + attn.rope_dim, attn_v_width=attn.v_dim,
+                       attn_heads=attn.heads, attn_length=int(length), attn_layers=len(sites))
+        routed = [f for f in self.feed_forwards() if isinstance(f, ExpertSpec)]
+        if routed:
+            # three grouped products a site (its forward and two gradients),
+            # which take one path
+            grouped, slots = self.grouped_sites(tokens), self.slot_sites(tokens)
+            out.update(
+                moe_kernel_sites=3 * sum(grouped_matmul.takes_kernel(*s) for s in grouped),
+                moe_grouped_sites=3 * len(grouped),
+                moe_slot_kernel_sites=sum(slot_sum.takes_kernel(*s) for s in slots),
+                moe_slot_sites=len(slots),
+                hidden=self.hidden_size, expert_width=routed[0].width, layers=len(routed),
+                expert_layers=len(routed), top_k=routed[0].top_k,
+                expert_products=routed[0].products())
+            if routed[0].shared_width:
+                out["shared_width"] = routed[0].shared_width
+        if self.conv_taps():
+            out.update(conv_layers=kinds.count("conv"), conv_width=self.hidden_size)
+        out.update(self.attention_constants(length))
+        ssm = self.mamba_spec()
+        if ssm is not None:
+            # one scan a state-space layer
+            layers = kinds.count("mamba")
+            takes = ssd_op.takes_kernel(length, ssm.heads, ssm.head_dim, ssm.groups,
+                                        ssm.state, ssm.chunk)
+            out.update(ssd_kernel_sites=layers * takes, ssd_sites=layers, ssm_layers=layers,
+                       ssm_heads=ssm.heads, ssm_head_dim=ssm.head_dim, ssm_state=ssm.state,
+                       ssm_groups=ssm.groups, ssm_chunk=ssm.chunk)
+        return out
 
     @nn.compact
     def __call__(self, tokens, train: bool = False):

@@ -4,6 +4,7 @@ base.py:18-26)."""
 
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import jax.numpy as jnp
@@ -78,7 +79,7 @@ def create(
         # in-repo NLP ceiling is the 2-layer LSTM). num_classes = vocab
         # size; trains under task="nwp" like the RNNs, so every federated
         # algorithm (FedAvg/FedOpt/FedProx/...) runs it unchanged.
-        from fedml_tpu.models.transformer import TransformerLM, causal_attention
+        from fedml_tpu.models.transformer import TransformerLM
 
         if kw.get("moe_experts"):
             raise ValueError(
@@ -90,11 +91,9 @@ def create(
             )
         kw.setdefault("max_len", int(input_shape[0]))
         m = TransformerLM(vocab_size=num_classes, **kw)
-        site = (m.num_heads, m.num_heads, m.embed_dim // m.num_heads)
         return ModelDef(
             m, input_shape, num_classes, input_dtype=jnp.int32,
-            name="transformer",
-            attention_sites=(site,) * m.num_layers if m.attn_fn is causal_attention else (),
+            name="transformer", flush_attrs=functools.partial(m.flush_attrs, input_shape[0]),
         )
 
     if name == "decoder":
@@ -111,36 +110,15 @@ def create(
         from fedml_tpu.models.decoder import DecoderLM, ExpertSpec, counter_names
 
         m = DecoderLM(vocab_size=num_classes, **kw)
-        # a spec that cannot be expressed fails here, by name, not at first trace
+        flush_attrs = functools.partial(m.flush_attrs, input_shape[0])
+        # a spec that cannot be expressed fails here, by name, not at first
+        # trace: the constants ask every layer for its numbers
+        flush_attrs(1)
         routed = [f for f in m.feed_forwards() if isinstance(f, ExpertSpec)]
-        attrs = {}
-        if routed:
-            # ``layers`` is what the experts' counters are summed over: the
-            # expert layers (every layer, where none is dense)
-            attrs = {"hidden": m.hidden_size, "expert_width": routed[0].width,
-                     "layers": len(routed), "expert_layers": len(routed),
-                     "top_k": routed[0].top_k,
-                     # grouped products a held pair runs forward: 3 gated, 2 ungated
-                     "expert_products": routed[0].products()}
-            if routed[0].shared_width:
-                attrs["shared_width"] = routed[0].shared_width
-        if m.conv_taps():
-            attrs.update(conv_layers=m.kinds().count("conv"), conv_width=m.hidden_size)
-        # attention layers of more than one shape: the per-kind numbers
-        attrs.update(m.attention_constants(input_shape[0]))
-        ssm = m.mamba_spec()
-        if ssm is not None:
-            attrs.update(ssm_layers=m.kinds().count("mamba"), ssm_heads=ssm.heads,
-                         ssm_head_dim=ssm.head_dim, ssm_state=ssm.state,
-                         ssm_groups=ssm.groups, ssm_chunk=ssm.chunk)
         return ModelDef(
             m, input_shape, num_classes, input_dtype=jnp.int32, name="decoder",
             counters=counter_names(routed[0].biased) if routed else (),
-            counter_attrs=attrs,
-            attention_sites=m.attention_sites(),
-            rope_sites=m.rope_sites(),
-            grouped_sites=m.grouped_sites if routed else None,
-            slot_sites=m.slot_sites if routed else None,
+            flush_attrs=flush_attrs,
         )
 
     if name in ("resnet56", "resnet110"):

@@ -21,7 +21,7 @@ from fedml_tpu.models.norms import fp32_layer_norm
 import jax
 import jax.numpy as jnp
 
-from fedml_tpu.ops.attention import attention
+from fedml_tpu.ops.attention import attention, takes_kernel
 
 causal_attention = functools.partial(attention, causal=True)
 
@@ -121,6 +121,16 @@ class TransformerLM(nn.Module):
     attn_fn: Callable = causal_attention
     moe_experts: int = 0
     moe_stats_axis: Optional[str] = None
+
+    def flush_attrs(self, length: int, batch: int) -> dict:
+        """``ModelDef.flush_attrs``: one attention site a layer where the
+        blocks' attention is ``ops/attention.attention``, and how many of
+        them take its kernel at ``length``."""
+        if self.attn_fn is not causal_attention:
+            return {}
+        site = (self.num_heads, self.num_heads, self.embed_dim // self.num_heads)
+        return {"attn_kernel_sites": self.num_layers * takes_kernel(length, *site),
+                "attn_sites": self.num_layers}
 
     @nn.compact
     def __call__(self, tokens, pos_offset: int = 0, train: bool = False):

@@ -40,7 +40,7 @@ def takes_kernel(T: int, H: int, KV: int, D: int, R: int = 0, V: Optional[int] =
     of one tile share a K/V head. A site of latent attention has two more
     widths, ``R`` of the second score term and ``V`` of the values: the
     kernel takes it where every head has keys of its own, in whole lane
-    tiles, and values as wide as them (``ModelDef.attention_sites`` holds
+    tiles, and values as wide as them (``DecoderLM.attention_sites`` holds
     the sites as the arguments after ``T``)."""
     if T % CHUNK or T > MAX_LENGTH or H % KV:
         return False

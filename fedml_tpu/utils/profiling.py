@@ -1,13 +1,12 @@
-"""Profiling subsystem — per-round device FLOPs, MFU, and jax.profiler
+"""Profiling subsystem — MFU, device-time slopes, and jax.profiler
 traces.
 
 SURVEY §5 assigns this slot jax.profiler + per-round host metrics; the
 reference has only ad-hoc timers (`time.perf_counter` around aggregation,
 FedAVGAggregator.py:4,78; JSON-size log per message, message.py:77-78; the
-TRPC latency sweep, trpc_comm_manager.py:146-211). Here the compiled XLA
-cost model supplies exact per-call FLOPs, so MFU = achieved/peak is a
-first-class per-round metric, and a trace directory flag captures a full
-device timeline viewable in TensorBoard/Perfetto.
+TRPC latency sweep, trpc_comm_manager.py:146-211). Here MFU = achieved/peak
+over the published per-chip peaks, and a trace directory flag captures a
+full device timeline viewable in TensorBoard/Perfetto.
 """
 
 from __future__ import annotations
@@ -55,18 +54,6 @@ def device_peak_flops(dtype: str = "bfloat16", device=None) -> Optional[float]:
         f"no published peak FLOP/s for TPU device_kind "
         f"{device.device_kind!r} in fedml_tpu.utils.profiling._PEAKS"
     )
-
-
-def compiled_flops(jitted_fn, *args, **kwargs) -> Optional[float]:
-    """FLOPs for ONE call of a jitted function, from XLA's compiled cost
-    analysis. Lowering does not execute the function (donated buffers are
-    untouched). Returns None where the backend exposes no cost model."""
-    try:
-        compiled = jitted_fn.lower(*args, **kwargs).compile()
-        flops = float(compiled.cost_analysis().get("flops", 0.0))
-        return flops if flops > 0 else None
-    except Exception:
-        return None
 
 
 def mfu(

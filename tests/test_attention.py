@@ -202,7 +202,8 @@ def _api(name, length):
 ])
 def test_what_the_api_reports_is_what_the_traced_program_contains(name, length, kernel_sites, rope):
     api = _api(name, length)
-    assert api._site_attrs == {"attn_kernel_sites": kernel_sites, "attn_sites": 2, **rope}
+    sites = {k: v for k, v in api._flush_attrs.items() if k.endswith("sites")}
+    assert sites == {"attn_kernel_sites": kernel_sites, "attn_sites": 2, **rope}
     tokens = jax.ShapeDtypeStruct((2, length), jnp.int32)
     forward = lambda variables, x: api.model.apply(variables, x, train=True)[0]
     assert _pallas_calls(forward, api.global_vars, tokens) == kernel_sites
@@ -221,4 +222,3 @@ def test_flush_span_carries_the_two_attributes_and_a_model_without_attention_non
         (a["attn_kernel_sites"], a["attn_sites"]) == (0, 2) for a in flushes)
     # no state-space layer, no scan sites
     assert not any(k.startswith("ssd_") for a in flushes for k in a)
-    assert create_model("lr", "synthetic", (6,), 3).attention_sites == ()

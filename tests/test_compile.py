@@ -531,13 +531,12 @@ def test_session_stores_live_at_the_resolved_directory():
 
 
 def test_one_writer_of_jax_compilation_cache_dir():
-    """Exactly one statement in the program, the bench, the smoke and the
-    tests points jax's cache directory somewhere: the resolver's bind."""
+    """Exactly one statement in the program, the smoke and the tests
+    points jax's cache directory somewhere: the resolver's bind."""
     writer = re.compile(r'update\(\s*"jax_compilation_cache_dir"')
     files = [
         *(_REPO / "fedml_tpu").rglob("*.py"),
         *(_REPO / "tests").glob("*.py"),
-        _REPO / "bench.py",
         _REPO / "chip_smoke.py",
     ]
     hits = [

@@ -108,8 +108,8 @@ def test_loss_and_every_gradient_match_the_reference(route):
     spec, length, docs, kernel = ROUTES[route]
     ref, cfg = reference(), config(spec, length)
     model = build(spec, length)
-    assert len(model.attention_sites) == spec["layer_types"].count("full_attention")
-    assert all(takes_kernel(length, *site) is kernel for site in model.attention_sites)
+    assert len(model.module.attention_sites()) == spec["layer_types"].count("full_attention")
+    assert all(takes_kernel(length, *site) is kernel for site in model.module.attention_sites())
     flat = ref.init_params(5, cfg)
     have = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     assert set(have) == {"params"}
@@ -316,22 +316,27 @@ def test_the_filters_length_is_asked_only_where_a_layer_is_a_convolution():
     assert "conv" in LAYER_KINDS
     model = build(dict(SPEC, layer_types=["full_attention"], first_k_dense_replace=0,
                        conv_L_cache=None))
-    assert "conv_layers" not in model.counter_attrs and len(model.attention_sites) == 1
+    assert "conv_layers" not in model.flush_attrs(1) and len(model.module.attention_sites()) == 1
 
 
 def test_create_model_reports_sites_for_attention_layers_only_and_the_conv_constants():
     model = build(SPEC)
-    assert model.attention_sites == ((4, 2, 8),)
+    assert model.module.attention_sites() == ((4, 2, 8),)
     assert model.counters == COUNTERS + (BIAS_COUNTER,)
-    assert model.counter_attrs == {"hidden": 32, "expert_width": 12, "layers": 2,
-                                   "expert_layers": 2, "top_k": 2, "expert_products": 3,
-                                   "conv_layers": 2, "conv_width": 32}
+    assert model.flush_attrs(1) == {"attn_kernel_sites": 0, "attn_sites": 1,
+                                    "rope_kernel_sites": 0, "rope_sites": 2,
+                                    "moe_kernel_sites": 0, "moe_grouped_sites": 18,
+                                    "moe_slot_kernel_sites": 0, "moe_slot_sites": 4,
+                                    "hidden": 32, "expert_width": 12, "layers": 2,
+                                    "expert_layers": 2, "top_k": 2, "expert_products": 3,
+                                    "conv_layers": 2, "conv_width": 32}
     only_conv = build(dict(SPEC, layer_types=["conv", "conv"], first_k_dense_replace=2))
-    assert only_conv.attention_sites == () and only_conv.counters == ()
-    assert only_conv.counter_attrs == {"conv_layers": 2, "conv_width": 32}
+    assert only_conv.module.attention_sites() == () and only_conv.counters == ()
+    assert only_conv.flush_attrs(1) == {"conv_layers": 2, "conv_width": 32}
     # the accepted specs keep a site a layer and carry no conv constants
     mellum = create_model("decoder", "random_tokens", (LENGTH,), VOCAB, num_experts=4)
-    assert mellum.attention_sites == ((4, 2, 32),) * 2 and "conv_layers" not in mellum.counter_attrs
+    assert mellum.module.attention_sites() == ((4, 2, 32),) * 2
+    assert "conv_layers" not in mellum.flush_attrs(1)
 
 
 def test_the_sites_are_what_the_traced_layers_hand_the_attention_core(monkeypatch):
@@ -352,7 +357,7 @@ def test_the_sites_are_what_the_traced_layers_hand_the_attention_core(monkeypatc
         seen.clear()
         model = build(dict(SPEC, layer_types=kinds, first_k_dense_replace=0))
         jax.eval_shape(model.init, jax.random.PRNGKey(0))
-        assert tuple(seen) == model.attention_sites
+        assert tuple(seen) == model.module.attention_sites()
         assert len(seen) == len(kinds) - kinds.count("conv")
 
 

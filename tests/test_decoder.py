@@ -440,7 +440,7 @@ def test_create_model_builds_it_and_the_spec_arrives_whole():
     model = create_model("decoder", "random_tokens", (LENGTH,), VOCAB, **dict(BASE, **spec))
     assert tuple(model.module.layer_types) == ("sliding_attention", "full_attention")
     assert dict(model.module.rope_parameters["full_attention"]) == YARN
-    assert model.counters == COUNTERS and model.counter_attrs["layers"] == 2
+    assert model.counters == COUNTERS and model.flush_attrs(1)["layers"] == 2
     params = model.init(jax.random.PRNGKey(0))
     assert set(params) == {"params"}
     assert params["params"]["layers_1"]["experts_gate"].shape == (2, 32, 16)

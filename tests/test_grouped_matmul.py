@@ -87,7 +87,7 @@ def test_the_routed_expert_cells_training_shapes_take_the_kernels(cell):
     for spec, takes in ((cfg["model"], True), (cfg["rehearse"]["model"], False)):
         model = create_model(spec["name"], spec["dataset"], tuple(spec["input_shape"]),
                              int(spec["num_classes"]), **spec.get("kwargs", {}))
-        shapes = model.grouped_sites(traffic["batch_size"] * spec["input_shape"][0])
+        shapes = model.module.grouped_sites(traffic["batch_size"] * spec["input_shape"][0])
         assert [takes_kernel(*s) for s in shapes] == [takes] * len(shapes)
         if takes:
             assert 3 * len(shapes) == EXPERT_CELLS[cell]

@@ -149,8 +149,8 @@ def test_logits_loss_and_every_gradient_match_the_reference(route):
     leaf's largest gradient."""
     spec, length, docs, kernel = ROUTES[route]
     model, (program, plain) = both_sides(spec, length, docs)
-    assert all(takes_kernel(length, *site) is kernel for site in model.attention_sites)
-    assert all(op.takes_kernel(length, *site) is kernel for site in model.rope_sites)
+    assert all(takes_kernel(length, *site) is kernel for site in model.module.attention_sites())
+    assert all(op.takes_kernel(length, *site) is kernel for site in model.module.rope_sites())
     logits, loss, grads = gaps(program, plain)
     assert logits <= 1e-5 and loss <= 1e-6 and grads <= 2e-5, (logits, loss, grads)
 
@@ -289,17 +289,20 @@ def test_the_cell_s_sites_constants_and_parameters_are_the_published_ones():
     model = create_model("decoder", m["dataset"], tuple(m["input_shape"]), m["num_classes"],
                          **m["kwargs"])
     full, sliding = (48, 8, 128), (64, 8, 128)
-    assert model.attention_sites == (full, sliding, sliding, sliding, full)
-    assert model.rope_sites == ((48, 128, 64), (8, 128, 64)) + ((64, 128), (8, 128)) * 3 + (
-        (48, 128, 64), (8, 128, 64))
-    assert all(takes_kernel(4096, *site) for site in model.attention_sites)
-    assert all(op.takes_kernel(4096, *site) for site in model.rope_sites)
-    sites = model.grouped_sites(4096)
+    assert model.module.attention_sites() == (full, sliding, sliding, sliding, full)
+    assert model.module.rope_sites() == ((48, 128, 64), (8, 128, 64)) + (
+        (64, 128), (8, 128)) * 3 + ((48, 128, 64), (8, 128, 64))
+    assert all(takes_kernel(4096, *site) for site in model.module.attention_sites())
+    assert all(op.takes_kernel(4096, *site) for site in model.module.rope_sites())
+    sites = model.module.grouped_sites(4096)
     assert set(sites) == {(4096, 2048, 512, 16), (4096, 512, 2048, 16)} and len(sites) == 12
     assert all(grouped_matmul.takes_kernel(*site) for site in sites)
-    assert model.counter_attrs == {
-        "hidden": 2048, "expert_width": 512, "layers": 4, "expert_layers": 4, "top_k": 8,
-        "expert_products": 3, "shared_width": 512, "attn_length": 4096, "attn_window": 512,
+    assert model.flush_attrs(1) == {
+        "attn_kernel_sites": 5, "attn_sites": 5, "rope_kernel_sites": 10, "rope_sites": 10,
+        "moe_kernel_sites": 36, "moe_grouped_sites": 36, "moe_slot_kernel_sites": 8,
+        "moe_slot_sites": 8, "hidden": 2048, "expert_width": 512, "layers": 4,
+        "expert_layers": 4, "top_k": 8, "expert_products": 3, "shared_width": 512,
+        "attn_length": 4096, "attn_window": 512,
         "attn_full_layers": 2, "attn_full_heads": 48, "attn_full_kv_heads": 8,
         "attn_full_head_dim": 128, "attn_full_rotary_dim": 64, "attn_sliding_layers": 3,
         "attn_sliding_heads": 64, "attn_sliding_kv_heads": 8, "attn_sliding_head_dim": 128,
@@ -311,7 +314,9 @@ def test_the_cell_s_sites_constants_and_parameters_are_the_published_ones():
         o = json.loads((ROOT / "benchmarks" / "configs" / f"{other}.json").read_text())["model"]
         built = create_model("decoder", o["dataset"], tuple(o["input_shape"]), o["num_classes"],
                              **o["kwargs"])
-        assert not any(k.startswith("attn_") for k in built.counter_attrs), other
+        attrs = built.flush_attrs(1)
+        assert "attn_window" not in attrs and not any(
+            k.startswith(("attn_full_", "attn_sliding_")) for k in attrs), other
 
 
 def test_the_sites_are_what_the_traced_layers_hand_the_operators(monkeypatch):
@@ -332,10 +337,10 @@ def test_the_sites_are_what_the_traced_layers_hand_the_operators(monkeypatch):
     monkeypatch.setattr(decoder, "rotary", rope_spy)
     model = create_model("decoder", "random_tokens", (LENGTH,), VOCAB, **SPEC)
     jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    assert tuple(attention_seen) == model.attention_sites == (
+    assert tuple(attention_seen) == model.module.attention_sites() == (
         (6, 1, 16), (8, 1, 16), (8, 1, 16), (8, 1, 16), (6, 1, 16))
-    assert tuple(rope_seen) == model.rope_sites
-    assert model.rope_sites[:2] == ((6, 16, 8), (1, 16, 8))
+    assert tuple(rope_seen) == model.module.rope_sites()
+    assert model.module.rope_sites()[:2] == ((6, 16, 8), (1, 16, 8))
 
 
 def test_the_configuration_file_builds_and_keeps_the_source_s_keys():

@@ -102,7 +102,7 @@ def test_loss_and_every_gradient_match_the_reference(route):
     spec, length, docs, kernel = ROUTES[route]
     ref, cfg = reference(), config(spec, length)
     model = create_model("decoder", "random_tokens", (length,), VOCAB, **cfg["model"]["kwargs"])
-    assert all(takes_kernel(length, *site) is kernel for site in model.attention_sites)
+    assert all(takes_kernel(length, *site) is kernel for site in model.module.attention_sites())
     flat = ref.init_params(5, cfg)
     have = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     assert set(have) == {"params"}
@@ -334,20 +334,25 @@ def test_specs_that_are_not_expressed_are_refused_by_name(change, names):
 
 def test_create_model_reports_the_latent_sites_the_counters_and_the_constants():
     model = create_model("decoder", "random_tokens", (LENGTH,), VOCAB, **SPEC)
-    assert model.attention_sites == ((2, 2, 16, 8, 16),) * 3
+    assert model.module.attention_sites() == ((2, 2, 16, 8, 16),) * 3
     assert model.counters == COUNTERS + (BIAS_COUNTER,)
-    assert model.counter_attrs == {"hidden": 32, "expert_width": 12, "layers": 2,
-                                   "expert_layers": 2, "top_k": 2, "expert_products": 3,
-                                   "shared_width": 24}
+    assert model.flush_attrs(1) == {"attn_kernel_sites": 0, "attn_sites": 3,
+                                    "attn_qk_width": 24, "attn_v_width": 16, "attn_heads": 2,
+                                    "attn_length": LENGTH, "attn_layers": 3,
+                                    "moe_kernel_sites": 0, "moe_grouped_sites": 18,
+                                    "moe_slot_kernel_sites": 0, "moe_slot_sites": 4,
+                                    "hidden": 32, "expert_width": 12, "layers": 2,
+                                    "expert_layers": 2, "top_k": 2, "expert_products": 3,
+                                    "shared_width": 24}
     # gate, up and down of each expert layer over 64 tokens: top-2 of 8 with
     # 4 held bounds the rows at twice the even share, 128; 32 <-> 12
-    assert model.grouped_sites(64) == ((128, 32, 12, 4),) * 2 + ((128, 12, 32, 4),) + (
+    assert model.module.grouped_sites(64) == ((128, 32, 12, 4),) * 2 + ((128, 12, 32, 4),) + (
         (128, 32, 12, 4),) * 2 + ((128, 12, 32, 4),)
     # grouped-query specs keep their sites, counters and constants
     mellum = create_model("decoder", "random_tokens", (LENGTH,), VOCAB, num_experts=4)
-    assert mellum.attention_sites == ((4, 2, 32),) * 2 and mellum.counters == COUNTERS
-    assert mellum.counter_attrs["layers"] == mellum.counter_attrs["expert_layers"] == 2
-    assert "shared_width" not in mellum.counter_attrs
+    assert mellum.module.attention_sites() == ((4, 2, 32),) * 2 and mellum.counters == COUNTERS
+    assert mellum.flush_attrs(1)["layers"] == mellum.flush_attrs(1)["expert_layers"] == 2
+    assert "shared_width" not in mellum.flush_attrs(1)
 
 
 def test_the_sites_are_what_the_traced_layers_hand_the_attention_core(monkeypatch):
@@ -368,7 +373,7 @@ def test_the_sites_are_what_the_traced_layers_hand_the_attention_core(monkeypatc
         seen.clear()
         model = create_model("decoder", "random_tokens", (LENGTH,), VOCAB, **spec)
         jax.eval_shape(model.init, jax.random.PRNGKey(0))
-        assert tuple(seen) == model.attention_sites
+        assert tuple(seen) == model.module.attention_sites()
 
 
 # --- (e) pins -----------------------------------------------------------------

@@ -207,6 +207,6 @@ def test_rope_sites_are_what_the_traced_layers_hand_the_operator(name, monkeypat
     monkeypatch.setattr(decoder, "rotary", spy)
     model = create_model("decoder", "random_tokens", (24,), 61, **spec)
     jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    assert tuple(seen) == model.rope_sites == sites
-    assert create_model("transformer", "random_tokens", (24,), 61, num_layers=1, num_heads=2,
-                        embed_dim=32).rope_sites == ()
+    assert tuple(seen) == model.module.rope_sites() == sites
+    assert "rope_sites" not in create_model("transformer", "random_tokens", (24,), 61,
+                                            num_layers=1, num_heads=2, embed_dim=32).flush_attrs(1)

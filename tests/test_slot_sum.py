@@ -112,11 +112,11 @@ def test_the_routed_expert_cells_training_steps_take_the_kernel(cell):
     for spec, takes in ((cfg["model"], EXPERT_CELLS[cell]), (cfg["rehearse"]["model"], False)):
         model = create_model(spec["name"], spec["dataset"], tuple(spec["input_shape"]),
                              int(spec["num_classes"]), **spec.get("kwargs", {}))
-        sites = model.slot_sites(traffic["batch_size"] * spec["input_shape"][0])
-        assert sites and len(sites) == 2 * model.counter_attrs["expert_layers"]
+        sites = model.module.slot_sites(traffic["batch_size"] * spec["input_shape"][0])
+        assert sites and len(sites) == 2 * model.flush_attrs(1)["expert_layers"]
         assert [op.takes_kernel(*s) for s in sites] == [takes] * len(sites)
         if spec is cfg["model"]:
-            assert not any(op.takes_kernel(*s) for s in model.slot_sites(16384))
+            assert not any(op.takes_kernel(*s) for s in model.module.slot_sites(16384))
 
 
 @pytest.mark.parametrize("N,top_k,d,R,takes", [

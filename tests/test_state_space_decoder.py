@@ -115,9 +115,9 @@ def test_loss_and_every_gradient_match_the_reference(route):
     spec, length, docs, kernel = ROUTES[route]
     ref, cfg = reference(), config(spec, length)
     model = build(spec, length)
-    assert len(model.attention_sites) == spec["hybrid_override_pattern"].count("*")
-    assert all(takes_kernel(length, *site) is kernel for site in model.attention_sites)
-    scan = model.counter_attrs
+    assert len(model.module.attention_sites()) == spec["hybrid_override_pattern"].count("*")
+    assert all(takes_kernel(length, *site) is kernel for site in model.module.attention_sites())
+    scan = model.flush_attrs(1)
     assert ssd.takes_kernel(length, *(scan["ssm_" + k] for k in (
         "heads", "head_dim", "groups", "state", "chunk"))) is (spec is SCAN_KERNEL_SPEC)
     flat = ref.init_params(5, cfg)
@@ -324,9 +324,11 @@ def test_relu2_is_refused_in_a_stack_of_layer_types_and_unknown_keys_by_name():
 def test_create_model_reports_one_site_no_rope_and_the_span_constants():
     model = build(SPEC)
     assert model.module.kinds() == tuple(PARTS[c] for c in "MEM*E")
-    assert model.attention_sites == ((4, 2, 8),) and model.rope_sites == ()
+    assert model.module.attention_sites() == ((4, 2, 8),) and model.module.rope_sites() == ()
     assert model.counters == COUNTERS + (BIAS_COUNTER,)
-    assert model.counter_attrs == {
+    assert model.flush_attrs(1) == {
+        "attn_kernel_sites": 0, "attn_sites": 1, "moe_kernel_sites": 0, "moe_grouped_sites": 12,
+        "moe_slot_kernel_sites": 0, "moe_slot_sites": 4, "ssd_kernel_sites": 0, "ssd_sites": 2,
         "hidden": 32, "expert_width": 12, "layers": 2, "expert_layers": 2, "top_k": 2,
         "expert_products": 2, "shared_width": 20,
         "ssm_layers": 2, "ssm_heads": 8, "ssm_head_dim": 8, "ssm_state": 16, "ssm_groups": 2,
@@ -334,15 +336,17 @@ def test_create_model_reports_one_site_no_rope_and_the_span_constants():
     # the published shapes: one site of 32 query heads on 2 key/value heads of
     # 128, which takes the kernel at 4096 (16 query heads a key head)
     wide = build(dict(SPEC, num_attention_heads=32, head_dim=128, hybrid_override_pattern="M*"), 4096)
-    assert wide.attention_sites == ((32, 2, 128),) and takes_kernel(4096, 32, 2, 128)
+    assert wide.module.attention_sites() == ((32, 2, 128),) and takes_kernel(4096, 32, 2, 128)
     # the accepted specs carry three products a pair and no state-space constants
     mellum = create_model("decoder", "random_tokens", (LENGTH,), VOCAB, num_experts=4)
-    assert mellum.counter_attrs["expert_products"] == 3
-    assert not any(k.startswith("ssm_") for k in mellum.counter_attrs) and mellum.rope_sites
+    assert mellum.flush_attrs(1)["expert_products"] == 3
+    assert not any(k.startswith("ssm_") for k in mellum.flush_attrs(1))
+    assert mellum.module.rope_sites()
     only_mixers = build(dict(SPEC, hybrid_override_pattern="MM"))
-    assert only_mixers.attention_sites == () and only_mixers.counters == ()
-    assert set(only_mixers.counter_attrs) == {
-        "ssm_layers", "ssm_heads", "ssm_head_dim", "ssm_state", "ssm_groups", "ssm_chunk"}
+    assert only_mixers.module.attention_sites() == () and only_mixers.counters == ()
+    assert set(only_mixers.flush_attrs(1)) == {
+        "ssd_kernel_sites", "ssd_sites", "ssm_layers", "ssm_heads", "ssm_head_dim", "ssm_state",
+        "ssm_groups", "ssm_chunk"}
 
 
 def test_the_sites_are_what_the_traced_layers_hand_the_attention_core(monkeypatch):
@@ -364,7 +368,7 @@ def test_the_sites_are_what_the_traced_layers_hand_the_attention_core(monkeypatc
         seen.clear()
         model = build(dict(SPEC, hybrid_override_pattern=pattern))
         jax.eval_shape(model.init, jax.random.PRNGKey(0))
-        assert tuple(seen) == model.attention_sites and len(seen) == pattern.count("*")
+        assert tuple(seen) == model.module.attention_sites() and len(seen) == pattern.count("*")
     assert not turned
 
 

@@ -93,7 +93,7 @@ def test_robust_stats_kernel_compiles_for_v5e(one_chip, C, D):
         # layers, eight under the window of 512 on its sliding ones (slow too)
         pytest.param(1, (1, 4096, 48, 8, 128), None, jnp.bfloat16, marks=pytest.mark.slow),
         pytest.param(1, (1, 4096, 64, 8, 128), 512, jnp.bfloat16, marks=pytest.mark.slow),
-        (1, (8, 4096, 1, 1, 64), None, jnp.bfloat16),      # bench.py's row: a head a batch row
+        (1, (8, 4096, 1, 1, 64), None, jnp.bfloat16),      # a head a batch row
         (1, (1, 2048, 8, 2, 128), 512, jnp.float32),
     ],
     ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else getattr(v, "__name__", str(v)),
