@@ -103,6 +103,23 @@ A one-part stack (``hybrid_override_pattern``, a string over ``M``, ``E``,
   ``moe_shared_expert_intermediate_size`` where ``n_shared_experts``.
 - ``-``, a dense ``relu(x W_up)**2 W_down`` of ``intermediate_size`` alone.
 
+A SambaY stack (``mb_per_layer``, HF's ``phi4flash``; arXiv:2507.06607):
+``x = x + Mixer(LN(x))``, ``x = x + MLP(LN(x))`` with LayerNorms (scale and
+bias, ``layer_norm_eps``) and a gated-SiLU MLP of ``intermediate_size`` in
+every layer, no positions, and a tied head under a final LayerNorm. Of the
+source's ``num_hidden_layers`` L the stack holds ``layers_held`` (a pipeline
+stage), each layer's kind from its published index l
+(:meth:`DecoderLM.sambay_layers`): a Mamba layer where ``l % mb_per_layer ==
+0`` and differential attention (:meth:`DecoderLayer.differential`) elsewhere,
+over a window before L / 2; at L / 2 the Mamba-1 mixer
+(:meth:`DecoderLayer.mamba_one`, ``ops/selective_scan.py``; ``mamba_d_state``,
+``mamba_d_conv``, ``mamba_expand``) keeps its scan output as
+the memory and at L / 2 + 1 full attention keeps its keys and values; from
+L / 2 + 2 on a Mamba layer is a Gated Memory Unit, ``(SiLU(n W_in) * memory)
+W_out``, and attention attends over the kept keys and values
+(``cross_attention``). What a layer keeps is handed to the later layers
+beside the residual stream, so their gradients reach it.
+
 The keys are the decoder's own where families spell one thing differently:
 a spec written from HF's ``lfm2_moe`` gives its ``num_dense_layers`` as
 ``first_k_dense_replace``, its ``norm_eps`` as ``rms_norm_eps``, and its
@@ -147,13 +164,10 @@ length than ``num_hidden_layers``; ``n_groups`` that does not divide
 ``layer_types``; a ``-`` layer without ``intermediate_size``. No key here
 gives a projection a bias (HF's ``attention_bias``, ``mlp_bias``,
 ``mamba_proj_bias``: an unknown key is refused by its name), a gated
-short convolution a bias (HF's
-``conv_bias`` true), a tied head a scale of its own (the table is drawn at
-unit RMS, so ``tie_word_embeddings`` starts the logits at deviation
-``sqrt(hidden_size)`` where a source's 0.02 table starts them under 1),
-documents packed into one sequence (the scan, the convolutions and attention
-would have to reset at their boundaries) or sequences over the attention
-kernel's ``MAX_LENGTH``. A second tower conditioned on this one and decoding
+short convolution a bias (HF's ``conv_bias`` true), documents packed into
+one sequence (the scan, the convolutions and attention would have to reset
+at their boundaries) or sequences over the attention kernel's
+``MAX_LENGTH``. A second tower conditioned on this one and decoding
 by diffusion over blocks have no key in any ``config.json`` read here and
 no module: the decoder is one causal stack trained by next-token loss.
 
@@ -174,15 +188,19 @@ import jax.numpy as jnp
 from jax.custom_batching import custom_vmap
 
 from fedml_tpu.ops import attention as attention_op, grouped_matmul, slot_sum
-from fedml_tpu.ops import rotary as rotary_op, ssd as ssd_op
+from fedml_tpu.ops import rotary as rotary_op, selective_scan as sscan_op, ssd as ssd_op
 from fedml_tpu.ops.attention import attention
 from fedml_tpu.ops.rotary import rotary
+from fedml_tpu.ops.selective_scan import selective_scan
 from fedml_tpu.ops.short_conv import gated_short_conv, silu_short_conv
 from fedml_tpu.ops.ssd import ssd
 
 LAYER_KINDS = ("full_attention", "sliding_attention", "conv")
-# The kinds of layer that call attention (``attention`` is a one-part stack's).
-ATTENTION_KINDS = ("attention", "full_attention", "sliding_attention")
+# The kinds of layer that call attention (``attention`` is a one-part stack's,
+# ``cross_attention`` a SambaY cross-decoder's).
+ATTENTION_KINDS = ("attention", "full_attention", "sliding_attention", "cross_attention")
+# eps of the RMS norm over each differential pair's 2 x head_dim outputs
+SUBLN_EPS = 1e-5
 # What a layer kind's rotary parameters (``rope_parameters[kind]``) may hold.
 ROPE_KEYS = ("rope_type", "rope_theta", "factor", "original_max_position_embeddings",
              "beta_fast", "beta_slow", "attention_factor", "partial_rotary_factor")
@@ -680,6 +698,31 @@ class RMSNorm(nn.Module):
         return (x32 * jax.lax.rsqrt(var + self.eps) * scale).astype(x.dtype)
 
 
+@functools.partial(jax.checkpoint, static_argnums=(3,))
+def layer_norm(x, scale, bias, eps: float):
+    """LayerNorm with a learned scale and bias: statistics, scale and bias in
+    float32, one rounding to x's dtype. A ``jax.checkpoint``: between the
+    passes it keeps ``x`` as it came (bfloat16 in the training cell) where
+    autodiff would keep float32 arrays of its size."""
+    x32 = x.astype(jnp.float32)
+    centred = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(centred), axis=-1, keepdims=True)
+    return (centred * jax.lax.rsqrt(var + eps) * scale + bias).astype(x.dtype)
+
+
+class LayerNorm(nn.Module):
+    """:func:`layer_norm` with its two leaves, ``scale`` (ones) and ``bias``
+    (zeros)."""
+
+    eps: float = 1e-5
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        bias = self.param("bias", nn.initializers.zeros, (x.shape[-1],))
+        return layer_norm(x, scale, bias, self.eps)
+
+
 @dataclasses.dataclass(frozen=True)
 class AttentionSpec:
     """One layer's attention, in numbers. ``rope_dim`` 0 is grouped-query
@@ -704,14 +747,24 @@ class AttentionSpec:
     qk_norm: bool = False
     rotary: bool = True
     rotary_dim: int = 0
+    differential: bool = False
 
     def site(self) -> Tuple[int, ...]:
         """The shapes ``ops/attention.attention`` is called with, as
         ``takes_kernel`` takes them after the length
         (``DecoderLM.attention_sites``): what the layer below computes from
-        and what the round's ``flush`` span reports cannot drift apart."""
+        and what the round's ``flush`` span reports cannot drift apart. A
+        differential map is half the query heads on half the key heads, its
+        values two key heads wide."""
+        if self.differential:
+            return (self.heads // 2, self.kv_heads // 2, self.head_dim, 0, 2 * self.head_dim)
         latent = (self.rope_dim, self.v_dim) if self.rope_dim else ()
         return (self.heads, self.kv_heads, self.head_dim) + latent
+
+    def sites(self) -> Tuple[Tuple[int, ...], ...]:
+        """A layer's calls of ``ops/attention.attention``: one, or a
+        differential layer's two maps."""
+        return (self.site(),) * (2 if self.differential else 1)
 
     def rope_sites(self) -> Tuple[Tuple[int, ...], ...]:
         """The (heads, dims a head) of each call of ``ops/rotary.rotary`` in
@@ -793,12 +846,55 @@ class MambaSpec:
         return self.inner + 2 * self.groups * self.state
 
 
+@dataclasses.dataclass(frozen=True)
+class Mamba1Spec:
+    """One Mamba-1 mixer or Gated Memory Unit of a SambaY stack, in numbers
+    (:meth:`DecoderLayer.mamba_one` has the equations): ``inner`` channels
+    (``mamba_expand x hidden_size``), a state of ``state`` numbers a channel,
+    a convolution of ``taps`` with a bias, the step's low rank ``dt_rank``
+    (``ceil(hidden_size / 16)``, Mamba's own), and what the step's bias is
+    drawn from."""
+
+    inner: int
+    state: int
+    taps: int
+    dt_rank: int
+    dt_min: float = 0.001
+    dt_max: float = 0.1
+    dt_floor: float = 1e-4
+
+
 def gated_mlp(x, w_gate, w_up, w_down):
     return jnp.dot(jax.nn.silu(jnp.dot(x, w_gate)) * jnp.dot(x, w_up), w_down)
 
 
 def relu2_mlp(x, w_up, w_down):
     return jnp.dot(jnp.square(jax.nn.relu(jnp.dot(x, w_up))), w_down)
+
+
+@jax.checkpoint
+def silu_gate(gate, up):
+    """``SiLU(gate) * up`` in float32, rounded once to ``up``'s dtype, and
+    recomputed in the backward pass: between the passes it keeps its two
+    operands, which the products' gradients read anyway, and not the
+    product, which only the next product's gradient reads (84 MB a layer in
+    bfloat16 at 4 096 tokens of 10 240)."""
+    return (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)).astype(up.dtype)
+
+
+@functools.partial(jax.checkpoint, static_argnums=(4,))
+def differential_combine(first, second, lambdas, scale, lambda_init: float):
+    """``RMSNorm(first - lambda second) * scale * (1 - lambda_init)`` over the
+    last axis (a pair's 2 x head_dim), ``lambda = exp(lq1 . lk1) - exp(lq2 .
+    lk2) + lambda_init`` from the four ``lambdas``; float32, one rounding to
+    ``first``'s dtype. A ``jax.checkpoint``: between the passes it keeps the
+    two maps' outputs (the attention kernels keep them anyway) and not the
+    float32 difference and norm of their size."""
+    lq1, lk1, lq2, lk2 = (v.astype(jnp.float32) for v in lambdas)
+    lam = jnp.exp(jnp.sum(lq1 * lk1)) - jnp.exp(jnp.sum(lq2 * lk2)) + lambda_init
+    o = first.astype(jnp.float32) - lam * second.astype(jnp.float32)
+    o = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1, keepdims=True) + SUBLN_EPS)
+    return (o * scale.astype(jnp.float32) * (1.0 - lambda_init)).astype(first.dtype)
 
 
 @functools.partial(jax.checkpoint, static_argnums=(3, 4))
@@ -819,6 +915,21 @@ def gated_group_norm(y, z, scale, groups: int, eps: float):
 def a_log_init(key, shape, dtype=jnp.float32):
     """``log(a)``, ``a`` uniform in [1, 16]: the source's own draw."""
     return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+def symmetric_uniform(bound: float):
+    """Uniform in [-bound, bound): Mamba's draw of ``dt_proj`` at
+    ``dt_rank ** -0.5``."""
+
+    def init(key, shape, dtype=jnp.float32):
+        return jax.random.uniform(key, shape, dtype, -bound, bound)
+
+    return init
+
+
+def a_log_range_init(key, shape, dtype=jnp.float32):
+    """Mamba-1's ``A_log`` [channels, N]: ``log(1..N)`` in every channel."""
+    return jnp.broadcast_to(jnp.log(jnp.arange(1, shape[-1] + 1, dtype=dtype)), shape)
 
 
 def dt_bias_init(dt_min: float, dt_max: float, dt_floor: float):
@@ -856,7 +967,19 @@ class DecoderLayer(nn.Module):
     ``ssd_out`` beneath it, ``gated_norm``, ``out``; numbers in ``ssm``),
     ``attention`` (``qkv``, ``attention_full``, ``out`` and, where the spec
     has no rotary, no ``rope``), ``experts`` (the five scopes above) or
-    ``mlp``. Its ``ffn`` is ``None`` where the part is a mixer."""
+    ``mlp``. Its ``ffn`` is ``None`` where the part is a mixer.
+
+    A layer of a SambaY stack (``layer_norm``: LayerNorms with a bias, eps
+    ``rms_norm_eps``, and a dense gated-SiLU MLP) is called with and returns
+    ``carried``, what earlier layers hand on: ``memory``, the scan output of
+    the Mamba-1 layer that ``keeps`` it, and ``kv``, the keys and values of
+    the full attention layer that ``keeps`` them. Its scopes: ``in_proj``,
+    ``conv``, ``x_proj``, ``selective_scan``, ``gate``, ``out`` (``mamba1``);
+    ``gmu``, ``out`` (``gmu``); ``qkv`` (``q`` on a cross layer),
+    ``attention_sliding`` / ``attention_full`` / ``attention_cross``,
+    ``diff_combine``, ``out`` (differential attention); ``mlp``.
+    ``lambda_init`` is the differential layer's, set from its published
+    index."""
 
     kind: str
     attn: AttentionSpec
@@ -865,6 +988,10 @@ class DecoderLayer(nn.Module):
     rms_norm_eps: float
     conv_taps: int = 0
     ssm: Optional[MambaSpec] = None
+    mamba1: Optional[Mamba1Spec] = None
+    layer_norm: bool = False
+    lambda_init: float = 0.0
+    keeps: bool = False
 
     @nn.nowrap
     def grouped_query(self, n, cos, sin, init):
@@ -960,6 +1087,115 @@ class DecoderLayer(nn.Module):
                 G, self.rms_norm_eps)
 
     @nn.nowrap
+    def differential(self, n, kv, init):
+        """Differential attention (arXiv:2410.05258, HF's ``phi4flash``): [B,
+        T, heads x head_dim] and the keys and values it read, ``(k1, k2, V)``.
+
+            [q | k | v] = n Wqkv         heads + 2 kv_heads of head_dim, no rotary
+            q1, q2 = q's halves; k1, k2 and v1, v2 = k's and v's halves
+            V_g = [v1_g | v2_g]          two heads of values a key head
+            o_i = softmax(q1_i k1_g^T / sqrt(D)) V_g
+                  - lambda softmax(q2_i k2_g^T / sqrt(D)) V_g      g = i // 2, causal
+            lambda = exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init
+            o_i <- RMSNorm(o_i) * (1 - lambda_init)                 over 2 D, learned scale
+
+        A cross layer projects q alone (``Wq``) and reads ``kv``, the keys
+        and values of the layer that kept them. Each map is one call of
+        ``ops/attention.attention`` with values twice as wide as keys; lambda,
+        the difference and the norm are float32, rounded once."""
+        B, T, d = n.shape
+        H, KV, D = self.attn.heads, self.attn.kv_heads, self.attn.head_dim
+        if self.kind == "cross_attention":
+            with jax.named_scope("q"):
+                q = jnp.dot(n, self.param("Wq", init, (d, H * D))).reshape(B, T, H, D)
+            k1, k2, v = kv
+        else:
+            with jax.named_scope("qkv"):
+                qkv = jnp.dot(n, self.param("Wqkv", init, (d, (H + 2 * KV) * D)))
+                q, k, v = jnp.split(qkv, [H * D, (H + KV) * D], axis=-1)
+                q = q.reshape(B, T, H, D)
+                k, v = k.reshape(B, T, KV, D), v.reshape(B, T, KV, D)
+                k1, k2 = k[:, :, :KV // 2], k[:, :, KV // 2:]
+                v = jnp.concatenate([v[:, :, :KV // 2], v[:, :, KV // 2:]], axis=-1)
+        window = self.sliding_window if self.kind == "sliding_attention" else None
+        with jax.named_scope("attention_" + self.kind.split("_")[0]):
+            first = attention(q[:, :, :H // 2], k1, v, causal=True, window=window)
+            second = attention(q[:, :, H // 2:], k2, v, causal=True, window=window)
+        with jax.named_scope("diff_combine"):
+            o = differential_combine(
+                first, second,
+                [self.param(name, nn.initializers.normal(0.1), (D,))
+                 for name in ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2")],
+                self.param("subln", nn.initializers.ones, (2 * D,)), self.lambda_init)
+        return o.reshape(B, T, H // 2 * 2 * D), (k1, k2, v)
+
+    @nn.nowrap
+    def mamba_one(self, n, init):
+        """The Mamba-1 mixer (arXiv:2312.00752; HF's ``phi4flash``
+        ``Phi3Mamba``) ahead of its output projection: the gated output [B,
+        T, inner] and the scan's output ``y``, the memory a SambaY stack hands
+        on (taken before the gate).
+
+            [u | z] = n W_in                 d -> 2 inner, no bias
+            u = SiLU(conv(u) + b)            causal, depthwise, ``taps`` a channel
+            [dt_r | B | C] = u W_x           inner -> dt_rank + N + N, no bias
+            dt = dt_r W_dt                   dt_rank -> inner (its bias in the scan)
+            y = selective_scan(u, dt, -exp(A_log), B, C, D, dt_bias)
+            out = y * SiLU(z)
+
+        The step, the decays and the states are float32
+        (``ops/selective_scan.py``)."""
+        d, m = n.shape[-1], self.mamba1
+        E, N, R = m.inner, m.state, m.dt_rank
+        with jax.named_scope("in_proj"):
+            u, z = jnp.split(jnp.dot(n, self.param("in_proj", init, (d, 2 * E))), 2, axis=-1)
+        with jax.named_scope("conv"):
+            u = silu_short_conv(
+                u, self.param("conv", nn.initializers.normal(m.taps ** -0.5), (E, m.taps)),
+                self.param("conv_bias", nn.initializers.zeros, (E,)))
+        with jax.named_scope("x_proj"):
+            dt, Bm, Cm = jnp.split(
+                jnp.dot(u, self.param("x_proj", init, (E, R + 2 * N))), [R, R + N], axis=-1)
+            dt = jnp.dot(dt, self.param("dt_proj", symmetric_uniform(R ** -0.5), (R, E)))
+        with jax.named_scope("selective_scan"):
+            y = selective_scan(
+                u, dt, -jnp.exp(self.param("A_log", a_log_range_init, (E, N)).astype(jnp.float32)),
+                Bm, Cm, self.param("D", nn.initializers.ones, (E,)),
+                self.param("dt_bias", dt_bias_init(m.dt_min, m.dt_max, m.dt_floor), (E,)))
+        with jax.named_scope("gate"):
+            out = silu_gate(z, y)
+        return out, y
+
+    @nn.nowrap
+    def sambay(self, x, carried, init):
+        """``x + Mixer(LN(x))``, then ``x + MLP(LN(x))``, of a SambaY layer,
+        and what it hands on. A ``gmu`` layer's mixer is the Gated Memory
+        Unit ``SiLU(n W_in) * memory``."""
+        d = x.shape[-1]
+        n = LayerNorm(self.rms_norm_eps, name="input_layernorm")(x)
+        if self.kind == "mamba1":
+            a, memory = self.mamba_one(n, init)
+            if self.keeps:
+                carried = dict(carried, memory=memory)
+        elif self.kind == "gmu":
+            memory = carried["memory"]
+            with jax.named_scope("gmu"):
+                w_in = self.param("in_proj", init, (d, memory.shape[-1]))
+                a = jax.nn.silu(jnp.dot(n, w_in)) * memory
+        else:
+            a, kv = self.differential(n, carried.get("kv"), init)
+            if self.keeps:
+                carried = dict(carried, kv=kv)
+        with jax.named_scope("out"):
+            x = x + jnp.dot(a, self.param("out_proj", init, (a.shape[-1], d)))
+        n = LayerNorm(self.rms_norm_eps, name="post_attention_layernorm")(x)
+        with jax.named_scope("mlp"):
+            gate = jnp.dot(n, self.param("mlp_gate", init, (d, self.ffn)))
+            up = jnp.dot(n, self.param("mlp_up", init, (d, self.ffn)))
+            x = x + jnp.dot(silu_gate(gate, up), self.param("mlp_down", init, (self.ffn, d)))
+        return x, carried
+
+    @nn.nowrap
     def expert_layer(self, n, init):
         """The held routed experts' part plus the shared expert's, and the
         layer's counters. Ungated experts (and their shared expert) have no
@@ -1015,11 +1251,13 @@ class DecoderLayer(nn.Module):
             return x + jnp.dot(a, self.param(leaf, init, (a.shape[-1], d)))
 
     @nn.compact
-    def __call__(self, x, cos, sin):
+    def __call__(self, x, cos, sin, carried=None):
         B, T, d = x.shape
         init = nn.initializers.normal(0.02)
         if self.kind in PARTS.values():
             return self.one_part(x, init)
+        if self.layer_norm:
+            return self.sambay(x, carried, init)
         n = RMSNorm(self.rms_norm_eps, name="input_layernorm")(x)
         if self.kind == "conv":
             a, leaf = self.short_conv(n, init), "out_proj"
@@ -1108,6 +1346,72 @@ class DecoderLM(nn.Module):
     time_step_floor: float = 1e-4
     mlp_hidden_act: str = "silu"
     moe_shared_expert_intermediate_size: Optional[int] = None
+    # a SambaY stack (HF's phi4flash keys): a Mamba layer every mb_per_layer
+    # of num_hidden_layers, of which this chip holds [layers_held[0],
+    # layers_held[1]); the Mamba-1 sizes
+    mb_per_layer: Optional[int] = None
+    layers_held: Optional[Sequence[int]] = None
+    layer_norm_eps: float = 1e-5
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+
+    def sambay(self) -> bool:
+        """Whether the stack is a SambaY decoder-hybrid-decoder (arXiv:2507.06607)."""
+        return self.mb_per_layer is not None
+
+    def sambay_layers(self) -> Tuple[Tuple[int, str, bool], ...]:
+        """Each held layer of a SambaY stack: its published index ``l``, its
+        kind and whether it keeps what later layers read, by the source's
+        rules over ``L = num_hidden_layers``: a Mamba layer where ``l %
+        mb_per_layer == 0``, else attention; from ``L / 2`` on the
+        cross-decoder, where the first Mamba layer keeps its scan output as
+        the memory and the first attention layer (full) its keys and values,
+        and from ``L / 2 + 2`` on every Mamba layer is a Gated Memory Unit
+        over that memory and every attention layer attends over those keys
+        (``cross_attention``); before ``L / 2`` attention is windowed."""
+        for key in ("layer_types", "hybrid_override_pattern", "kv_lora_rank",
+                    "num_attention_heads_per_layer"):
+            if getattr(self, key) is not None:
+                raise ValueError(f"{key} beside mb_per_layer: a SambaY stack's layers follow "
+                                 "from mb_per_layer and layers_held")
+        if (self.first_k_dense_replace or self.use_qk_norm or self.intermediate_size is None
+                or not self.tie_word_embeddings):
+            raise ValueError("a SambaY stack has dense MLPs of intermediate_size in every layer, "
+                             "no QK norm and a tied head")
+        L, every = int(self.num_hidden_layers or 0), int(self.mb_per_layer)
+        lo, hi = self.layers_held or (0, L)
+        if every < 1 or not 0 <= lo < hi <= L:
+            raise ValueError(f"layers_held {(lo, hi)} within num_hidden_layers {L}, "
+                             f"mb_per_layer {every}")
+        half = L // 2
+        out = []
+        for l in range(lo, hi):
+            if l % every == 0:
+                kind, keeps = ("gmu", False) if l >= half + 2 else ("mamba1", l >= half)
+            elif l >= half + 2:
+                kind, keeps = "cross_attention", False
+            else:
+                kind, keeps = ("sliding_attention", False) if l < half else ("full_attention", True)
+            out.append((l, kind, keeps))
+        kept = {kind for _, kind, keeps in out if keeps}
+        for reader, source in (("gmu", "mamba1"), ("cross_attention", "full_attention")):
+            if any(kind == reader for _, kind, _ in out) and source not in kept:
+                at = half if source == "mamba1" else half + 1
+                raise ValueError(
+                    f"layers_held {(lo, hi)}: a {reader} layer reads what the {source} layer at "
+                    f"{at} keeps, which this chip does not hold")
+        return tuple(out)
+
+    def mamba1_spec(self) -> Optional[Mamba1Spec]:
+        """The Mamba-1 mixers' and Gated Memory Units' numbers; ``None``
+        outside a SambaY stack."""
+        if not self.sambay():
+            return None
+        d = int(self.hidden_size)
+        return Mamba1Spec(int(self.mamba_expand) * d, int(self.mamba_d_state),
+                          int(self.mamba_d_conv), -(-d // 16), float(self.time_step_min),
+                          float(self.time_step_max), float(self.time_step_floor))
 
     def one_part(self) -> bool:
         """Whether the stack is told by ``hybrid_override_pattern``: every
@@ -1117,6 +1421,8 @@ class DecoderLM(nn.Module):
 
     def kinds(self) -> Tuple[str, ...]:
         """Every layer's kind; the depth is its length."""
+        if self.sambay():
+            return tuple(kind for _, kind, _ in self.sambay_layers())
         if self.one_part():
             pattern = str(self.hybrid_override_pattern)
             if self.layer_types is not None:
@@ -1209,8 +1515,12 @@ class DecoderLM(nn.Module):
         if self.kv_lora_rank is None:
             if self.num_attention_heads % self.num_key_value_heads:
                 raise ValueError("num_attention_heads must be a multiple of num_key_value_heads")
+            if self.sambay() and (self.num_attention_heads % 2 or self.num_key_value_heads % 2):
+                raise ValueError("differential attention halves the query and key/value heads")
             return AttentionSpec(self.num_attention_heads, self.num_key_value_heads, self.head_dim,
-                                 qk_norm=bool(self.use_qk_norm), rotary=not self.one_part())
+                                 qk_norm=bool(self.use_qk_norm),
+                                 rotary=not (self.one_part() or self.sambay()),
+                                 differential=self.sambay())
         if self.one_part():
             raise ValueError(
                 "kv_lora_rank beside hybrid_override_pattern: a one-part stack's attention "
@@ -1346,6 +1656,8 @@ class DecoderLM(nn.Module):
         ``first_k_dense_replace`` layers, the expert layer's numbers after; in
         a one-part stack the expert layer's numbers in an ``E`` layer, the
         dense width in a ``-`` layer and ``None`` in a mixer's."""
+        if self.sambay():
+            return (int(self.intermediate_size),) * len(self.kinds())
         if self.one_part():
             kinds = self.kinds()
             if "mlp" in kinds and self.intermediate_size is None:
@@ -1361,11 +1673,11 @@ class DecoderLM(nn.Module):
             self.expert_spec(),) * (len(self.kinds()) - dense)
 
     def attention_sites(self) -> Tuple[Tuple[int, ...], ...]:
-        """The calls of ``ops/attention.attention`` in a forward pass: one
-        site a layer that has attention (a ``conv`` layer calls no
-        attention), that layer's own."""
-        return tuple(spec.site() for kind, spec in zip(self.kinds(), self.attention_specs())
-                     if kind in ATTENTION_KINDS)
+        """The calls of ``ops/attention.attention`` in a forward pass: a
+        layer that has attention gives its own (a ``conv`` layer calls no
+        attention), one or a differential layer's two."""
+        return tuple(site for kind, spec in zip(self.kinds(), self.attention_specs())
+                     if kind in ATTENTION_KINDS for site in spec.sites())
 
     def attention_layers(self) -> int:
         return sum(kind in ATTENTION_KINDS for kind in self.kinds())
@@ -1442,10 +1754,64 @@ class DecoderLM(nn.Module):
             out.update(ssd_kernel_sites=layers * takes, ssd_sites=layers, ssm_layers=layers,
                        ssm_heads=ssm.heads, ssm_head_dim=ssm.head_dim, ssm_state=ssm.state,
                        ssm_groups=ssm.groups, ssm_chunk=ssm.chunk)
+        if self.sambay():
+            out.update(self.sambay_constants(length))
         return out
+
+    def sambay_constants(self, length: int) -> dict:
+        """A SambaY stack's ``flush`` constants: of its selective scans (one
+        a Mamba-1 layer and local step) the calls that take the kernels and
+        all of them, the layers, channels and state (``sscan_*``); of its
+        differential attention the trained length, the window, the layers
+        of each kind, the pairs of query heads, the key/value groups, the
+        keys' and the values' widths (``diff_*``)."""
+        kinds, out = self.kinds(), {}
+        m, attn = self.mamba1_spec(), self.attention_spec()
+        scans = kinds.count("mamba1")
+        if scans:
+            out.update(sscan_kernel_sites=scans * sscan_op.takes_kernel(length, m.inner, m.state),
+                       sscan_sites=scans, sscan_layers=scans, sscan_inner=m.inner,
+                       sscan_state=m.state)
+        if any(kind in ATTENTION_KINDS for kind in kinds):
+            out.update(diff_length=int(length), diff_window=int(self.sliding_window),
+                       **{f"diff_{kind.split('_')[0]}_layers": kinds.count(kind)
+                          for kind in ("sliding_attention", "full_attention", "cross_attention")},
+                       diff_pairs=attn.heads // 2, diff_kv_groups=attn.kv_heads // 2,
+                       diff_key_dim=attn.head_dim, diff_value_dim=2 * attn.head_dim)
+        return out
+
+    @nn.nowrap
+    def sambay_stack(self, tokens):
+        """The logits of a SambaY stack: the held layers in order, each
+        handed what the earlier ones keep (:meth:`DecoderLayer.sambay`),
+        a final LayerNorm and the tied head."""
+        embed = nn.Embed(self.vocab_size, self.hidden_size, name="embed_tokens",
+                         embedding_init=self.embedding_init())
+        x, carried = embed(tokens), {}
+        m, ffns = self.mamba1_spec(), self.feed_forwards()
+        layers = zip(self.sambay_layers(), self.attention_specs())
+        for i, ((l, kind, keeps), spec) in enumerate(layers):
+            x, carried = DecoderLayer(
+                kind, spec, ffns[i], self.sliding_window, self.layer_norm_eps, mamba1=m,
+                layer_norm=True, lambda_init=0.8 - 0.6 * math.exp(-0.3 * l), keeps=keeps,
+                name=f"layers_{i}",
+            )(x, None, None, carried)
+        x = LayerNorm(self.layer_norm_eps, name="norm")(x)
+        return embed.attend(x)
+
+    @nn.nowrap
+    def embedding_init(self):
+        """A tied table at the sources' scale (normal 0.02: the logits start
+        under 1); an untied one at unit RMS: under an RMS norm a 0.02
+        embedding is drowned by the attention branch's mean over the
+        context, which every position shares, and a fresh router collapses
+        onto a few experts."""
+        return nn.initializers.normal(0.02 if self.tie_word_embeddings else 1.0)
 
     @nn.compact
     def __call__(self, tokens, train: bool = False):
+        if self.sambay():
+            return self.sambay_stack(tokens)
         B, T = tokens.shape
         kinds, specs, taps = self.kinds(), self.attention_specs(), self.conv_taps()
         attn, ssm = self.attention_spec(), self.mamba_spec()
@@ -1464,11 +1830,8 @@ class DecoderLM(nn.Module):
             else:
                 rope = self.rope_by_kind()
                 tables = {kind: rotary_tables(rope[kind], self.head_dim, T) for kind in turning}
-        # unit-RMS embedding: under an RMS norm a 0.02 embedding is drowned by
-        # the attention branch's mean over the context, which every position
-        # shares, and a fresh router collapses onto a few experts
         embed = nn.Embed(self.vocab_size, self.hidden_size, name="embed_tokens",
-                         embedding_init=nn.initializers.normal(1.0))
+                         embedding_init=self.embedding_init())
         x = embed(tokens)
         for i, (kind, spec, ffn) in enumerate(zip(kinds, specs, self.feed_forwards())):
             x = DecoderLayer(

@@ -41,17 +41,22 @@ def takes_kernel(T: int, H: int, KV: int, D: int, R: int = 0, V: Optional[int] =
     widths, ``R`` of the second score term and ``V`` of the values: the
     kernel takes it where every head has keys of its own, in whole lane
     tiles, and values as wide as them (``DecoderLM.attention_sites`` holds
-    the sites as the arguments after ``T``)."""
+    the sites as the arguments after ``T``). A grouped-query site whose
+    values are wider than its keys (``V`` over ``D``, differential
+    attention's) takes it where the values are whole lane tiles: its q and k
+    are padded to them, one head a tile."""
     if T % CHUNK or T > MAX_LENGTH or H % KV:
         return False
     if R:
         return H == KV and D % LANES == 0 and V == D
+    if V is not None and V != D:
+        return V > D and V % LANES == 0
     return H == KV or (H // KV) % heads_per_tile(H, D) == 0
 
 
 def attention(q, k, v, causal: bool = False, window: Optional[int] = None,
               q_rope=None, k_rope=None, scale: Optional[float] = None):
-    """q [B, T, H, D], k and v [B, T, KV, D] → [B, T, H, D]; ``window``
+    """q [B, T, H, D], k [B, T, KV, D] and v [B, T, KV, V] → [B, T, H, V]; ``window``
     keeps, beside the causal mask, only the keys with ``i - j < window``.
     ``q_rope`` [B, T, H, R] and ``k_rope`` [B, T, 1, R] add the second score
     term; ``scale`` multiplies the scores (default ``D ** -0.5``)."""

@@ -427,8 +427,10 @@ def flash_attention_bthd(
 ):
     """Blockwise attention in the framework's layout, the signature of
     ``parallel/ring_attention.full_attention``: q [B, T, H, D], k and v
-    [B, Tk, KV, D] with KV dividing H; ``window`` keeps, beside the causal
-    mask, only the keys with ``i - j < window``. Sequence lengths are whole
+    [B, Tk, KV, D] with KV dividing H, or v [B, Tk, KV, V] of values wider
+    than the keys in whole lane tiles (the result is then [B, T, H, V]);
+    ``window`` keeps, beside the causal mask, only the keys with
+    ``i - j < window``. Sequence lengths are whole
     numbers of ``chunk`` (or shorter than one: the chunk is then the
     sequence) and at most ``MAX_LENGTH``. Differentiable via the backward
     kernel.
@@ -443,6 +445,16 @@ def flash_attention_bthd(
     Tk, KV = k.shape[1], k.shape[2]
     if interpret is None:
         interpret = _use_interpret()
+    V = v.shape[-1]
+    if q_rope is None and V > D and V % LANES == 0 and v.shape[:3] == k.shape[:3]:
+        # values wider than keys (differential attention's two heads of
+        # values on one of keys): q and k padded with zero lanes to the
+        # values' width give the same scores, one head a lane tile, which is
+        # what a narrower head's score costs the MXU in a shared tile
+        pad = ((0, 0), (0, 0), (0, 0), (0, V - D))
+        return flash_attention_bthd(
+            jnp.pad(q, pad), jnp.pad(k, pad), v, causal=causal, window=window, chunk=chunk,
+            interpret=interpret, scale=D ** -0.5 if scale is None else scale)
     if k.shape != v.shape or k.shape[0] != B or k.shape[3] != D or H % KV:
         raise ValueError(
             f"q {q.shape} needs k and v of one shape [B, Tk, KV, D] with KV "

@@ -107,7 +107,8 @@ def make_ring_attention(mesh: Mesh, axis_name: str = "seq", causal: bool = False
 
 def full_attention(q, k, v, causal: bool = False, window: Optional[int] = None,
                    q_rope=None, k_rope=None, scale: Optional[float] = None):
-    """Reference O(T²) attention: q [B, T, H, D], k/v [B, T, KV, D].
+    """Reference O(T²) attention: q [B, T, H, D], k [B, T, KV, D] and v
+    [B, T, KV, V] (values may be wider than keys) -> [B, T, H, V].
 
     ``window`` keeps, beside the causal mask, only the keys with
     ``i - j < window`` (sliding-window layers). With fewer key/value heads
@@ -177,4 +178,4 @@ def _grouped_query_attention(q, k, v, causal: bool, window: Optional[int]):
     if causal or window is not None:
         s = jnp.where(_attention_mask(T, causal, window)[None, None, None], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
-    return jnp.einsum("bkgqs,bskd->bqkgd", p, v).reshape(B, T, H, D).astype(q.dtype)
+    return jnp.einsum("bkgqs,bskd->bqkgd", p, v).reshape(B, T, H, v.shape[-1]).astype(q.dtype)

@@ -49,6 +49,12 @@ CELLS = {
         "attn_full_kv_heads": 8, "attn_full_head_dim": 128, "attn_full_rotary_dim": 64,
         "attn_sliding_layers": 3, "attn_sliding_heads": 64, "attn_sliding_kv_heads": 8,
         "attn_sliding_head_dim": 128, "attn_sliding_rotary_dim": 128},
+    "phi-4-mini-flash-reasoning.silo2t4k-sambay": {
+        "attn_kernel_sites": 6, "attn_sites": 6, "sscan_kernel_sites": 1, "sscan_sites": 1,
+        "sscan_layers": 1, "sscan_inner": 5120, "sscan_state": 16, "diff_length": 4096,
+        "diff_window": 512, "diff_sliding_layers": 1, "diff_full_layers": 1,
+        "diff_cross_layers": 1, "diff_pairs": 20, "diff_kv_groups": 10, "diff_key_dim": 64,
+        "diff_value_dim": 128},
 }
 
 

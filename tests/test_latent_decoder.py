@@ -409,7 +409,12 @@ def test_the_sites_are_what_the_traced_layers_hand_the_attention_core(monkeypatc
 # row holds a held pair, and Mellum's 4 096 tokens a step take
 # ``ops/slot_sum``'s kernel (at the parent 7ed97b7 the six read
 # 7807aa87a686398f, 2ef7c050eeac5d0a, ca0b95984ccd4fb7, e7137c8985465d0e,
-# 37291c2624a2a267 and 23cb7c783d5d8684); both GPT-2 pins hold.
+# 37291c2624a2a267 and 23cb7c783d5d8684); both GPT-2 pins hold. The SambaY
+# stack (a Mamba-1 scan, differential attention over values twice the keys'
+# width, LayerNorms) is a branch that no accepted spec takes: the ten pins
+# hold, and LFM2's two (computed at the parent a80e48a by this same function)
+# join them, so that every accepted program that shares the attention entry
+# with the widened values is pinned.
 PINS = {
     "attention.silo4": "940131b509805ea9",
     "attention.silo2": "50842107702d88df",
@@ -421,6 +426,8 @@ PINS = {
     "nemotron-twotower-30b-a3b.rehearse": "897a09af8bb81a0f",
     "laguna-xs.2.full": "2bd3a81119a8c809",
     "laguna-xs.2.rehearse": "8fa8ca783b6b41b4",
+    "lfm2-8b-a1b.full": "3f14deae87464d80",
+    "lfm2-8b-a1b.rehearse": "4e6c8767908fc2a3",
 }
 ATTENTION_STEPS = {"attention.silo4": ((4, 1024, 12, 12, 64), None),
                    "attention.silo2": ((2, 2048, 32, 4, 128), 1024)}

@@ -323,6 +323,71 @@ def test_state_space_scan_kernels_compile_for_v5e_under_a_client_vmap(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 0.2 * 2**30
 
 
+def _selective_scan_compiled(one_chip, clients):
+    """``ops/selective_scan.selective_scan`` forward + gradient at
+    phi-4-mini-flash-reasoning.silo2t4k-sambay's training step (one document
+    of 4 096 positions, 5 120 channels, state 16), for ``clients`` under a
+    client vmap (each with its own A, D and step bias) or none, compiled with
+    the kernels."""
+    from fedml_tpu.ops import selective_scan as op
+
+    T, C, N = 4096, 5120, 16
+    assert op.takes_kernel(T, C, N)
+
+    def loss(*operands):
+        scan = jax.vmap(op.selective_scan) if clients else op.selective_scan
+        return jnp.sum(scan(*operands).astype(jnp.float32))
+
+    lead = (clients,) if clients else ()
+    shapes = [((1, T, C), jnp.bfloat16), ((1, T, C), jnp.bfloat16), ((C, N), jnp.float32),
+              ((1, T, N), jnp.bfloat16), ((1, T, N), jnp.bfloat16), ((C,), jnp.float32),
+              ((C,), jnp.float32)]
+    args = [jax.ShapeDtypeStruct(lead + s, d, sharding=one_chip) for s, d in shapes]
+    saved, op._use_interpret = op._use_interpret, lambda: False
+    try:
+        return jax.jit(jax.value_and_grad(loss, argnums=tuple(range(7)))).lower(*args).compile()
+    finally:
+        op._use_interpret = saved
+
+
+@pytest.mark.parametrize("clients", [0, 2], ids=["one_client", "client_vmap"])
+def test_selective_scan_kernels_compile_for_v5e_within_their_plan(one_chip, clients):
+    """The Mamba-1 scan goes to its two kernels (``sscan_fwd``, ``sscan_bwd``:
+    the per-position columns of B and C, the row picks and lane picks, the
+    backward's 4.2 MiB of recomputed chunk states within the VMEM it asks
+    for), and its plan outside them is the small regrouped B and C and the
+    clients' sums: under 0.2 GiB, where the plain form's autodiff would keep
+    a [T, C, N] float32 state a block. About 3 s each."""
+    compiled = _selective_scan_compiled(one_chip, clients)
+    text = compiled.as_text()
+    assert "sscan_fwd" in text and "sscan_bwd" in text and text.count("tpu_custom_call") == 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.2 * 2**30
+
+
+@pytest.mark.parametrize("T,window", [
+    (1024, None), (1024, 512),
+    # the cell's own length: 15-30 s each here, outside tier 1; the cell
+    # compiles them on the chip
+    pytest.param(4096, None, marks=pytest.mark.slow),
+    pytest.param(4096, 512, marks=pytest.mark.slow),
+], ids=["full_1024", "window_1024", "full_4096", "window_4096"])
+def test_differential_map_with_values_twice_the_keys_compiles_for_v5e(one_chip, T, window):
+    """One differential map as phi-4-mini-flash-reasoning's layers hand it
+    to the kernel: 20 query heads on 10 key/value heads of 64 with values of
+    128, forward and gradient: the keys padded to the values' width, one
+    custom call each way."""
+    from fedml_tpu.ops import flash_attention_bthd
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention_bthd(
+            q, k, v, causal=True, window=window, interpret=False).astype(jnp.float32))
+
+    args = [jax.ShapeDtypeStruct((1, T, h, d), jnp.bfloat16, sharding=one_chip)
+            for h, d in ((20, 64), (10, 64), (10, 128))]
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(*args).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 2
+
+
 def test_femnist_cnn_round_program_compiles_for_one_v5e_chip(one_chip):
     """The production round program (``api.round_fn``) of the north star —
     FEMNIST CNN, 10 clients/round, batch 20 — lowered at its real round-0
