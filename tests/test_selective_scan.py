@@ -7,7 +7,8 @@ gradient of every operand, at a length of whole chunks and at one that is
 not, with steps of extreme size, in float32 and bfloat16 operands, under the
 clients' ``vmap`` with weights of its own and inside a ``scan``; causality
 and the state's reach across chunks; the kernels' shape rule; and that the
-tolerance catches states kept in bfloat16."""
+tolerance catches states kept in bfloat16 and the backward kernel's lane
+sums taken in bfloat16."""
 
 import jax
 import jax.numpy as jnp
@@ -20,17 +21,25 @@ from fedml_tpu.ops.selective_scan import selective_scan
 NAMES = ("u", "dt", "A", "B", "C", "D", "delta_bias")
 
 
-def case(lead, T, C, N, dtype, seed=0, dt_shift=0.0):
+def case(lead, T, C, N, dtype, seed=0, dt_shift=0.0, sharp=False):
     """Operands as the mixer hands them over: u, dt, B, C in the compute
     dtype; A (-1 .. -N, moved by a little a channel), D and the step's bias
-    float32; ``dt_shift`` moves every step before its softplus."""
+    float32; ``dt_shift`` moves every step before its softplus; ``sharp``
+    scales B and C by a different power of ten at each position of a group
+    of 16 (B by 10^(t mod 4 - 2), C by 10^(1 - t mod 3)), so that a sum over
+    a group that mixes its positions up is far off."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 8)
     u = jax.random.normal(ks[0], lead + (T, C), jnp.float32).astype(dtype)
     dt = (jax.random.normal(ks[1], lead + (T, C), jnp.float32) + dt_shift).astype(dtype)
     A = -jnp.arange(1, N + 1, dtype=jnp.float32)[None, :] * jnp.exp(
         0.1 * jax.random.normal(ks[2], (C, N), jnp.float32))
-    B = jax.random.normal(ks[3], lead + (T, N), jnp.float32).astype(dtype)
-    Cm = jax.random.normal(ks[4], lead + (T, N), jnp.float32).astype(dtype)
+    B = jax.random.normal(ks[3], lead + (T, N), jnp.float32)
+    Cm = jax.random.normal(ks[4], lead + (T, N), jnp.float32)
+    if sharp:
+        t = jnp.arange(T)[:, None]
+        B = B * 10.0 ** (t % 4 - 2)
+        Cm = Cm * 10.0 ** (1 - t % 3)
+    B, Cm = B.astype(dtype), Cm.astype(dtype)
     D = jax.random.normal(ks[5], (C,), jnp.float32)
     bias = jax.random.normal(ks[6], (C,), jnp.float32) - 2.0
     cot = jax.random.normal(ks[7], lead + (T, C), jnp.float32)
@@ -103,17 +112,20 @@ def test_the_plain_form_is_the_recurrence_written_out():
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("lead,T,C,N,shift", [
-    ((2,), 40, 24, 8, 0.0),          # the plain form: two sequences
-    ((), 130, 16, 16, 0.0),          # the plain form: no multiple of its block of 128
-    ((), 256, 512, 16, 0.0),         # the kernels: one chunk, one forward block
-    ((1,), 296, 1024, 16, 0.0),      # two chunks, the second padded; two forward blocks
-    ((), 256, 512, 16, 12.0),        # steps near 12: every decay under exp(-12)
-    ((), 256, 512, 16, -12.0),       # steps near 6e-6: nearly nothing decays or enters
+@pytest.mark.parametrize("lead,T,C,N,shift,sharp", [
+    ((2,), 40, 24, 8, 0.0, False),          # the plain form: two sequences
+    ((), 130, 16, 16, 0.0, False),          # the plain form: no multiple of its block of 128
+    ((), 256, 512, 16, 0.0, False),         # the kernels: one chunk, one block each way
+    ((1,), 296, 1024, 16, 0.0, False),      # two chunks, the second padded; two blocks
+    ((), 256, 512, 16, 12.0, False),        # steps near 12: every decay under exp(-12)
+    ((), 256, 512, 16, -12.0, False),       # steps near 6e-6: nearly nothing decays or enters
+    ((), 552, 1536, 16, 0.0, False),        # three chunks, the third padded; three blocks
+    ((), 256, 512, 16, 0.0, True),          # B and C a power of ten apart within a group
 ], ids=["plain", "plain_no_multiple", "kernels", "kernels_padded_two_blocks",
-        "kernels_long_steps", "kernels_short_steps"])
-def test_value_and_every_gradient_match_the_recurrence(lead, T, C, N, shift, dtype):
-    operands, cot = case(lead, T, C, N, dtype, dt_shift=shift)
+        "kernels_long_steps", "kernels_short_steps", "kernels_padded_three_chunks_three_blocks",
+        "kernels_sharp_within_a_group"])
+def test_value_and_every_gradient_match_the_recurrence(lead, T, C, N, shift, sharp, dtype):
+    operands, cot = case(lead, T, C, N, dtype, dt_shift=shift, sharp=sharp)
     assert op.takes_kernel(T, C, N) is (T >= 256)
     got = value_and_grads(selective_scan, operands, cot)
     want = value_and_grads(lifted(recurrence, lead), operands, cot)
@@ -194,3 +206,29 @@ def test_the_tolerance_catches_states_kept_in_bfloat16(monkeypatch):
     scale = float(np.max(np.abs(want)))
     gap = float(np.max(np.abs(np.asarray(got, np.float64) - want)))
     assert gap > 100 * TOLERANCE[jnp.float32] * scale
+
+
+def test_the_tolerance_catches_a_lane_sum_taken_in_bfloat16(monkeypatch):
+    """The backward kernel's lane sums (dB, dC: a group's products summed over
+    the channels) taken as a single-pass bfloat16 product would take them,
+    each product rounded to bfloat16 before float32 accumulation, where the
+    kernel adds them in float32: dB and dC are then orders over the float32
+    tolerance off the recurrence."""
+    operands, cot = case((), 256, 512, 16, jnp.float32)
+    want = value_and_grads(recurrence, operands, cot)
+    exact = op._lane_sums
+
+    def in_bfloat16(parts):
+        return exact([p.astype(jnp.bfloat16).astype(jnp.float32) for p in parts])
+
+    jax.clear_caches()   # the kernels' jitted traces hold the exact sums
+    monkeypatch.setattr(op, "_lane_sums", in_bfloat16)
+    try:
+        got = value_and_grads(selective_scan, operands, cot)
+    finally:
+        jax.clear_caches()
+    for name in ("B", "C"):
+        at = 1 + NAMES.index(name)
+        g, w = np.asarray(got[at]), np.asarray(want[at])
+        gap = float(np.max(np.abs(g - w))) / float(np.max(np.abs(w)))
+        assert gap > 10 * TOLERANCE[jnp.float32], (name, gap)

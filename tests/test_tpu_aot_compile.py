@@ -353,11 +353,14 @@ def _selective_scan_compiled(one_chip, clients):
 @pytest.mark.parametrize("clients", [0, 2], ids=["one_client", "client_vmap"])
 def test_selective_scan_kernels_compile_for_v5e_within_their_plan(one_chip, clients):
     """The Mamba-1 scan goes to its two kernels (``sscan_fwd``, ``sscan_bwd``:
-    the per-position columns of B and C, the row picks and lane picks, the
-    backward's 4.2 MiB of recomputed chunk states within the VMEM it asks
-    for), and its plan outside them is the small regrouped B and C and the
-    clients' sums: under 0.2 GiB, where the plain form's autodiff would keep
-    a [T, C, N] float32 state a block. About 3 s each."""
+    the per-position columns of B and C; the backward's slabs of 512
+    channels, 8.0 MiB of recomputed chunk states and 8.0 MiB of their decays,
+    0.5 MiB of a group's state gradients, the strided stores into them, the
+    group's [16, lanes] tiles read from them and the transposes of its lane
+    sums, within the VMEM it asks for), and its plan outside them is the
+    small regrouped B and C and the clients' sums: under 0.2 GiB, where the
+    plain form's autodiff would keep a [T, C, N] float32 state a block.
+    About 3 s each."""
     compiled = _selective_scan_compiled(one_chip, clients)
     text = compiled.as_text()
     assert "sscan_fwd" in text and "sscan_bwd" in text and text.count("tpu_custom_call") == 2
