@@ -189,6 +189,7 @@ from jax.custom_batching import custom_vmap
 
 from fedml_tpu.ops import attention as attention_op, grouped_matmul, slot_sum
 from fedml_tpu.ops import rotary as rotary_op, selective_scan as sscan_op, ssd as ssd_op
+from fedml_tpu.ops import table_grad
 from fedml_tpu.ops.attention import attention
 from fedml_tpu.ops.rotary import rotary
 from fedml_tpu.ops.selective_scan import selective_scan
@@ -685,6 +686,25 @@ def routed_experts(x, router, w_gate, w_up, w_down, bias=None, *, top_k: int,
             (pairs > bound).astype(f32),
         ] + ([] if bias is None else [moved.astype(f32)]))
     return y, jax.lax.stop_gradient(counters)
+
+
+class TokenTable(nn.Embed):
+    """The token table: ``nn.Embed``'s parameter and initialiser, whose
+    lookup (``ops/table_grad.lookup``) also hands on the table for a tied
+    head, so that the head's weight gradient comes back to the lookup's
+    backward, which adds the rows' gradients onto it (onto zeros where the
+    head is untied; in the kernel where ``table_grad.takes_kernel`` holds)."""
+
+    def __call__(self, inputs):
+        """``(rows, head)``: ``nn.Embed``'s rows, and the table to hand
+        :meth:`logits`."""
+        (embedding,) = self.promote_dtype(self.embedding, dtype=self.dtype, inexact=False)
+        return table_grad.lookup(embedding, inputs)
+
+    def logits(self, query, head):
+        """``nn.Embed.attend`` over the table the lookup handed on."""
+        query, head = self.promote_dtype(query, head, dtype=self.dtype)
+        return jnp.dot(query, head.T)
 
 
 class RMSNorm(nn.Module):
@@ -1756,6 +1776,9 @@ class DecoderLM(nn.Module):
                        ssm_groups=ssm.groups, ssm_chunk=ssm.chunk)
         if self.sambay():
             out.update(self.sambay_constants(length))
+        # the lookup's one table gradient a step
+        out.update(embed_kernel_sites=int(table_grad.takes_kernel(
+            self.vocab_size, self.hidden_size, tokens)), embed_sites=1)
         return out
 
     def sambay_constants(self, length: int) -> dict:
@@ -1785,9 +1808,8 @@ class DecoderLM(nn.Module):
         """The logits of a SambaY stack: the held layers in order, each
         handed what the earlier ones keep (:meth:`DecoderLayer.sambay`),
         a final LayerNorm and the tied head."""
-        embed = nn.Embed(self.vocab_size, self.hidden_size, name="embed_tokens",
-                         embedding_init=self.embedding_init())
-        x, carried = embed(tokens), {}
+        x, head = self.embed(tokens)
+        carried = {}
         m, ffns = self.mamba1_spec(), self.feed_forwards()
         layers = zip(self.sambay_layers(), self.attention_specs())
         for i, ((l, kind, keeps), spec) in enumerate(layers):
@@ -1797,7 +1819,21 @@ class DecoderLM(nn.Module):
                 name=f"layers_{i}",
             )(x, None, None, carried)
         x = LayerNorm(self.layer_norm_eps, name="norm")(x)
-        return embed.attend(x)
+        return head(x)
+
+    @nn.nowrap
+    def embed(self, tokens):
+        """``(rows, head)``: the token table's (``embed_tokens``) rows, and
+        the head over a hidden state: the table itself where tied, so that
+        the kernel adds the lookup's gradient onto the head's weight-gradient
+        product in one pass, where XLA adds it in HBM a row at a time."""
+        table = TokenTable(self.vocab_size, self.hidden_size, name="embed_tokens",
+                           embedding_init=self.embedding_init())
+        rows, tied = table(tokens)
+        if self.tie_word_embeddings:
+            return rows, functools.partial(table.logits, head=tied)
+        return rows, nn.Dense(self.vocab_size, use_bias=False, name="lm_head",
+                              kernel_init=nn.initializers.normal(0.02))
 
     @nn.nowrap
     def embedding_init(self):
@@ -1830,16 +1866,11 @@ class DecoderLM(nn.Module):
             else:
                 rope = self.rope_by_kind()
                 tables = {kind: rotary_tables(rope[kind], self.head_dim, T) for kind in turning}
-        embed = nn.Embed(self.vocab_size, self.hidden_size, name="embed_tokens",
-                         embedding_init=self.embedding_init())
-        x = embed(tokens)
+        x, head = self.embed(tokens)
         for i, (kind, spec, ffn) in enumerate(zip(kinds, specs, self.feed_forwards())):
             x = DecoderLayer(
                 kind, spec, ffn, self.sliding_window, self.rms_norm_eps, taps, ssm,
                 name=f"layers_{i}",
             )(x, *tables.get(kind, (None, None)))
         x = RMSNorm(self.rms_norm_eps, name="norm")(x)
-        if self.tie_word_embeddings:
-            return embed.attend(x)
-        return nn.Dense(self.vocab_size, use_bias=False, name="lm_head",
-                        kernel_init=nn.initializers.normal(0.02))(x)
+        return head(x)

@@ -191,14 +191,16 @@ def _api(name, length):
 @pytest.mark.parametrize("name,length,kernel_sites,rope", [
     ("transformer", 256, 2, {}), ("transformer", 64, 0, {}),
     # q and k of both layers go through the rotate-half operator
-    # and nine grouped products and two slot sums of each expert layer, at
-    # widths under a lane tile
+    # and nine grouped products and two slot sums of each expert layer and
+    # the token table's gradient, at widths under a lane tile
     ("decoder", 256, 2, {"rope_kernel_sites": 4, "rope_sites": 4,
                          "moe_kernel_sites": 0, "moe_grouped_sites": 18,
-                         "moe_slot_kernel_sites": 0, "moe_slot_sites": 4}),
+                         "moe_slot_kernel_sites": 0, "moe_slot_sites": 4,
+                         "embed_kernel_sites": 0, "embed_sites": 1}),
     ("decoder", 48, 0, {"rope_kernel_sites": 0, "rope_sites": 4,
                         "moe_kernel_sites": 0, "moe_grouped_sites": 18,
-                        "moe_slot_kernel_sites": 0, "moe_slot_sites": 4}),
+                        "moe_slot_kernel_sites": 0, "moe_slot_sites": 4,
+                        "embed_kernel_sites": 0, "embed_sites": 1}),
 ])
 def test_what_the_api_reports_is_what_the_traced_program_contains(name, length, kernel_sites, rope):
     api = _api(name, length)

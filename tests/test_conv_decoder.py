@@ -329,10 +329,12 @@ def test_create_model_reports_sites_for_attention_layers_only_and_the_conv_const
                                     "moe_slot_kernel_sites": 0, "moe_slot_sites": 4,
                                     "hidden": 32, "expert_width": 12, "layers": 2,
                                     "expert_layers": 2, "top_k": 2, "expert_products": 3,
-                                    "conv_layers": 2, "conv_width": 32}
+                                    "conv_layers": 2, "conv_width": 32,
+                                    "embed_kernel_sites": 0, "embed_sites": 1}
     only_conv = build(dict(SPEC, layer_types=["conv", "conv"], first_k_dense_replace=2))
     assert only_conv.module.attention_sites() == () and only_conv.counters == ()
-    assert only_conv.flush_attrs(1) == {"conv_layers": 2, "conv_width": 32}
+    assert only_conv.flush_attrs(1) == {"conv_layers": 2, "conv_width": 32,
+                                        "embed_kernel_sites": 0, "embed_sites": 1}
     # the accepted specs keep a site a layer and carry no conv constants
     mellum = create_model("decoder", "random_tokens", (LENGTH,), VOCAB, num_experts=4)
     assert mellum.module.attention_sites() == ((4, 2, 32),) * 2

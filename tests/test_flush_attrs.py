@@ -26,35 +26,39 @@ CELLS = {
     "femnist-cnn.c10": {},
     "mellum2-12b-a2.5b.silo2": {
         "attn_kernel_sites": 4, "attn_sites": 4, "rope_kernel_sites": 8, "rope_sites": 8,
-        **MOE, "hidden": 2304, "expert_width": 896, "top_k": 8},
+        **MOE, "hidden": 2304, "expert_width": 896, "top_k": 8, "embed_kernel_sites": 1,
+        "embed_sites": 1},
     "kanana-2-30b-a3b.silo2b1": {
         "attn_kernel_sites": 5, "attn_sites": 5, "attn_qk_width": 192, "attn_v_width": 128,
         "attn_heads": 32, "attn_length": 2048, "attn_layers": 5,
-        **MOE, "hidden": 2048, "expert_width": 768, "top_k": 6, "shared_width": 1536},
+        **MOE, "hidden": 2048, "expert_width": 768, "top_k": 6, "shared_width": 1536,
+        "embed_kernel_sites": 1, "embed_sites": 1},
     "lfm2-8b-a1b.silo2t4k": {
         "attn_kernel_sites": 1, "attn_sites": 1, "rope_kernel_sites": 2, "rope_sites": 2,
         **MOE, "moe_slot_kernel_sites": 0, "hidden": 2048, "expert_width": 1792, "top_k": 4,
-        "conv_layers": 4, "conv_width": 2048},
+        "conv_layers": 4, "conv_width": 2048, "embed_kernel_sites": 1, "embed_sites": 1},
     "nemotron-twotower-30b-a3b.silo2t4k-ssm": {
         "attn_kernel_sites": 1, "attn_sites": 1, "moe_kernel_sites": 18,
         "moe_grouped_sites": 18, "moe_slot_kernel_sites": 6, "moe_slot_sites": 6,
         "ssd_kernel_sites": 3, "ssd_sites": 3, "hidden": 2688, "expert_width": 1856,
         "layers": 3, "expert_layers": 3, "top_k": 6, "expert_products": 2,
         "shared_width": 3712, "ssm_layers": 3, "ssm_heads": 64, "ssm_head_dim": 64,
-        "ssm_state": 128, "ssm_groups": 8, "ssm_chunk": 128},
+        "ssm_state": 128, "ssm_groups": 8, "ssm_chunk": 128, "embed_kernel_sites": 1,
+        "embed_sites": 1},
     "laguna-xs.2.silo2t4k-swa": {
         "attn_kernel_sites": 5, "attn_sites": 5, "rope_kernel_sites": 10, "rope_sites": 10,
         **MOE, "hidden": 2048, "expert_width": 512, "top_k": 8, "shared_width": 512,
         "attn_length": 4096, "attn_window": 512, "attn_full_layers": 2, "attn_full_heads": 48,
         "attn_full_kv_heads": 8, "attn_full_head_dim": 128, "attn_full_rotary_dim": 64,
         "attn_sliding_layers": 3, "attn_sliding_heads": 64, "attn_sliding_kv_heads": 8,
-        "attn_sliding_head_dim": 128, "attn_sliding_rotary_dim": 128},
+        "attn_sliding_head_dim": 128, "attn_sliding_rotary_dim": 128, "embed_kernel_sites": 1,
+        "embed_sites": 1},
     "phi-4-mini-flash-reasoning.silo2t4k-sambay": {
         "attn_kernel_sites": 6, "attn_sites": 6, "sscan_kernel_sites": 1, "sscan_sites": 1,
         "sscan_layers": 1, "sscan_inner": 5120, "sscan_state": 16, "diff_length": 4096,
         "diff_window": 512, "diff_sliding_layers": 1, "diff_full_layers": 1,
         "diff_cross_layers": 1, "diff_pairs": 20, "diff_kv_groups": 10, "diff_key_dim": 64,
-        "diff_value_dim": 128},
+        "diff_value_dim": 128, "embed_kernel_sites": 1, "embed_sites": 1},
 }
 
 

@@ -283,8 +283,9 @@ def test_the_cell_s_sites_constants_and_parameters_are_the_published_ones():
     """At the cell's size: one attention site a layer with its own heads
     (48 | 64 on 8 of 128), two rotary calls a layer (64 of 128 dims turned
     on the full layers), every one of them the kernels' at T = 4 096, the
-    grouped products at K 2 048 / N 512 the kernels' too; the ``flush``
-    span's per-kind constants; 489 707 520 held parameters."""
+    grouped products at K 2 048 / N 512 the kernels' too, and the token
+    table's gradient; the ``flush`` span's per-kind constants; 489 707 520
+    held parameters."""
     m = CONFIG["model"]
     model = create_model("decoder", m["dataset"], tuple(m["input_shape"]), m["num_classes"],
                          **m["kwargs"])
@@ -306,7 +307,7 @@ def test_the_cell_s_sites_constants_and_parameters_are_the_published_ones():
         "attn_full_layers": 2, "attn_full_heads": 48, "attn_full_kv_heads": 8,
         "attn_full_head_dim": 128, "attn_full_rotary_dim": 64, "attn_sliding_layers": 3,
         "attn_sliding_heads": 64, "attn_sliding_kv_heads": 8, "attn_sliding_head_dim": 128,
-        "attn_sliding_rotary_dim": 128}
+        "attn_sliding_rotary_dim": 128, "embed_kernel_sites": 1, "embed_sites": 1}
     shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     assert sum(a.size for a in jax.tree_util.tree_leaves(shapes)) == 489_707_520
     # one head count and no window between the kinds: no per-kind constants

@@ -343,7 +343,8 @@ def test_create_model_reports_the_latent_sites_the_counters_and_the_constants():
                                     "moe_slot_kernel_sites": 0, "moe_slot_sites": 4,
                                     "hidden": 32, "expert_width": 12, "layers": 2,
                                     "expert_layers": 2, "top_k": 2, "expert_products": 3,
-                                    "shared_width": 24}
+                                    "shared_width": 24, "embed_kernel_sites": 0,
+                                    "embed_sites": 1}
     # gate, up and down of each expert layer over 64 tokens: top-2 of 8 with
     # 4 held bounds the rows at twice the even share, 128; 32 <-> 12
     assert model.module.grouped_sites(64) == ((128, 32, 12, 4),) * 2 + ((128, 12, 32, 4),) + (
@@ -414,11 +415,16 @@ def test_the_sites_are_what_the_traced_layers_hand_the_attention_core(monkeypatc
 # width, LayerNorms) is a branch that no accepted spec takes: the ten pins
 # hold, and LFM2's two (computed at the parent a80e48a by this same function)
 # join them, so that every accepted program that shares the attention entry
-# with the widened values is pinned.
+# with the widened values is pinned. The token table's lookup meant to change
+# every program whose step of tokens ``ops/table_grad.takes_kernel`` sends to
+# the kernel: of the pinned ones only Mellum's full-size trace, whose 2 x 4 096
+# tokens fit the kernel's fast memory (at the parent be07dff it read
+# 047105c6ae2b0a8e); the other full-size traces' 4 x 4 096 tokens outgrow it,
+# and the rehearsals' widths are no lane tiles, so their pins hold.
 PINS = {
     "attention.silo4": "940131b509805ea9",
     "attention.silo2": "50842107702d88df",
-    "mellum2-12b-a2.5b.full": "047105c6ae2b0a8e",
+    "mellum2-12b-a2.5b.full": "3e6cb6f9e7176063",
     "mellum2-12b-a2.5b.rehearse": "e5325920fefbc11d",
     "gpt2-124m.full": "28bcf5bfcdd2a422",
     "gpt2-124m.rehearse": "8ce4b5786b6eb411",

@@ -24,7 +24,7 @@ import numpy as np
 import pytest
 
 from fedml_tpu.models import create_model
-from fedml_tpu.ops import selective_scan as sscan
+from fedml_tpu.ops import selective_scan as sscan, table_grad
 from fedml_tpu.ops.attention import takes_kernel
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
@@ -82,15 +82,19 @@ def flatten(tree):
             for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]}
 
 
-def both_sides(spec, length, docs, dtype=jnp.float32, ref=None):
+def both_sides(spec, length, docs, dtype=jnp.float32, ref=None, repeated=False):
     """(program, reference) as (logits, loss, gradients) on the reference's
-    seed weights; the program's held in ``dtype``."""
+    seed weights; the program's held in ``dtype``. ``repeated``: documents of
+    the pad id 0 at every third position and four other ids between, so that
+    each row of the tied table's gradient sums many rows."""
     ref, cfg = ref or reference(), config(spec, length)
     model = create_model("decoder", "random_tokens", (length,), VOCAB, **cfg["model"]["kwargs"])
     flat = ref.init_params(5, cfg)
     have = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     assert {k: v.shape for k, v in flatten(have["params"]).items()} == ref.param_shapes(cfg)
     doc = jax.random.randint(jax.random.PRNGKey(9), (docs, length + 1), 1, VOCAB)
+    if repeated:
+        doc = jnp.where(jnp.arange(length + 1) % 3 == 0, 0, doc % 4 + 1)
     x, y = doc[:, :-1], doc[:, 1:]
     mask = jnp.ones((docs,), jnp.float32)
 
@@ -126,9 +130,11 @@ def gaps(program, plain):
 
 
 ROUTES = {
-    # spec, length, documents, whether attention and the scan take the kernels
-    "plain_route": (SPEC, LENGTH, 3, False),
-    "kernel_route_interpreted": (KERNEL_SPEC, KERNEL_LENGTH, 1, True),
+    # spec, length, documents, whether attention, the scan and the tied
+    # table's gradient take the kernels, whether the ids repeat
+    "plain_route": (SPEC, LENGTH, 3, False, False),
+    "kernel_route_interpreted": (KERNEL_SPEC, KERNEL_LENGTH, 1, True, False),
+    "kernel_route_repeated_ids": (KERNEL_SPEC, KERNEL_LENGTH, 1, True, True),
 }
 
 
@@ -143,12 +149,14 @@ def test_logits_loss_and_every_gradient_match_the_reference(route):
     2e-4 for the lambda leaves, whose gradient is ONE sum over every
     position, pair and lane of ``dL/do * map2`` that cancels to a small share
     of its terms' size (8.2e-5 on the kernel route when written: the order
-    of the sums shows through the cancellation)."""
-    spec, length, docs, kernel = ROUTES[route]
-    model, (program, plain) = both_sides(spec, length, docs)
+    of the sums shows through the cancellation). With repeated ids (the pad
+    id 0 among them) the table's gradient adds up to 86 rows into one."""
+    spec, length, docs, kernel, repeated = ROUTES[route]
+    model, (program, plain) = both_sides(spec, length, docs, repeated=repeated)
     m = model.module.mamba1_spec()
     assert all(takes_kernel(length, *site) is kernel for site in model.module.attention_sites())
     assert sscan.takes_kernel(length, m.inner, m.state) is kernel
+    assert table_grad.takes_kernel(VOCAB, spec["hidden_size"], docs * length) is kernel
     logits, loss, grads, lam = gaps(program, plain)
     assert logits <= 1e-5 and loss <= 1e-6 and grads <= 2e-5 and lam <= 2e-4, (
         logits, loss, grads, lam)
@@ -239,7 +247,8 @@ def test_specs_that_are_not_expressed_are_refused_by_name(change, name):
 def test_the_cell_s_sites_constants_and_parameters_are_the_published_ones():
     """At the cell's size: both maps of the three attention layers on the
     kernel (20 query heads on 10 key/value heads of 64, values of 128), the
-    scan on its kernels, and 577 178 752 held parameters."""
+    scan on its kernels, the tied table's gradient on its kernel, and
+    577 178 752 held parameters."""
     m = CONFIG["model"]
     model = create_model(m["name"], m["dataset"], tuple(m["input_shape"]), int(m["num_classes"]),
                          **m["kwargs"])
@@ -249,13 +258,14 @@ def test_the_cell_s_sites_constants_and_parameters_are_the_published_ones():
         "sscan_layers": 1, "sscan_inner": 5120, "sscan_state": 16, "diff_length": 4096,
         "diff_window": 512, "diff_sliding_layers": 1, "diff_full_layers": 1,
         "diff_cross_layers": 1, "diff_pairs": 20, "diff_kv_groups": 10, "diff_key_dim": 64,
-        "diff_value_dim": 128}
+        "diff_value_dim": 128, "embed_kernel_sites": 1, "embed_sites": 1}
     shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))["params"]
     assert sum(a.size for a in jax.tree_util.tree_leaves(shapes)) == 577_178_752
     # and at 64 tokens, the evaluation's, every core takes its plain form
     short = create_model(m["name"], m["dataset"], (64,), int(m["num_classes"]), **m["kwargs"])
     attrs = short.flush_attrs(256)
     assert attrs["attn_kernel_sites"] == attrs["sscan_kernel_sites"] == 0
+    assert attrs["embed_kernel_sites"] == 0
 
 
 def test_the_sites_are_what_the_traced_layers_hand_the_attention_core(monkeypatch):

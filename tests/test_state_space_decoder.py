@@ -332,7 +332,7 @@ def test_create_model_reports_one_site_no_rope_and_the_span_constants():
         "hidden": 32, "expert_width": 12, "layers": 2, "expert_layers": 2, "top_k": 2,
         "expert_products": 2, "shared_width": 20,
         "ssm_layers": 2, "ssm_heads": 8, "ssm_head_dim": 8, "ssm_state": 16, "ssm_groups": 2,
-        "ssm_chunk": 16}
+        "ssm_chunk": 16, "embed_kernel_sites": 0, "embed_sites": 1}
     # the published shapes: one site of 32 query heads on 2 key/value heads of
     # 128, which takes the kernel at 4096 (16 query heads a key head)
     wide = build(dict(SPEC, num_attention_heads=32, head_dim=128, hybrid_override_pattern="M*"), 4096)
@@ -346,7 +346,7 @@ def test_create_model_reports_one_site_no_rope_and_the_span_constants():
     assert only_mixers.module.attention_sites() == () and only_mixers.counters == ()
     assert set(only_mixers.flush_attrs(1)) == {
         "ssd_kernel_sites", "ssd_sites", "ssm_layers", "ssm_heads", "ssm_head_dim", "ssm_state",
-        "ssm_groups", "ssm_chunk"}
+        "ssm_groups", "ssm_chunk", "embed_kernel_sites", "embed_sites"}
 
 
 def test_the_sites_are_what_the_traced_layers_hand_the_attention_core(monkeypatch):

@@ -391,6 +391,79 @@ def test_differential_map_with_values_twice_the_keys_compiles_for_v5e(one_chip, 
     assert compiled.as_text().count("tpu_custom_call") == 2
 
 
+def test_tied_table_gradient_kernel_compiles_for_v5e_at_the_sambay_shape(one_chip):
+    """``ops/table_grad``'s kernel at phi-4-mini-flash-reasoning.silo2t4k-
+    sambay's step: 4 096 ids into the tied table of 25 008 x 2 560, bfloat16,
+    the rows' gradient in VMEM (20 MiB) beside the 512-row blocks, within
+    the VMEM it asks for; the gradient written over the head's (one custom
+    call, no copy of the table planned). About 1 s."""
+    from fedml_tpu.ops import table_grad as op
+
+    V, d, T = 25008, 2560, 4096
+    assert op.takes_kernel(V, d, T)
+    ids = jax.ShapeDtypeStruct((1, T), jnp.int32, sharding=one_chip)
+    dx = jax.ShapeDtypeStruct((1, T, d), jnp.bfloat16, sharding=one_chip)
+    head = jax.ShapeDtypeStruct((V, d), jnp.bfloat16, sharding=one_chip)
+    saved, op._use_interpret = op._use_interpret, lambda: False
+    try:
+        compiled = jax.jit(op.table_grad, donate_argnums=(2,)).lower(ids, dx, head).compile()
+    finally:
+        op._use_interpret = saved
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and "table_grad" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+def _decoder_text(one_chip, tied: bool, kernel: bool):
+    """The optimized v5e program of a small decoder's loss and gradient
+    (256 wide, 1 000 ids, 2 x 64 tokens; the parameters in bfloat16, as the
+    round program applies them), its table the head where ``tied``, with the
+    table's gradient on the kernel or, where ``kernel`` is false, on
+    autodiff's transpose of ``take``."""
+    from fedml_tpu.models import create_model
+    from fedml_tpu.ops import table_grad as op
+
+    model = create_model("decoder", "random_tokens", (64,), 1000, hidden_size=256,
+                         num_attention_heads=4, num_key_value_heads=2, head_dim=64,
+                         num_hidden_layers=1, layer_types=["full_attention"],
+                         first_k_dense_replace=1, intermediate_size=512,
+                         tie_word_embeddings=tied)
+    assert op.takes_kernel(1000, 256, 128)
+    shapes = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16, sharding=one_chip),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    tokens = jax.ShapeDtypeStruct((2, 64), jnp.int32, sharding=one_chip)
+
+    def loss(variables, x):
+        logits = model.apply(variables, x, train=True)[0]
+        return jnp.sum(jax.nn.log_softmax(logits.astype(jnp.float32))[..., 0])
+
+    saved = op._use_interpret, op.takes_kernel
+    op._use_interpret = lambda: False
+    if not kernel:
+        op.takes_kernel = lambda *a: False
+    try:
+        return jax.jit(jax.grad(loss)).lower(shapes, tokens).compile().as_text()
+    finally:
+        op._use_interpret, op.takes_kernel = saved
+
+
+@pytest.mark.parametrize("kernel", [True, False], ids=["kernel", "take_transpose"])
+@pytest.mark.parametrize("tied", [True, False], ids=["tied", "untied"])
+def test_a_decoder_s_table_gradient_is_no_scatter_into_the_table(one_chip, tied, kernel):
+    """The probe the tied table's cost was found by: in the optimized v5e
+    program of a decoder's gradient, no ``scatter`` has the table's shape
+    where the kernel takes the lookup's gradient, and the kernel adds onto
+    the head's product in place (onto zeros where the head is its own);
+    autodiff's transpose of ``take`` is such a scatter. About 2 s each."""
+    import re
+
+    text = _decoder_text(one_chip, tied, kernel)
+    table_scatter = re.search(r"= bf16\[1000,256\]\S* scatter\(", text)
+    assert (table_scatter is None) is kernel
+    assert text.count("tpu_custom_call") == int(kernel)
+
+
 def test_femnist_cnn_round_program_compiles_for_one_v5e_chip(one_chip):
     """The production round program (``api.round_fn``) of the north star —
     FEMNIST CNN, 10 clients/round, batch 20 — lowered at its real round-0
